@@ -46,10 +46,6 @@ if not _logger.handlers and _os.environ.get("APEX_TPU_VERBOSE_LOGGING", "0") == 
     )
     _logger.addHandler(_handler)
 
-from apex_tpu import _compat  # noqa: E402
-
-_compat.install()  # jax.shard_map on older jax — see _compat docstring
-
 from apex_tpu import amp  # noqa: E402,F401
 from apex_tpu import multi_tensor_apply  # noqa: E402,F401
 from apex_tpu import optimizers  # noqa: E402,F401

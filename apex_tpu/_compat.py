@@ -1,86 +1,13 @@
-"""JAX version-compat shims.
+"""Readers for XLA's cost and memory analyses.
 
-This tree targets the current ``jax.shard_map(f, mesh=..., in_specs=...,
-out_specs=..., check_vma=...)`` surface. On older jax (e.g. 0.4.x) that
-API lives at ``jax.experimental.shard_map.shard_map`` with the
-replication-check kwarg still named ``check_rep``; without a shim every
-sharded code path — including ``bench.py`` and the 8-virtual-device test
-mesh — fails with ``AttributeError: module 'jax' has no attribute
-'shard_map'`` before running anything. :func:`install` bridges exactly
-that gap and is a no-op wherever ``jax.shard_map`` already exists (the
-shim never shadows a real implementation).
-
-It is also the home of the **XLA analysis normalizers** the cost
-accounting layer (``apex_tpu.telemetry.costs``) consults: the
-``cost_analysis`` / ``memory_analysis`` surfaces differ by jax version
-AND backend — on jax 0.4.37 ``Lowered.cost_analysis()`` returns a flat
-dict, ``Compiled.cost_analysis()`` a LIST of per-computation dicts, and
-``Compiled.memory_analysis()`` a ``CompiledMemoryStats`` extension
-object (attributes, not keys); other versions/backends return None, a
-dict, or omit the method entirely. :func:`cost_analysis_dict` and
-:func:`memory_analysis_dict` fold every observed variant into one
-plain-dict shape (or None — "the backend can't report" is a value here,
-never an exception), so the cost block's producers degrade gracefully
-instead of version-forking at every call site.
+The cost accounting layer (``apex_tpu.telemetry.costs``) wants one
+plain-dict shape from an AOT stage. On the jax this tree runs (0.9.0)
+``Lowered.cost_analysis()`` and ``Compiled.cost_analysis()`` both return
+a flat ``{metric: float}`` dict and ``Compiled.memory_analysis()``
+returns a ``CompiledMemoryStats`` object (attributes, not keys); a
+backend that does not report returns None. "Can't report" is a value
+here (None); anything else the call raises is the caller's to see.
 """
-
-import functools
-
-import jax
-
-
-def install():
-    """Idempotently install the handful of current-jax surfaces this
-    tree uses that an older jax spells differently. Each shim installs
-    only when the real attribute is missing — never shadows one."""
-    _install_shard_map()
-    _install_axis_size()
-
-
-def _install_shard_map():
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-    except ImportError:  # neither surface: let call sites raise honestly
-        return
-
-    @functools.wraps(_shard_map)
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, **kw):
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma  # old name of the same knob
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    jax.shard_map = shard_map
-
-
-def _install_axis_size():
-    # lax.axis_size(name): the STATIC size of a mapped axis. On old jax
-    # the same lookup lives on the trace-time axis env (a psum(1, name)
-    # would be traced, breaking static uses like shape arithmetic).
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return
-
-    def axis_size(axis_name):
-        from jax._src import core
-
-        env = core.get_axis_env()
-        if isinstance(axis_name, (tuple, list)):
-            size = 1
-            for name in axis_name:
-                size *= env.axis_size(name)
-            return size
-        return env.axis_size(axis_name)
-
-    lax.axis_size = axis_size
-
-
-# --------------------------------------------------------------------------
-# XLA cost/memory analysis normalizers (telemetry.costs feature detection)
 
 # CompiledMemoryStats attribute names → the one key set the cost block
 # speaks. Every field is device-side; the host_* twins are ignored.
@@ -94,70 +21,20 @@ _MEMORY_FIELDS = (
 
 
 def cost_analysis_dict(stage):
-    """One flat ``{metric: float}`` dict from a ``Lowered`` or
+    """The flat ``{metric: float}`` dict of a ``Lowered`` or
     ``Compiled`` stage's ``cost_analysis()``, or None when the backend
-    can't report.
-
-    Observed variants, all folded here (jax 0.4.37 calibration):
-
-    * method absent (old stages, custom wrappers) → None
-    * returns None / raises (unimplemented backend) → None
-    * ``Lowered.cost_analysis()`` → a flat dict → passed through
-    * ``Compiled.cost_analysis()`` → a LIST of per-computation dicts
-      (one per partition/computation) → key-wise SUM across the list
-      (a multi-computation executable's flops are the total it runs)
-    * empty list / list of non-dicts → None
-    """
-    fn = getattr(stage, "cost_analysis", None)
-    if fn is None:
-        return None
-    try:
-        raw = fn()
-    except Exception:
-        return None
-    if isinstance(raw, dict):
-        return dict(raw) or None
-    if isinstance(raw, (list, tuple)):
-        dicts = [d for d in raw if isinstance(d, dict)]
-        if not dicts:
-            return None
-        out = {}
-        for d in dicts:
-            for k, v in d.items():
-                if isinstance(v, (int, float)):
-                    out[k] = out.get(k, 0) + v
-        return out or None
-    return None
+    reports nothing."""
+    raw = stage.cost_analysis()
+    return dict(raw) if raw else None
 
 
 def memory_analysis_dict(compiled):
     """One plain dict (``argument/output/temp/alias/generated_code
-    _size_in_bytes`` ints) from ``Compiled.memory_analysis()``, or None.
-
-    Folds: method absent → None; returns None / raises → None; a
-    ``CompiledMemoryStats`` extension object → attribute read; an
-    already-plain dict (some backends) → key filter. Missing individual
-    fields degrade to 0 (the stats object always carries the full set
-    on backends that report at all)."""
-    fn = getattr(compiled, "memory_analysis", None)
-    if fn is None:
-        return None
-    try:
-        raw = fn()
-    except Exception:
-        return None
+    _size_in_bytes`` ints) from ``Compiled.memory_analysis()``, or None
+    when the backend reports nothing (None, or a stats object with
+    every field 0 — a stubbed surface carries no information)."""
+    raw = compiled.memory_analysis()
     if raw is None:
         return None
-    out = {}
-    for field in _MEMORY_FIELDS:
-        v = raw.get(field) if isinstance(raw, dict) \
-            else getattr(raw, field, None)
-        if isinstance(v, (int, float)) and not isinstance(v, bool):
-            out[field] = int(v)
-        else:
-            out[field] = 0
-    if not any(out.values()):
-        # a stats object with every field 0 carries no information
-        # (e.g. a backend that stubs the surface) — report "can't"
-        return None
-    return out
+    out = {field: int(getattr(raw, field)) for field in _MEMORY_FIELDS}
+    return out if any(out.values()) else None

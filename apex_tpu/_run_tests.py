@@ -49,6 +49,7 @@ SUITES = {
                 "test_router.py", "test_router_chaos.py"],
     "api_parity": ["test_api_parity_round3.py"],
     "harness": ["test_run_tests.py", "test_bench_contract.py",
+                "test_chip_smoke.py",
                 "test_compile_cache.py", "test_resilience.py",
                 "test_apexlint.py"],
     "telemetry": ["test_telemetry.py", "test_bench_labels.py",

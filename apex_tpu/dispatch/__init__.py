@@ -112,8 +112,8 @@ OP_CHOICES = {
     # packed prefill program ("recompute", vLLM's recompute
     # preemption) vs copy the swapped pages back host→device and
     # resume decode directly ("swap"). Keyed on the resumed stream's
-    # token length ("s") — the crossover against the ~65 ms relay
-    # dispatch floor is shape-dependent, not a constant
+    # token length ("s") — the crossover against a prefill dispatch
+    # is shape-dependent, not a constant
     "kv_restore": ("recompute", "swap"),
 }
 
@@ -165,15 +165,13 @@ def normalize_dtype(dtype):
 
 
 def current_backend():
-    """The active jax backend name ("tpu"/"cpu"/...), or None when no
-    backend is initializable — a lookup then misses (never raises: a
-    dispatch consult must not take down a trace)."""
-    try:
-        import jax
+    """The active jax backend name ("tpu"/"cpu"/...). A backend that
+    fails to initialise raises here like anywhere else: a consult that
+    swallowed it would resolve every choice as if no table existed and
+    hide the broken device behind the built-in defaults."""
+    import jax
 
-        return jax.default_backend()
-    except Exception:
-        return None
+    return jax.default_backend()
 
 
 def _key(entry):
@@ -222,13 +220,10 @@ def load_table(path=None):
 
 
 def lookup_entry(op, dtype, backend=None, path=None, **dims):
-    """The full table entry for this key, or None (disabled / miss /
-    unknown backend)."""
+    """The full table entry for this key, or None (disabled / miss)."""
     if not dispatch_enabled():
         return None
     backend = backend or current_backend()
-    if backend is None:
-        return None
     entries, _ = load_table(path)
     return entries.get((op, bucket(**dims), normalize_dtype(dtype),
                         backend))

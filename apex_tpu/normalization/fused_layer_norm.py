@@ -108,22 +108,21 @@ def _resolve_pallas(x_shape, n_norm_axes, use_pallas, dtype=None):
         return False, False, None
     # imports below the early return: the pure-jnp default path must not
     # require jax.experimental.pallas to be importable
-    from apex_tpu.ops.attention import _tpu_available
+    from apex_tpu.ops.attention import _on_cpu, _tpu_available
     from apex_tpu.ops import layer_norm_pallas as lnp
 
     if not lnp.supported(rows, hidden):
         return False, False, None
-    on_tpu = _tpu_available()
     if from_table:
-        return True, not on_tpu, tile_pref
+        return True, _on_cpu(), tile_pref
     from apex_tpu.dispatch import tiles
 
-    if not on_tpu and tiles.env_flag("APEX_PALLAS_INTERPRET"):
+    if _on_cpu() and tiles.env_flag("APEX_PALLAS_INTERPRET"):
         # the CPU leg of a pinned pallas A/B (autotune_steps --smoke):
         # run the kernel in interpret mode instead of silently falling
         # back to jnp — a "pallas" label over a jnp run is label drift
         return True, True, tile_pref
-    return on_tpu, False, tile_pref
+    return _tpu_available(), False, tile_pref
 
 
 def would_use_pallas(x_shape, n_norm_axes=1, use_pallas=None, dtype=None):

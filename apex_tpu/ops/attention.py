@@ -7,8 +7,10 @@ blockwise-softmax attention with causal and segment-id (varlen) masking.
 
 On TPU this lowers to the Pallas flash-attention kernel (memory-bound
 optimal: no [s, s] score tensor ever touches HBM; fwd and bwd are tiled
-VMEM-resident loops with fp32 online-softmax accumulators). Elsewhere
-(CPU test mesh) it falls back to the numerically-equivalent dense form.
+VMEM-resident loops with fp32 online-softmax accumulators). On the CPU
+test mesh it takes the numerically-equivalent dense form. The choice
+reads the platform JAX reports; a backend that fails to initialise
+raises here like anywhere else — it is never read as "no TPU".
 
 Layout: [batch, heads, seq, head_dim] (the kernel's native layout).
 """
@@ -53,11 +55,18 @@ def _dense_attention(q, k, v, causal, sm_scale, segment_ids):
 
 
 @functools.lru_cache(maxsize=1)
+def _platform():
+    return jax.devices()[0].platform
+
+
 def _tpu_available():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
+    return _platform() == "tpu"
+
+
+def _on_cpu():
+    """The one condition under which a Pallas kernel may run in
+    interpret mode without an explicit argument asking for it."""
+    return _platform() == "cpu"
 
 
 def _block(n, cap):
@@ -179,11 +188,12 @@ def fused_attention(q, k, v, *, causal=False, sm_scale=None,
         # single-pass structure saves); an explicit per-call impl="rows"
         # is honored for every supported shape so A/B rows stay truthful
         seq_ok = impl == "rows" or sk <= 2048
-        # off-TPU the kernel can still run in interpret mode when the
-        # choice came from a (backend-keyed, CPU-measured) table entry
-        # or the pinned-A/B CPU leg asks for it (autotune --smoke) —
-        # never silently: a "rows" label over a dense run is label drift
-        interp = (not _tpu_available()
+        # on the CPU the kernel can still run in interpret mode when
+        # the choice came from a (backend-keyed, CPU-measured) table
+        # entry or the pinned-A/B CPU leg asks for it (autotune
+        # --smoke) — never silently: a "rows" label over a dense run is
+        # label drift
+        interp = (_on_cpu()
                   and (from_table
                        or tiles.env_flag("APEX_PALLAS_INTERPRET")))
         if ((_tpu_available() or interp) and seq_ok

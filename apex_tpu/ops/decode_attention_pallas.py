@@ -47,12 +47,13 @@ Layouts:
                                       int8 KV tier (ISSUE 20), or None
 
 int8 KV tier (serving.kv_tier): when the pages are int8 codes, the
-per-(page, head) scales ride as two more scalar-prefetch-INDEXED
-operands — the same ``page_table[i, j]`` gather as the page blocks,
-one bf16 scalar per head per grid step — and both impls dequantize at
-read (fp32 multiply next to the existing widening cast; no
-dequantized page copy is ever materialized). The VMEM model budgets
-the scale blocks at the int8 itemsize (tiles.decode_vmem_bytes).
+per-(page, head) scales are gathered through the page table by XLA
+(``b * h * max_pages`` elements) and ride as two more operands, one
+resident fp32 ``[block_h, max_pages]`` row block per (slot, head
+block). Both impls dequantize at read — the kernel scales the scores
+and the context sum per head rather than the page, so no dequantized
+page copy is ever materialized. The VMEM model budgets the scale
+blocks at the int8 itemsize (tiles.decode_vmem_bytes).
 """
 
 import functools
@@ -141,6 +142,12 @@ def _pick_bh(h, ps, d, dtype, block_h, tile_pref):
 
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             scale, ps, n_pages, quant):
+    """Every value keeps the layout it is loaded in — heads on the
+    leading (untiled) axis, page positions on sublanes, head_dim on
+    lanes — so the body is broadcasts and keepdims reductions only.
+    Mosaic refuses the shape casts a 2-D ``[bh, d]`` formulation needs
+    (``[bh, d] <-> [bh, 1, d]`` moves heads between the sublane and the
+    leading axis: "infer-vector-layout: unsupported shape cast")."""
     if quant:
         ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -158,36 +165,44 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * ps < length)
     def _page():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * jnp.float32(scale)
-        k = k_ref[:, 0].astype(jnp.float32)          # [bh, ps, d]
+        q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [bh, 1, d]
+        k = k_ref[:, 0].astype(jnp.float32)                    # [bh, ps, d]
         v = v_ref[:, 0].astype(jnp.float32)
+        # [bh, ps, 1] scores: sublane-broadcast multiply + lane
+        # reduction (see module docstring — q_len=1 makes the MXU moot)
+        s = jnp.sum(q * k, axis=-1, keepdims=True)
         if quant:
-            # dequantize at read: one bf16 scale per head for THIS
-            # page (scalar-prefetch-indexed like the page blocks)
-            k = k * ks_ref[:, 0, 0].astype(jnp.float32)[:, None, None]
-            v = v * vs_ref[:, 0, 0].astype(jnp.float32)[:, None, None]
-        # [bh, ps] scores: broadcast-multiply + lane reduction (see
-        # module docstring — q_len=1 makes the MXU moot)
-        s = jnp.sum(q[:, None, :] * k, axis=-1)
-        col = j * ps + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        masked = col >= length
+            # dequantize at read: this page's per-head scale is lane j
+            # of the resident [bh, 1, n_pages] row block (an iota mask,
+            # no dynamic lane index); one scale per head factors out of
+            # both reductions, so it multiplies [bh, ps, 1] and
+            # [bh, 1, d] instead of the [bh, ps, d] page
+            here = lax.broadcasted_iota(
+                jnp.int32, (k.shape[0], 1, n_pages), 2) == j
+            ks = jnp.sum(jnp.where(here, ks_ref[0, 0], 0.0), axis=-1,
+                         keepdims=True)                        # [bh, 1, 1]
+            vs = jnp.sum(jnp.where(here, vs_ref[0, 0], 0.0), axis=-1,
+                         keepdims=True)
+            s = s * ks
+        pos = j * ps + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        masked = pos >= length
         s = jnp.where(masked, jnp.float32(NEG_INF), s)
-        m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=-1,
-                                                keepdims=True))
+        m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_scr[...] - m_new)
         p = jnp.exp(s - m_new)
         p = jnp.where(masked, 0.0, p)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1,
-                                                  keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(
-            p[:, :, None] * v, axis=1)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        ctx = jnp.sum(p * v, axis=1, keepdims=True)            # [bh, 1, d]
+        if quant:
+            ctx = ctx * vs
+        acc_scr[...] = acc_scr[...] * alpha + ctx
         m_scr[...] = m_new
 
     @pl.when(j == n_pages - 1)
     def _finish():
         l = l_scr[...]
         o = acc_scr[...] / jnp.where(l > 0, l, 1.0)
-        o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
@@ -198,9 +213,8 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     docstring). Call :func:`decode_attention` for the dispatched
     surface; this entry raises on unsupported geometry. With
     ``k_scale``/``v_scale`` (``[h, pages]`` — the int8 KV tier) the
-    scales ride as two extra operands whose BlockSpec gathers the
-    SAME ``page_table[i, j]`` page the K/V blocks do, and the kernel
-    dequantizes at read."""
+    scales of each slot's pages ride as two extra operands and the
+    kernel dequantizes at read."""
     b, h, d = q.shape
     n_pages_total, ps = k_pages.shape[1], k_pages.shape[2]
     max_pages = page_table.shape[1]
@@ -224,7 +238,7 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
         return (hb, pt[i, j], 0, 0)
 
     def sc_map(i, hb, j, pt, ln):
-        return (hb, pt[i, j], 0)
+        return (i, hb, 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, bh, 1, d), q_map),
@@ -233,11 +247,20 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
     ]
     operands = [q4, k_pages, v_pages]
     if quant:
-        # [h, pages] -> [h, pages, 1]: a trailing unit axis keeps the
-        # block's minor dim spanning its full array axis (the same
-        # Mosaic last-two-dims legality argument as the page blocks)
-        in_specs += [pl.BlockSpec((bh, 1, 1), sc_map)] * 2
-        operands += [k_scale[:, :, None], v_scale[:, :, None]]
+        # [h, pages] -> [b, h/bh, bh, 1, max_pages]: XLA gathers each
+        # slot's scales through the page table, so the block's last two
+        # dims span their array axes whatever bh is (a (bh, 1, 1) block
+        # over [h, pages, 1] is refused by the Mosaic lowering unless
+        # bh == h), heads sit on the leading axis like the K/V blocks',
+        # and the block index is constant along the page axis — one DMA
+        # per (slot, head block), not one per grid step
+        def slot_scales(scale):
+            g = scale[:, page_table].astype(jnp.float32)  # [h, b, mp]
+            return g.reshape(h // bh, bh, b, 1, max_pages).transpose(
+                2, 0, 1, 3, 4)
+
+        in_specs += [pl.BlockSpec((1, 1, bh, 1, max_pages), sc_map)] * 2
+        operands += [slot_scales(k_scale), slot_scales(v_scale)]
 
     kern = functools.partial(_kernel, scale=float(sm_scale), ps=ps,
                              n_pages=max_pages, quant=quant)
@@ -249,9 +272,9 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, bh, 1, d), q_map),
             scratch_shapes=[
-                pltpu.VMEM((bh, d), jnp.float32),
-                pltpu.VMEM((bh, 1), jnp.float32),
-                pltpu.VMEM((bh, 1), jnp.float32),
+                pltpu.VMEM((bh, 1, d), jnp.float32),
+                pltpu.VMEM((bh, 1, 1), jnp.float32),
+                pltpu.VMEM((bh, 1, 1), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
@@ -334,8 +357,8 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     ``APEX_DECODE_ATTN_IMPL`` are preferences that fall back, and an
     unpinned call consults the dispatch table (op "decode_attention").
     ``block_h`` is the per-call tile demand (raises when illegal);
-    ``interpret`` defaults to off-TPU autodetect for explicitly
-    requested or table-driven pallas runs. ``k_scale``/``v_scale``
+    ``interpret`` defaults to True on the CPU platform only (an
+    explicit argument wins; any other platform compiles the kernel). ``k_scale``/``v_scale``
     (``[h, P]``) engage the int8 KV tier's dequantize-at-read on
     either impl; int8 pages WITHOUT scales raise — codes are
     meaningless without their scales, there is no honorable
@@ -368,10 +391,7 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
             f"h={h} ps={k_pages.shape[2]} d={d}")
     if eff == "pallas" and ok:
         if interpret is None:
-            try:
-                interpret = jax.devices()[0].platform != "tpu"
-            except RuntimeError:
-                interpret = True
+            interpret = jax.devices()[0].platform == "cpu"
         return decode_attention_pallas(
             q, k_pages, v_pages, page_table, lengths, sm_scale,
             k_scale=k_scale, v_scale=v_scale,

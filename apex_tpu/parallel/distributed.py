@@ -39,15 +39,8 @@ from apex_tpu.parallel import collectives
 
 
 def pvary(x, axis_name):
-    """invariant → varying cast (per-replica ownership); wraps the current
-    jax spelling (lax.pcast, with fallback to the older lax.pvary)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis_name, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_name)
-    # pre-pvary jax has no replication typing to satisfy (shard_map runs
-    # with the replication check off throughout this tree) — identity
-    return x
+    """invariant → varying cast (per-replica ownership)."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def allreduce_gradients(grads, axis_name="data", gradient_average=True,

@@ -8,8 +8,17 @@ the environment, so rank/world-size travel in APEX_TPU_* vars and spawned
 scripts call ``init_distributed()`` (which passes them to
 ``jax.distributed.initialize`` explicitly).
 
+A chip belongs to one process at a time and ONE process drives all the
+chips of its host, so several ranks on one host are only a CPU
+rehearsal of a multi-host job: the launcher refuses ``--nproc`` > 1
+unless the children are held to the CPU (``JAX_PLATFORMS=cpu``) —
+otherwise the second child would fail or hang waiting for the chip the
+first one holds. On a TPU host run the script directly (one process,
+every local chip in its mesh). The launcher itself never imports jax.
+
 Usage:
-    python -m apex_tpu.parallel.multiproc [--nproc N] script.py args
+    JAX_PLATFORMS=cpu python -m apex_tpu.parallel.multiproc [--nproc N] \
+        script.py args
 and in script.py:
     from apex_tpu.parallel.multiproc import init_distributed
     init_distributed()   # no-op when not launched by multiproc
@@ -54,6 +63,13 @@ def main():
     if not argv:
         print(__doc__)
         sys.exit(1)
+    if nproc > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        sys.exit(
+            f"multiproc: --nproc {nproc} starts {nproc} processes on "
+            "this host, but a chip belongs to one process and one "
+            "process drives all local chips: set JAX_PLATFORMS=cpu for "
+            "the CPU rehearsal, or run the script directly on a TPU "
+            "host")
     port = int(os.environ.get("MASTER_PORT", "29500"))
     procs = []
     for rank in range(nproc):

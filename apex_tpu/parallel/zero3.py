@@ -326,7 +326,7 @@ def capability_costs(cfg=None, page_size=16, num_pages=64):
     argument size, a strict LOWER bound on unsharded serving peak HBM
     (no activations, no workspace, no XLA temps). Returns ``(block,
     verdict)`` where ``verdict = costs.starvation(peak_hbm_bytes,
-    "tpu")`` — ``"exceeds-hbm"`` for :func:`capability_config` is the
+    costs.V5E_KIND)`` — ``"exceeds-hbm"`` for :func:`capability_config` is the
     committed proof that the unsharded path cannot run at this scale at
     all (the CLAUDE.md OOM-class capability exception)."""
     import functools
@@ -355,5 +355,6 @@ def capability_costs(cfg=None, page_size=16, num_pages=64):
                 "output_size_in_bytes": 0, "temp_size_in_bytes": 0,
                 "generated_code_size_in_bytes": 0,
                 "alias_size_in_bytes": 0},
-        platform="tpu", source="eval_shape")
-    return block, _costs.starvation(block["peak_hbm_bytes"], "tpu")
+        device_kind=_costs.V5E_KIND, source="eval_shape")
+    return block, _costs.starvation(block["peak_hbm_bytes"],
+                                    _costs.V5E_KIND)

@@ -386,7 +386,7 @@ def decode_block(params, cache, tokens, lengths, page_table,
                  decode_block_h=None, interpret=None):
     """K decode steps in ONE dispatch (ISSUE 17): a ``lax.scan`` over
     :func:`decode_step` with in-program per-slot stop detection, so a
-    single device round trip amortizes the relay's per-dispatch floor
+    single device round trip amortizes the per-dispatch host cost
     across up to K tokens per slot.
 
     ``k`` is a STATIC program constant — at most a second

@@ -192,14 +192,13 @@ class FusedScaleMaskSoftmax:
         interpret = self._pallas_interpret
         if use and not interpret:
             from apex_tpu.dispatch import tiles as _tiles
-            from apex_tpu.ops.attention import _tpu_available
+            from apex_tpu.ops.attention import _on_cpu
 
-            if from_table:
-                interpret = not _tpu_available()
-            elif _tiles.env_flag("APEX_PALLAS_INTERPRET"):
-                # CPU leg of a pinned pallas A/B (autotune --smoke):
-                # interpret mode instead of a silent jnp fallback
-                interpret = not _tpu_available()
+            if from_table or _tiles.env_flag("APEX_PALLAS_INTERPRET"):
+                # a CPU-measured table entry, or the CPU leg of a
+                # pinned pallas A/B (autotune --smoke): interpret mode
+                # instead of a silent jnp fallback — on the CPU only
+                interpret = _on_cpu()
         return bool(use), interpret, tile_pref
 
     def forward_fused_softmax(self, input, mask):

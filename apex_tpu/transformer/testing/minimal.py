@@ -747,19 +747,17 @@ def training_overlap_profile(devices, cfg, topology, num_microbatches=4,
     from apex_tpu.telemetry import costs
 
     comm = costs.wire_bytes(costs.comm_from_jaxpr(jaxpr), sizes)
-    comm_ms = costs.comm_ms_from_axis_bytes(comm, "tpu")
+    # an analytic bound against the v5e envelope, whatever device traces
+    comm_ms = costs.comm_ms_from_axis_bytes(comm, costs.V5E_KIND)
     floor_ms = None
     if include_floor:
-        try:
-            from apex_tpu import _compat
+        from apex_tpu import _compat
 
-            ca = _compat.cost_analysis_dict(jax.jit(f).lower(batch))
-            flops = ca.get("flops") if ca else None
-            if flops:
-                floor_ms = round(
-                    float(flops) / costs.V5E_PEAK_BF16_FLOPS * 1e3, 6)
-        except Exception:
-            floor_ms = None
+        ca = _compat.cost_analysis_dict(jax.jit(f).lower(batch))
+        flops = ca.get("flops") if ca else None
+        if flops:
+            floor_ms = round(
+                float(flops) / costs.V5E_PEAK_BF16_FLOPS * 1e3, 6)
     return {"schedule": costs.collective_schedule(jaxpr, axes=dp_names),
             "overlap_bound": costs.overlap_bound(floor_ms,
                                                  comm_ms=comm_ms),

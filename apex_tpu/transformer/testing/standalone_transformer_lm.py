@@ -803,10 +803,10 @@ class GPTModel(nn.Module):
         if not fused:
             return False, interpret, None
         from apex_tpu.ops import xent_pallas
-        from apex_tpu.ops.attention import _tpu_available
+        from apex_tpu.ops.attention import _on_cpu, _tpu_available
 
         if from_table and not interpret:
-            interpret = not _tpu_available()
+            interpret = _on_cpu()
         if not (interpret or _tpu_available()):
             return False, interpret, None
         return (xent_pallas.supported(b * s, cfg.vocab_size // tp, h),

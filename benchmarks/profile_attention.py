@@ -20,7 +20,8 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")  # force-CPU tiny sanity mode
 
 from apex_tpu.dispatch import tiles  # noqa: E402
-from benchmarks._timing import Tracer, bench_k  # noqa: E402
+from benchmarks._timing import (Tracer, bench_k,  # noqa: E402
+                                device_peak_flops)
 
 B, H, S, D = (2, 2, 128, 32) if SMOKE else (8, 12, 1024, 64)
 # APEX_ATTN_SEQ overrides s (batch rescaled toward constant b*s tokens)
@@ -39,7 +40,7 @@ if LONG_SEQ:
 K = bench_k(SMOKE)  # see benchmarks/_timing.bench_k
 # fwd = 4*b*h*s^2*d/2 (causal); bwd = 2x fwd
 FLOPS = 4 * B * H * S * S * D * 3 // 2
-PEAK = 197e12
+PEAK = device_peak_flops()  # None on the CPU: no MFU is printed
 
 
 def measure(name, attn_fn, wrt_qkv=False, fwd_only=False):

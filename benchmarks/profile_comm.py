@@ -55,7 +55,6 @@ from benchmarks._timing import Tracer, bench_k  # noqa: E402
 from apex_tpu.dispatch.tiles import env_flag  # noqa: E402
 from apex_tpu.parallel import collectives  # noqa: E402
 from apex_tpu.telemetry import costs  # noqa: E402
-from apex_tpu.telemetry.costs import V5E_PEAK_BF16_FLOPS as PEAK  # noqa: E402
 from apex_tpu.transformer.parallel_state import (  # noqa: E402
     PIPELINE_AXIS,
     TENSOR_AXIS,
@@ -192,7 +191,7 @@ n_params = sum(
 OVERLAP_BUCKETS = overlap_mod.pin_overlap_buckets_env(
     GRAD_OVERLAP, nelems=n_params)
 
-TRACER = Tracer(K, peak_flops=PEAK)
+TRACER = Tracer(K)
 # nelems: the table tier resolves in the stamp exactly as it does at
 # the step's own trace time — a table-driven compressed run must
 # stamp; axes: `hierarchical` reports whether the two-stage path
@@ -279,7 +278,7 @@ span = TRACER.scan_time(
            "scheme": snap["scheme"],
            "hierarchical": snap["hierarchical"],
            "zero_stage": ZERO_STAGE})
-print(span.format_row(PEAK))
+print(span.format_row(TRACER.peak_flops))
 if span.seconds:
     toks = M * global_mb * S
     print(f"{'':24s} -> {toks/span.seconds:.0f} tok/s")

@@ -31,14 +31,14 @@ SMOKE = smoke_mode("APEX_MHA_SMOKE")  # tiny CPU sanity mode
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
-from benchmarks._timing import (bench_k, measure_dispatch_overhead,  # noqa: E402
-                                sync)
+from benchmarks._timing import (bench_k, device_peak_flops,  # noqa: E402
+                                measure_dispatch_overhead, sync)
 
 from apex_tpu.contrib.multihead_attn import SelfMultiheadAttn
 from apex_tpu.ops.attention import flash_supported  # noqa: E402
 
 K = bench_k(SMOKE)  # see benchmarks/_timing.bench_k
-PEAK = 197e12  # v5e bf16
+PEAK = device_peak_flops()  # None on the CPU: no MFU is printed
 
 OVERHEAD = measure_dispatch_overhead(K)
 print(f"dispatch overhead {OVERHEAD*1e3:.1f} ms")
@@ -113,7 +113,8 @@ def run_case(name, seq, fwd_only, fast):
     proj = 2 * seq * BATCH * HIDDEN * 4 * HIDDEN
     bmm = 2 * BATCH * HEADS * seq * seq * d * 2
     fl = (proj + bmm) * (1 if fwd_only else 3)
-    print(f"{name:36s} {dt*1e3:8.3f} ms  MFU={fl/dt/PEAK*100:5.1f}%")
+    mfu = f"  MFU={fl/dt/PEAK*100:5.1f}%" if PEAK else ""
+    print(f"{name:36s} {dt*1e3:8.3f} ms{mfu}")
     return dt
 
 

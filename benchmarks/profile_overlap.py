@@ -74,7 +74,6 @@ from apex_tpu import overlap as overlap_mod  # noqa: E402
 from apex_tpu.overlap import prefetch as prefetch_mod  # noqa: E402
 from apex_tpu.serving import ServingEngine, synthetic_trace  # noqa: E402
 from apex_tpu.telemetry import costs as _costs  # noqa: E402
-from apex_tpu.telemetry.costs import V5E_PEAK_BF16_FLOPS as PEAK  # noqa: E402
 from apex_tpu.transformer.parallel_state import (  # noqa: E402
     PIPELINE_AXIS,
     TENSOR_AXIS,
@@ -156,7 +155,7 @@ n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
 BUCKETS = overlap_mod.pin_overlap_buckets_env(GRAD_MODE,
                                               nelems=n_params)
 
-TRACER = Tracer(K, peak_flops=PEAK)
+TRACER = Tracer(K)
 print(f"params: {n_params/1e6:.2f}M  dp={N}  grad={GRAD_MODE}"
       + (f" buckets={BUCKETS}" if BUCKETS else "")
       + f"  prefetch={PREFETCH_DEPTH}  serve_overlap={SERVE_OVERLAP}  "
@@ -185,7 +184,7 @@ try:
     STEP_COMM = _costs.wire_bytes(
         _costs.comm_from_jaxpr(_jaxpr), _axis_sizes)
     STEP_COMM_MS = _costs.comm_ms_from_axis_bytes(
-        STEP_COMM, jax.devices()[0].platform)
+        STEP_COMM, jax.devices()[0].device_kind)
     print(f"{'collective schedule':28s} {SCHEDULE['verdict']} "
           f"({SCHEDULE['collectives']} dp collective(s), "
           f"{SCHEDULE['compute_after_first_collective']} compute eqn(s) "
@@ -221,7 +220,7 @@ span = TRACER.scan_time(
     extra={"n_params": n_params, "dp": N, "grad_overlap": GRAD_MODE,
            "buckets": BUCKETS, "collective_schedule": SCHEDULE},
     on_fail="span")
-print(span.format_row(PEAK))
+print(span.format_row(TRACER.peak_flops))
 
 # ------------------------------------------------ input pipeline row
 # A per-dispatch feed loop (one small jitted step per batch, synced

@@ -34,7 +34,8 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")  # force-CPU tiny sanity mode
 
-from benchmarks._timing import measure_dispatch_overhead, sync  # noqa: E402
+from benchmarks._timing import (device_peak_flops,  # noqa: E402
+                                measure_dispatch_overhead, sync)
 
 from apex_tpu.amp.scaler import LossScaler  # noqa: E402
 from apex_tpu.optimizers.fused_adam import fused_adam  # noqa: E402
@@ -47,7 +48,7 @@ from apex_tpu.transformer.testing import (  # noqa: E402
 )
 
 ON_TPU = not SMOKE and jax.devices()[0].platform == "tpu"
-PEAK = 197e12  # v5e bf16
+PEAK = device_peak_flops()  # None on the CPU: no MFU is printed
 K = 8 if ON_TPU else 2
 
 mesh = Mesh(np.asarray(jax.devices()[:1]), (TENSOR_AXIS,))
@@ -129,11 +130,12 @@ def measure(name, model_kind, cfg, b, s, vocab, tx):
     dt = (time.perf_counter() - t0 - OVERHEAD) / K
     if dt <= 0:
         print(f"{name}: non-positive step time after overhead subtraction "
-              "(relay flap straddled the calibration); unusable")
+              "(the calibration outweighed the step); unusable")
         return
-    mfu = 6.0 * n_params * b * s / dt / PEAK if ON_TPU else float("nan")
-    print(f"{name}: step {dt*1e3:.1f} ms  ->  {b*s/dt:,.0f} tokens/s  "
-          f"MFU {mfu*100:.1f}%")
+    mfu = (f"  MFU {6.0 * n_params * b * s / dt / PEAK * 100:.1f}%"
+           if PEAK else "")
+    print(f"{name}: step {dt*1e3:.1f} ms  ->  {b*s/dt:,.0f} tokens/s"
+          f"{mfu}")
 
 
 def main():

@@ -66,7 +66,6 @@ from apex_tpu.serving.router import (  # noqa: E402
     Router,
     router_block,
 )
-from apex_tpu.telemetry.costs import V5E_PEAK_BF16_FLOPS as PEAK  # noqa: E402
 from apex_tpu.transformer.testing import TransformerConfig  # noqa: E402
 
 K = 2 if SMOKE else 8  # calibration scan length only — the fleet
@@ -106,7 +105,7 @@ os.environ["APEX_SERVE_PREFIX_CACHE"] = "1" if PREFIX else "0"
 
 params = smodel.init_gpt_params(cfg)
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
-TRACER = Tracer(K, peak_flops=PEAK)
+TRACER = Tracer(K)
 flight.beat("backend_init")
 print(f"router: {n_params / 1e6:.1f}M params x {N_REPLICAS} replicas "
       f"(shared), {SLOTS} slots, {PAGES} pages x {PS} each, "

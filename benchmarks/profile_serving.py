@@ -78,7 +78,6 @@ from apex_tpu.serving import sampling as sampling_mod  # noqa: E402
 from apex_tpu.serving import scheduler as sched_mod  # noqa: E402
 from apex_tpu.serving import speculative as spec_mod  # noqa: E402
 from apex_tpu.telemetry import costs as _costs  # noqa: E402
-from apex_tpu.telemetry.costs import V5E_PEAK_BF16_FLOPS as PEAK  # noqa: E402
 from apex_tpu.transformer.testing import TransformerConfig  # noqa: E402
 
 K = 2 if SMOKE else 32
@@ -228,7 +227,7 @@ engine = ServingEngine(cfg, num_slots=SLOTS, page_size=PS,
                        num_pages=PAGES, max_seq=MAX_SEQ,
                        prefill_len=PRE_LEN)
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(engine.params))
-TRACER = Tracer(K, peak_flops=PEAK)
+TRACER = Tracer(K)
 flight.beat("backend_init")  # Tracer measured overhead => backend is up
 print(f"serving: {n_params / 1e6:.1f}M params, {SLOTS} slots, "
       f"{PAGES} pages x {PS}, quant={'int8' if WQ else 'off'}, "
@@ -290,7 +289,7 @@ span = TRACER.scan_time(
      jnp.asarray(lengths0, dtype=jnp.int32)),
     (jnp.asarray(pt0),), flops_per_iter=decode_flops,
     capture_cost=_costs.enabled(default=not SMOKE), on_fail="span")
-print(span.format_row(PEAK))
+print(span.format_row(TRACER.peak_flops))
 scan_tps = None
 if span.seconds:
     scan_tps = SLOTS / span.seconds

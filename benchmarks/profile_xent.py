@@ -25,7 +25,8 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")  # force-CPU tiny sanity mode
 
-from benchmarks._timing import Tracer, bench_k  # noqa: E402
+from benchmarks._timing import (Tracer, bench_k,  # noqa: E402
+                                device_peak_flops)
 
 from apex_tpu.ops import xent_pallas as xp  # noqa: E402
 
@@ -33,7 +34,7 @@ ON_TPU = not SMOKE and jax.devices()[0].platform == "tpu"
 H, V = (768, 50304) if ON_TPU else (128, 384)
 K = bench_k(not ON_TPU, default=64)  # few-ms rows; 64 keeps the
 # giant-HBM materialized case bounded while noise drops to ~0.5 ms
-PEAK = 197e12
+PEAK = device_peak_flops()  # None on the CPU: no MFU is printed
 # logits + dlogits matmuls dominate: 3 * 2*n*V*h (fwd + dX + dE)
 FLOPS_PER_ROW = 3 * 2 * V * H
 INTERPRET = not ON_TPU
@@ -117,11 +118,12 @@ def measure(name, fn, n):
         return
     dt = span.seconds
     mem = f"  peak-temp {peak/1e9:5.2f} GB" if peak is not None else ""
+    mfu = f"  MFU={flops/dt/PEAK*100:5.1f}%" if PEAK else ""
     print(f"{name:34s} {dt*1e3:8.2f} ms  {flops/dt/1e12:6.1f} TF/s"
-          f"  MFU={flops/dt/PEAK*100:5.1f}%{mem}")
+          f"{mfu}{mem}")
 
 
-TRACER = Tracer(K, peak_flops=PEAK)
+TRACER = Tracer(K)
 print(f"LM head h={H} V={V} (K={K}, overhead {TRACER.overhead_ms:.1f} ms)")
 
 # Fused (small-HBM) cases first: the relay's degraded mode selectively

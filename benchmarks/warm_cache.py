@@ -66,9 +66,6 @@ def warm_target(name, cmd, extra_env, timeout):
             env.pop(k, None)
         else:
             env[k] = v
-    # warming REQUIRES the cache on (that is its entire job) — but the
-    # escape hatch stays honored: an explicit APEX_COMPILE_CACHE=0 wins
-    env.setdefault("APEX_COMPILE_CACHE", "1")
     flight.beat("attempt_start", label=f"warm:{name}")
     # apexlint: disable=APX004 — warm-subprocess wall for the echo line, not a measurement (the warm pass times nothing, PERF.md §6)
     t0 = time.perf_counter()
@@ -124,7 +121,7 @@ def main():
     from apex_tpu import compile_cache as _cc
     from apex_tpu.dispatch.tiles import env_int
 
-    if _cc.requested() is False:
+    if _cc.target_dir() is None:
         print("warm_cache: APEX_COMPILE_CACHE=0 — nothing to warm",
               flush=True)
         return 0
@@ -330,7 +327,7 @@ def main():
 
     from apex_tpu import compile_cache
 
-    print(f"warm_cache: cache dir {compile_cache.cache_dir()}", flush=True)
+    print(f"warm_cache: cache dir {compile_cache.target_dir()}", flush=True)
     return 0 if ok_b8 else 1
 
 

@@ -241,19 +241,18 @@ def test_watchdog_ladder_retries_degraded_b16_config(monkeypatch, capsys):
     assert json.loads(out[0])["value"] == 130.0
 
 
-def test_watchdog_cpu_only_box_runs_once(monkeypatch, capsys):
-    """A clean first-attempt CPU line (no TPU hardware) collapses the
-    ladder: no second full bench for a CPU 'A/B'."""
+def test_watchdog_without_a_chip_prints_nothing(monkeypatch, capsys):
+    """A child that finds no TPU (NO_CHIP_RC, no JSON) ends the run at
+    once: no retry, no ladder, no line on stdout, that exit code — a
+    box without a chip gets no throughput under the metric's name."""
     sys.path.insert(0, REPO)
     import bench
 
     calls = []
 
     def fake_attempt(state, extra_env=None, **kw):
-        calls.append((extra_env or {}).get("APEX_BENCH_BATCH") == "16")
-        rec = dict(_fake_rec(90.0, False),
-                   metric="gpt2s_train_tokens_per_sec (cpu)")
-        return json.dumps(rec), rec, 0
+        calls.append(extra_env)
+        return None, None, bench.NO_CHIP_RC
 
     monkeypatch.setattr(bench, "_attempt_once", fake_attempt)
     monkeypatch.setattr(bench.time, "sleep", lambda s: None)
@@ -263,11 +262,28 @@ def test_watchdog_cpu_only_box_runs_once(monkeypatch, capsys):
               "APEX_REMAT", "APEX_BENCH_BATCH"):
         monkeypatch.delenv(k, raising=False)
     rc = bench._watchdog()
-    out = [l for l in capsys.readouterr().out.splitlines()
-           if l.startswith("{")]
-    assert rc == 0
-    assert calls == [False]
-    assert json.loads(out[0])["value"] == 90.0
+    assert rc == bench.NO_CHIP_RC != 0
+    assert len(calls) == 1
+    assert capsys.readouterr().out.strip() == ""
+
+
+def test_watchdog_cpu_line_is_never_the_headline(monkeypatch, capsys):
+    """A child line that claims the CPU outside --smoke is not the
+    requested backend: it can only be the error-path fallback, and the
+    run fails."""
+    sys.path.insert(0, REPO)
+    import bench
+
+    def fake_attempt(state, extra_env=None, **kw):
+        rec = dict(_fake_rec(90.0, False),
+                   metric="gpt2s_train_tokens_per_sec (cpu)")
+        return json.dumps(rec), rec, 0
+
+    monkeypatch.setattr(bench, "_attempt_once", fake_attempt)
+    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
+    monkeypatch.setenv("APEX_BENCH_ATTEMPTS", "1")
+    monkeypatch.delenv("APEX_BENCH_SMOKE", raising=False)
+    assert bench._watchdog() != 0
 
 
 def test_watchdog_lazy_cap_after_timeout(monkeypatch, capsys):

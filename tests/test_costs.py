@@ -1,6 +1,6 @@
 """The attribution layer (ISSUE 7): apex_tpu.telemetry.costs cost-block
-schema + derivations, the _compat cost/memory normalizers across every
-observed jax-0.4.37 shape variant, comm-volume accounting from jaxprs
+schema + derivations, the _compat readers of the installed jax's
+cost/memory analysis surfaces, comm-volume accounting from jaxprs
 (incl. the multichip training step), the tiles.py VMEM validation hook,
 profiler-capture artifact stamps, the ledger inspection CLI, and the
 PR-1 invariant: asking XLA to count a program's flops leaves the traced
@@ -40,7 +40,7 @@ def test_build_derives_floors_and_mfu_bound():
         xla_flops=peak * 1e-3,            # 1 ms/step compute floor
         hbm_bytes=bw * 2e-3,              # 2 ms/step bandwidth floor
         steps=10, model_flops_per_step=peak * 0.9e-3,  # 0.9ms of "model"
-        platform="tpu", source="compiled")
+        device_kind=costs.V5E_KIND, source="compiled")
     assert block["steps"] == 10  # metadata, never a divisor
     assert block["xla_flops_per_step"] == pytest.approx(peak * 1e-3)
     assert block["compute_floor_ms"] == pytest.approx(1.0)
@@ -65,7 +65,7 @@ def test_build_cpu_platform_has_no_roofline():
     """No committed envelope off-TPU: floors and bound stay None (the
     same rule as bench.py's mfu=None on CPU)."""
     block = costs.build(xla_flops=1e9, hbm_bytes=1e6, steps=1,
-                        platform="cpu", source="lowered")
+                        device_kind="cpu", source="lowered")
     assert block["peak_flops"] is None
     assert block["compute_floor_ms"] is None
     assert block["mfu_bound"] is None
@@ -81,7 +81,7 @@ def test_null_block_is_valid_and_all_none():
 
 def test_capture_without_stage_degrades_not_raises():
     block = costs.capture(lowered=None, compiled=None, steps=4,
-                          model_flops_per_step=123.0, platform="cpu")
+                          model_flops_per_step=123.0, device_kind="cpu")
     assert block["source"] is None
     assert block["xla_flops_per_step"] is None
     assert block["model_flops_per_step"] == 123.0
@@ -97,7 +97,7 @@ def test_capture_real_aot_stage_reports_xla_numbers():
     lowered = f.lower(x)
     compiled = lowered.compile()
     block = costs.capture(lowered=lowered, compiled=compiled, steps=1,
-                          platform="cpu")
+                          device_kind="cpu")
     assert block["source"] in ("compiled", "lowered")
     assert block["xla_flops_per_step"] and block["xla_flops_per_step"] > 0
     assert costs.validate(block) == []
@@ -137,7 +137,7 @@ def test_xla_counts_scan_body_once_calibration():
     # one body + loop overhead, nowhere near 16 bodies
     assert f_one <= f_scan < 2 * f_one
 
-    block = costs.capture(lowered=scan16, steps=16, platform="cpu")
+    block = costs.capture(lowered=scan16, steps=16, device_kind="cpu")
     assert block["steps"] == 16
     assert block["xla_flops_per_step"] == pytest.approx(f_scan)
 
@@ -150,7 +150,7 @@ def test_capture_escape_hatch_env(monkeypatch):
     f = jax.jit(lambda x: x + 1)
     lowered = f.lower(jnp.ones(4))
     block = costs.capture(lowered=lowered, compiled=None, steps=2,
-                          platform="cpu")
+                          device_kind="cpu")
     assert block["source"] is None
     assert block["xla_flops_per_step"] is None
     monkeypatch.setenv("APEX_COST_ANALYSIS", "1")
@@ -189,25 +189,17 @@ def test_validate_record_polices_cost_block(tmp_path):
     assert any("cost:" in p for p in ledger.validate_record(rec2))
 
 
-# ------------------------------------------------- _compat normalizers
+# ------------------------------------------------- _compat readers
 
 
 class _Stage:
-    def __init__(self, raw=None, raise_=False, absent=False):
-        if not absent:
-            self._raw, self._raise = raw, raise_
-            self.cost_analysis = self._call
-            self.memory_analysis = self._call
-
-    def _call(self):
-        if self._raise:
-            raise NotImplementedError("backend can't report")
-        return self._raw
+    def __init__(self, raw=None):
+        self._raw = raw
+        self.cost_analysis = self.memory_analysis = lambda: self._raw
 
 
 class _MemStats:
-    """The CompiledMemoryStats extension-object variant: attributes,
-    not keys."""
+    """The CompiledMemoryStats shape: attributes, not keys."""
     argument_size_in_bytes = 64
     output_size_in_bytes = 32
     temp_size_in_bytes = 128
@@ -215,68 +207,45 @@ class _MemStats:
     generated_code_size_in_bytes = 8
 
 
-def test_cost_analysis_dict_variants():
-    # absent method (old stages, custom wrappers)
-    assert _compat.cost_analysis_dict(object()) is None
-    # returns None / raises (unimplemented backend)
+class _ZeroStats(_MemStats):
+    argument_size_in_bytes = output_size_in_bytes = 0
+    temp_size_in_bytes = alias_size_in_bytes = 0
+    generated_code_size_in_bytes = 0
+
+
+def test_cost_analysis_dict_none_and_dict():
     assert _compat.cost_analysis_dict(_Stage(raw=None)) is None
-    assert _compat.cost_analysis_dict(_Stage(raise_=True)) is None
-    # Lowered-style flat dict: passed through
+    assert _compat.cost_analysis_dict(_Stage(raw={})) is None
     assert _compat.cost_analysis_dict(
         _Stage(raw={"flops": 10.0})) == {"flops": 10.0}
-    # Compiled-style list of per-computation dicts: key-wise sum
-    out = _compat.cost_analysis_dict(_Stage(raw=[
-        {"flops": 10.0, "bytes accessed": 4.0},
-        {"flops": 5.0, "transcendentals": 1.0}]))
-    assert out == {"flops": 15.0, "bytes accessed": 4.0,
-                   "transcendentals": 1.0}
-    # degenerate lists
-    assert _compat.cost_analysis_dict(_Stage(raw=[])) is None
-    assert _compat.cost_analysis_dict(_Stage(raw=["hlo"])) is None
-    assert _compat.cost_analysis_dict(_Stage(raw={})) is None
-    assert _compat.cost_analysis_dict(_Stage(raw=42)) is None
 
 
-def test_memory_analysis_dict_variants():
-    assert _compat.memory_analysis_dict(object()) is None
+def test_memory_analysis_dict_none_stats_and_zero():
     assert _compat.memory_analysis_dict(_Stage(raw=None)) is None
-    assert _compat.memory_analysis_dict(_Stage(raise_=True)) is None
-    # extension-object variant (attribute read)
     out = _compat.memory_analysis_dict(_Stage(raw=_MemStats()))
     assert out == {"argument_size_in_bytes": 64,
                    "output_size_in_bytes": 32,
                    "temp_size_in_bytes": 128,
                    "alias_size_in_bytes": 16,
                    "generated_code_size_in_bytes": 8}
-    # plain-dict variant (key filter; missing fields degrade to 0)
-    out = _compat.memory_analysis_dict(
-        _Stage(raw={"temp_size_in_bytes": 7, "host_temp_size_in_bytes": 9}))
-    assert out["temp_size_in_bytes"] == 7
-    assert out["argument_size_in_bytes"] == 0
-    assert "host_temp_size_in_bytes" not in out
     # all-zero stats carry no information -> "can't report"
-    assert _compat.memory_analysis_dict(
-        _Stage(raw={"temp_size_in_bytes": 0})) is None
+    assert _compat.memory_analysis_dict(_Stage(raw=_ZeroStats())) is None
 
 
-def test_real_jax_0437_surfaces_normalize():
-    """Calibration against the container's actual jax: whatever shapes
-    Lowered/Compiled return here, the normalizers fold them into the
-    one flat shape (or None) — this is the test that breaks loudly on
-    a jax upgrade that changes the surface."""
+def test_installed_jax_surfaces_read():
+    """Calibration against the installed jax: Lowered and Compiled
+    report flat numeric dicts and Compiled reports memory stats — the
+    test that breaks loudly on a jax upgrade that changes the
+    surface."""
     f = jax.jit(lambda x: jnp.tanh(x @ x))
     lowered = f.lower(jnp.ones((8, 8), jnp.float32))
     compiled = lowered.compile()
     for stage in (lowered, compiled):
         ca = _compat.cost_analysis_dict(stage)
-        assert ca is None or (isinstance(ca, dict) and all(
-            isinstance(v, (int, float)) for v in ca.values()))
+        assert isinstance(ca, dict) and ca["flops"] > 0
+        assert all(isinstance(v, (int, float)) for v in ca.values())
     ma = _compat.memory_analysis_dict(compiled)
-    assert ma is None or set(ma) == set(_compat._MEMORY_FIELDS)
-    # at least one of the surfaces must report on CPU jax-0.4.37 —
-    # otherwise the whole attribution layer is silently dark
-    assert _compat.cost_analysis_dict(compiled) is not None \
-        or _compat.cost_analysis_dict(lowered) is not None
+    assert set(ma) == set(_compat._MEMORY_FIELDS)
 
 
 # ---------------------------------------------------- comm accounting
@@ -349,20 +318,40 @@ def test_training_comm_bytes_multichip_topology():
     assert "tp" not in comm2, comm2      # size-1 axis filtered
 
 
+# ------------------------------------------------------ peaks table
+
+
+def test_peaks_keyed_by_device_kind_unknown_kind_raises():
+    """The v5e row serves "TPU v5 lite" only: the CPU gets no roofline
+    (None everywhere) and an accelerator kind without published peaks
+    is an error, never the v5e constants."""
+    assert costs.peak_flops_for(costs.V5E_KIND) == costs.V5E_PEAK_BF16_FLOPS
+    assert costs.peaks_for("cpu") is None and costs.peaks_for(None) is None
+    for fn in (costs.peak_flops_for, costs.hbm_bw_for,
+               costs.hbm_capacity_for, costs.ici_bw_for):
+        assert fn("cpu") is None
+        with pytest.raises(ValueError, match="no published peaks"):
+            fn("TPU v4")
+    with pytest.raises(ValueError, match="no published peaks"):
+        costs.build(xla_flops=1.0, device_kind="tpu")
+    assert costs.build(xla_flops=1.0, device_kind="cpu")["mfu_bound"] is None
+
+
 # ------------------------------------------------ starvation economics
 
 
 def test_starvation_verdicts(monkeypatch):
     monkeypatch.delenv("APEX_STARVE_HBM_BYTES", raising=False)
     cap = costs.V5E_HBM_CAPACITY_BYTES
-    assert costs.starvation(cap + 1, "tpu") == "exceeds-hbm"
+    assert costs.starvation(cap + 1, costs.V5E_KIND) == "exceeds-hbm"
     # no committed threshold: nothing below capacity is flagged
-    assert costs.starvation(cap - 1, "tpu") is None
+    assert costs.starvation(cap - 1, costs.V5E_KIND) is None
     monkeypatch.setenv("APEX_STARVE_HBM_BYTES", str(2 ** 30))
-    assert costs.starvation(2 ** 30 + 1, "tpu") == "starvation-risk"
-    assert costs.starvation(2 ** 30 - 1, "tpu") is None
-    assert costs.starvation(None, "tpu") is None
-    assert costs.starvation(0, "tpu") is None
+    assert costs.starvation(2 ** 30 + 1,
+                            costs.V5E_KIND) == "starvation-risk"
+    assert costs.starvation(2 ** 30 - 1, costs.V5E_KIND) is None
+    assert costs.starvation(None, costs.V5E_KIND) is None
+    assert costs.starvation(0, costs.V5E_KIND) is None
 
 
 # ------------------------------------------- tiles VMEM validation hook
@@ -536,7 +525,7 @@ def test_cost_capture_leaves_jaxpr_byte_identical():
     before = str(jax.make_jaxpr(step)(params, x))
     lowered = f.lower(params, x)
     block = costs.capture(lowered=lowered, compiled=lowered.compile(),
-                          steps=1, platform="cpu")
+                          steps=1, device_kind="cpu")
     costs.comm_from_jaxpr(jax.make_jaxpr(step)(params, x))
     assert block["source"] is not None
     after = str(jax.make_jaxpr(step)(params, x))
@@ -565,7 +554,8 @@ def test_overlap_bound_arithmetic_and_degradation():
 
 def test_build_stamps_overlap_bound_and_validates():
     peak = costs.V5E_PEAK_BF16_FLOPS
-    block = costs.build(xla_flops=peak * 2e-3, steps=4, platform="tpu",
+    block = costs.build(xla_flops=peak * 2e-3, steps=4,
+                        device_kind=costs.V5E_KIND,
                         source="compiled", host_ms=0.7, comm_ms=0.3)
     ob = block["overlap_bound"]
     assert ob["compute_floor_ms"] == pytest.approx(2.0)
@@ -580,7 +570,7 @@ def test_build_stamps_overlap_bound_and_validates():
 
 def test_attach_overlap_onto_existing_block():
     block = costs.build(xla_flops=costs.V5E_PEAK_BF16_FLOPS * 1e-3,
-                        steps=2, platform="tpu", source="compiled")
+                        steps=2, device_kind=costs.V5E_KIND, source="compiled")
     out = costs.attach_overlap(block, host_ms=2.5)
     assert out["overlap_bound"]["hideable_ms"] == pytest.approx(1.0)
     assert out["overlap_bound"]["bound_step_ms"] == pytest.approx(2.5)

@@ -19,6 +19,7 @@ def test_multiproc_two_process_psum():
     env = dict(os.environ)
     env["MASTER_PORT"] = "29531"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the launcher refuses N ranks on a chip
     out = subprocess.run(
         [sys.executable, "-m", "apex_tpu.parallel.multiproc", "--nproc", "2",
          os.path.join(REPO, "tests", "multiproc_worker.py")],
@@ -37,6 +38,7 @@ def test_imagenet_example_two_process():
     env = dict(os.environ)
     env["MASTER_PORT"] = "29541"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the launcher refuses N ranks on a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     out = subprocess.run(
         [sys.executable, "-m", "apex_tpu.parallel.multiproc", "--nproc", "2",
@@ -59,6 +61,7 @@ def test_pretrain_example_two_process(tp, port):
     env["MASTER_PORT"] = port
     env["APEX_TEST_TP"] = tp
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the launcher refuses N ranks on a chip
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
     out = subprocess.run(
         [sys.executable, "-m", "apex_tpu.parallel.multiproc", "--nproc", "2",
@@ -78,6 +81,7 @@ def test_simple_distributed_example_two_process():
     env = dict(os.environ)
     env["MASTER_PORT"] = "29537"
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # the launcher refuses N ranks on a chip
     # one device per process: the conftest's 8-device flag would make a
     # 16-device gloo mesh and slow every one of the 500 dispatches
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"

@@ -436,16 +436,16 @@ def test_collective_schedule_axes_and_degradation():
 
 
 def test_comm_ms_from_axis_bytes():
-    assert costs.comm_ms_from_axis_bytes(None, "tpu") is None
-    assert costs.comm_ms_from_axis_bytes({}, "tpu") == 0.0
+    assert costs.comm_ms_from_axis_bytes(None, costs.V5E_KIND) is None
+    assert costs.comm_ms_from_axis_bytes({}, costs.V5E_KIND) == 0.0
     assert costs.comm_ms_from_axis_bytes({"dp": 1}, "cpu") is None
     ms = costs.comm_ms_from_axis_bytes(
-        {"dp": costs.V5E_ICI_BYTES_PER_S_ENVELOPE}, "tpu")
+        {"dp": costs.V5E_ICI_BYTES_PER_S_ENVELOPE}, costs.V5E_KIND)
     assert abs(ms - 1e3) < 1e-6
 
 
 def test_capture_overlap_bound_passthrough():
-    block = costs.capture(steps=2, platform="tpu", host_ms=0.5,
+    block = costs.capture(steps=2, device_kind=costs.V5E_KIND, host_ms=0.5,
                           comm_ms=0.25)
     ob = block["overlap_bound"]
     assert ob["host_ms"] == 0.5 and ob["comm_ms"] == 0.25
@@ -717,8 +717,7 @@ def test_profile_overlap_smoke_cli(tmp_path, shared_smoke_cache_dir):
     ledger_path = tmp_path / "ledger.jsonl"
     env = dict(os.environ, APEX_BENCH_SMOKE="1",
                APEX_TELEMETRY_LEDGER=str(ledger_path),
-               APEX_COMPILE_CACHE="1",
-               APEX_COMPILE_CACHE_DIR=shared_smoke_cache_dir,
+               JAX_COMPILATION_CACHE_DIR=shared_smoke_cache_dir,
                APEX_OVERLAP_GRAD="bucketed", APEX_PREFETCH="1",
                APEX_SERVE_OVERLAP="1")
     env.pop("APEX_FAULT_PLAN", None)

@@ -53,9 +53,8 @@ DESIGNATED_READERS = (
     ("apex_tpu/dispatch/__init__.py", "APEX_DISPATCH_TABLE",
      "table-path override; path, not a typed value"),
     ("apex_tpu/compile_cache/__init__.py", "APEX_COMPILE_CACHE",
-     "tri-state hard-on/off/unset-follows-harness"),
-    ("apex_tpu/compile_cache/__init__.py", "APEX_COMPILE_CACHE_DIR",
-     "cache dir path"),
+     "=0 leaves the checkout without a cache; read with JAX's own "
+     "JAX_COMPILATION_CACHE_DIR in the one placement function"),
     ("apex_tpu/checkpoint.py", "APEX_CKPT_*",
      "durability knobs: retention 0 is legal (env_int is positive-only) "
      "and queue/async resolve once at ctor time"),
