@@ -55,7 +55,7 @@ SUITES = {
     "telemetry": ["test_telemetry.py", "test_bench_labels.py",
                   "test_dispatch.py", "test_dispatch_tiles.py",
                   "test_costs.py", "test_window_report.py",
-                  "test_flight.py"],
+                  "test_flight.py", "test_spans.py"],
     "api_audit": ["test_noop_knob_audit.py"],
     "checkpoint": ["test_checkpoint.py", "test_checkpoint_durable.py",
                    "test_checkpoint_chaos.py", "test_resume_parity.py"],

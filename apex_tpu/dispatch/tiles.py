@@ -219,10 +219,10 @@ def env_float(name, default):
 def env_flag(name):
     """Boolean env gate: True iff the var is exactly ``"1"`` — the
     parse every ``=1`` collection/arming knob in the repo uses
-    (APEX_TELEMETRY, APEX_SERVE_EVENTS, APEX_BENCH_SMOKE,
-    APEX_PROFILE_CAPTURE, ...). One home next to env_int/env_choice/
-    env_float so the gates cannot drift to ``bool(v)``-style parses
-    per module (tools/apexlint APX002 polices raw reads)."""
+    (APEX_TELEMETRY, APEX_SERVE_EVENTS, APEX_BENCH_SMOKE, ...). One
+    home next to env_int/env_choice/env_float so the gates cannot drift
+    to ``bool(v)``-style parses per module (tools/apexlint APX002
+    polices raw reads)."""
     return os.environ.get(name) == "1"
 
 

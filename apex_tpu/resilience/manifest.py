@@ -52,7 +52,7 @@ PASS_ROWS = (
     "gpt_remat_sel", "attn_seq4096", "overlap_base", "overlap_on",
     "zero3",
     "bench", "bench_b32",
-    "bench_b32_remat", "bench_profile", "serving",
+    "bench_b32_remat", "serving",
     "serving_sampling", "serving_spec", "serving_prefix",
     "serving_resilience", "serving_multitok", "serving_tp",
     "serving_kv_quant", "serving_kv_swap", "serving_router",

@@ -190,6 +190,7 @@ from apex_tpu.serving import tp as tp_mod
 from apex_tpu.serving.kv_cache import (PageAllocator, init_cache,
                                        pages_needed)
 from apex_tpu.serving.scheduler import ContinuousBatchingScheduler, Request
+from apex_tpu.telemetry import spans
 
 
 def detokenize(tokens):
@@ -489,9 +490,10 @@ class ServingEngine:
                 cache, _, logits = smodel.decode_step(
                     params, cache, tokens, lengths, page_table,
                     qparams=qparams, **decode_kw)
-                toks = sampling_mod.sample_tokens(
-                    logits, temps, top_ks, top_ps, keys, counters,
-                    lengths > 0)
+                with jax.named_scope("sample"):
+                    toks = sampling_mod.sample_tokens(
+                        logits, temps, top_ks, top_ps, keys, counters,
+                        lengths > 0)
                 return cache, toks, logits
         else:
             def _decode(params, qparams, cache, tokens, lengths,
@@ -558,6 +560,10 @@ class ServingEngine:
         # decode fetch): run wall minus this is the HOST slice of the
         # serving loop — the overlap_bound input
         self.device_dispatch_s = 0.0
+        # (rid, n_tokens, wall) of every fetch that gave a request
+        # tokens since the last round closed: the ``emitted`` attribute
+        # of the next ``engine.round`` span
+        self._emitted = []
         # wall seconds inside swap-tier staging copies (device_get at
         # swap-out + scatter at swap-in) — the host-copy clock the
         # kv_restore crossover sweep measures against the replay
@@ -662,14 +668,17 @@ class ServingEngine:
         ``serve_prefill`` chaos site — but keeps its own failure
         label, so a degraded round's verdict names the dispatch that
         actually wedged."""
-        site = "serve_prefill" if phase == "verify" \
-            else f"serve_{phase}"
+        program = "prefill" if phase == "verify" else phase
+        site = f"serve_{program}"
 
         def call():
-            faults_mod.fire(site, tick=self.tick,
-                            step=self.decode_steps,
-                            call=self.prefill_batches)
-            return fn()
+            # the call until it returns its futures (with ``recover``
+            # on, the fetch too: it is inside the watchdog)
+            with spans.span(f"{program}.dispatch"):
+                faults_mod.fire(site, tick=self.tick,
+                                step=self.decode_steps,
+                                call=self.prefill_batches)
+                return fn()
 
         if not self.recover:
             return call()
@@ -939,6 +948,60 @@ class ServingEngine:
             jnp.ones((len(slot_indices),), bool))
         return np.asarray(toks)
 
+    def _commit_first_token(self, slot, tok, wall):
+        """Per-slot bookkeeping of one prefilled prompt: its first
+        token, the walls and lifecycle events of that seam, and the
+        prompt's registration with the prefix cache."""
+        req = slot.request
+        slot.pos = len(req.prompt)
+        req.out_tokens.append(tok)
+        slot.next_token = tok
+        self.tokens_generated += 1
+        self._emitted.append((req.rid, 1, wall))
+        # prefill always samples the request's FIRST token — this
+        # dispatch's fetch wall IS the TTFT stamp
+        if req.first_token_wall is None:
+            req.first_token_wall = wall
+        if self.events is not None:
+            self.events.record("prefill_done", req.rid, tick=self.tick,
+                               wall=wall)
+            self.events.record("first_token", req.rid, tick=self.tick,
+                               wall=wall)
+        if req.done():
+            self._finish(req, wall, self.tick)
+        # register the fresh prompt's pages with the prefix cache
+        # (between dispatches; tail snapshots copy here)
+        if self.prefix is not None:
+            adopted, copies = self.prefix.register(
+                req.prompt, slot.pages, ("req", req.rid))
+            if adopted:
+                self.prefix.acquire(adopted)
+                slot.shared_pages.extend(adopted)
+            for src, dst in copies:
+                self._copy_page(src, dst)
+
+    def _finish(self, req, wall, tick):
+        """The one seam at which a request's last token has landed:
+        its finish wall, its ``finished`` event, and its three spans
+        ``request.queue`` / ``request.prefill`` / ``request.decode``,
+        stamped after the fact from the four walls it carries (they
+        tile ``enqueue_wall .. finish_wall``; a stream that was
+        preempted and admitted again after its first token has no
+        such tiling and records none)."""
+        if req.finish_wall is None:
+            req.finish_wall = wall
+        if self.events is not None:
+            self.events.record("finished", req.rid, tick=tick, wall=wall)
+        walls = (req.enqueue_wall, req.admitted_wall,
+                 req.first_token_wall, req.finish_wall)
+        if None in walls or list(walls) != sorted(walls):
+            return
+        spans.record("request.queue", walls[0], walls[1], rid=req.rid,
+                     prompt=len(req.prompt))
+        spans.record("request.prefill", walls[1], walls[2], rid=req.rid)
+        spans.record("request.decode", walls[2], walls[3], rid=req.rid,
+                     tokens=len(req.out_tokens))
+
     def _pack_greedy(self, items, sizes):
         """Greedy bucket split shared by admission prefill and the
         speculative verify: a batch closes when the next packed
@@ -969,50 +1032,53 @@ class ServingEngine:
         Returns ``(logits, t0)`` — the caller fetches what it needs
         and closes the ``device_dispatch_s`` timing seam."""
         S, R, W = self.prefill_len, self.prefill_requests, self._gather_w
-        ids = np.zeros((S,), np.int32)
-        positions = np.zeros((S,), np.int32)
-        seg = np.zeros((S,), np.int32)
-        token_rows = np.full((S,), self.num_slots, np.int32)
-        gather_idx = np.zeros((R * W,), np.int32)
-        pt = np.zeros((self.num_slots + 1, self.max_pages), np.int32)
-        pt[:self.num_slots] = self.scheduler.page_table_rows()
-        cursor = 0
-        for r, (si, fed, write_from, gathers) in enumerate(rows):
-            n = len(fed)
-            ids[cursor:cursor + n] = fed
-            positions[cursor:cursor + n] = np.arange(n)
-            seg[cursor:cursor + n] = r + 1
-            token_rows[cursor + write_from:cursor + n] = si
-            for j, gp in enumerate(gathers):
-                gather_idx[r * W + j] = cursor + gp
-            cursor += n
-        keep = None
-        if self.kv_quant:
-            # keep_scale row (kv_tier.prefill_scatter_quant): 1 for
-            # pages whose existing int8 content must survive this
-            # dispatch's scale growth, 0 for pages this dispatch fully
-            # rewrites (fresh pages — stale codes there must NOT pin
-            # the scale). A row writing from write_from>0 (verify
-            # replay) keeps the partially-valid page holding position
-            # write_from-1 and zeroes only the pages past it.
-            keep = np.ones((self.num_pages,), np.float32)
-            for si, fed, write_from, _ in rows:
-                pages = self.scheduler.slots[si].pages
-                first = (0 if write_from == 0
-                         else (write_from - 1) // self.page_size + 1)
-                for j in range(first,
-                               (len(fed) - 1) // self.page_size + 1):
-                    if j < len(pages):
-                        keep[pages[j]] = 0.0
+        with spans.span("prefill.pack", rows=len(rows)) as sp:
+            ids = np.zeros((S,), np.int32)
+            positions = np.zeros((S,), np.int32)
+            seg = np.zeros((S,), np.int32)
+            token_rows = np.full((S,), self.num_slots, np.int32)
+            gather_idx = np.zeros((R * W,), np.int32)
+            pt = np.zeros((self.num_slots + 1, self.max_pages), np.int32)
+            pt[:self.num_slots] = self.scheduler.page_table_rows()
+            cursor = 0
+            for r, (si, fed, write_from, gathers) in enumerate(rows):
+                n = len(fed)
+                ids[cursor:cursor + n] = fed
+                positions[cursor:cursor + n] = np.arange(n)
+                seg[cursor:cursor + n] = r + 1
+                token_rows[cursor + write_from:cursor + n] = si
+                for j, gp in enumerate(gathers):
+                    gather_idx[r * W + j] = cursor + gp
+                cursor += n
+            keep = None
+            if self.kv_quant:
+                # keep_scale row (kv_tier.prefill_scatter_quant): 1 for
+                # pages whose existing int8 content must survive this
+                # dispatch's scale growth, 0 for pages this dispatch fully
+                # rewrites (fresh pages — stale codes there must NOT pin
+                # the scale). A row writing from write_from>0 (verify
+                # replay) keeps the partially-valid page holding position
+                # write_from-1 and zeroes only the pages past it.
+                keep = np.ones((self.num_pages,), np.float32)
+                for si, fed, write_from, _ in rows:
+                    pages = self.scheduler.slots[si].pages
+                    first = (0 if write_from == 0
+                             else (write_from - 1) // self.page_size + 1)
+                    for j in range(first,
+                                   (len(fed) - 1) // self.page_size + 1):
+                        if j < len(pages):
+                            keep[pages[j]] = 0.0
+            sp.set(tokens=cursor)
         t0 = time.perf_counter()
-
-        def call():
+        with spans.span("prefill.stage"):
             args = [self.params, self.cache, jnp.asarray(ids),
                     jnp.asarray(positions), jnp.asarray(seg),
                     jnp.asarray(token_rows), jnp.asarray(pt),
                     jnp.asarray(gather_idx)]
             if keep is not None:
                 args.append(jnp.asarray(keep))
+
+        def call():
             cache, logits = self._prefill_fn(*args)
             if self.recover:
                 # fetch INSIDE the watchdog: the sync on the gathered
@@ -1051,13 +1117,15 @@ class ServingEngine:
                 rows.append((si, fed, 0, [len(fed) - 1]))
             logits, t0 = self._packed_call(rows)
             self.prefill_batches += 1
-            _ = np.asarray(logits[:1, :1])  # close the dispatch seam
-            wall = time.perf_counter()
+            with spans.span("prefill.fetch"):
+                _ = np.asarray(logits[:1, :1])  # close the dispatch seam
+                wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
-            for si, fed in batch:
-                slot = sch.slots[si]
-                slot.pos = len(fed)
-                slot.next_token = int(slot.known[len(fed)])
+            with spans.span("prefill.commit"):
+                for si, fed in batch:
+                    slot = sch.slots[si]
+                    slot.pos = len(fed)
+                    slot.next_token = int(slot.known[len(fed)])
 
     def _run_prefill(self, slot_indices):
         """Pack the newly admitted slots' prompts into [prefill_len]
@@ -1099,44 +1167,16 @@ class ServingEngine:
                     for si in batch]
             logits, t0 = self._packed_call(rows)
             self.prefill_batches += 1
-            # rows r*W hold each request's last-prompt-token logits
-            sel = logits[np.arange(len(batch)) * self._gather_w]
-            next_toks = self._sample_first_tokens(sel, batch)
-            wall = time.perf_counter()
+            with spans.span("prefill.fetch"):
+                # rows r*W hold each request's last-prompt-token logits
+                sel = logits[np.arange(len(batch)) * self._gather_w]
+                next_toks = self._sample_first_tokens(sel, batch)
+                wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
-            for r, si in enumerate(batch):
-                slot = sch.slots[si]
-                slot.pos = len(slot.request.prompt)
-                tok = int(next_toks[r])
-                slot.request.out_tokens.append(tok)
-                slot.next_token = tok
-                self.tokens_generated += 1
-                # prefill always samples the request's FIRST token —
-                # this dispatch's fetch wall IS the TTFT stamp
-                if slot.request.first_token_wall is None:
-                    slot.request.first_token_wall = wall
-                if slot.request.done():
-                    slot.request.finish_wall = wall
-                if self.events is not None:
-                    rid = slot.request.rid
-                    self.events.record("prefill_done", rid,
-                                       tick=self.tick, wall=wall)
-                    self.events.record("first_token", rid,
-                                       tick=self.tick, wall=wall)
-                    if slot.request.done():
-                        self.events.record("finished", rid,
-                                           tick=self.tick, wall=wall)
-                # register the fresh prompt's pages with the prefix
-                # cache (between dispatches; tail snapshots copy here)
-                if self.prefix is not None:
-                    adopted, copies = self.prefix.register(
-                        slot.request.prompt, slot.pages,
-                        ("req", slot.request.rid))
-                    if adopted:
-                        self.prefix.acquire(adopted)
-                        slot.shared_pages.extend(adopted)
-                    for src, dst in copies:
-                        self._copy_page(src, dst)
+            with spans.span("prefill.commit"):
+                for r, si in enumerate(batch):
+                    self._commit_first_token(sch.slots[si],
+                                             int(next_toks[r]), wall)
         return resumed + slot_indices
 
     # ------------------------------------------------------- speculative
@@ -1203,9 +1243,10 @@ class ServingEngine:
                              list(range(pos, pos + len(draft) + 1))))
             logits, t0 = self._packed_call(rows, phase="verify")
             self.verify_calls += 1
-            greedy = np.asarray(jnp.argmax(
-                logits.astype(jnp.float32), axis=-1))
-            wall = time.perf_counter()
+            with spans.span("prefill.fetch"):
+                greedy = np.asarray(jnp.argmax(
+                    logits.astype(jnp.float32), axis=-1))
+                wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
             for r, (i, draft) in enumerate(batch):
                 slot = sch.slots[i]
@@ -1224,11 +1265,9 @@ class ServingEngine:
                 slot.pos = len(req.prompt) + len(req.out_tokens) - 1
                 slot.next_token = req.out_tokens[-1]
                 self.tokens_generated += len(added)
+                self._emitted.append((req.rid, len(added), wall))
                 if req.done():
-                    req.finish_wall = wall
-                    if self.events is not None:
-                        self.events.record("finished", req.rid,
-                                           tick=self.tick, wall=wall)
+                    self._finish(req, wall, self.tick)
                 verified.append(i)
         return verified
 
@@ -1298,36 +1337,37 @@ class ServingEngine:
         round defers it); ``steps`` maps lane -> how many of the
         block's scan steps that lane's bookkeeping consumes."""
         sch = self.scheduler
-        tokens, lengths = sch.decode_inputs()
-        for i in zero_length_lanes:
-            lengths[i] = 0  # this round's tokens came via verify
-        pt = np.asarray(sch.page_table_rows(), np.int32)
-        if self.decode_k > 1:
-            steps, steps_dev, warm_tokens, warm_steps = \
-                self._stage_block(assert_lanes)
-            for i in assert_lanes:
-                if steps_dev[i]:
-                    self._assert_writable(
-                        sch.slots[i], sch.slots[i].pos,
-                        sch.slots[i].pos + int(steps_dev[i]) - 1)
-        else:
-            steps = {i: 1 for i in assert_lanes}
-            for i in assert_lanes:
-                self._assert_writable(sch.slots[i], sch.slots[i].pos,
-                                      sch.slots[i].pos)
-        args = [self.params, self.qparams, self.cache,
-                jnp.asarray(tokens, dtype=jnp.int32),
-                jnp.asarray(lengths, dtype=jnp.int32),
-                jnp.asarray(pt)]
-        if self.decode_k > 1:
-            args += [jnp.asarray(steps_dev), jnp.asarray(warm_tokens),
-                     jnp.asarray(warm_steps)]
-        if self.sampling:
-            temps, top_ks, top_ps, keys, counters = \
-                sampling_mod.lane_arrays(sch.slots, self.num_slots)
-            args += [jnp.asarray(temps), jnp.asarray(top_ks),
-                     jnp.asarray(top_ps), jnp.asarray(keys),
-                     jnp.asarray(counters)]
+        with spans.span("decode.stage"):
+            tokens, lengths = sch.decode_inputs()
+            for i in zero_length_lanes:
+                lengths[i] = 0  # this round's tokens came via verify
+            pt = np.asarray(sch.page_table_rows(), np.int32)
+            if self.decode_k > 1:
+                steps, steps_dev, warm_tokens, warm_steps = \
+                    self._stage_block(assert_lanes)
+                for i in assert_lanes:
+                    if steps_dev[i]:
+                        self._assert_writable(
+                            sch.slots[i], sch.slots[i].pos,
+                            sch.slots[i].pos + int(steps_dev[i]) - 1)
+            else:
+                steps = {i: 1 for i in assert_lanes}
+                for i in assert_lanes:
+                    self._assert_writable(sch.slots[i], sch.slots[i].pos,
+                                          sch.slots[i].pos)
+            args = [self.params, self.qparams, self.cache,
+                    jnp.asarray(tokens, dtype=jnp.int32),
+                    jnp.asarray(lengths, dtype=jnp.int32),
+                    jnp.asarray(pt)]
+            if self.decode_k > 1:
+                args += [jnp.asarray(steps_dev), jnp.asarray(warm_tokens),
+                         jnp.asarray(warm_steps)]
+            if self.sampling:
+                temps, top_ks, top_ps, keys, counters = \
+                    sampling_mod.lane_arrays(sch.slots, self.num_slots)
+                args += [jnp.asarray(temps), jnp.asarray(top_ks),
+                         jnp.asarray(top_ps), jnp.asarray(keys),
+                         jnp.asarray(counters)]
         t0 = time.perf_counter()
 
         def call():
@@ -1378,9 +1418,16 @@ class ServingEngine:
         (``overlap=`` / ``APEX_SERVE_OVERLAP``) the round is the
         deferred-fetch pipelined variant — same schedule, same tokens
         (see the module docstring); the serial body is untouched."""
-        if self.overlap:
-            return self._step_overlap(arrivals)
-        return self._step_serial(arrivals)
+        with spans.span("engine.round", tick=self.tick) as sp:
+            info = self._step_overlap(arrivals) if self.overlap \
+                else self._step_serial(arrivals)
+            # every (rid, n_tokens, wall) whose tokens a fetch of this
+            # round handed to a request (the overlapped round: the
+            # fetch of the round before)
+            emitted, self._emitted = self._emitted, []
+            sp.set(prefilled=len(info["prefilled"]),
+                   decoded=info["decoded_slots"], emitted=emitted)
+        return info
 
     def _fire_burst(self, tick):
         """Chaos: the ``serve_burst`` site (ISSUE 15) — fabricate and
@@ -1466,6 +1513,22 @@ class ServingEngine:
         self._drain_preempted(tick)
         return [i for i in alive if sch.slots[i] is not None]
 
+    def _cow_prefix_hits(self, admitted):
+        """Prefix-cache hits skip the packed prefill: their COW copies
+        run here (between dispatches) and their covered suffix replays
+        through the decode program. Returns the slots still to
+        prefill."""
+        to_prefill = []
+        for i in admitted:
+            slot = self.scheduler.slots[i]
+            if slot.prefix_hit:
+                for src, dst in slot.cow_copies:
+                    self._copy_page(src, dst)
+                slot.cow_copies = []
+            else:
+                to_prefill.append(i)
+        return to_prefill
+
     def _step_serial(self, arrivals=None):
         now = self.tick
         self._fire_burst(now)
@@ -1484,29 +1547,23 @@ class ServingEngine:
 
     def _round_serial(self, now):
         sch = self.scheduler
-        wall = time.perf_counter()
-        evicted = sch.evict_done(now, wall)
-        shed = self._shed_queue(now, wall) if self.shed else []
-        admitted = sch.admit(now, wall)
-        if self.events is not None:
-            for r in evicted:
-                self.events.record("evicted", r.rid, tick=now, wall=wall)
-            for i in admitted:
-                self.events.record("admitted", sch.slots[i].request.rid,
-                                   tick=now, wall=wall)
+        with spans.span("engine.schedule") as sp:
+            wall = time.perf_counter()
+            evicted = sch.evict_done(now, wall)
+            shed = self._shed_queue(now, wall) if self.shed else []
+            admitted = sch.admit(now, wall)
+            if self.events is not None:
+                for r in evicted:
+                    self.events.record("evicted", r.rid, tick=now,
+                                       wall=wall)
+                for i in admitted:
+                    self.events.record("admitted",
+                                       sch.slots[i].request.rid,
+                                       tick=now, wall=wall)
+            to_prefill = self._cow_prefix_hits(admitted)
+            sp.set(evicted=len(evicted), admitted=len(admitted),
+                   queue_depth=sch.queue_depth())
         self.resilience.admissions += len(admitted)
-        # prefix-cache hits skip the packed prefill: their COW copies
-        # run here (between dispatches) and their covered suffix
-        # replays through the decode program below
-        to_prefill = []
-        for i in admitted:
-            slot = sch.slots[i]
-            if slot.prefix_hit:
-                for src, dst in slot.cow_copies:
-                    self._copy_page(src, dst)
-                slot.cow_copies = []
-            else:
-                to_prefill.append(i)
         prefilled = self._run_prefill(to_prefill) if to_prefill else []
         active = sch.active_indices()
         verified = []
@@ -1543,12 +1600,18 @@ class ServingEngine:
         if decode_lanes:
             next_toks, t0, steps = self._dispatch_decode(
                 decode_lanes, zero_length_lanes=verified)
-            plan, decoded = self._advance_counts(decode_lanes, steps)
-            next_toks = np.asarray(next_toks)
-            wall2 = time.perf_counter()
+            with spans.span("decode.fetch"):
+                # the count bookkeeping runs while the device works:
+                # it is part of the wait, not of the round's host time
+                plan, decoded = self._advance_counts(decode_lanes, steps)
+                next_toks = np.asarray(next_toks)
+                wall2 = time.perf_counter()
             self.device_dispatch_s += wall2 - t0
-            self._fill_plan(plan, next_toks, wall2, now)
-        self._sample_gauges(now)
+            with spans.span("decode.commit"):
+                self._fill_plan(plan, next_toks, wall2, now)
+                self._sample_gauges(now)
+        else:
+            self._sample_gauges(now)
         # a slot whose LAST token was just produced frees at the next
         # round's evict — one round of slack, never a starved queue
         self.tick += 1
@@ -1757,6 +1820,7 @@ class ServingEngine:
             e["req"].out_tokens[e["out_idx"]] = tok
             e["slot"].next_token = tok
             rid = e["req"].rid
+            self._emitted.append((rid, 1, wall))
             if e["first"]:
                 if e["req"].first_token_wall is None:
                     e["req"].first_token_wall = wall
@@ -1766,11 +1830,7 @@ class ServingEngine:
                     self.events.record("first_token", rid,
                                        tick=tick, wall=wall)
             if e["done"]:
-                if e["req"].finish_wall is None:
-                    e["req"].finish_wall = wall
-                if self.events is not None:
-                    self.events.record("finished", rid,
-                                       tick=tick, wall=wall)
+                self._finish(e["req"], wall, tick)
 
     # ----------------------------------- overlapped round (ISSUE 14)
 
@@ -1825,17 +1885,9 @@ class ServingEngine:
             for i in admitted:
                 self.events.record("admitted", sch.slots[i].request.rid,
                                    tick=now, wall=wall)
-        to_prefill = []
-        for i in admitted:
-            slot = sch.slots[i]
-            if slot.prefix_hit:
-                # COW copies are device work: they queue behind the
-                # in-flight decode and run before any dependent read
-                for src, dst in slot.cow_copies:
-                    self._copy_page(src, dst)
-                slot.cow_copies = []
-            else:
-                to_prefill.append(i)
+        # COW copies are device work: they queue behind the in-flight
+        # decode and run before any dependent read
+        to_prefill = self._cow_prefix_hits(admitted)
         # ---- sync point: round t's values land (finished /
         # first-token events), then the evictions planned above are
         # RECORDED — after the finished events they must follow

@@ -96,8 +96,11 @@ def init_gpt_params(cfg, seed=0):
 
 def _mm(x, w, dtype):
     """x @ w^T, fp32 accumulation (the layers.py `_mm` idiom)."""
+    x = x.astype(dtype)
+    with jax.named_scope("weights_cast"):
+        w = w.astype(dtype)   # fp32 serving weights: cast in every step
     return lax.dot_general(
-        x.astype(dtype), w.astype(dtype),
+        x, w,
         (((x.ndim - 1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32).astype(dtype)
 
@@ -162,24 +165,38 @@ def _trunk_layer(x, lp, qr, cfg, attn):
     for this layer's k/v and the attention itself, returning the
     ``[rows, n_heads*head_dim]`` context."""
     dtype = x.dtype
-    ln1 = _layer_norm(x, lp["input_layernorm"], cfg.layernorm_epsilon)
-    sa = lp["self_attention"]
-    qkv = _wmat(ln1, sa["query_key_value"]["weight"], qr.get("qkv"),
-                dtype) + sa["query_key_value"]["bias"].astype(dtype)
-    q, k, v = _split_qkv(qkv, cfg.num_attention_heads, cfg.head_dim)
-    ctx = attn(q, k, v)
-    attn_out = _wmat(ctx, sa["dense"]["weight"], qr.get("dense"),
-                     dtype) + sa["dense"]["bias"].astype(dtype)
-    x = x + attn_out
-    ln2 = _layer_norm(x, lp["post_attention_layernorm"],
-                      cfg.layernorm_epsilon)
-    mlp = lp["mlp"]
-    inter = _wmat(ln2, mlp["dense_h_to_4h"]["weight"], qr.get("h4"),
-                  dtype) + mlp["dense_h_to_4h"]["bias"].astype(dtype)
-    inter = jax.nn.gelu(inter, approximate=True)
-    out = _wmat(inter, mlp["dense_4h_to_h"]["weight"], qr.get("4h"),
-                dtype) + mlp["dense_4h_to_h"]["bias"].astype(dtype)
-    return x + out
+    # the scopes name each op's stretch of the layer in the device trace
+    # (``tf_op``) and the compiled HLO; ``attn`` adds ``kv_write`` and
+    # ``attend`` under ``layer/attn``
+    with jax.named_scope("layer"):
+        with jax.named_scope("attn"):
+            sa = lp["self_attention"]
+            with jax.named_scope("qkv"):
+                ln1 = _layer_norm(x, lp["input_layernorm"],
+                                  cfg.layernorm_epsilon)
+                qkv = _wmat(ln1, sa["query_key_value"]["weight"],
+                            qr.get("qkv"), dtype) \
+                    + sa["query_key_value"]["bias"].astype(dtype)
+                q, k, v = _split_qkv(qkv, cfg.num_attention_heads,
+                                     cfg.head_dim)
+            ctx = attn(q, k, v)
+            with jax.named_scope("out"):
+                attn_out = _wmat(ctx, sa["dense"]["weight"],
+                                 qr.get("dense"), dtype) \
+                    + sa["dense"]["bias"].astype(dtype)
+                x = x + attn_out
+        with jax.named_scope("mlp"):
+            ln2 = _layer_norm(x, lp["post_attention_layernorm"],
+                              cfg.layernorm_epsilon)
+            mlp = lp["mlp"]
+            inter = _wmat(ln2, mlp["dense_h_to_4h"]["weight"],
+                          qr.get("h4"), dtype) \
+                + mlp["dense_h_to_4h"]["bias"].astype(dtype)
+            inter = jax.nn.gelu(inter, approximate=True)
+            out = _wmat(inter, mlp["dense_4h_to_h"]["weight"],
+                        qr.get("4h"), dtype) \
+                + mlp["dense_4h_to_h"]["bias"].astype(dtype)
+            return x + out
 
 
 # --------------------------------------------------------------- prefill
@@ -210,15 +227,16 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     S = ids.shape[0]
 
     word = params["word_embeddings"]
-    x = jnp.take(word, ids, axis=0) \
-        + jnp.take(params["embedding"]["position_embeddings"],
-                   positions, axis=0)
-    x = x.astype(dtype)
+    with jax.named_scope("embed"):
+        x = jnp.take(word, ids, axis=0) \
+            + jnp.take(params["embedding"]["position_embeddings"],
+                       positions, axis=0)
+        x = x.astype(dtype)
 
-    dest_page = jnp.take_along_axis(
-        token_rows_to_pages(page_table, token_rows),
-        (positions // ps)[:, None], axis=1)[:, 0]
-    dest_off = positions % ps
+        dest_page = jnp.take_along_axis(
+            token_rows_to_pages(page_table, token_rows),
+            (positions // ps)[:, None], axis=1)[:, 0]
+        dest_off = positions % ps
 
     quant = kv_tier_mod.is_quantized(cache)
     if quant and keep_scale is None:
@@ -238,33 +256,39 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
             # scatter through the quantize-at-write codec) — then
             # packed causal+segment attention over the full bucket
             nonlocal cache
-            if quant:
-                cache = kv_tier_mod.prefill_scatter_quant(
-                    cache, i, "k", k, dest_page, dest_off, keep_scale)
-                cache = kv_tier_mod.prefill_scatter_quant(
-                    cache, i, "v", v, dest_page, dest_off, keep_scale)
-            else:
-                cache["k"] = cache["k"].at[
-                    i, :, dest_page, dest_off, :].set(
-                    k.astype(cache["k"].dtype))
-                cache["v"] = cache["v"].at[
-                    i, :, dest_page, dest_off, :].set(
-                    v.astype(cache["v"].dtype))
-            ctx = fused_attention(
-                q.transpose(1, 0, 2)[None],
-                k.transpose(1, 0, 2)[None],
-                v.transpose(1, 0, 2)[None], causal=True,
-                sm_scale=1.0 / math.sqrt(hd),
-                segment_ids=(seg2, seg2))
-            return ctx[0].transpose(1, 0, 2).reshape(S, n_heads * hd)
+            with jax.named_scope("kv_write"):
+                if quant:
+                    cache = kv_tier_mod.prefill_scatter_quant(
+                        cache, i, "k", k, dest_page, dest_off,
+                        keep_scale)
+                    cache = kv_tier_mod.prefill_scatter_quant(
+                        cache, i, "v", v, dest_page, dest_off,
+                        keep_scale)
+                else:
+                    cache["k"] = cache["k"].at[
+                        i, :, dest_page, dest_off, :].set(
+                        k.astype(cache["k"].dtype))
+                    cache["v"] = cache["v"].at[
+                        i, :, dest_page, dest_off, :].set(
+                        v.astype(cache["v"].dtype))
+            with jax.named_scope("attend"):
+                ctx = fused_attention(
+                    q.transpose(1, 0, 2)[None],
+                    k.transpose(1, 0, 2)[None],
+                    v.transpose(1, 0, 2)[None], causal=True,
+                    sm_scale=1.0 / math.sqrt(hd),
+                    segment_ids=(seg2, seg2))
+                return ctx[0].transpose(1, 0, 2).reshape(S, n_heads * hd)
 
         x = _trunk_layer(x, params["transformer"][f"layer_{i}"], {},
                          cfg, attn)
 
-    x = _layer_norm(x, params["transformer"]["final_layernorm"],
-                    cfg.layernorm_epsilon)
-    x_last = jnp.take(x, last_idx, axis=0)
-    logits = _mm(x_last, word, dtype)
+    with jax.named_scope("final_norm"):
+        x = _layer_norm(x, params["transformer"]["final_layernorm"],
+                        cfg.layernorm_epsilon)
+    with jax.named_scope("lm_head"):
+        x_last = jnp.take(x, last_idx, axis=0)
+        logits = _mm(x_last, word, dtype)
     return cache, logits
 
 
@@ -299,20 +323,21 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
     ps = cache["k"].shape[3]
     B = tokens.shape[0]
 
-    active = lengths > 0
-    positions = jnp.maximum(lengths - 1, 0)
-    write_page = jnp.where(
-        active,
-        jnp.take_along_axis(page_table, (positions // ps)[:, None],
-                            axis=1)[:, 0],
-        0)
-    write_off = jnp.where(active, positions % ps, 0)
-
     word = params["word_embeddings"]
-    x = jnp.take(word, tokens, axis=0) \
-        + jnp.take(params["embedding"]["position_embeddings"],
-                   positions, axis=0)
-    x = x.astype(dtype)
+    with jax.named_scope("embed"):
+        active = lengths > 0
+        positions = jnp.maximum(lengths - 1, 0)
+        write_page = jnp.where(
+            active,
+            jnp.take_along_axis(page_table, (positions // ps)[:, None],
+                                axis=1)[:, 0],
+            0)
+        write_off = jnp.where(active, positions % ps, 0)
+
+        x = jnp.take(word, tokens, axis=0) \
+            + jnp.take(params["embedding"]["position_embeddings"],
+                       positions, axis=0)
+        x = x.astype(dtype)
 
     ql = qparams["layers"] if qparams is not None else None
     quant = kv_tier_mod.is_quantized(cache)
@@ -324,38 +349,43 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
             # dispatched fifth family (quantized pages ride with their
             # per-(page, head) scale planes)
             nonlocal cache
-            if quant:
-                cache = kv_tier_mod.decode_scatter_quant(
-                    cache, i, "k", k, write_page, write_off)
-                cache = kv_tier_mod.decode_scatter_quant(
-                    cache, i, "v", v, write_page, write_off)
-            else:
-                cache["k"] = cache["k"].at[
-                    i, :, write_page, write_off, :].set(
-                    k.astype(cache["k"].dtype))  # [B, H, d] values
-                cache["v"] = cache["v"].at[
-                    i, :, write_page, write_off, :].set(
-                    v.astype(cache["v"].dtype))
-            ctx = dap.decode_attention(
-                q.astype(dtype), cache["k"][i], cache["v"][i],
-                page_table, lengths, sm_scale=1.0 / math.sqrt(hd),
-                k_scale=cache["k_scale"][i] if quant else None,
-                v_scale=cache["v_scale"][i] if quant else None,
-                impl=decode_impl, block_h=decode_block_h,
-                interpret=interpret)
-            return ctx.reshape(B, n_heads * hd).astype(dtype)
+            with jax.named_scope("kv_write"):
+                if quant:
+                    cache = kv_tier_mod.decode_scatter_quant(
+                        cache, i, "k", k, write_page, write_off)
+                    cache = kv_tier_mod.decode_scatter_quant(
+                        cache, i, "v", v, write_page, write_off)
+                else:
+                    cache["k"] = cache["k"].at[
+                        i, :, write_page, write_off, :].set(
+                        k.astype(cache["k"].dtype))  # [B, H, d] values
+                    cache["v"] = cache["v"].at[
+                        i, :, write_page, write_off, :].set(
+                        v.astype(cache["v"].dtype))
+            with jax.named_scope("attend"):
+                ctx = dap.decode_attention(
+                    q.astype(dtype), cache["k"][i], cache["v"][i],
+                    page_table, lengths, sm_scale=1.0 / math.sqrt(hd),
+                    k_scale=cache["k_scale"][i] if quant else None,
+                    v_scale=cache["v_scale"][i] if quant else None,
+                    impl=decode_impl, block_h=decode_block_h,
+                    interpret=interpret)
+                return ctx.reshape(B, n_heads * hd).astype(dtype)
 
         x = _trunk_layer(x, params["transformer"][f"layer_{i}"],
                          ql[i] if ql is not None else {}, cfg, attn)
 
-    x = _layer_norm(x, params["transformer"]["final_layernorm"],
-                    cfg.layernorm_epsilon)
-    logits = _wmat(x, word,
-                   qparams["word_logits"] if qparams is not None
-                   else None, dtype)
-    next_tokens = jnp.where(
-        active, jnp.argmax(logits.astype(jnp.float32), axis=-1)
-        .astype(jnp.int32), 0)
+    with jax.named_scope("final_norm"):
+        x = _layer_norm(x, params["transformer"]["final_layernorm"],
+                        cfg.layernorm_epsilon)
+    with jax.named_scope("lm_head"):
+        logits = _wmat(x, word,
+                       qparams["word_logits"] if qparams is not None
+                       else None, dtype)
+    with jax.named_scope("sample"):
+        next_tokens = jnp.where(
+            active, jnp.argmax(logits.astype(jnp.float32), axis=-1)
+            .astype(jnp.int32), 0)
     return cache, next_tokens, logits
 
 

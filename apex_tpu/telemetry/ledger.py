@@ -62,7 +62,7 @@ INFRA_KNOB_PREFIXES = (
     "APEX_TELEMETRY_LEDGER", "APEX_TELEMETRY_PATH",
     "APEX_COMPILE_CACHE", "APEX_WARM_ONLY", "APEX_WARM_TIMEOUT",
     "APEX_PROBE_", "APEX_FAULT_PLAN", "APEX_COLLECT_MANIFEST",
-    "APEX_PROFILE_", "APEX_COST_ANALYSIS", "APEX_SERVE_BENCH",
+    "APEX_COST_ANALYSIS", "APEX_SERVE_BENCH",
     "APEX_FLIGHT_",  # flight recorder / supervisor (ISSUE 16): where
                      # beats land + reap thresholds — never the program
 )
@@ -402,14 +402,6 @@ def validate_record(rec):
                     isinstance(ck["last_step"], int)
                     and not isinstance(ck["last_step"], bool)):
                 problems.append("checkpoint.last_step is not an int")
-    prof = rec.get("profile")
-    if prof is not None:
-        # the profiler artifact stamp (telemetry.profiling): a capture
-        # whose hash/extent fields are malformed could pass off an
-        # edited trace as the one the record captured
-        from apex_tpu.telemetry import profiling as _profiling
-
-        problems += _profiling.validate_block(prof)
     cost = rec.get("cost")
     if cost is not None:
         # the attribution block (apex_tpu.telemetry.costs): a malformed

@@ -36,6 +36,7 @@ from apex_tpu.amp.scaler import LossScaler
 from apex_tpu.optimizers.fused_adam import fused_adam
 from apex_tpu.optimizers.fused_lamb import fused_lamb
 from apex_tpu.optimizers.fused_sgd import fused_sgd
+from apex_tpu.telemetry import spans
 from apex_tpu.transformer.parallel_state import DATA_AXIS, TENSOR_AXIS
 from apex_tpu.transformer.testing import (
     BertModel,
@@ -115,7 +116,6 @@ def main(argv=None):
         argv, extra_args_provider=_extra_args,
         world_size=len(devices), ignore_unknown_args=False)
     args.rank = jax.process_index()
-    timers = global_vars.get_timers()
 
     tp = args.tensor_model_parallel_size
     if args.pipeline_model_parallel_size != 1:
@@ -189,22 +189,30 @@ def main(argv=None):
         """n_steps training steps under one dispatch."""
         def local(params, opt_state, scaler_state, ids, pos, labels):
             def body(carry, _):
+                # the scopes name each op's stretch of the step in the
+                # device trace (``tf_op``) and the compiled HLO
                 p, o, ss = carry
-                scale = scaler.scale(jnp.float32(1.0), ss)
-                loss, grads = jax.value_and_grad(fwd_loss)(
-                    p, ids, pos, labels, scale)
-                grads = jax.tree_util.tree_map(
-                    lambda g: lax.pmean(g, DATA_AXIS), grads)
-                grads, found_inf = scaler.unscale(grads, ss)
-                found_inf = lax.pmax(found_inf, TENSOR_AXIS)
-                nss = scaler.update(ss, found_inf)
-                updates, no = tx.update(grads, o, p)
-                np_ = jax.tree_util.tree_map(
-                    lambda a, u: jnp.where(found_inf, a,
-                                           a + u.astype(a.dtype)),
-                    p, updates)
-                no = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(found_inf, old, new), no, o)
+                with jax.named_scope("fwd_bwd"):
+                    scale = scaler.scale(jnp.float32(1.0), ss)
+                    loss, grads = jax.value_and_grad(fwd_loss)(
+                        p, ids, pos, labels, scale)
+                with jax.named_scope("grad_pmean"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: lax.pmean(g, DATA_AXIS), grads)
+                with jax.named_scope("unscale"):
+                    grads, found_inf = scaler.unscale(grads, ss)
+                    found_inf = lax.pmax(found_inf, TENSOR_AXIS)
+                    nss = scaler.update(ss, found_inf)
+                with jax.named_scope("optimizer"):
+                    updates, no = tx.update(grads, o, p)
+                with jax.named_scope("apply_update"):
+                    np_ = jax.tree_util.tree_map(
+                        lambda a, u: jnp.where(found_inf, a,
+                                               a + u.astype(a.dtype)),
+                        p, updates)
+                    no = jax.tree_util.tree_map(
+                        lambda new, old: jnp.where(found_inf, old, new),
+                        no, o)
                 return (np_, no, nss), lax.pmean(loss, DATA_AXIS) / scale
 
             carry, losses = lax.scan(
@@ -221,62 +229,68 @@ def main(argv=None):
 
         return jax.jit(step, donate_argnums=(0, 1, 2))
 
-    params = jax.jit(jax.shard_map(
-        init_fn, mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
-        out_specs=P(), check_vma=False))(ids, pos, labels)
+    # each set-up span ends when its call returns, not when the device
+    # is done: trace + lower + compile or cache load, and the enqueue
+    with spans.span("trainer.setup.init"):
+        params = jax.jit(jax.shard_map(
+            init_fn, mesh=mesh,
+            in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
+            out_specs=P(), check_vma=False))(ids, pos, labels)
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     # the initial state carries the sharding the step returns
     # (replicated over the mesh): fed back as chunk 2's input, the
     # step's outputs then hit chunk 1's executable instead of compiling
     # the same program again under another input sharding
-    opt_state = jax.jit(lambda p: tx.init(p), out_shardings=repl)(params)
-    scaler_state = jax.device_put(
-        jax.tree_util.tree_map(np.asarray, scaler.init()), repl)
+    with spans.span("trainer.setup.opt_init"):
+        opt_state = jax.jit(lambda p: tx.init(p),
+                            out_shardings=repl)(params)
+        scaler_state = jax.device_put(
+            jax.tree_util.tree_map(np.asarray, scaler.init()), repl)
 
     # --- checkpoint/resume (reference checkpointing args :646-669) ---
     start_iter = 0
     if args.load:
-        from apex_tpu import checkpoint as ckpt_mod
+        with spans.span("trainer.setup.load"):
+            from apex_tpu import checkpoint as ckpt_mod
 
-        # restore directly onto the replicated mesh sharding (a plain
-        # concrete template would inherit whatever mix of committed
-        # devices each state happened to be created on)
-        with ckpt_mod.CheckpointManager(args.load) as lm:
-            step0 = lm.latest_step()
-            # keys None = metadata unreadable → optimistically try the
-            # full restore (a failure there surfaces, as it should)
-            keys = lm.tree_keys(step0) if step0 is not None else None
-            # --finetune loads weights ONLY (megatron semantics): a
-            # restored optimizer count would pin the lr schedule at the
-            # old run's decay floor
-            full = (step0 is not None and not args.no_load_optim
-                    and not args.finetune
-                    and (keys is None or "opt" in keys))
-            if step0 is not None and full:
-                tmpl = {"params": ckpt_mod.abstract_like(params, repl),
-                        "opt": ckpt_mod.abstract_like(opt_state, repl),
-                        "scaler": ckpt_mod.abstract_like(scaler_state,
-                                                         repl)}
-                restored = lm.restore(step0, tmpl)
-                params = restored["params"]
-                opt_state = restored["opt"]
-                scaler_state = restored["scaler"]
-            elif step0 is not None:
-                # params-only: checkpoint was written with
-                # --no-save-optim, or --no-load-optim was passed
-                # (megatron's warn-and-continue posture)
-                if (args.rank == 0 and not args.no_load_optim
-                        and not args.finetune):
-                    # reached without an explicit weights-only flag: the
-                    # checkpoint itself lacks the opt subtree
-                    print("checkpoint has no optimizer state (saved with "
-                          "--no-save-optim); loading params only",
-                          flush=True)
-                params = lm.restore(
-                    step0,
-                    {"params": ckpt_mod.abstract_like(params, repl)},
-                    partial=True)["params"]
+            # restore directly onto the replicated mesh sharding (a plain
+            # concrete template would inherit whatever mix of committed
+            # devices each state happened to be created on)
+            with ckpt_mod.CheckpointManager(args.load) as lm:
+                step0 = lm.latest_step()
+                # keys None = metadata unreadable → optimistically try the
+                # full restore (a failure there surfaces, as it should)
+                keys = lm.tree_keys(step0) if step0 is not None else None
+                # --finetune loads weights ONLY (megatron semantics): a
+                # restored optimizer count would pin the lr schedule at the
+                # old run's decay floor
+                full = (step0 is not None and not args.no_load_optim
+                        and not args.finetune
+                        and (keys is None or "opt" in keys))
+                if step0 is not None and full:
+                    tmpl = {"params": ckpt_mod.abstract_like(params, repl),
+                            "opt": ckpt_mod.abstract_like(opt_state, repl),
+                            "scaler": ckpt_mod.abstract_like(scaler_state,
+                                                             repl)}
+                    restored = lm.restore(step0, tmpl)
+                    params = restored["params"]
+                    opt_state = restored["opt"]
+                    scaler_state = restored["scaler"]
+                elif step0 is not None:
+                    # params-only: checkpoint was written with
+                    # --no-save-optim, or --no-load-optim was passed
+                    # (megatron's warn-and-continue posture)
+                    if (args.rank == 0 and not args.no_load_optim
+                            and not args.finetune):
+                        # reached without an explicit weights-only flag: the
+                        # checkpoint itself lacks the opt subtree
+                        print("checkpoint has no optimizer state (saved with "
+                              "--no-save-optim); loading params only",
+                              flush=True)
+                    params = lm.restore(
+                        step0,
+                        {"params": ckpt_mod.abstract_like(params, repl)},
+                        partial=True)["params"]
         if step0 is None:
             # the Megatron posture: warn loudly, start from scratch
             if args.rank == 0:
@@ -326,45 +340,57 @@ def main(argv=None):
     tokens_per_sec = 0.0
     compile_and_run = None
     chunks = []
-    timers("interval-time").start()
+    mark = time.perf_counter()   # where the chunk before ended
     while done < args.train_iters:
-        params, opt_state, scaler_state, losses = run_chunk(
-            params, opt_state, scaler_state, ids, pos, labels)
-        # fetching the chunk's losses waits for the device
-        losses = np.asarray(losses)
-        last_loss = float(losses[-1])
-        done += log_n
-        # save when a multiple of save_interval falls inside this chunk
-        # (correct on any chunk grid, aligned or not)
-        if args.save_interval and done % args.save_interval < log_n:
-            save_state(done)
-        elapsed = timers("interval-time").elapsed()
-        # per-chunk record for callers that check a run (chip_smoke.py):
-        # wall seconds, when the chunk ended, how many executables the
-        # step has compiled so far (1 once warm), and the bytes each
-        # local device holds while the train state is live (None where
-        # the backend keeps no memory stats)
-        chunks.append({
-            "iter": done, "losses": losses.tolist(), "seconds": elapsed,
-            "t_end": time.perf_counter(),
-            "programs": run_chunk._cache_size(),
-            "bytes_in_use": {
-                str(d.id): (d.memory_stats() or {}).get("bytes_in_use")
-                for d in jax.local_devices()}})
-        if first_chunk:
-            first_chunk = False
-            # first chunk includes compile; don't count it in throughput
-            compile_and_run = elapsed
-            if args.rank == 0:
-                print(f" iter {done}: loss {last_loss:.4f} "
-                      f"(first chunk incl. compile {compile_and_run:.1f}s)",
-                      flush=True)
-            continue
-        tokens_per_sec = log_n * dp * b_local * s / elapsed
-        if args.rank == 0:
-            print(f" iter {done}: loss {last_loss:.4f}  "
-                  f"{tokens_per_sec:,.0f} tokens/s  "
-                  f"({elapsed/log_n*1e3:.1f} ms/iter)", flush=True)
+        with spans.span("trainer.chunk", steps=log_n) as chunk:
+            with spans.span("chunk.dispatch"):
+                # returns once the chunk is enqueued; the first call
+                # holds trace + lower + compile or cache load
+                params, opt_state, scaler_state, losses = run_chunk(
+                    params, opt_state, scaler_state, ids, pos, labels)
+            with spans.span("chunk.fetch"):
+                # fetching the chunk's losses waits for the device
+                losses = np.asarray(losses)
+            with spans.span("chunk.host"):
+                last_loss = float(losses[-1])
+                done += log_n
+                # save when a multiple of save_interval falls inside
+                # this chunk (correct on any chunk grid, aligned or not)
+                if args.save_interval and done % args.save_interval < log_n:
+                    save_state(done)
+                now = time.perf_counter()
+                elapsed, mark = now - mark, now
+                # per-chunk record for callers that check a run
+                # (chip_smoke.py): wall seconds since the chunk before
+                # was fetched and saved, when the chunk ended, how many
+                # executables the step has compiled so far (1 once
+                # warm), and the bytes each local device holds while
+                # the train state is live (None where the backend keeps
+                # no memory stats)
+                chunks.append({
+                    "iter": done, "losses": losses.tolist(),
+                    "seconds": elapsed, "t_end": now,
+                    "programs": run_chunk._cache_size(),
+                    "bytes_in_use": {
+                        str(d.id): (d.memory_stats() or {}).get(
+                            "bytes_in_use")
+                        for d in jax.local_devices()}})
+                chunk.set(iter=done, programs=chunks[-1]["programs"])
+                if first_chunk:
+                    first_chunk = False
+                    # first chunk includes compile; don't count it in
+                    # throughput
+                    compile_and_run = elapsed
+                    if args.rank == 0:
+                        print(f" iter {done}: loss {last_loss:.4f} "
+                              f"(first chunk incl. compile "
+                              f"{compile_and_run:.1f}s)", flush=True)
+                    continue
+                tokens_per_sec = log_n * dp * b_local * s / elapsed
+                if args.rank == 0:
+                    print(f" iter {done}: loss {last_loss:.4f}  "
+                          f"{tokens_per_sec:,.0f} tokens/s  "
+                          f"({elapsed/log_n*1e3:.1f} ms/iter)", flush=True)
     if tokens_per_sec == 0.0 and compile_and_run:
         # single-chunk run: report throughput from the compile chunk rather
         # than a misleading 0 (flagged as compile-inclusive)

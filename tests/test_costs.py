@@ -24,7 +24,7 @@ sys.path.insert(0, REPO)
 
 from apex_tpu import _compat
 from apex_tpu.dispatch import tiles
-from apex_tpu.telemetry import costs, ledger, profiling
+from apex_tpu.telemetry import costs, ledger
 
 
 # ---------------------------------------------------------------- build()
@@ -374,74 +374,6 @@ def test_tiles_model_vmem_and_compare():
                               xla_bytes=None) is None
     assert tiles.compare_vmem("nope", dims, "float32", None,
                               xla_bytes=100) is None
-
-
-# --------------------------------------------------- profiler artifacts
-
-
-def test_artifact_block_hashes_and_tamper_evidence(tmp_path):
-    d = tmp_path / "capture"
-    d.mkdir()
-    (d / "trace.pb").write_bytes(b"abc")
-    (d / "meta.json").write_bytes(b"{}")
-    block = profiling.artifact_block(str(d))
-    assert block["files"] == 2 and block["bytes"] == 5
-    assert profiling.validate_block(block) == []
-    # tamper evidence: editing a file changes the stamped hash
-    (d / "trace.pb").write_bytes(b"abX")
-    assert profiling.artifact_block(str(d))["sha256"] != block["sha256"]
-    # empty/unreadable dir reports zero files, hash None — still valid
-    empty = profiling.artifact_block(str(tmp_path / "nope"))
-    assert empty["files"] == 0 and empty["sha256"] is None
-    assert profiling.validate_block(empty) == []
-
-
-def test_profile_validate_block_teeth():
-    assert profiling.validate_block("x") == ["profile is not a dict"]
-    bad = {"dir": 3, "files": -1, "bytes": "many", "sha256": "short"}
-    problems = profiling.validate_block(bad)
-    assert len(problems) == 4, problems
-    # files without a content hash: the tamper-evidence gap
-    assert profiling.validate_block(
-        {"dir": "d", "files": 2, "bytes": 5, "sha256": None})
-
-
-def test_profile_refusal_under_fault_plan(monkeypatch):
-    monkeypatch.setenv("APEX_FAULT_PLAN", json.dumps({"faults": []}))
-    assert profiling.refusal() is not None
-    monkeypatch.delenv("APEX_FAULT_PLAN")
-    assert profiling.refusal() is None
-
-
-def test_profile_trace_degrades_without_jax_profiler(tmp_path,
-                                                     monkeypatch):
-    """The feature-detect contract: a backend without a working
-    jax.profiler still runs the body (traced=False)."""
-    import jax.profiler as jp
-
-    def boom(*a, **k):
-        raise RuntimeError("no profiler on this backend")
-
-    monkeypatch.setattr(jp, "trace", boom)
-    ran = []
-    with profiling.trace(str(tmp_path)) as traced:
-        ran.append(traced)
-    assert ran == [False]
-
-
-def test_profile_knob_parsing(monkeypatch):
-    monkeypatch.delenv("APEX_PROFILE_TIMEOUT", raising=False)
-    assert profiling.timeout_s() == profiling.DEFAULT_TIMEOUT_S
-    monkeypatch.setenv("APEX_PROFILE_TIMEOUT", "120")
-    assert profiling.timeout_s() == 120
-    monkeypatch.setenv("APEX_PROFILE_TIMEOUT", "bogus")
-    assert profiling.timeout_s() == profiling.DEFAULT_TIMEOUT_S
-    monkeypatch.setenv("APEX_PROFILE_DIR", str("/tmp/x"))
-    assert profiling.profile_root() == "/tmp/x"
-    monkeypatch.setenv("APEX_PROFILE_CAPTURE", "1")
-    assert profiling.requested() is True
-    monkeypatch.setenv("APEX_PROFILE_INNER", "1")
-    assert profiling.capture_active() is True
 
 
 # ------------------------------------------------- ledger inspection CLI

@@ -64,8 +64,6 @@ DESIGNATED_READERS = (
      "tamper-evident stamp: present-vs-absent, value hashed into ids"),
     ("apex_tpu/telemetry/metrics.py", "APEX_TELEMETRY_PATH",
      "metrics sink path"),
-    ("apex_tpu/telemetry/profiling.py", "APEX_PROFILE_DIR",
-     "profile artifact root path"),
     ("apex_tpu/resilience/faults.py", "APEX_FAULT_PLAN",
      "the injection engine: reads the plan json/path itself"),
     ("apex_tpu/parallel/multiproc.py", "APEX_TPU_COORDINATOR",
