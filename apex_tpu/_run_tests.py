@@ -39,7 +39,8 @@ SUITES = {
     "ops": ["test_ops_attention.py", "test_softmax_pallas.py",
             "test_attention_pallas.py", "test_xent_pallas.py",
             "test_mosaic_block_rules.py", "test_tile_params.py",
-            "test_decode_attention_pallas.py"],
+            "test_decode_attention_pallas.py",
+            "test_decode_attention_mosaic.py"],
     "serving": ["test_serving.py", "test_serving_slo.py",
                 "test_serving_generation.py",
                 "test_serving_resilience.py",

@@ -98,10 +98,6 @@ OP_CHOICES = {
     "remat": ("none", "selective", "full"),
     "bench_batch": None,  # any positive int (as str)
     "grad_comm": ("off", "int8", "hier", "int8_hier"),
-    # the FIFTH Pallas family (serving decode, ISSUE 10): the q_len=1
-    # paged-KV kernel (ops/decode_attention_pallas.py) vs the XLA
-    # gather-attention reference path
-    "decode_attention": ("jnp", "pallas"),
     # bucket count of the bucket-interleaved gradient reduction
     # (apex_tpu.overlap, ISSUE 14), keyed on the flat grad payload
     # like "grad_comm" — choice is the count as a string, the

@@ -84,10 +84,10 @@ XENT_MAX_VMEM = 16 * 1024 * 1024
 
 # decode attention (ops/decode_attention_pallas.py — the serving
 # q_len=1 kernel over paged K/V, ISSUE 10): per grid step, block_h
-# heads' K and V page blocks plus the fp32 online-softmax accumulators
-# stay VMEM-resident. The page/head_dim block dims always span their
-# full array axes (the kernel's layout puts them last), so legality
-# here is divisibility of block_h into h plus the working-set budget.
+# heads' K and V page blocks [page_size, block_h, head_dim] plus the
+# fp32 online-softmax accumulators stay VMEM-resident. Heads are the
+# block's second-minor axis, so a block is all of h or whole sublane
+# tiles of it; legality is that, divisibility, and the working set.
 DECODE_VMEM_BUDGET = 8 * 1024 * 1024
 
 PARAM_KEYS = {
@@ -174,7 +174,7 @@ def env_choice(name, allowed):
     else None — an unknown value warns ONCE per (knob, value) and is
     ignored (env knobs are preferences, never raises; per-call
     arguments raise instead). The one implementation behind
-    APEX_DECODE_ATTN_IMPL and APEX_SERVE_WEIGHT_QUANT, so the
+    APEX_SERVE_WEIGHT_QUANT and APEX_SERVE_ARRIVALS, so the
     warn-once-and-ignore semantics cannot drift per module."""
     v = os.environ.get(name)
     if v in (None, ""):
@@ -446,27 +446,45 @@ def _xent_legal(dims, dtype, params):
 
 # ------------------------------------------------------ decode attention
 
+def _decode_sublanes(itembytes):
+    """Rows of one sublane tile at this itemsize (fp32 8, bf16 16,
+    int8 32): what a head block that is not all of h must divide by,
+    heads being the second-minor axis of the kernel's page block."""
+    return SUBLANE * max(1, 4 // itembytes)
+
+
 def decode_vmem_bytes(bh, ps, d, itembytes):
     """Resident set of one decode-attention grid step: block_h heads'
-    K + V page blocks plus the fp32 q row and (acc, m, l) online-softmax
-    accumulators. At the int8 itemsize (the quantized KV tier,
-    ISSUE 20) the per-(page, head) bf16 scale blocks ride as two more
-    operands — 2 bytes per head each — so the model budgets them too."""
-    scales = 2 * bh * 2 if itembytes == 1 else 0
-    return 2 * bh * ps * d * itembytes + 4 * bh * d + 4 * bh * (d + 2) \
-        + scales
+    K and V page blocks ``[ps, bh, d]``, each held twice by the
+    pipeline, as they sit in VMEM (heads padded to the sublane tile,
+    head_dim to the lane width), plus the fp32 q rows and (acc, m, l)
+    online-softmax accumulators. The int8 tier's per-(page, head)
+    scale rows are a few KB and not counted."""
+    sub = _decode_sublanes(itembytes)
+    rows = -(-bh // sub) * sub
+    lanes = -(-d // LANE) * LANE
+    return 4 * ps * rows * lanes * itembytes \
+        + 4 * (-(-bh // SUBLANE) * SUBLANE) * (2 * lanes + 2 * LANE)
+
+
+def _decode_head_block_ok(bh, h, itembytes):
+    return h % bh == 0 and (bh == h
+                            or bh % _decode_sublanes(itembytes) == 0)
 
 
 def decode_block_h(h, ps, d, itembytes):
-    """The decode-attention heuristic: largest power-of-two head block
-    dividing h whose page working set fits the budget (>= 1 — a single
-    head's page block is the kernel's minimum unit; 0 only when even
-    that overflows)."""
-    cap = max(1, DECODE_VMEM_BUDGET // max(1, decode_vmem_bytes(
-        1, ps, d, itembytes)))
-    b = chain_block(h, cap)
-    return b if decode_vmem_bytes(b, ps, d, itembytes) \
-        <= DECODE_VMEM_BUDGET else 0
+    """The decode-attention heuristic: the largest legal head block
+    (all of h, or a divisor of h that is whole sublane tiles) whose
+    page working set fits the budget — every grid step costs the same
+    fixed overhead whatever it moves, so fewer, larger steps win
+    (measured at h=20 on the v5e, PERF.md §6 PR 26). 0 when none
+    fits."""
+    for b in range(h, 0, -1):
+        if _decode_head_block_ok(b, h, itembytes) \
+                and decode_vmem_bytes(b, ps, d, itembytes) \
+                <= DECODE_VMEM_BUDGET:
+            return b
+    return 0
 
 
 def _decode_legal(dims, dtype, params):
@@ -474,15 +492,19 @@ def _decode_legal(dims, dtype, params):
     bh = params.get("block_h")
     problems = []
     if bh is not None:
+        item = itemsize(dtype)
         if not isinstance(bh, int) or bh < 1:
             problems.append(f"block_h={bh!r} must be a positive int")
         elif h % bh:
             problems.append(f"block_h={bh} does not divide h={h}")
-        elif decode_vmem_bytes(bh, ps, d, itemsize(dtype)) \
-                > DECODE_VMEM_BUDGET:
+        elif not _decode_head_block_ok(bh, h, item):
+            problems.append(
+                f"block_h={bh} is neither h={h} nor a multiple of the "
+                f"{_decode_sublanes(item)}-row sublane tile")
+        elif decode_vmem_bytes(bh, ps, d, item) > DECODE_VMEM_BUDGET:
             problems.append(
                 f"block_h={bh}: page working set "
-                f"{decode_vmem_bytes(bh, ps, d, itemsize(dtype))} B "
+                f"{decode_vmem_bytes(bh, ps, d, item)} B "
                 f"exceeds the {DECODE_VMEM_BUDGET} B VMEM budget at "
                 f"ps={ps} d={d}")
     return problems
@@ -638,17 +660,11 @@ def candidates(op, dims, dtype, max_candidates=8):
     # pow2 neighborhood of the incumbent: /8 .. x4 (tiles far below the
     # VMEM cap re-read the streamed operands proportionally more — a
     # sweep minute is better spent near the cap; the per-call knob can
-    # still request anything legal). decode_attention's head block has
-    # no sublane floor (a single head's page block is the minimum unit).
-    floor = 1 if op == "decode_attention" else SUBLANE
-    b = max(floor, base[key] // 8)
+    # still request anything legal).
+    b = max(SUBLANE, base[key] // 8)
     while b <= base[key] * 4:
         add({key: b})
         b *= 2
-    if op == "decode_attention":
-        # the all-heads-in-one-step tile is the natural upper candidate
-        # even when h is not a power of two (h=12 -> 12)
-        add({key: dims["h"]})
     if op in ("attention", "attention_bwd"):
         # the split k-major block rides the bwd entry: sweep block_k at
         # the heuristic q block where the split pass is eligible at all

@@ -1,6 +1,6 @@
 """Pallas TPU decode attention over a paged KV cache (q_len = 1).
 
-The FIFTH dispatch family (ISSUE 10): serving decode is a genuinely
+The FIFTH kernel family (ISSUE 10): serving decode is a genuinely
 different program shape from every training kernel in ops/ — one query
 row per sequence, the whole cost is streaming the KV cache out of HBM,
 and the cache is PAGED (block-granular allocation,
@@ -12,39 +12,53 @@ per-sequence context lengths ride as SCALAR-PREFETCH operands
 (``pltpu.PrefetchScalarGridSpec``) so the K/V BlockSpec index maps do
 the gather — grid step ``(i, hb, j)`` DMAs page ``page_table[i, j]``
 for ``block_h`` heads directly from the paged arrays; allocation is
-pure index arithmetic, never a reshape. Online-softmax accumulators
-(fp32 m/l/acc) live in VMEM scratch across the sequential page axis;
-pages at or beyond the sequence's context length are skipped
-(``pl.when`` — the padded page-table tail points at the reserved null
-page 0, fetched but never read into the accumulators).
+pure index arithmetic. Online-softmax accumulators (fp32 m/l/acc) live
+in VMEM scratch across the sequential page axis; pages at or beyond
+the sequence's context length are skipped (``pl.when``) and not even
+fetched: past a slot's last page the block index stays where it was.
 
-Scores and the context reduction are computed as broadcast-multiply +
-lane reductions rather than 1-row MXU matmuls: with q_len = 1 the MXU
-would idle on a [1, d] operand anyway, and decode is bandwidth-bound —
-the VPU keeps pace with the DMA stream.
+The kernel reads the cache where XLA keeps it. A decode step scatters
+``[slots, h, d]`` rows into ``cache[layer, :, page, offset, :]``, and
+for that scatter XLA holds the cache with (h, d) as the minor tile —
+physically ``[layers, pages, page_size, h, d]``. The kernel takes that
+view (a transpose that is a bitcast there) and the layer as a block
+coordinate, so the engine's stacked float cache reaches the custom
+call with no copy; a page is a ``[page_size, block_h, d]`` block,
+heads on sublanes. Measured on the v5e at GPT-2 large, 16 slots
+(PERF.md §6, PR 26): row-major ``[block_h, page_size, d]`` blocks cost
+a relayout of every layer's slice every step (22.7 ms a round beside a
+5.1 ms kernel), and the whole stacked array in that form makes the
+compiler copy the whole cache once per layer.
 
-Dispatch (the same shape as the four existing families):
+Scores and the context reduction are broadcast-multiplies and
+reductions on the VPU: with q_len = 1 a product is one weight load per
+(head, page) on the MXU, not a stream. Measured there too: 6.2 ms a
+round against a 3.6 ms byte floor at GPT-2 large, and head-batched
+``dot_general`` products were within 5% of the VPU body.
+
+Which program runs (no knob):
 
     per-call ``impl=`` (raises on un-honorable)
-      > ``set_decode_impl`` / ``APEX_DECODE_ATTN_IMPL`` (fall back)
-      > dispatch-table entry (op "decode_attention")
-      > built-in ``jnp``
+      > the kernel, where the default backend is a TPU and
+        :func:`supported` holds for the cache geometry
+      > the jnp reference (the CPU; an unsupported geometry)
 
-The built-in default is the XLA gather-attention reference
-(:func:`decode_attention_reference`) per the measured-dispatch rule —
-no device A/B has landed for this family yet (queued in PERF.md §2);
-the Pallas kernel engages via knob or a measured table entry. Tile
-axis: ``block_h`` (heads per grid step), judged by
-``apex_tpu.dispatch.tiles`` (op "decode_attention") with the usual
-asymmetry — per-call raises, setter/env/table fall back per shape.
+Tile axis: ``block_h`` (heads per grid step), judged by
+``apex_tpu.dispatch.tiles`` (op "decode_attention"): all of h, or a
+divisor of h that is whole sublane tiles; the heuristic takes the
+largest that fits VMEM (every grid step costs the same fixed overhead).
+Per-call ``block_h=`` raises when illegal; ``set_block_h`` /
+``APEX_DECODE_ATTN_BLOCK_H`` are preferences that fall back per shape.
 
 Layouts:
   q                [b, h, d]          (one query row per sequence slot)
-  k_pages/v_pages  [h, pages, page_size, d]
+  k_pages/v_pages  [h, pages, page_size, d], or the engine's stacked
+                   [layers, h, pages, page_size, d] with ``layer=``
   page_table       [b, max_pages]     int32 (padding -> null page 0)
   lengths          [b]                int32 (0 = inactive slot -> 0 out)
   k_scale/v_scale  [h, pages]         per-(page, head) scales of the
                                       int8 KV tier (ISSUE 20), or None
+                                      (stacked like the pages)
 
 int8 KV tier (serving.kv_tier): when the pages are int8 codes, the
 per-(page, head) scales are gathered through the page table by XLA
@@ -52,11 +66,11 @@ per-(page, head) scales are gathered through the page table by XLA
 resident fp32 ``[block_h, max_pages]`` row block per (slot, head
 block). Both impls dequantize at read — the kernel scales the scores
 and the context sum per head rather than the page, so no dequantized
-page copy is ever materialized. The VMEM model budgets the scale
-blocks at the int8 itemsize (tiles.decode_vmem_bytes).
+page copy is ever materialized.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -69,28 +83,7 @@ from apex_tpu.dispatch import tiles
 NEG_INF = -1e30  # python float: jnp scalars would be captured consts
                  # inside the pallas kernel (Mosaic requires operands)
 
-# Process-wide impl preference (tri-state; falls back per shape — only
-# per-call impl= raises on un-honorable requests, CLAUDE.md asymmetry)
-_IMPL = None
-
-
-def set_decode_impl(impl):
-    """Pin the process-wide decode-attention impl preference ("jnp" |
-    "pallas"), or un-pin with None (env/table/built-in apply again).
-    Shapes the pinned kernel can't run fall back to the jnp reference
-    silently; a setter CALL with an unknown impl still raises."""
-    global _IMPL
-    if impl not in (None, "jnp", "pallas"):
-        raise ValueError(f"unknown decode-attention impl {impl!r}")
-    _IMPL = impl
-
-
-def _env_impl():
-    """APEX_DECODE_ATTN_IMPL preference (tiles.env_choice: unknown
-    values warn once and are ignored — an env knob is a preference,
-    never a raise)."""
-    return tiles.env_choice("APEX_DECODE_ATTN_IMPL", ("jnp", "pallas"))
-
+KERNEL_NAME = "paged_decode_attention"  # the device op's name in a trace
 
 # Process-wide head-block preference (same fall-back semantics as the
 # other families' tile setters)
@@ -119,9 +112,9 @@ def supported(h, pages, page_size, d, dtype=None):
             and tiles.decode_block_h(h, page_size, d, itembytes) != 0)
 
 
-def _pick_bh(h, ps, d, dtype, block_h, tile_pref):
+def _pick_bh(h, ps, d, dtype, block_h):
     """Effective head block: per-call (raises via the shared model) >
-    setter/env (fall back) > table pref (falls back) > heuristic."""
+    setter/env (fall back) > heuristic."""
     dims = {"b": 1, "h": h, "pages": 1, "ps": ps, "d": d}
     if block_h is not None:
         problems = tiles.legal("decode_attention", dims, dtype,
@@ -130,10 +123,7 @@ def _pick_bh(h, ps, d, dtype, block_h, tile_pref):
             raise ValueError("decode_attention_pallas: "
                              + "; ".join(problems))
         return block_h
-    prefs = [_BLOCK_H, tiles.env_int("APEX_DECODE_ATTN_BLOCK_H")]
-    if tile_pref:
-        prefs.append(dict(tile_pref).get("block_h"))
-    for p in prefs:
+    for p in (_BLOCK_H, tiles.env_int("APEX_DECODE_ATTN_BLOCK_H")):
         if p is not None and not tiles.legal(
                 "decode_attention", dims, dtype, {"block_h": p}):
             return p
@@ -142,12 +132,13 @@ def _pick_bh(h, ps, d, dtype, block_h, tile_pref):
 
 def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
             scale, ps, n_pages, quant):
-    """Every value keeps the layout it is loaded in — heads on the
-    leading (untiled) axis, page positions on sublanes, head_dim on
-    lanes — so the body is broadcasts and keepdims reductions only.
-    Mosaic refuses the shape casts a 2-D ``[bh, d]`` formulation needs
-    (``[bh, d] <-> [bh, 1, d]`` moves heads between the sublane and the
-    leading axis: "infer-vector-layout: unsupported shape cast")."""
+    """One (slot, head block, page) step. A page arrives as
+    ``[ps, bh, d]``: positions on the leading (untiled) axis, heads on
+    sublanes, head_dim on lanes. The softmax runs over the leading
+    axis, so its max and sums are elementwise across vregs and only
+    the score's contraction over head_dim reduces lanes; nothing is
+    reshaped (Mosaic refuses casts that move heads between the
+    sublane and the leading axis)."""
     if quant:
         ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -165,34 +156,31 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j * ps < length)
     def _page():
-        q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # [bh, 1, d]
-        k = k_ref[:, 0].astype(jnp.float32)                    # [bh, ps, d]
-        v = v_ref[:, 0].astype(jnp.float32)
-        # [bh, ps, 1] scores: sublane-broadcast multiply + lane
-        # reduction (see module docstring — q_len=1 makes the MXU moot)
-        s = jnp.sum(q * k, axis=-1, keepdims=True)
+        q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)  # [bh, d]
+        k = k_ref[...].astype(jnp.float32)                    # [ps, bh, d]
+        s = jnp.sum(q[None] * k, axis=-1, keepdims=True)      # [ps, bh, 1]
         if quant:
             # dequantize at read: this page's per-head scale is lane j
-            # of the resident [bh, 1, n_pages] row block (an iota mask,
-            # no dynamic lane index); one scale per head factors out of
-            # both reductions, so it multiplies [bh, ps, 1] and
-            # [bh, 1, d] instead of the [bh, ps, d] page
+            # of the resident [bh, n_pages] row block (an iota mask, no
+            # dynamic lane index); one scale per head factors out of
+            # both reductions, so it multiplies the [ps, bh, 1] scores
+            # and the [bh, d] context instead of the [ps, bh, d] page
             here = lax.broadcasted_iota(
-                jnp.int32, (k.shape[0], 1, n_pages), 2) == j
-            ks = jnp.sum(jnp.where(here, ks_ref[0, 0], 0.0), axis=-1,
-                         keepdims=True)                        # [bh, 1, 1]
-            vs = jnp.sum(jnp.where(here, vs_ref[0, 0], 0.0), axis=-1,
+                jnp.int32, (q.shape[0], n_pages), 1) == j
+            ks = jnp.sum(jnp.where(here, ks_ref[...], 0.0), axis=-1,
+                         keepdims=True)                        # [bh, 1]
+            vs = jnp.sum(jnp.where(here, vs_ref[...], 0.0), axis=-1,
                          keepdims=True)
-            s = s * ks
-        pos = j * ps + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = s * ks[None]
+        pos = j * ps + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         masked = pos >= length
         s = jnp.where(masked, jnp.float32(NEG_INF), s)
-        m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_scr[...], jnp.max(s, axis=0))   # [bh, 1]
         alpha = jnp.exp(m_scr[...] - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - m_new[None])
         p = jnp.where(masked, 0.0, p)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        ctx = jnp.sum(p * v, axis=1, keepdims=True)            # [bh, 1, d]
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=0)
+        ctx = jnp.sum(p * v_ref[...].astype(jnp.float32), axis=0)  # [bh, d]
         if quant:
             ctx = ctx * vs
         acc_scr[...] = acc_scr[...] * alpha + ctx
@@ -202,21 +190,22 @@ def _kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     def _finish():
         l = l_scr[...]
         o = acc_scr[...] / jnp.where(l > 0, l, 1.0)
-        o_ref[0] = o.astype(o_ref.dtype)
+        o_ref[...] = o.astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
                             sm_scale, *, k_scale=None, v_scale=None,
-                            block_h=None, interpret=False,
-                            tile_pref=None):
+                            layer=None, block_h=None, interpret=False):
     """The Pallas paged-decode kernel (layouts in the module
     docstring). Call :func:`decode_attention` for the dispatched
     surface; this entry raises on unsupported geometry. With
     ``k_scale``/``v_scale`` (``[h, pages]`` — the int8 KV tier) the
     scales of each slot's pages ride as two extra operands and the
-    kernel dequantizes at read."""
+    kernel dequantizes at read. With ``layer`` (a static int) the
+    pages and scales are the engine's stacked ``[layers, ...]`` arrays
+    and the layer is one more coordinate of the K/V block index."""
     b, h, d = q.shape
-    n_pages_total, ps = k_pages.shape[1], k_pages.shape[2]
+    n_pages_total, ps = k_pages.shape[-3], k_pages.shape[-2]
     max_pages = page_table.shape[1]
     quant = k_scale is not None
     if not supported(h, n_pages_total, ps, d, k_pages.dtype):
@@ -225,70 +214,75 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
             f"ps={ps} d={d} ({k_pages.dtype})")
     # judged at the CACHE dtype — the K/V pages are the streamed
     # working set the VMEM model budgets (same itemsize supported()
-    # gates with; the int8 itemsize implies the scale operands, which
-    # tiles.decode_vmem_bytes budgets too)
-    bh = _pick_bh(h, ps, d, k_pages.dtype, block_h, tile_pref)
-    q4 = q[:, :, None, :]                   # [b, h, 1, d]
-    grid = (b, h // bh, max_pages)
+    # gates with)
+    bh = _pick_bh(h, ps, d, k_pages.dtype, block_h)
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+        if quant:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+
+    # [layers, h, pages, ps, d] seen as [layers, pages, ps, h, d]: the
+    # layout XLA keeps a cache in that a step scatters [slots, h, d]
+    # rows into (heads x head_dim as the minor tile), so for the
+    # engine's stacked float cache this transpose is a bitcast and the
+    # kernel reads the cache where it lies. Row-major [.., ps, d]
+    # blocks cost a relayout of every layer's slice, every step.
+    def paged(x):
+        return jnp.transpose(x, (0, 2, 3, 1, 4))
 
     def q_map(i, hb, j, pt, ln):
-        return (i, hb, 0, 0)
+        return (i, hb, 0)
 
     def kv_map(i, hb, j, pt, ln):
-        return (hb, pt[i, j], 0, 0)
+        # past the slot's last page the index stays on it: an
+        # unchanged block index is not fetched again, so the padded
+        # tail of a page table costs no DMA
+        last = jnp.maximum(ln[i] - 1, 0) // ps
+        return (layer, pt[i, jnp.minimum(j, last)], 0, hb, 0)
 
-    def sc_map(i, hb, j, pt, ln):
-        return (i, hb, 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, bh, 1, d), q_map),
-        pl.BlockSpec((bh, 1, ps, d), kv_map),
-        pl.BlockSpec((bh, 1, ps, d), kv_map),
-    ]
-    operands = [q4, k_pages, v_pages]
+    kv_spec = pl.BlockSpec((None, None, ps, bh, d), kv_map)
+    in_specs = [pl.BlockSpec((None, bh, d), q_map), kv_spec, kv_spec]
+    operands = [q, paged(k_pages), paged(v_pages)]
     if quant:
-        # [h, pages] -> [b, h/bh, bh, 1, max_pages]: XLA gathers each
-        # slot's scales through the page table, so the block's last two
-        # dims span their array axes whatever bh is (a (bh, 1, 1) block
-        # over [h, pages, 1] is refused by the Mosaic lowering unless
-        # bh == h), heads sit on the leading axis like the K/V blocks',
-        # and the block index is constant along the page axis — one DMA
-        # per (slot, head block), not one per grid step
+        # [h, pages] -> [b, h, max_pages]: XLA gathers each slot's
+        # scales through the page table (a few KB), heads on sublanes
+        # like the K/V blocks', and the block index is constant along
+        # the page axis — one DMA per (slot, head block)
         def slot_scales(scale):
-            g = scale[:, page_table].astype(jnp.float32)  # [h, b, mp]
-            return g.reshape(h // bh, bh, b, 1, max_pages).transpose(
-                2, 0, 1, 3, 4)
+            return scale[layer][:, page_table].astype(
+                jnp.float32).transpose(1, 0, 2)
 
-        in_specs += [pl.BlockSpec((1, 1, bh, 1, max_pages), sc_map)] * 2
+        in_specs += [pl.BlockSpec((None, bh, max_pages), q_map)] * 2
         operands += [slot_scales(k_scale), slot_scales(v_scale)]
 
     kern = functools.partial(_kernel, scale=float(sm_scale), ps=ps,
                              n_pages=max_pages, quant=quant)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(b, h // bh, max_pages),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bh, 1, d), q_map),
+            out_specs=pl.BlockSpec((None, bh, d), q_map),
             scratch_shapes=[
-                pltpu.VMEM((bh, 1, d), jnp.float32),
-                pltpu.VMEM((bh, 1, 1), jnp.float32),
-                pltpu.VMEM((bh, 1, 1), jnp.float32),
+                pltpu.VMEM((bh, d), jnp.float32),
+                pltpu.VMEM((bh, 1), jnp.float32),
+                pltpu.VMEM((bh, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
-    return out[:, :, 0, :]
 
 
 def decode_attention_reference(q, k_pages, v_pages, page_table,
                                lengths, sm_scale, k_scale=None,
                                v_scale=None):
-    """The jnp gather-attention reference (and the family's built-in
-    default impl): gather each slot's pages, mask past the context
+    """The jnp gather-attention reference (what runs where the kernel
+    cannot: the CPU, an unsupported geometry, a GSPMD-partitioned
+    cache): gather each slot's pages, mask past the context
     length, exact fp32 softmax. Inactive slots (length 0) return 0 —
     the same fully-masked-row semantics as every attention kernel in
     ops/. ``k_scale``/``v_scale`` (``[h, pages]``, the int8 KV tier)
@@ -323,49 +317,51 @@ def decode_attention_reference(q, k_pages, v_pages, page_table,
     return jnp.sum(p[..., None] * v, axis=2).astype(q.dtype)
 
 
-def _effective_impl(impl, q, k_pages, page_table):
-    """``(impl, from_table, tile_pref)``: per-call > setter > env >
-    dispatch-table entry for this cache-geometry bucket > built-in
-    "jnp". A table "pallas" measured on CPU runs in interpret mode —
-    the way it was measured (same contract as ops.attention)."""
+def _effective_impl(impl, h, pages, page_size, d, dtype):
+    """The rule, from what the code can observe: a per-call ``impl`` is
+    a demand; otherwise the Pallas kernel where the default backend is
+    a TPU and :func:`supported` holds for the cache geometry, the jnp
+    reference everywhere else."""
     if impl is not None:
-        return impl, False, None
-    pref = _IMPL or _env_impl()
-    if pref is not None:
-        return pref, False, None
-    from apex_tpu import dispatch
+        return impl
+    if jax.default_backend() == "tpu" and supported(h, pages, page_size,
+                                                    d, dtype):
+        return "pallas"
+    return "jnp"
 
-    b, h, d = q.shape
-    choice, params = dispatch.lookup_params(
-        "decode_attention", dtype=q.dtype, b=b, h=h,
-        pages=page_table.shape[1], ps=k_pages.shape[2], d=d)
-    pref_t = tuple(sorted(params.items())) if params else None
-    if choice:
-        return choice, True, pref_t
-    return "jnp", False, pref_t
+
+def resolved(h, pages, page_size, d, dtype, impl=None, block_h=None):
+    """``(impl, block_h)`` a :func:`decode_attention` call with this
+    cache geometry and these demands runs with (``block_h`` None on
+    the jnp path) — for a caller that reports what it was built
+    with."""
+    eff = _effective_impl(impl, h, pages, page_size, d, dtype)
+    if eff != "pallas":
+        return eff, None
+    return eff, _pick_bh(h, page_size, d, dtype, block_h)
 
 
 def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                      sm_scale=None, k_scale=None, v_scale=None,
-                     impl=None, block_h=None,
-                     interpret=None, tile_pref=None):
+                     layer=None, impl=None, block_h=None,
+                     interpret=None):
     """Dispatched paged decode attention (q: [b, h, d]; pages:
-    [h, P, ps, d]; page_table: [b, max_pages]; lengths: [b]).
+    [h, P, ps, d]; page_table: [b, max_pages]; lengths: [b]). With
+    ``layer`` (a static int) the pages and scales are stacked
+    ``[layers, ...]`` arrays and the call attends that layer's.
 
     ``impl`` is a per-call DEMAND ("jnp" | "pallas"; "pallas" on an
-    unsupported geometry raises); ``set_decode_impl`` /
-    ``APEX_DECODE_ATTN_IMPL`` are preferences that fall back, and an
-    unpinned call consults the dispatch table (op "decode_attention").
-    ``block_h`` is the per-call tile demand (raises when illegal);
-    ``interpret`` defaults to True on the CPU platform only (an
-    explicit argument wins; any other platform compiles the kernel). ``k_scale``/``v_scale``
+    unsupported geometry raises). Unset, the kernel runs where the
+    default backend is a TPU and the geometry is supported, the jnp
+    reference otherwise. ``block_h`` is the per-call tile demand
+    (raises when illegal, and on the jnp path); ``interpret`` defaults
+    to True on the CPU platform only (an explicit argument wins; any
+    other platform compiles the kernel). ``k_scale``/``v_scale``
     (``[h, P]``) engage the int8 KV tier's dequantize-at-read on
     either impl; int8 pages WITHOUT scales raise — codes are
     meaningless without their scales, there is no honorable
     fallback."""
     if sm_scale is None:
-        import math
-
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if impl is not None and impl not in ("jnp", "pallas"):
         raise ValueError(f"unknown decode-attention impl {impl!r}")
@@ -376,34 +372,27 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         raise ValueError(
             "decode_attention: int8 pages without k_scale/v_scale — "
             "quantized codes are meaningless without their scales")
-    eff, from_table, pref_t = _effective_impl(impl, q, k_pages,
-                                              page_table)
-    if tile_pref:
-        merged = dict(pref_t or ())
-        merged.update(dict(tile_pref))
-        pref_t = tuple(sorted(merged.items()))
-    b, h, d = q.shape
-    ok = supported(h, k_pages.shape[1], k_pages.shape[2], d,
-                   k_pages.dtype)
-    if eff == "pallas" and not ok and impl == "pallas":
-        raise ValueError(
-            f"decode_attention: impl='pallas' cannot be honored for "
-            f"h={h} ps={k_pages.shape[2]} d={d}")
-    if eff == "pallas" and ok:
+    h, d = q.shape[1:]
+    pages, ps = k_pages.shape[-3:-1]
+    eff = _effective_impl(impl, h, pages, ps, d, k_pages.dtype)
+    if eff == "pallas":
+        # (a demand on a geometry the kernel does not support raises
+        # in decode_attention_pallas; the rule never picks one)
         if interpret is None:
             interpret = jax.devices()[0].platform == "cpu"
         return decode_attention_pallas(
             q, k_pages, v_pages, page_table, lengths, sm_scale,
-            k_scale=k_scale, v_scale=v_scale,
-            block_h=block_h, interpret=interpret, tile_pref=pref_t)
-    # the jnp path is what actually runs from here on: an explicit
-    # per-call tile demand cannot be honored on it, whatever
-    # preference resolved the impl (a "pallas" setter/table choice
-    # that fell back on unsupported geometry included) — per-call
-    # raises, preferences fall back
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
+            block_h=block_h, interpret=interpret)
+    # a per-call tile demand cannot be honored on the jnp path,
+    # whether the rule or the caller chose it
     if block_h is not None:
         raise ValueError("decode_attention: block_h tiles the pallas "
                          "kernel; it cannot be honored on the jnp path")
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[layer], v_scale[layer]
     return decode_attention_reference(q, k_pages, v_pages, page_table,
                                       lengths, sm_scale,
                                       k_scale=k_scale, v_scale=v_scale)

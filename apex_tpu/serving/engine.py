@@ -62,7 +62,11 @@ Knob resolution at engine build (the CLAUDE.md asymmetry):
   back per shape.
 * ``decode_impl=`` / ``decode_block_h=`` ride per-call into the
   decode-attention family on every step (raising semantics live
-  there); None defers to the family's setter/env/table resolution.
+  there); None defers to the family's rule (the paged Pallas kernel
+  on a TPU where it supports the cache geometry, the jnp reference
+  otherwise; ``tp > 1`` passes ``"jnp"``). ``engine.decode_attn_impl``
+  / ``.decode_attn_block_h`` say what the decode program was built
+  with.
 * ``policy=`` per-call unknown policies RAISE
   (``scheduler.resolve_policy``); None defers to ``APEX_SERVE_SCHED``
   (vocabulary ``fifo`` | ``priority``).
@@ -177,6 +181,7 @@ import numpy as np
 
 from apex_tpu import compile_cache
 from apex_tpu import resilience as res_mod
+from apex_tpu.ops import decode_attention_pallas as dap
 from apex_tpu.resilience import faults as faults_mod
 from apex_tpu.serving import kv_tier as kv_tier_mod
 from apex_tpu.serving import lifecycle
@@ -250,6 +255,12 @@ class ServingEngine:
             tp, n_heads=cfg.num_attention_heads)
         self.qparams = smodel.quantize_decode_params(
             self.params, cfg) if self.weight_quant else None
+        # a tensor-parallel engine partitions the decode jaxpr by GSPMD
+        # from a head-sharded cache (serving/tp.py); a pallas_call is
+        # not partitionable that way, so tp > 1 takes the jnp
+        # reference unless the caller demanded otherwise
+        if decode_impl is None and self.tp > 1:
+            decode_impl = "jnp"
         self.decode_impl = decode_impl
         self.decode_block_h = decode_block_h
         self.interpret = interpret
@@ -470,6 +481,16 @@ class ServingEngine:
         decode_kw = dict(cfg=cfg, decode_impl=self.decode_impl,
                          decode_block_h=self.decode_block_h,
                          interpret=self.interpret)
+        # which decode-attention program the decode program is built
+        # with ("pallas" | "jnp") and its head block (None on jnp);
+        # the decode.dispatch span carries both
+        _, _, pages, ps, hd = self.cache["k"].shape
+        self.decode_attn_impl, self.decode_attn_block_h = dap.resolved(
+            cfg.num_attention_heads, pages, ps, hd,
+            self.cache["k"].dtype, self.decode_impl, self.decode_block_h)
+        self._dispatch_attrs = {"prefill": {}, "decode": dict(
+            attn_impl=self.decode_attn_impl,
+            block_h=self.decode_attn_block_h)}
 
         # the decode program: at K=1 the single decode step; at K>1 the
         # ONE lax.scan K-block program replaces it (K is static — at
@@ -674,7 +695,8 @@ class ServingEngine:
         def call():
             # the call until it returns its futures (with ``recover``
             # on, the fetch too: it is inside the watchdog)
-            with spans.span(f"{program}.dispatch"):
+            with spans.span(f"{program}.dispatch",
+                            **self._dispatch_attrs[program]):
                 faults_mod.fire(site, tick=self.tick,
                                 step=self.decode_steps,
                                 call=self.prefill_batches)

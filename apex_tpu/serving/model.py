@@ -314,7 +314,8 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
     ``qparams`` (from :func:`quantize_decode_params`) switches the
     decode matmuls to the int8 records; ``decode_impl`` /
     ``decode_block_h`` ride per-call into the decode-attention family
-    (None = the family's own knob/table resolution).
+    (None = the family's own rule: the Pallas kernel on a TPU where it
+    supports the geometry, the jnp reference otherwise).
     """
     from apex_tpu.ops import decode_attention_pallas as dap
 
@@ -363,13 +364,26 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
                         i, :, write_page, write_off, :].set(
                         v.astype(cache["v"].dtype))
             with jax.named_scope("attend"):
+                # the float cache goes in whole, the layer as an
+                # index: the kernel reads the stacked array where it
+                # lies (a per-layer slice handed to its custom call is
+                # a copy XLA has to make, 72 a round). The int8 tier
+                # hands over its layer's slice: its page-rewrite codec
+                # makes XLA keep the codes in another layout, from
+                # which the whole stacked array would be re-laid for
+                # every layer, and one layer's slice is the bounded cost
+                if quant:
+                    kv = dict(k_pages=cache["k"][i], v_pages=cache["v"][i],
+                              k_scale=cache["k_scale"][i],
+                              v_scale=cache["v_scale"][i])
+                else:
+                    kv = dict(k_pages=cache["k"], v_pages=cache["v"],
+                              layer=i)
                 ctx = dap.decode_attention(
-                    q.astype(dtype), cache["k"][i], cache["v"][i],
-                    page_table, lengths, sm_scale=1.0 / math.sqrt(hd),
-                    k_scale=cache["k_scale"][i] if quant else None,
-                    v_scale=cache["v_scale"][i] if quant else None,
+                    q.astype(dtype), page_table=page_table,
+                    lengths=lengths, sm_scale=1.0 / math.sqrt(hd),
                     impl=decode_impl, block_h=decode_block_h,
-                    interpret=interpret)
+                    interpret=interpret, **kv)
                 return ctx.reshape(B, n_heads * hd).astype(dtype)
 
         x = _trunk_layer(x, params["transformer"][f"layer_{i}"],

@@ -71,8 +71,7 @@ from benchmarks.autotune_steps import FLIP_MARGIN, _upsert_entry  # noqa: E402
 # the kernel each family's tile sweep measures — and the choice a FRESH
 # table entry records (an existing entry keeps its step-level choice)
 FAMILY_CHOICE = {"attention": "rows", "layer_norm": "pallas",
-                 "softmax": "pallas", "lm_head": "fused",
-                 "decode_attention": "pallas"}
+                 "softmax": "pallas", "lm_head": "fused"}
 
 
 def sweep_groups(smoke):
@@ -93,8 +92,6 @@ def sweep_groups(smoke):
                  dims=dict(b=1, h=4, sq=256, sk=256)),
             dict(op="lm_head", dtype="bfloat16",
                  dims=dict(n=512, v=1024, h=256)),
-            dict(op="decode_attention", dtype="bfloat16",
-                 dims=dict(b=4, h=4, pages=4, ps=64, d=64)),
         ]
     return [
         dict(op="attention", dtype="bfloat16",
@@ -109,10 +106,6 @@ def sweep_groups(smoke):
              dims=dict(b=8, h=12, sq=1024, sk=1024)),
         dict(op="lm_head", dtype="bfloat16",
              dims=dict(n=8192, v=50304, h=768)),
-        # the serving decode shape (benchmarks/profile_serving.py:
-        # 8 slots x GPT-2-small heads over 128-token pages)
-        dict(op="decode_attention", dtype="bfloat16",
-             dims=dict(b=8, h=12, pages=8, ps=128, d=64)),
     ]
 
 
@@ -234,32 +227,6 @@ def _child_program(op, dims, dtype, params, interpret):
             return body
 
         return make_body, q0, (k0, v0)
-
-    if op == "decode_attention":
-        from apex_tpu.ops import decode_attention_pallas as dap
-
-        b, h, pages, ps, d = (dims[k] for k in
-                              ("b", "h", "pages", "ps", "d"))
-        total = b * pages + 1  # every slot's table distinct + null 0
-        q0 = jnp.asarray(rs.randn(b, h, d), jdt)
-        kp0 = jnp.asarray(rs.randn(h, total, ps, d), jdt)
-        vp0 = jnp.asarray(rs.randn(h, total, ps, d), jdt)
-        pt0 = jnp.asarray(
-            rs.permutation(np.arange(1, total))[:b * pages].reshape(
-                b, pages), jnp.int32)
-        len0 = jnp.full((b,), pages * ps, jnp.int32)
-
-        def make_body(eps, kp0, vp0, pt0, len0):
-            def body(q, _):
-                # inference kernel: fwd only, chained through q
-                y = dap.decode_attention_pallas(
-                    q, kp0, vp0, pt0, len0, 1.0 / float(np.sqrt(d)),
-                    block_h=params.get("block_h"), interpret=interpret)
-                return (q + eps.astype(q.dtype)
-                        * y.astype(q.dtype)), ()
-            return body
-
-        return make_body, q0, (kp0, vp0, pt0, len0)
 
     if op == "lm_head":
         from apex_tpu.ops import xent_pallas as xp

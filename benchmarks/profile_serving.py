@@ -30,7 +30,7 @@ Two evidence classes in one Tracer run (ISSUE 10):
 The ledger record carries the validated ``serving`` block
 ``{tokens_per_s, p50_ms, p99_ms, trace_id, kv_pages}`` and the
 ``slo`` block (``ledger.validate_record``) and PINS every shaping
-knob — ``APEX_SERVE_WEIGHT_QUANT``, ``APEX_DECODE_ATTN_IMPL``,
+knob — ``APEX_SERVE_WEIGHT_QUANT``,
 ``APEX_SERVE_KV_QUANT``, ``APEX_SERVE_KV_SWAP`` (check 8),
 ``APEX_SERVE_SLO_TTFT_MS``, ``APEX_SERVE_SLO_TPOT_MS``,
 ``APEX_SERVE_ARRIVALS``, ``APEX_SERVE_SCHED`` (check 9) — at their
@@ -64,7 +64,7 @@ from apex_tpu.telemetry import flight  # noqa: E402
 
 flight.beat("proc_start")  # ISSUE 16: no-op unless APEX_FLIGHT_DIR
 
-from apex_tpu import compile_cache, dispatch  # noqa: E402
+from apex_tpu import compile_cache  # noqa: E402
 from apex_tpu.dispatch import tiles as _tiles  # noqa: E402
 from apex_tpu.serving import (  # noqa: E402
     ServingEngine,
@@ -97,33 +97,14 @@ else:
         apply_query_key_layer_scaling=False, bf16=True)
     SLOTS, PS, PAGES, MAX_SEQ, PRE_LEN = 8, 128, 72, 1024, 512
 
-MAX_PAGES = -(-MAX_SEQ // PS)
-
 # ---------------------------------------------------------------- pins
-# Resolve BOTH serving dispatch knobs and pin them into the
+# Resolve the serving weight-quant knob and pin it into the
 # environment BEFORE anything traces: the ledger record's knobs then
 # carry exactly the values the measured program ran under (check 8),
 # and the engine's own resolution (env > table > built-in) reads the
 # very same pins — label and program cannot drift apart.
 WQ = quant_mod.resolve()
 os.environ["APEX_SERVE_WEIGHT_QUANT"] = "1" if WQ else "0"
-IMPL = os.environ.get("APEX_DECODE_ATTN_IMPL")
-if IMPL not in ("jnp", "pallas"):
-    choice, tparams = dispatch.lookup_params(
-        "decode_attention", dtype=jnp.bfloat16, b=SLOTS,
-        h=cfg.num_attention_heads, pages=MAX_PAGES, ps=PS,
-        d=cfg.head_dim)
-    IMPL = choice or "jnp"
-    # pinning the impl env SHORT-CIRCUITS the kernel's table consult,
-    # which would silently drop the same entry's measured block_h tile
-    # — the bench would then time a different program than unpinned
-    # dispatch runs. Pin the tile payload alongside the impl (and into
-    # the record's knobs), so label and program stay one thing.
-    if tparams and tparams.get("block_h") \
-            and not os.environ.get("APEX_DECODE_ATTN_BLOCK_H"):
-        os.environ["APEX_DECODE_ATTN_BLOCK_H"] = str(
-            tparams["block_h"])
-os.environ["APEX_DECODE_ATTN_IMPL"] = IMPL
 
 # ...and the SLO label's knobs (ISSUE 11, check 9): arrival process,
 # thresholds and scheduler policy resolved ONCE here and pinned back
@@ -226,6 +207,7 @@ os.environ["APEX_SERVE_SLO_TPOT_MS"] = repr(SLO_TPOT_MS)
 engine = ServingEngine(cfg, num_slots=SLOTS, page_size=PS,
                        num_pages=PAGES, max_seq=MAX_SEQ,
                        prefill_len=PRE_LEN)
+IMPL = engine.decode_attn_impl
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(engine.params))
 TRACER = Tracer(K)
 flight.beat("backend_init")  # Tracer measured overhead => backend is up

@@ -48,7 +48,7 @@ def test_dry_run_passes_is_marked_and_uses_the_placed_cache(tmp_path):
     assert record["dry_run"] is True
     assert set(record["phases"]) == {"trainer", "server", "kernels"}
     assert record["phases"]["trainer"]["compiles_after_warmup"] == 0
-    for engine in ("default", "pallas"):
+    for engine in ("default", "jnp"):
         assert record["phases"]["server"][engine][
             "compiles_after_warmup"] == 0
     agreement = record["phases"]["server"]["agreement"]

@@ -160,8 +160,8 @@ def test_decode_scatter_quant_rmw_preserves_and_zeroes():
 # -------------------------------- dequantize-at-read attention parity
 
 
-def _attn_data(seed=3):
-    B, H, P, PS, D, MAXP = 4, 4, 16, 32, 64, 4
+def _attn_data(seed=3, H=4):
+    B, P, PS, D, MAXP = 4, 16, 32, 64, 4
     rs = np.random.RandomState(seed)
     q = jnp.asarray(rs.randn(B, H, D), jnp.float32)
     kf = rs.randn(H, P, PS, D).astype(np.float32)
@@ -179,9 +179,11 @@ def _attn_data(seed=3):
             v_scale, pt, lens, sm)
 
 
-@pytest.mark.parametrize("bh", [1, 2, 4])
-def test_decode_attention_int8_parity_across_block_h(bh):
-    (q, kf, vf, k8, v8, ks, vs, pt, lens, sm) = _attn_data()
+@pytest.mark.parametrize("h,bh", [(4, 4), (64, 32), (64, 64)])
+def test_decode_attention_int8_parity_across_block_h(h, bh):
+    # heads are the page block's second-minor axis: a block is all of
+    # h or whole 32-row int8 sublane tiles of it
+    (q, kf, vf, k8, v8, ks, vs, pt, lens, sm) = _attn_data(H=h)
     ref8 = dap.decode_attention_reference(q, k8, v8, pt, lens, sm,
                                           k_scale=ks, v_scale=vs)
     got = dap.decode_attention_pallas(q, k8, v8, pt, lens, sm,

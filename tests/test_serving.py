@@ -338,7 +338,6 @@ def test_check8_unpinned_serving_row_fails(tmp_path):
     out = run_check_bench_labels(*_check8_env(tmp_path, {}))
     assert out.returncode == 1
     assert "APEX_SERVE_WEIGHT_QUANT" in out.stdout
-    assert "APEX_DECODE_ATTN_IMPL" in out.stdout
     # multi-token decode blocks (ISSUE 17): the block size is a third
     # compiled-program axis the citation must pin
     assert "APEX_SERVE_DECODE_K" in out.stdout
@@ -353,7 +352,6 @@ def test_check8_pinned_serving_row_clean(tmp_path):
 
     out = run_check_bench_labels(*_check8_env(
         tmp_path, {"APEX_SERVE_WEIGHT_QUANT": "0",
-                   "APEX_DECODE_ATTN_IMPL": "jnp",
                    "APEX_SERVE_DECODE_K": "1",
                    "APEX_SERVE_KV_QUANT": "0",
                    "APEX_SERVE_KV_SWAP": "0"}))
@@ -394,8 +392,6 @@ def test_profile_serving_smoke_emits_validated_row(tmp_path):
     assert sv["tokens_per_s"] > 0 and sv["p50_ms"] <= sv["p99_ms"]
     assert sv["trace_id"].startswith("tr-") and sv["kv_pages"] > 0
     assert rec["knobs"].get("APEX_SERVE_WEIGHT_QUANT") in ("0", "1")
-    assert rec["knobs"].get("APEX_DECODE_ATTN_IMPL") in ("jnp",
-                                                         "pallas")
     # ISSUE 11: the slo block, its pins, and the overlap stamp
     slo = rec["slo"]
     assert slo["arrival_process"] == rec["knobs"]["APEX_SERVE_ARRIVALS"]

@@ -672,7 +672,6 @@ def test_serving_block_generation_field_teeth():
 
 
 BASE_PINS = {"APEX_SERVE_WEIGHT_QUANT": "0",
-             "APEX_DECODE_ATTN_IMPL": "jnp",
              # ISSUE 17: serving rows must also pin the decode block
              # size (check 8 — an unpinned K cannot be audited)
              "APEX_SERVE_DECODE_K": "1",

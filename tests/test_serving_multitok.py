@@ -394,7 +394,6 @@ def _record(decode_block_k, **knobs):
     from tests.test_serving_slo import SLO_PINS, _good_slo
 
     pins = {"APEX_SERVE_WEIGHT_QUANT": "0",
-            "APEX_DECODE_ATTN_IMPL": "jnp",
             "APEX_SERVE_KV_QUANT": "0", "APEX_SERVE_KV_SWAP": "0",
             **SLO_PINS, **knobs}
     slo = dict(_good_slo(), decode_block_k=decode_block_k)

@@ -121,9 +121,6 @@ DESIGNATED_READERS = (
      "label pin (tri-state mirror of _knobs)"),
     ("benchmarks/profile_gpt.py", "APEX_CKPT_DIR",
      "durability arming path (same pattern as bench.py)"),
-    ("benchmarks/profile_serving.py", "APEX_DECODE_ATTN_*",
-     "pin-riding: reads the incoming pin to stamp the RESOLVED "
-     "values back into the env and the record's knobs (check 8)"),
     ("benchmarks/warm_cache.py", "APEX_COLLECT_MANIFEST",
      "manifest-path handoff from probe_and_collect.sh"),
     # flight recorder + supervisor (ISSUE 16)

@@ -78,11 +78,10 @@ Checks:
    dispatch-table-cited records.
 8. **Serving pin-match** — a cited record carrying a ``serving``
    block (``benchmarks/profile_serving.py``: {tokens_per_s, p50_ms,
-   p99_ms, trace_id, kv_pages}) must PIN both serving dispatch knobs
-   in its recorded ``knobs``: ``APEX_SERVE_WEIGHT_QUANT`` and
-   ``APEX_DECODE_ATTN_IMPL``. The decode step's program is shaped by
-   both (int8 vs full-precision matmuls; pallas vs jnp gather
-   attention), and a serving row engaged through a process-wide
+   p99_ms, trace_id, kv_pages}) must PIN the serving dispatch knob
+   in its recorded ``knobs``: ``APEX_SERVE_WEIGHT_QUANT``. The decode
+   step's program is shaped by it (int8 vs full-precision matmuls),
+   and a serving row engaged through a process-wide
    setter alone carries no pin the label can be checked against —
    same teeth as checks 6-7. The harness stamps the RESOLVED values
    into its environment before the ledger write, so an unpinned run
@@ -314,9 +313,8 @@ def serving_problems(rec, rid):
         return []
     knobs = rec.get("knobs") if isinstance(rec.get("knobs"), dict) else {}
     problems = []
-    for knob in ("APEX_SERVE_WEIGHT_QUANT", "APEX_DECODE_ATTN_IMPL",
-                 "APEX_SERVE_DECODE_K", "APEX_SERVE_KV_QUANT",
-                 "APEX_SERVE_KV_SWAP"):
+    for knob in ("APEX_SERVE_WEIGHT_QUANT", "APEX_SERVE_DECODE_K",
+                 "APEX_SERVE_KV_QUANT", "APEX_SERVE_KV_SWAP"):
         if knob not in knobs:
             problems.append(
                 f"record {rid} carries a serving block but does not pin "
