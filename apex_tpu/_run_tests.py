@@ -47,7 +47,8 @@ SUITES = {
                 "test_serving_chaos.py",
                 "test_serving_multitok.py",
                 "test_serving_tp.py", "test_kv_tier.py",
-                "test_router.py", "test_router_chaos.py"],
+                "test_router.py", "test_router_chaos.py",
+                "test_mimo_serving.py"],
     "api_parity": ["test_api_parity_round3.py"],
     "harness": ["test_run_tests.py", "test_bench_contract.py",
                 "test_chip_smoke.py",

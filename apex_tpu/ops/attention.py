@@ -229,3 +229,100 @@ def fused_attention(q, k, v, *, causal=False, sm_scale=None,
                             kv=segment_ids[1].astype(jnp.int32))
     return fa.flash_attention(q, k, v, segment_ids=seg, causal=causal,
                               sm_scale=float(sm_scale), block_sizes=bs)
+
+
+# --------------------------------- packed grouped-query prefill attention
+
+def masked_softmax(s, masked, sink=None):
+    """Exact float32 softmax over the last axis of ``s`` where ``masked``
+    (broadcastable to ``s``) is False. ``sink`` (broadcastable, with a
+    last axis of 1) is one more logit of every row's denominator and of
+    nothing else. A fully masked row comes out 0. The one softmax of the
+    ``jnp`` attention forms: packed prefill here, both decode references
+    in ``decode_attention_pallas``."""
+    s = jnp.where(masked, -1e30, s)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    extra = 0.0
+    if sink is not None:
+        m = jnp.maximum(m, sink)
+        extra = jnp.exp(sink - m)
+    e = jnp.where(masked, 0.0, jnp.exp(s - m))
+    tot = jnp.sum(e, axis=-1, keepdims=True) + extra
+    return e / jnp.where(tot > 0, tot, 1.0)
+
+
+def _packed_gqa_dense(q, k, v, seg, sm_scale, window, sink):
+    """The jnp form of the packed grouped-query prefill: float32
+    softmax over causal, same-segment (and, with ``window``, the last
+    ``window`` positions) keys, the sink logit in the denominator."""
+    hq, S, dk = q.shape
+    n_kv = k.shape[0]
+    qg = q.reshape(n_kv, hq // n_kv, S, dk)
+    s = jnp.einsum("kgqd,ksd->kgqs", qg, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    row, col = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    masked = (col > row) | (seg[:, None] != seg[None, :])
+    if window is not None:
+        masked = masked | (row - col >= window)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(n_kv, hq // n_kv, 1, 1)
+    p = masked_softmax(s, masked, sink).astype(v.dtype)
+    out = jnp.einsum("kgqs,ksv->kgqv", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(hq, S, v.shape[2]).astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 7, 8))
+def _packed_gqa(q, k, v, seg, sm_scale, window, sink, impl, interpret):
+    if impl == "pallas":
+        from apex_tpu.ops import attention_pallas as ap
+
+        return ap.packed_gqa_attention_pallas(
+            q, k, v, seg, sm_scale, window=window, sink=sink,
+            interpret=interpret)
+    return _packed_gqa_dense(q, k, v, seg, sm_scale, window, sink)
+
+
+def _packed_gqa_fwd(q, k, v, seg, sm_scale, window, sink, impl, interpret):
+    return _packed_gqa(q, k, v, seg, sm_scale, window, sink, impl,
+                       interpret), None
+
+
+def _packed_gqa_bwd(sm_scale, window, impl, interpret, res, g):
+    raise NotImplementedError(
+        "packed_gqa_attention is the serving prefill's forward; its "
+        "backward (the training path of this block) is not built")
+
+
+_packed_gqa.defvjp(_packed_gqa_fwd, _packed_gqa_bwd)
+
+
+def packed_gqa_attention(q, k, v, segment_ids, *, sm_scale=None,
+                         window=None, sink=None, impl=None,
+                         interpret=None):
+    """Causal attention over ONE packed sequence with grouped query
+    heads, K and V of different widths, an optional window and an
+    optional per-head sink logit; forward only (differentiating raises).
+
+    q: [hq, S, dk]; k: [n_kv, S, dk]; v: [n_kv, S, dv]; segment_ids:
+    [S] (tokens attend within equal ids; packed order is position
+    order). Query head i reads KV head ``i // (hq / n_kv)``. ``window``:
+    a token sees the last ``window`` positions, itself included.
+    ``sink``: [hq] logits that join the softmax denominator only.
+    ``impl`` is a per-call demand ("jnp" | "pallas"; the kernel compiled
+    on a shape it does not support raises); unset, the kernel runs on a
+    TPU where ``attention_pallas.packed_supported`` holds, the jnp form
+    elsewhere. ``interpret`` defaults to True on the CPU platform only."""
+    from apex_tpu.ops import attention_pallas as ap
+
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if impl is not None and impl not in ("jnp", "pallas"):
+        raise ValueError(f"unknown packed-attention impl {impl!r}")
+    if impl is None:
+        impl = "pallas" if _tpu_available() and ap.packed_supported(
+            q.shape[1], q.shape[2], v.shape[2]) else "jnp"
+    if interpret is None:
+        interpret = _on_cpu()
+    return _packed_gqa(q, k, v, segment_ids.astype(jnp.int32),
+                       float(sm_scale), window, sink, impl, bool(interpret))

@@ -996,3 +996,137 @@ def _bwd_rule(causal, sm_scale, interpret, block_q, bwd_impl, dropout_p,
 
 
 fused_attention_rows.defvjp(_fwd_rule, _bwd_rule)
+
+
+# ------------------------------------- packed grouped-query prefill (fwd)
+#
+# The serving prefill of a model whose query heads share KV heads, whose
+# K and V differ in width, and whose layers may see a window and carry a
+# sink logit (serving/mimo.py): ONE packed sequence with segment ids,
+# forward only. The kernels above (GPT-2's prefill and the trainer's
+# flash forward and backward) are untouched.
+#
+#   q [hq, S, dk], k [n_kv, S, dk], v [n_kv, S, dv], seg [S] -> [hq, S, dv]
+#
+# Grid (query head, q block, k block), online softmax across the k
+# blocks. Packed index order is position order inside a segment, so
+# "causal" and "within the window" are index differences; a k block
+# wholly above the diagonal or wholly behind the window is neither
+# computed nor fetched (its block index is clamped onto a live one).
+
+PACKED_KERNEL_NAME = "packed_gqa_attention"
+
+
+def packed_block(S):
+    """The q and k block of the packed kernel: 256 rows, or all of a
+    shorter pack; 0 where S does not divide."""
+    blk = min(256, S)
+    return blk if S % blk == 0 and blk % 8 == 0 else 0
+
+
+def packed_supported(S, dk, dv):
+    """Whether Mosaic takes the packed kernel: blocks that divide S and
+    are whole lane tiles (or all of S), bounded widths."""
+    blk = packed_block(S)
+    return blk != 0 and (blk % 128 == 0) and dk <= 512 and dv <= 512
+
+
+def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
+                   blk, n_k, window, has_sink):
+    if has_sink:
+        sink_ref, o_ref, acc_scr, m_scr, l_scr = rest
+    else:
+        o_ref, acc_scr, m_scr, l_scr = rest
+    iq, ik = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        if has_sink:
+            m_scr[...] = jnp.broadcast_to(sink_ref[...], m_scr.shape)
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, jnp.float32(-1e30))
+            l_scr[...] = jnp.zeros_like(l_scr)
+
+    live = ik <= iq                        # not wholly above the diagonal
+    if window is not None:                 # nor wholly behind the window
+        live = live & ((ik + 1) * blk - 1 > iq * blk - window)
+
+    @pl.when(live)
+    def _block():
+        s = lax.dot_general(q_ref[...], k_ref[...],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        row = iq * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = ik * blk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        masked = (col > row) | (sq_ref[...] != skv_ref[...])
+        if window is not None:
+            masked = masked | (row - col >= window)
+        s = jnp.where(masked, jnp.float32(-1e30), s * jnp.float32(scale))
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(masked, 0.0, jnp.exp(s - m_new))
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    @pl.when(ik == n_k - 1)
+    def _finish():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def packed_gqa_attention_pallas(q, k, v, seg, sm_scale, *, window=None,
+                                sink=None, interpret=False):
+    """The packed grouped-query prefill kernel (layouts above)."""
+    hq, S, dk = q.shape
+    n_kv, dv = k.shape[0], v.shape[2]
+    blk = packed_block(S)
+    if blk == 0 or hq % n_kv or (not interpret
+                                 and not packed_supported(S, dk, dv)):
+        raise ValueError(f"packed_gqa_attention_pallas: unsupported "
+                         f"q {q.shape} k {k.shape} v {v.shape}")
+    group, n_blk = hq // n_kv, S // blk
+    has_sink = sink is not None
+
+    def k_block(iq, ik):
+        # a block that is not live keeps the index of one that is: an
+        # unchanged block index is not fetched again
+        lo = 0 if window is None else \
+            jnp.maximum(iq * blk - window + 1, 0) // blk
+        return jnp.clip(ik, lo, iq)
+
+    q_spec = pl.BlockSpec((None, blk, dk), lambda h, iq, ik: (h, iq, 0))
+    in_specs = [
+        q_spec,
+        pl.BlockSpec((None, blk, dk),
+                     lambda h, iq, ik: (h // group, k_block(iq, ik), 0)),
+        pl.BlockSpec((None, blk, dv),
+                     lambda h, iq, ik: (h // group, k_block(iq, ik), 0)),
+        pl.BlockSpec((blk, 1), lambda h, iq, ik: (iq, 0)),
+        pl.BlockSpec((1, blk), lambda h, iq, ik: (0, k_block(iq, ik))),
+    ]
+    seg = seg.astype(jnp.int32)
+    operands = [q, k, v, seg[:, None], seg[None, :]]
+    if has_sink:
+        in_specs.append(pl.BlockSpec((None, 1, 1), lambda h, iq, ik: (h, 0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(hq, 1, 1))
+    kern = functools.partial(_packed_kernel, scale=float(sm_scale), blk=blk,
+                             n_k=n_blk, window=window, has_sink=has_sink)
+    return pl.pallas_call(
+        kern,
+        grid=(hq, n_blk, n_blk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, blk, dv), lambda h, iq, ik: (h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((hq, S, dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk, dv), jnp.float32),
+                        pltpu.VMEM((blk, 1), jnp.float32),
+                        pltpu.VMEM((blk, 1), jnp.float32)],
+        interpret=interpret,
+        name=PACKED_KERNEL_NAME,
+    )(*operands)

@@ -79,6 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from apex_tpu.dispatch import tiles
+from apex_tpu.ops.attention import masked_softmax
 
 NEG_INF = -1e30  # python float: jnp scalars would be captured consts
                  # inside the pallas kernel (Mosaic requires operands)
@@ -277,6 +278,33 @@ def decode_attention_pallas(q, k_pages, v_pages, page_table, lengths,
       *operands)
 
 
+def _context_view(page_table, page_size, page_base=None, starts=None):
+    """``(page_base [b, n], starts [b])`` int32 with their defaults: entry
+    ``j`` of a slot's table holds positions from ``j * page_size``, and
+    the context starts at position 0."""
+    b, n = page_table.shape
+    if page_base is None:
+        page_base = jnp.broadcast_to(
+            jnp.arange(n, dtype=jnp.int32)[None, :] * page_size, (b, n))
+    if starts is None:
+        starts = jnp.zeros((b,), jnp.int32)
+    return page_base.astype(jnp.int32), starts.astype(jnp.int32)
+
+
+def _outside_context(page_table, page_size, lengths, page_base=None,
+                     starts=None):
+    """``[b, n * page_size]`` bool over a slot's gathered rows: True
+    where a row is NOT a position of ``[start, length)``. Shared by
+    both jnp references."""
+    page_base, starts = _context_view(page_table, page_size, page_base,
+                                      starts)
+    pos = (page_base[:, :, None] + jnp.arange(
+        page_size, dtype=jnp.int32)[None, None, :]).reshape(
+            page_table.shape[0], -1)
+    return (pos >= lengths.astype(jnp.int32)[:, None]) \
+        | (pos < starts[:, None])
+
+
 def decode_attention_reference(q, k_pages, v_pages, page_table,
                                lengths, sm_scale, k_scale=None,
                                v_scale=None):
@@ -306,14 +334,8 @@ def decode_attention_reference(q, k_pages, v_pages, page_table,
     s = jnp.sum(
         (q.astype(jnp.float32) * jnp.float32(sm_scale))[:, :, None, :]
         * k, axis=-1)                              # [b, h, S]
-    col = jnp.arange(s.shape[-1], dtype=jnp.int32)[None, None, :]
-    masked = col >= lengths.astype(jnp.int32)[:, None, None]
-    s = jnp.where(masked, NEG_INF, s)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    e = jnp.exp(s - m)
-    e = jnp.where(masked, 0.0, e)
-    tot = jnp.sum(e, axis=-1, keepdims=True)
-    p = jnp.where(tot > 0, e / jnp.where(tot > 0, tot, 1.0), 0.0)
+    masked = _outside_context(page_table, ps, lengths)[:, None, :]
+    p = masked_softmax(s, masked)
     return jnp.sum(p[..., None] * v, axis=2).astype(q.dtype)
 
 
@@ -396,3 +418,261 @@ def decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     return decode_attention_reference(q, k_pages, v_pages, page_table,
                                       lengths, sm_scale,
                                       k_scale=k_scale, v_scale=v_scale)
+
+
+# ------------------------------------------------- grouped-query decode
+#
+# A second cache layout and kernel for models whose query heads share KV
+# heads, whose K and V differ in width, and whose layers may see only a
+# window of the context and carry a sink logit (serving/mimo.py). GPT-2's
+# call above is untouched. The two paths share the masks and the softmax
+# of their jnp forms and nothing else, and that is owed a deletion
+# (ROADMAP S2): once GPT-2's cache is re-laid ``[pages, page_size, h*d]``
+# this kernel has to take a query group below 8 rows (pad it to a sublane
+# tile) and V 64 wide (chunked like K), and then ``paged_decode_attention``,
+# its reference, ``supported``/``resolved`` and the old layout go.
+#
+# Layouts:
+#   q            [b, hq, dk]            one query row per slot
+#   k_pages      [pages, page_size, n_kv * dk]   one layer; (h, d) minor,
+#   v_pages      [pages, page_size, n_kv * dv]   so 768 / 512 / 1536 /
+#                                       1024 lanes are whole tiles: no pad
+#   page_table   [b, n]      int32      the pages a slot reads, in any order
+#   page_base    [b, n]      int32      the position of row 0 of each entry
+#                                       (None: entry j holds j * page_size;
+#                                       a ring names whatever it holds now)
+#   starts       [b]         int32      first visible position (None: 0)
+#   lengths      [b]         int32      context length, the query's own
+#                                       position included (0 = inactive)
+#   sink         [hq]        float32    a logit that joins each head's
+#                                       softmax denominator only, or None
+# Query head i reads KV head i // (hq / n_kv). Out: [b, hq, dv].
+
+GROUPED_KERNEL_NAME = "grouped_decode_attention"
+
+
+def _kv_chunk(n_kv, dk, dv):
+    """KV heads a kernel step takes together: the fewest whose K columns
+    fill whole lane tiles (2 at dk = 192, 1 at 128 or 256), so that every
+    slice inside the kernel is tile-aligned; 0 where none does."""
+    if dv % 128:
+        return 0
+    return next((c for c in range(1, n_kv + 1)
+                 if n_kv % c == 0 and (c * dk) % 128 == 0), 0)
+
+
+def grouped_supported(hq, n_kv, dk, dv, page_size, dtype=None):
+    """Whether Mosaic takes the grouped kernel at this geometry: aligned
+    lane slices (:func:`_kv_chunk`), query groups that are whole float32
+    sublane tiles, and a K and V page (double-buffered) inside VMEM."""
+    itembytes = tiles.itemsize(dtype) if dtype is not None else 4
+    page_bytes = 2 * page_size * n_kv * (dk + dv) * itembytes
+    return (hq % n_kv == 0 and (hq // n_kv) % 8 == 0
+            and _kv_chunk(n_kv, dk, dv) != 0 and page_size % 8 == 0
+            and page_bytes <= 8 * 2 ** 20)
+
+
+def _band_queries(q, n_kv, c):
+    """[b, hq, dk] -> [b, hq, c * dk]: each head's query in the columns
+    of its KV head within its chunk of ``c``, zeros in the others', so
+    that one product against the chunk's K columns gives every head its
+    own KV head's scores with no unaligned slice."""
+    b, hq, dk = q.shape
+    if c == 1:
+        return q
+    within = (jnp.arange(hq) // (hq // n_kv)) % c             # [hq]
+    onehot = (within[:, None] == jnp.arange(c)[None, :]).astype(q.dtype)
+    return (q[:, :, None, :] * onehot[None, :, :, None]).reshape(
+        b, hq, c * dk)
+
+
+def _grouped_kernel(pt_ref, base_ref, start_ref, len_ref, q_ref, k_ref,
+                    v_ref, *rest, scale, ps, n_iter, n_kv, c, group, dk,
+                    dv, has_sink):
+    """One (slot, table entry) step: a page arrives as ``[ps, n_kv*dk]``
+    and ``[ps, n_kv*dv]``, positions on sublanes. Per chunk of ``c`` KV
+    heads the banded queries meet the chunk's K columns on the MXU
+    (scores ``[c*group, ps]``, positions on lanes), then each KV head's
+    probabilities meet its V columns."""
+    if has_sink:
+        sink_ref, o_ref, acc_scr, m_scr, l_scr = rest
+    else:
+        o_ref, acc_scr, m_scr, l_scr = rest
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        if has_sink:
+            # the sink is one more logit of the denominator: start the
+            # running max at it and the running sum at exp(0)
+            m_scr[...] = sink_ref[...]
+            l_scr[...] = jnp.ones_like(l_scr)
+        else:
+            m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
+            l_scr[...] = jnp.zeros_like(l_scr)
+
+    base, length, start = base_ref[i, j], len_ref[i], start_ref[i]
+
+    @pl.when((base < length) & (base + ps > start))
+    def _page():
+        pos = base + lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        masked = (pos >= length) | (pos < start)                 # [1, ps]
+        rows = c * group
+        for ci in range(n_kv // c):
+            r0 = ci * rows
+            qc = q_ref[r0:r0 + rows, :]                      # [rows, c*dk]
+            kc = k_ref[:, ci * c * dk:(ci + 1) * c * dk]     # [ps, c*dk]
+            s = lax.dot_general(qc, kc, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(masked, jnp.float32(NEG_INF),
+                          s * jnp.float32(scale))            # [rows, ps]
+            m_prev = m_scr[r0:r0 + rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)                  # [rows, 1]
+            p = jnp.where(masked, 0.0, jnp.exp(s - m_new))
+            l_scr[r0:r0 + rows, :] = l_scr[r0:r0 + rows, :] * alpha \
+                + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[r0:r0 + rows, :] = m_new
+            for u in range(c):
+                g = ci * c + u
+                a0, p0 = r0 + u * group, u * group
+                vu = v_ref[:, g * dv:(g + 1) * dv]           # [ps, dv]
+                ctx = lax.dot_general(
+                    p[p0:p0 + group, :].astype(vu.dtype), vu,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)      # [group, dv]
+                acc_scr[a0:a0 + group, :] = \
+                    acc_scr[a0:a0 + group, :] * alpha[p0:p0 + group, :] \
+                    + ctx
+
+    @pl.when(j == n_iter - 1)
+    def _finish():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def grouped_decode_attention_pallas(q, k_pages, v_pages, page_table,
+                                    lengths, sm_scale, *, n_kv,
+                                    page_base=None, starts=None, sink=None,
+                                    interpret=False):
+    """The grouped-query paged decode kernel (layouts above). Compiled,
+    it wants :func:`grouped_supported`; interpreted it takes any widths
+    (one chunk of all KV heads where none aligns)."""
+    b, hq, dk = q.shape
+    ps = k_pages.shape[1]
+    dv = v_pages.shape[2] // n_kv
+    n = page_table.shape[1]
+    if k_pages.shape[2] != n_kv * dk or hq % n_kv:
+        raise ValueError(
+            f"grouped_decode_attention: q {q.shape} and K pages "
+            f"{k_pages.shape} do not make {n_kv} KV heads of width {dk}")
+    if not interpret and not grouped_supported(hq, n_kv, dk, dv, ps,
+                                               k_pages.dtype):
+        raise ValueError(
+            f"grouped_decode_attention_pallas: unsupported geometry "
+            f"hq={hq} n_kv={n_kv} dk={dk} dv={dv} ps={ps}")
+    c = _kv_chunk(n_kv, dk, dv) or n_kv
+    group = hq // n_kv
+    page_base, starts = _context_view(page_table, ps, page_base, starts)
+    has_sink = sink is not None
+
+    def slot_map(i, j, pt, base, st, ln):
+        return (i, 0, 0)
+
+    def page_map(i, j, pt, base, st, ln):
+        return (pt[i, j], 0, 0)
+
+    in_specs = [pl.BlockSpec((None, hq, c * dk), slot_map),
+                pl.BlockSpec((None, ps, n_kv * dk), page_map),
+                pl.BlockSpec((None, ps, n_kv * dv), page_map)]
+    operands = [_band_queries(q, n_kv, c), k_pages, v_pages]
+    if has_sink:
+        in_specs.append(pl.BlockSpec((hq, 1), lambda i, j, *_: (0, 0)))
+        operands.append(sink.astype(jnp.float32).reshape(hq, 1))
+    kern = functools.partial(
+        _grouped_kernel, scale=float(sm_scale), ps=ps, n_iter=n, n_kv=n_kv,
+        c=c, group=group, dk=dk, dv=dv, has_sink=has_sink)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, n),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, hq, dv), slot_map),
+            scratch_shapes=[
+                pltpu.VMEM((hq, dv), jnp.float32),
+                pltpu.VMEM((hq, 1), jnp.float32),
+                pltpu.VMEM((hq, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
+        interpret=interpret,
+        name=GROUPED_KERNEL_NAME,
+    )(page_table.astype(jnp.int32), page_base, starts,
+      lengths.astype(jnp.int32), *operands)
+
+
+def grouped_decode_attention_reference(q, k_pages, v_pages, page_table,
+                                       lengths, sm_scale, *, n_kv,
+                                       page_base=None, starts=None,
+                                       sink=None):
+    """The jnp form of the grouped kernel: gather each slot's pages,
+    mask outside ``[start, length)``, exact float32 softmax with the sink
+    in its denominator. Inactive slots return 0."""
+    b, hq, dk = q.shape
+    ps = k_pages.shape[1]
+    dv = v_pages.shape[2] // n_kv
+    n = page_table.shape[1]
+    k = k_pages[page_table].reshape(b, n * ps, n_kv, dk).astype(jnp.float32)
+    v = v_pages[page_table].reshape(b, n * ps, n_kv, dv).astype(jnp.float32)
+    masked = _outside_context(page_table, ps, lengths, page_base,
+                              starts)[:, None, None, :]      # [b, 1, 1, S]
+    qg = q.astype(jnp.float32).reshape(b, n_kv, hq // n_kv, dk)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg, k,
+                   precision=lax.Precision.HIGHEST) * jnp.float32(sm_scale)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(1, n_kv, hq // n_kv, 1)
+    out = jnp.einsum("bkgs,bskv->bkgv", masked_softmax(s, masked, sink), v,
+                     precision=lax.Precision.HIGHEST)
+    return out.reshape(b, hq, dv).astype(q.dtype)
+
+
+def grouped_resolved(hq, n_kv, dk, dv, page_size, dtype, impl=None):
+    """The impl a :func:`grouped_decode_attention` call runs with: a
+    per-call demand, else the kernel on a TPU where the geometry is
+    supported, else the jnp form."""
+    if impl is not None:
+        if impl not in ("jnp", "pallas"):
+            raise ValueError(f"unknown decode-attention impl {impl!r}")
+        return impl
+    if jax.default_backend() == "tpu" and grouped_supported(
+            hq, n_kv, dk, dv, page_size, dtype):
+        return "pallas"
+    return "jnp"
+
+
+def grouped_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
+                             n_kv, sm_scale=None, page_base=None,
+                             starts=None, sink=None, impl=None,
+                             interpret=None):
+    """Dispatched grouped-query paged decode attention (layouts above).
+    ``impl`` is a per-call demand ("jnp" | "pallas"; "pallas" compiled on
+    an unsupported geometry raises); ``interpret`` defaults to True on
+    the CPU platform only."""
+    hq, dk = q.shape[1:]
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(dk)
+    ps = k_pages.shape[1]
+    dv = v_pages.shape[2] // n_kv
+    kw = dict(n_kv=n_kv, page_base=page_base, starts=starts, sink=sink)
+    if grouped_resolved(hq, n_kv, dk, dv, ps, k_pages.dtype,
+                        impl) == "pallas":
+        if interpret is None:
+            interpret = jax.devices()[0].platform == "cpu"
+        return grouped_decode_attention_pallas(
+            q, k_pages, v_pages, page_table, lengths, sm_scale,
+            interpret=interpret, **kw)
+    return grouped_decode_attention_reference(
+        q, k_pages, v_pages, page_table, lengths, sm_scale, **kw)

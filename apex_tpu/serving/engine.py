@@ -181,8 +181,8 @@ import numpy as np
 
 from apex_tpu import compile_cache
 from apex_tpu import resilience as res_mod
-from apex_tpu.ops import decode_attention_pallas as dap
 from apex_tpu.resilience import faults as faults_mod
+from apex_tpu.serving import family as family_mod
 from apex_tpu.serving import kv_tier as kv_tier_mod
 from apex_tpu.serving import lifecycle
 from apex_tpu.serving import model as smodel
@@ -192,8 +192,7 @@ from apex_tpu.serving import resilience as serve_res
 from apex_tpu.serving import sampling as sampling_mod
 from apex_tpu.serving import speculative as spec_mod
 from apex_tpu.serving import tp as tp_mod
-from apex_tpu.serving.kv_cache import (PageAllocator, init_cache,
-                                       pages_needed)
+from apex_tpu.serving.kv_cache import PageAllocator, pages_needed
 from apex_tpu.serving.scheduler import ContinuousBatchingScheduler, Request
 from apex_tpu.telemetry import spans
 
@@ -215,7 +214,25 @@ class ServingEngine:
                  kv_quant=None, kv_swap=None, kv_restore=None,
                  shed_ttft_ms=None, dispatch_timeout_s=None,
                  round_attempts=None, round_retry_wait_s=None, seed=0):
-        smodel.check_serving_config(cfg)
+        # the model family behind the round (serving/family.py), chosen
+        # by the config object alone: its check of the config, and the
+        # engine options it cannot honour (a demand raises by name, an
+        # environment preference is dropped)
+        self.family = family_mod.family_of(cfg)
+        self.family.check_config(cfg)
+        if self.family.refused:
+            opts = family_mod.settle_options(self.family, dict(
+                tp=tp, weight_quant=weight_quant, kv_quant=kv_quant,
+                kv_swap=kv_swap, prefix_cache=prefix_cache,
+                spec_decode=spec_decode, decode_k=decode_k,
+                overlap=overlap, decode_block_h=decode_block_h))
+            # (spec_decode takes no per-call "off": its resolved K is
+            # zeroed below)
+            tp, weight_quant, kv_quant, kv_swap, prefix_cache, decode_k, \
+                overlap, decode_block_h = (opts[name] for name in (
+                    "tp", "weight_quant", "kv_quant", "kv_swap",
+                    "prefix_cache", "decode_k", "overlap",
+                    "decode_block_h"))
         # the prefill/decode programs are the expensive compiles of a
         # server start: keep them in the persistent cache
         compile_cache.activate()
@@ -229,8 +246,12 @@ class ServingEngine:
         self.max_pages = -(-self.max_seq // self.page_size)
         self.prefill_len = int(prefill_len)
         self.prefill_requests = int(prefill_requests or num_slots)
+        # a family that prefills one dispatch a round: the serial
+        # round's admission stops at one dispatch's tokens
+        self._admit_tokens = self.prefill_len \
+            if self.family.one_prefill_a_round else None
         self.params = params if params is not None \
-            else smodel.init_gpt_params(cfg, seed)
+            else self.family.init_params(cfg, seed)
 
         # weight quant: per-call demand raises on un-honorable;
         # env/setter preferences fall back (quant.resolve)
@@ -253,7 +274,7 @@ class ServingEngine:
         # below with the params.
         self.tp = tp_mod.resolve_serve_tp(
             tp, n_heads=cfg.num_attention_heads)
-        self.qparams = smodel.quantize_decode_params(
+        self.qparams = self.family.quantize_decode_params(
             self.params, cfg) if self.weight_quant else None
         # a tensor-parallel engine partitions the decode jaxpr by GSPMD
         # from a head-sharded cache (serving/tp.py); a pallas_call is
@@ -264,11 +285,16 @@ class ServingEngine:
         self.decode_impl = decode_impl
         self.decode_block_h = decode_block_h
         self.interpret = interpret
+        # what a family is handed of the three (serving/family.py)
+        self._kernels = family_mod.Kernels(decode_impl, decode_block_h,
+                                           interpret)
 
         # generation knobs (ISSUE 13): sampling / speculative decode /
         # prefix cache, each defaulting OFF (measured-dispatch rule)
         self.sampling = sampling_mod.resolve(sampling)
         k = spec_mod.resolve_k(spec_decode)
+        if "spec_decode" in self.family.refused:
+            k = 0   # (a demand raised above; this drops APEX_SPEC_DECODE)
         if spec_decode is not None and k and k + 1 > self.prefill_len:
             raise ValueError(
                 f"spec_decode={k} cannot be honored: the verify window "
@@ -434,7 +460,7 @@ class ServingEngine:
         # verify chain needs K+1 rows; plain prefill reads row r*w
         self._gather_w = self.spec_k + 1
 
-        self._cache_dtype = smodel.compute_dtype(cfg)
+        self._cache_dtype = self.family.cache_dtype(cfg)
         # tp > 1: params + paged KV cache are device_put over the tp
         # mesh; the jitted programs below are UNTOUCHED — GSPMD
         # partitions them from these committed input shardings
@@ -472,22 +498,20 @@ class ServingEngine:
         #
         # the quantized prefill takes ONE extra operand — the
         # keep_scale row staged per dispatch (_packed_call)
+        family = self.family
+
         def _prefill(params, cache, ids, positions, seg, token_rows,
                      page_table, last_idx, keep_scale=None):
-            return smodel.prefill(params, cache, ids, positions, seg,
+            return family.prefill(params, cache, ids, positions, seg,
                                   token_rows, page_table, last_idx,
-                                  keep_scale, cfg=cfg)
+                                  keep_scale, cfg=cfg,
+                                  kernels=self._kernels)
 
-        decode_kw = dict(cfg=cfg, decode_impl=self.decode_impl,
-                         decode_block_h=self.decode_block_h,
-                         interpret=self.interpret)
         # which decode-attention program the decode program is built
         # with ("pallas" | "jnp") and its head block (None on jnp);
         # the decode.dispatch span carries both
-        _, _, pages, ps, hd = self.cache["k"].shape
-        self.decode_attn_impl, self.decode_attn_block_h = dap.resolved(
-            cfg.num_attention_heads, pages, ps, hd,
-            self.cache["k"].dtype, self.decode_impl, self.decode_block_h)
+        self.decode_attn_impl, self.decode_attn_block_h = \
+            family.decode_attention(cfg, self.cache, self._kernels)
         self._dispatch_attrs = {"prefill": {}, "decode": dict(
             attn_impl=self.decode_attn_impl,
             block_h=self.decode_attn_block_h)}
@@ -500,28 +524,31 @@ class ServingEngine:
             def _decode(params, qparams, cache, tokens, lengths,
                         page_table, steps, warm_tokens, warm_steps,
                         *lanes):
-                return smodel.decode_block(
+                return family.decode_block(
                     params, cache, tokens, lengths, page_table, steps,
-                    warm_tokens, warm_steps, lanes=lanes or None,
-                    k=self.decode_k, qparams=qparams, **decode_kw)
+                    warm_tokens, warm_steps, lanes or None,
+                    k=self.decode_k, cfg=cfg, qparams=qparams,
+                    kernels=self._kernels)
         elif self.sampling:
             def _decode(params, qparams, cache, tokens, lengths,
                         page_table, temps, top_ks, top_ps, keys,
                         counters):
-                cache, _, logits = smodel.decode_step(
-                    params, cache, tokens, lengths, page_table,
-                    qparams=qparams, **decode_kw)
+                # (cache, greedy tokens, logits[, extras]): the sampled
+                # tokens take the greedy ones' place
+                out = family.decode_step(
+                    params, cache, tokens, lengths, page_table, cfg=cfg,
+                    qparams=qparams, kernels=self._kernels)
                 with jax.named_scope("sample"):
                     toks = sampling_mod.sample_tokens(
-                        logits, temps, top_ks, top_ps, keys, counters,
+                        out[2], temps, top_ks, top_ps, keys, counters,
                         lengths > 0)
-                return cache, toks, logits
+                return (out[0], toks) + tuple(out[2:])
         else:
             def _decode(params, qparams, cache, tokens, lengths,
                         page_table):
-                return smodel.decode_step(
-                    params, cache, tokens, lengths, page_table,
-                    qparams=qparams, **decode_kw)
+                return family.decode_step(
+                    params, cache, tokens, lengths, page_table, cfg=cfg,
+                    qparams=qparams, kernels=self._kernels)
 
         def _copy(cache, src, dst):
             # one K/V page src -> dst across all layers/heads; src/dst
@@ -585,6 +612,11 @@ class ServingEngine:
         # tokens since the last round closed: the ``emitted`` attribute
         # of the next ``engine.round`` span
         self._emitted = []
+        # what a family's decode program returns beside its tokens
+        # (``extras``: small device arrays), fetched with them, and the
+        # attributes they and the family's own counts give the round
+        self._decode_extras = None
+        self._round_attrs = {}
         # wall seconds inside swap-tier staging copies (device_get at
         # swap-out + scatter at swap-in) — the host-copy clock the
         # kv_restore crossover sweep measures against the replay
@@ -611,10 +643,10 @@ class ServingEngine:
         (ctor, round recovery, failover drain), so a rebuild can never
         drop the int8 tier's scale leaves or drift the dtype (either
         would re-enter the jit caches as a second program)."""
-        return self._place_cache(init_cache(
-            self.cfg.num_layers, self.cfg.num_attention_heads,
-            self.num_pages, self.page_size, self.cfg.head_dim,
-            self._cache_dtype, kv_quant=self.kv_quant))
+        return self._place_cache(self.family.init_cache(
+            self.cfg, family_mod.Geometry(
+                self.num_slots, self.num_pages, self.page_size,
+                self._cache_dtype, self.kv_quant)))
 
     def decode_cache_size(self):
         """jit-cache entry count of the decode step — the
@@ -1393,16 +1425,18 @@ class ServingEngine:
         t0 = time.perf_counter()
 
         def call():
-            cache, toks, _ = self._decode_fn(*args)
+            out = self._decode_fn(*args)
+            cache, toks = out[0], out[1]
             if self.recover:
                 # fetch INSIDE the watchdog — the token sync is where
                 # a wedged decode round actually blocks
                 toks = np.asarray(toks)
-            return cache, toks
+            return cache, toks, (out[3] if len(out) > 3 else None)
 
         # state adopted only after a clean return (a timed-out
         # round's late result never overwrites the recovered engine)
-        self.cache, next_toks = self._dispatch("decode", call)
+        self.cache, next_toks, self._decode_extras = self._dispatch(
+            "decode", call)
         return next_toks, t0, steps
 
     def _sample_gauges(self, tick):
@@ -1447,8 +1481,12 @@ class ServingEngine:
             # round handed to a request (the overlapped round: the
             # fetch of the round before)
             emitted, self._emitted = self._emitted, []
+            attrs, self._round_attrs = self._round_attrs, {}
+            if self.family.round_attrs is not None and spans.enabled():
+                attrs.update(self.family.round_attrs(self.cfg,
+                                                     self.scheduler))
             sp.set(prefilled=len(info["prefilled"]),
-                   decoded=info["decoded_slots"], emitted=emitted)
+                   decoded=info["decoded_slots"], emitted=emitted, **attrs)
         return info
 
     def _fire_burst(self, tick):
@@ -1573,7 +1611,7 @@ class ServingEngine:
             wall = time.perf_counter()
             evicted = sch.evict_done(now, wall)
             shed = self._shed_queue(now, wall) if self.shed else []
-            admitted = sch.admit(now, wall)
+            admitted = sch.admit(now, wall, self._admit_tokens)
             if self.events is not None:
                 for r in evicted:
                     self.events.record("evicted", r.rid, tick=now,
@@ -1622,11 +1660,15 @@ class ServingEngine:
         if decode_lanes:
             next_toks, t0, steps = self._dispatch_decode(
                 decode_lanes, zero_length_lanes=verified)
-            with spans.span("decode.fetch"):
+            with spans.span("decode.fetch") as sp:
                 # the count bookkeeping runs while the device works:
                 # it is part of the wait, not of the round's host time
                 plan, decoded = self._advance_counts(decode_lanes, steps)
                 next_toks = np.asarray(next_toks)
+                if self._decode_extras is not None and spans.enabled():
+                    self._round_attrs = self.family.fetch_attrs(
+                        self._decode_extras)
+                    sp.set(**self._round_attrs)
                 wall2 = time.perf_counter()
             self.device_dispatch_s += wall2 - t0
             with spans.span("decode.commit"):

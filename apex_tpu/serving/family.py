@@ -1,0 +1,225 @@
+"""The seam between ``ServingEngine`` and a model family.
+
+The engine owns the round (scheduler, allocator, staging, fetch, spans);
+a family owns what the round dispatches: the check of its config, its
+weights, its cache, its prefill and decode programs, and which engine
+options it can honour. The family is chosen by the config object handed
+to the engine (``cfg.serving_family``, ``"gpt2"`` where a config does
+not say) and by nothing else: no knob, no environment variable.
+
+A family is a :class:`Family` of plain functions:
+
+* ``check_config(cfg)``: raise on what the programs do not model;
+* ``init_params(cfg, seed)``: weights when the caller brings none;
+* ``cache_dtype(cfg)``, ``init_cache(cfg, geometry)``: the KV state for
+  the engine's :class:`Geometry` (slots, pages, page size, cache dtype,
+  ``kv_quant``);
+* ``prefill(params, cache, ids, positions, seg, token_rows, page_table,
+  last_idx, keep_scale, *, cfg, kernels)`` -> ``(cache, logits)``;
+* ``decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+  qparams, kernels)`` -> ``(cache, next_tokens, logits[, extras])``;
+  ``extras`` is a dict of small device arrays the round fetches with
+  its tokens and ``fetch_attrs(extras)`` turns into span attributes;
+* ``decode_block`` (K steps in one dispatch) and
+  ``quantize_decode_params``, or None where the family has none;
+* ``decode_attention(cfg, cache, kernels)`` -> ``(impl, block_h)`` for
+  the ``decode.dispatch`` span;
+* ``refused``: the engine options the family cannot honour, by name. A
+  per-call demand of one raises at engine build, naming it; an
+  environment preference for one is dropped (CLAUDE.md: explicit request
+  != preference);
+* ``round_attrs(cfg, scheduler)``: more attributes of ``engine.round``,
+  or None;
+* ``one_prefill_a_round``: the serial round admits at most one prefill
+  dispatch's tokens (``prefill_len``); the rest of the queue waits a
+  round, so the decode lanes are never held for a second dispatch.
+
+A family is handed values, never the engine: ``kernels`` is the
+:class:`Kernels` the caller asked for (``decode_impl``,
+``decode_block_h``, ``interpret``). The two ``*_attrs`` functions run
+only while the span recorder is on.
+"""
+
+import dataclasses
+import functools
+from typing import Callable, Optional, Tuple
+
+import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class Geometry:
+    """What a family's cache is sized by."""
+    num_slots: int
+    num_pages: int
+    page_size: int
+    cache_dtype: object
+    kv_quant: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class Kernels:
+    """The caller's per-call demands on a family's attention kernels
+    (None: the family's own rule)."""
+    decode_impl: Optional[str] = None
+    decode_block_h: Optional[int] = None
+    interpret: Optional[bool] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    name: str
+    check_config: Callable
+    init_params: Callable
+    cache_dtype: Callable
+    init_cache: Callable
+    prefill: Callable
+    decode_step: Callable
+    decode_attention: Callable
+    decode_block: Optional[Callable] = None
+    quantize_decode_params: Optional[Callable] = None
+    fetch_attrs: Optional[Callable] = None
+    round_attrs: Optional[Callable] = None
+    refused: Tuple[str, ...] = ()
+    # admission stops at one prefill dispatch's tokens a round, the rest
+    # of the queue waits for the next (scheduler.admit's token_budget)
+    one_prefill_a_round: bool = False
+
+
+# what each refusable option looks like when it is OFF, as the per-call
+# argument that switches it off whatever the environment prefers
+OPTIONS_OFF = {"tp": 1, "weight_quant": False, "kv_quant": False,
+               "kv_swap": False, "prefix_cache": False, "spec_decode": 0,
+               "decode_k": 1, "overlap": False, "decode_block_h": None}
+
+
+def settle_options(family, options):
+    """``options`` (the engine's per-call arguments, by name) with every
+    option the family refuses switched off; raises ``ValueError`` naming
+    each refused option the caller DEMANDED (a value other than None and
+    other than off)."""
+    demanded = [name for name in family.refused
+                if options.get(name) not in (None, OPTIONS_OFF[name])]
+    if demanded:
+        raise ValueError(
+            f"the {family.name} serving family cannot honour: "
+            + ", ".join(f"{n}={options[n]!r}" for n in demanded))
+    return {**options, **{n: OPTIONS_OFF[n] for n in family.refused}}
+
+
+# ------------------------------------------------------------------ GPT-2
+
+def _gpt2():
+    from apex_tpu.ops import decode_attention_pallas as dap
+    from apex_tpu.serving import kv_cache
+    from apex_tpu.serving import model as smodel
+
+    def init_cache(cfg, geometry):
+        return kv_cache.init_cache(
+            cfg.num_layers, cfg.num_attention_heads, geometry.num_pages,
+            geometry.page_size, cfg.head_dim, geometry.cache_dtype,
+            kv_quant=geometry.kv_quant)
+
+    def prefill(params, cache, ids, positions, seg, token_rows, page_table,
+                last_idx, keep_scale=None, *, cfg, kernels):
+        return smodel.prefill(params, cache, ids, positions, seg,
+                              token_rows, page_table, last_idx, keep_scale,
+                              cfg=cfg)
+
+    def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+                    qparams, kernels):
+        return smodel.decode_step(params, cache, tokens, lengths,
+                                  page_table, cfg=cfg, qparams=qparams,
+                                  **dataclasses.asdict(kernels))
+
+    def decode_block(params, cache, tokens, lengths, page_table, steps,
+                     warm_tokens, warm_steps, lanes, *, k, cfg, qparams,
+                     kernels):
+        return smodel.decode_block(
+            params, cache, tokens, lengths, page_table, steps, warm_tokens,
+            warm_steps, lanes=lanes, k=k, cfg=cfg, qparams=qparams,
+            **dataclasses.asdict(kernels))
+
+    def decode_attention(cfg, cache, kernels):
+        _, _, pages, ps, hd = cache["k"].shape
+        return dap.resolved(cfg.num_attention_heads, pages, ps, hd,
+                            cache["k"].dtype, kernels.decode_impl,
+                            kernels.decode_block_h)
+
+    return Family(
+        name="gpt2", check_config=smodel.check_serving_config,
+        init_params=smodel.init_gpt_params,
+        cache_dtype=smodel.compute_dtype, init_cache=init_cache,
+        prefill=prefill, decode_step=decode_step,
+        decode_block=decode_block, decode_attention=decode_attention,
+        quantize_decode_params=smodel.quantize_decode_params)
+
+
+# ------------------------------------------------------------------- MiMo
+
+def _mimo():
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import mimo
+
+    def init_cache(cfg, geometry):
+        return mimo.init_cache(cfg, geometry.num_slots, geometry.num_pages,
+                               geometry.page_size, geometry.cache_dtype)
+
+    def prefill(params, cache, ids, positions, seg, token_rows, page_table,
+                last_idx, keep_scale=None, *, cfg, kernels):
+        return mimo.prefill(params, cache, ids, positions, seg, token_rows,
+                            page_table, last_idx, cfg=cfg,
+                            interpret=kernels.interpret)
+
+    def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+                    qparams, kernels):
+        return mimo.decode_step(params, cache, tokens, lengths, page_table,
+                                cfg=cfg, decode_impl=kernels.decode_impl,
+                                interpret=kernels.interpret)
+
+    def decode_attention(cfg, cache, kernels):
+        return mimo.decode_attention_resolved(
+            cfg, cache, kernels.decode_impl), None
+
+    def fetch_attrs(extras):
+        counts = np.asarray(extras["expert_tokens"])   # [moe layers, held]
+        return dict(experts_touched=int((counts > 0).sum()),
+                    experts_held=int(counts.size),
+                    expert_tokens_max=int(counts.max(initial=0)),
+                    expert_tokens_sum=int(counts.sum()))
+
+    def round_attrs(cfg, scheduler):
+        # what the round's decode read of each kind of state: pages of
+        # the pool that hold context (not the reserved ones), ring pages
+        # that hold part of a window
+        live, ring = scheduler.context_pages(cfg.sliding_window)
+        return dict(global_pages_live=live, window_pages=ring)
+
+    return Family(
+        name="mimo", check_config=mimo.check_config,
+        init_params=mimo.init_params,
+        cache_dtype=lambda cfg: jnp.dtype(cfg.cache_dtype),
+        init_cache=init_cache,
+        prefill=prefill, decode_step=decode_step,
+        decode_attention=decode_attention, fetch_attrs=fetch_attrs,
+        round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
+        one_prefill_a_round=True)
+
+
+_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo}
+
+
+@functools.lru_cache(maxsize=None)
+def _built(name):
+    return _BUILDERS[name]()   # imports the family's modules on first use
+
+
+def family_of(cfg):
+    """The family that serves ``cfg``: its ``serving_family`` attribute,
+    ``"gpt2"`` where it has none (``TransformerConfig``)."""
+    name = getattr(cfg, "serving_family", "gpt2")
+    if name not in _BUILDERS:
+        raise ValueError(f"no serving family {name!r} "
+                         f"(known: {sorted(_BUILDERS)})")
+    return _built(name)

@@ -135,3 +135,77 @@ class PageAllocator:
         assert len(live) + len(self._free) == self.num_pages - 1, (
             f"accounting drift: {len(live)} live + "
             f"{len(self._free)} free != {self.num_pages - 1}")
+
+
+# --------------------------------------------- two kinds of state, one cache
+#
+# A model whose layers alternate between GLOBAL attention (every token
+# kept) and WINDOW attention (only the last ``window`` tokens ever read)
+# holds two kinds of KV state side by side (serving/mimo.py):
+#
+# * global layers: the paged pool above, one K and one V array a layer of
+#   ``[num_pages, page_size, kv_heads * width]`` ((head, width) minor:
+#   whole lane tiles at 4 x 192, 4 x 128, so nothing is padded), pages
+#   handed out by :class:`PageAllocator` through the scheduler's page
+#   table; admission reserves THESE pages only.
+# * window layers: a RING of :func:`ring_pages` pages a slot, owned by
+#   the slot for the life of the engine: position ``t`` lives in ring page
+#   ``(t // page_size) % ring`` at row ``t % page_size``. Never allocated,
+#   never freed; a new request overwrites its slot's ring as it goes, and
+#   what it has not yet overwritten lies outside ``[start, length)`` and
+#   is masked. ``[1 + num_slots * ring, page_size, kv_heads * width]`` a
+#   layer; page 0 stays the null page here too.
+
+def ring_pages(window, page_size):
+    """Pages of a slot's ring: the window may straddle one page edge
+    more than its own length in pages."""
+    return -(-int(window) // int(page_size)) + 1
+
+
+def init_hybrid_cache(layer_kinds, num_pages, num_slots, page_size, window,
+                      global_kv, window_kv, dtype=jnp.bfloat16):
+    """Zeroed two-kind cache. ``layer_kinds``: one 0 (global) or 1
+    (window) a layer; ``global_kv`` / ``window_kv``: ``(kv_heads, k_width,
+    v_width)`` of each kind. Returns ``{"global_k", "global_v",
+    "window_k", "window_v"}``, each a list with one array a layer of its
+    kind, in layer order."""
+    ring = ring_pages(window, page_size)
+    cache = {"global_k": [], "global_v": [], "window_k": [], "window_v": []}
+    for kind in layer_kinds:
+        name, pages, (h, dk, dv) = (
+            ("window", 1 + num_slots * ring, window_kv) if kind
+            else ("global", num_pages, global_kv))
+        cache[name + "_k"].append(
+            jnp.zeros((pages, page_size, h * dk), dtype))
+        cache[name + "_v"].append(
+            jnp.zeros((pages, page_size, h * dv), dtype))
+    return cache
+
+
+def ring_table(num_slots, ring):
+    """``[num_slots, ring]`` int32: slot ``s`` owns pages ``1 + s * ring
+    .. 1 + s * ring + ring - 1`` of every window layer."""
+    return 1 + (jnp.arange(num_slots, dtype=jnp.int32)[:, None] * ring
+                + jnp.arange(ring, dtype=jnp.int32)[None, :])
+
+
+def ring_write(slots, positions, keep, ring, page_size):
+    """``(page, row)`` at which position ``positions[i]`` of slot
+    ``slots[i]`` is written; where ``keep`` is false the write goes to
+    the null page."""
+    page = 1 + slots * ring + (positions // page_size) % ring
+    return jnp.where(keep, page, 0), jnp.where(keep, positions % page_size, 0)
+
+
+def ring_view(lengths, ring, page_size, window):
+    """What a slot of context ``lengths[i]`` reads from its ring:
+    ``(page_base [b, ring], starts [b])``. Ring page ``r`` holds the
+    newest logical page ``L <= last`` with ``L % ring == r``, so its row
+    0 is position ``L * page_size`` (negative before the ring has filled:
+    wholly below ``start``, never read); ``starts`` is the first position
+    inside the window."""
+    last = jnp.maximum(lengths - 1, 0) // page_size
+    r = jnp.arange(ring, dtype=jnp.int32)[None, :]
+    logical = last[:, None] - (last[:, None] - r) % ring
+    return (logical * page_size).astype(jnp.int32), \
+        jnp.maximum(lengths - window, 0).astype(jnp.int32)

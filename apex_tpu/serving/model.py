@@ -1,4 +1,7 @@
-"""Pure prefill / decode-step functions over the GPTModel param tree.
+"""Pure prefill / decode-step functions over the GPTModel param tree:
+the GPT-2 family of the serving engine (``serving/family.py`` is the
+seam; the MiMo family, with grouped-query attention, window layers and
+held experts, is ``serving/mimo.py``).
 
 The serving forward consumes the EXACT parameter tree
 ``GPTModel.init`` produces (standalone_transformer_lm.py — flagship
@@ -27,10 +30,13 @@ jaxpr-stability contract):
   next token. Decode matmuls optionally run int8-quantized weights
   (``apex_tpu.serving.quant`` — knob-gated, default OFF).
 
-Serving constraints (validated by :func:`check_serving_config`): no
-dropout, no query-key layer scaling (its coeff is a training-range
+Constraints of THIS family (validated by :func:`check_serving_config`):
+no dropout, no query-key layer scaling (its coeff is a training-range
 trick; minimal.py disables it for the same uniformity reason), single
-chip (tp=1 param shapes), no MoE/sequence/context parallelism.
+chip (tp=1 param shapes), no sequence/context parallelism, and the
+dense GPT-2 MLP only: a ``TransformerConfig`` with ``num_moe_experts``
+is the trainer's Switch block, which has no serving program (the engine
+as a whole serves experts through the MiMo family).
 """
 
 import math
@@ -56,7 +62,9 @@ def check_serving_config(cfg):
         problems.append("apply_query_key_layer_scaling (training-range "
                         "trick; set False like minimal.py)")
     if cfg.num_moe_experts:
-        problems.append("MoE")
+        problems.append("num_moe_experts (the trainer's Switch block has "
+                        "no serving program in the gpt2 family; held "
+                        "experts are served by serving/mimo.py)")
     if cfg.sequence_parallel or cfg.context_parallel_axis:
         problems.append("sequence/context parallelism (single-chip "
                         "serving engine)")

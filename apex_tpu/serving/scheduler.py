@@ -293,11 +293,18 @@ class ContinuousBatchingScheduler:
                 pages = self.allocator.alloc(owner, n)
         return pages
 
-    def admit(self, tick, wall_time=None):
+    def admit(self, tick, wall_time=None, token_budget=None):
         """Admission of every queued request that fits under the
         active policy, stopping at the first selected candidate that
         does not (head-of-line blocking — the no-starvation rule).
-        Returns the newly filled slot indices. ``wall_time`` (the
+        Returns the newly filled slot indices. ``token_budget`` (None:
+        no bound) also stops it at the first candidate whose known
+        stream would take this round's admissions past that many
+        tokens (the first always enters): the engine of a family that
+        prefills one dispatch a round hands in ``prefill_len``, and
+        what is left waits in the queue for the next round, so no
+        round holds the decode lanes for more than one prefill
+        dispatch. ``wall_time`` (the
         engine's host clock, one read per round) stamps each
         admission's ``admitted_wall`` — the same wall seam as
         :meth:`evict_done`, so replay latencies are seconds, not tick
@@ -316,6 +323,9 @@ class ContinuousBatchingScheduler:
             if not free:
                 break
             known = req.resume_tokens or req.prompt
+            if token_budget is not None and admitted \
+                    and len(known) > token_budget:
+                break
             shared, covered, tail = [], 0, None
             # a RESUMED request skips the prefix lookup: its effective
             # prompt is the preempted stream, not the prompt the cache
@@ -361,6 +371,8 @@ class ContinuousBatchingScheduler:
             if wall_time is not None:
                 req.admitted_wall = wall_time
             admitted.append(idx)
+            if token_budget is not None:
+                token_budget -= len(known)
         return admitted
 
     # -------------------------------------- KV-pressure preemption (15)
@@ -490,6 +502,28 @@ class ContinuousBatchingScheduler:
                 for j, p in enumerate(slot.pages):
                     rows[i][j] = p
         return rows
+
+    def context_pages(self, window=None):
+        """What a decode round reads of the two kinds of KV state
+        (serving/kv_cache.py), summed over the live slots: ``(global
+        pages, window pages)``. A global layer reads every page that
+        holds context, ``ceil(pos / page_size)`` a slot (admission
+        reserves more: the answer's pages too); a window layer reads the
+        ring pages that hold part of the slot's last ``window``
+        positions, one or two at a window no longer than a page. The
+        ring itself is the slot's, written at ``position mod ring``,
+        never allocated or freed: nothing of it is accounted here beyond
+        this count."""
+        ps = self.page_size
+        global_pages = window_pages = 0
+        for slot in self.slots:
+            if slot is None or slot.pos < 1:
+                continue
+            last = (slot.pos - 1) // ps
+            global_pages += last + 1
+            if window is not None:
+                window_pages += last - max(0, slot.pos - window) // ps + 1
+        return global_pages, window_pages
 
     def decode_inputs(self):
         """(tokens, lengths) int lists for the decode step: length 0

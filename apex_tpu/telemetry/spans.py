@@ -18,7 +18,14 @@ closes); there is no counter API, no exporter and no sink: read the ring
 in the process (:func:`snapshot`) or open the profiler trace.
 
 The ring holds ``CAPACITY`` = 2**17 records: a 51 s window at ten times
-PR 24's round rate (~10 rounds/s x ~10 spans). A record is a 7-tuple of
+PR 24's round rate (~10 rounds/s x ~10 spans). The fastest cell since
+(``serve-mimo-decode``, PR 27) makes 33 rounds/s x ~7 spans (6 a decode
+round, 11 with a prefill, 3 a finished request) = ~240 records/s: a 51 s
+window, a 40 s drain and the ramp before them are ~25,000 records, a
+fifth of the ring, and ``covers(t_open)`` held there (every
+``program_span`` metric of its traced run read). At that cell's 8.4 ms
+byte floor (119 rounds/s) it would be ~80,000: still inside. A record is
+a 7-tuple of
 two floats, two ints, a shared name and an optional small dict, about
 250 bytes (500 with attributes), so a full ring is bounded by ~64 MB and
 a day-long server holds its last 2**17 spans, no more. What fell off is
@@ -146,6 +153,12 @@ def record(name, t0, t1, rid=None, **attrs):
         stack = _stack()
         _append((next(_ids), stack[-1] if stack else None, name, t0, t1,
                  rid, attrs or None))
+
+
+def enabled():
+    """Whether spans are recorded: a caller whose ATTRIBUTES cost host
+    work asks before computing them."""
+    return _enabled
 
 
 def set_enabled(on):

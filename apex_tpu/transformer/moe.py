@@ -214,3 +214,132 @@ class ExpertParallelMLP(nn.Module):
         # [T, E, C] x [E, C, H] -> [T, H]
         out = jnp.einsum("tec,ech->th", combine.astype(x.dtype), expert_out)
         return out.astype(x.dtype)
+
+
+# ----------------------------------------------- dropless held-experts MLP
+#
+# Serving's expert layer (serving/mimo.py): sigmoid routing with a
+# selection-only bias over ALL experts, and the MLP of the experts this
+# chip HOLDS, with no capacity and no [T, E, C] one-hot: the T x k
+# assignments are sorted by expert, the held ones lead, one grouped
+# matmul runs over them and each token gathers its own rows back.
+# ``switch_routing`` above stays the trainer's.
+
+def route_sigmoid_topk(x, router_w, router_bias, k, norm_topk=True,
+                       scaling=1.0):
+    """Sigmoid top-k routing with a selection-only correction bias
+    (the ``noaux_tc`` method, one group).
+
+    x: [T, H]; router_w: [E, H]; router_bias: [E]. Scores are
+    ``sigmoid(x router_w^T)`` in float32; the top ``k`` of ``score +
+    bias`` are chosen; the weights are the chosen SCORES (the bias
+    selects and never weighs), divided by their sum when ``norm_topk``.
+    Returns ``(experts [T, k] int32, weights [T, k] float32)``."""
+    logits = lax.dot_general(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = lax.top_k(scores + router_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), w * scaling
+
+
+GMM_TILES = (128, 1024, 1024)   # rows, contraction, columns of a step
+
+
+def _effective_gmm_impl(impl, m):
+    """A per-call ``impl`` is a demand; otherwise the Pallas grouped
+    matmul where the default backend is a TPU and the tiles divide the
+    rows, ``lax.ragged_dot`` everywhere else."""
+    if impl is not None:
+        if impl not in ("pallas", "ragged_dot"):
+            raise ValueError(f"unknown grouped-matmul impl {impl!r}")
+        return impl
+    if jax.default_backend() == "tpu" and m % GMM_TILES[0] == 0:
+        return "pallas"
+    return "ragged_dot"
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, impl=None, interpret=None):
+    """``lhs[rows of group g] @ rhs[g]`` for row groups laid end to end
+    from row 0: lhs [m, k], rhs [g, k, n], group_sizes [g] int32 (their
+    sum may be less than m). Rows past the last group are UNDEFINED
+    (the Pallas kernel visits no tile there): mask them with a select,
+    never a multiply. float32 accumulation, result in lhs's dtype.
+
+    The kernel is JAX's ``megablox.gmm`` (grid: column tile x visited
+    (group, row tile) x contraction tile; a group's weights are read
+    once per row tile it touches, an untouched group's never)."""
+    m, k = lhs.shape
+    n = rhs.shape[-1]
+    if _effective_gmm_impl(impl, m) == "ragged_dot":
+        return lax.ragged_dot(lhs, rhs, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+    import importlib
+
+    # the package's ``gmm`` attribute is its custom_vjp wrapper; the
+    # kernel with the tiling and interpret arguments is the module's
+    gmm = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    tm, tk, tn = GMM_TILES
+    if m % tm:
+        raise ValueError(f"grouped_matmul: {m} rows do not divide by the "
+                         f"row tile {tm}")
+    return gmm.gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+                   preferred_element_type=jnp.float32,
+                   tiling=(tm, min(tk, k), min(tn, n)),
+                   interpret=interpret).astype(lhs.dtype)
+
+
+def held_experts_mlp(x, experts, weights, w_gate, w_up, w_down, first,
+                     valid=None, impl=None, interpret=None):
+    """The partial MoE sum of the experts this chip holds; dropless.
+
+    x: [T, H]; experts/weights: [T, k] from :func:`route_sigmoid_topk`
+    (over ALL experts); w_gate, w_up: [count, H, F], w_down: [count, F,
+    H], the held experts ``first .. first + count - 1``; valid: [T]
+    bool or None: rows that are tokens (a packed batch's padding and an
+    empty decode lane are not: they get 0 and cost no expert a row).
+    Returns ``(y [T, H], tokens_per_held [count] int32)``: ``y[t]`` is the weighted
+    sum over t's chosen experts that are held (zero when none is) and
+    the count is how many assignments each held expert received.
+
+    The T x k assignments are sorted with the held experts' first and
+    in expert order, so the grouped matmul's groups start at row 0 and
+    no tile is visited past the last held row. Every assignment keeps
+    its row whoever holds its expert: no capacity, nothing dropped."""
+    T, H = x.shape
+    k = experts.shape[1]
+    count = w_gate.shape[0]
+    local = experts.reshape(-1) - first                     # [T*k]
+    is_held = (local >= 0) & (local < count)
+    if valid is not None:
+        is_held = is_held & jnp.repeat(valid, k)
+    order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
+    tokens_per_held = jnp.sum(
+        jax.nn.one_hot(jnp.where(is_held, local, count), count + 1,
+                       dtype=jnp.int32), axis=0)[:count]
+    rows = jnp.take(x, order // k, axis=0)                  # [T*k, H]
+    with jax.named_scope("gmm"):
+        gate = grouped_matmul(rows, w_gate, tokens_per_held, impl=impl,
+                              interpret=interpret)
+        up = grouped_matmul(rows, w_up, tokens_per_held, impl=impl,
+                            interpret=interpret)
+        inner = (jax.nn.silu(gate.astype(jnp.float32))
+                 * up.astype(jnp.float32)).astype(x.dtype)
+        out = grouped_matmul(inner, w_down, tokens_per_held, impl=impl,
+                             interpret=interpret)
+    # each assignment's row back beside its token (a gather through the
+    # inverse permutation), rows of absent experts selected away
+    inverse = jnp.argsort(order)
+    back = jnp.take(out, inverse, axis=0).reshape(T, k, H)
+    w = jnp.where(is_held.reshape(T, k), weights, 0.0)
+    y = jnp.sum(jnp.where(is_held.reshape(T, k, 1),
+                          back.astype(jnp.float32), 0.0)
+                * w[..., None], axis=1)
+    return y.astype(x.dtype), tokens_per_held

@@ -16,6 +16,11 @@ h=768, 12 heads, vocab 50304) with random weights from a seed:
   streams part the check asks the prefill program whether the two
   tokens were tied within bf16 resolution — a tie may fall either way,
   anything else is a failure.
+* server, MiMo family — the same engine path over the rehearse twin of
+  ``perf/configs/mimo-v2.5-ep16.json`` (two KV caches, grouped-query
+  decode and prefill kernels, held experts through the grouped matmul,
+  all compiled by Mosaic on the chip), greedy tokens judged against the
+  plain float32 reference ``perf/references/mimo_v2.py``.
 * kernels  — each Pallas family the default path does not reach (rows
   attention fwd+bwd in both backward structures, with segment ids, with
   dropout; layer norm; scale-mask softmax; the fused LM head; bf16 and
@@ -426,6 +431,104 @@ def run_server(size, log, seen, interpret):
     return recs
 
 
+# ------------------------------------------------------- server, MiMo family
+
+MIMO = dict(slots=32, page_size=16, pages=96, max_seq=256, prefill_len=256,
+            requests=6, prompt=(8, 120), new_tokens=(20, 40))
+MIMO_DRY = dict(MIMO, slots=4, pages=48, requests=3, new_tokens=(6, 12))
+
+
+def run_mimo_server(log, interpret):
+    """The MiMo-V2 family through the same ``ServingEngine`` path at the
+    rehearse size of ``perf/configs/mimo-v2.5-ep16.json`` (published K
+    192 / V 128 head widths, 32 heads on 2 and 4 KV heads, window 16,
+    16 experts top-4 of which 4 are held): on the chip both attention
+    kernels and the grouped expert matmul are compiled by Mosaic; the
+    engine's greedy tokens are judged against the plain float32
+    reference ``perf/references/mimo_v2.py`` (within ``_TIE_STEPS``
+    bfloat16 steps of its best logit at every position)."""
+    import importlib.util
+
+    from apex_tpu.serving import ServingEngine, mimo
+    from apex_tpu.serving.scheduler import Request
+
+    with open(os.path.join(ROOT, "perf", "configs",
+                           "mimo-v2.5-ep16.json")) as f:
+        config = json.load(f)
+    config.update(config.pop("rehearse"))
+    config["held_experts"] = tuple(config["held_experts"])
+    spec = importlib.util.spec_from_file_location(
+        "mimo_v2_reference",
+        os.path.join(ROOT, "perf", "references", "mimo_v2.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+
+    size = MIMO_DRY if interpret else MIMO
+    cfg = mimo.MiMoConfig.from_dict(config)
+    params = mimo.init_params(cfg, jax.random.PRNGKey(0))
+    t0 = time.perf_counter()
+    engine = ServingEngine(
+        cfg, params=params, num_slots=size["slots"],
+        page_size=size["page_size"], num_pages=size["pages"],
+        max_seq=size["max_seq"], prefill_len=size["prefill_len"],
+        decode_impl="pallas" if interpret else None,
+        interpret=True if interpret else None)
+    engine.run_trace([Request(rid=-1, prompt=[1, 2, 3], max_new_tokens=2)])
+    cold_s = time.perf_counter() - t0
+
+    rs = np.random.RandomState(0)
+    requests = [Request(
+        rid=i, prompt=rs.randint(0, cfg.vocab_size,
+                                 rs.randint(*size["prompt"])).tolist(),
+        max_new_tokens=int(rs.randint(*size["new_tokens"])), arrival=i)
+        for i in range(size["requests"])]
+    t_run = time.perf_counter()
+    rounds = _drain(engine, requests)
+    t_end = time.perf_counter()
+    assert all(len(r.out_tokens) == r.max_new_tokens for r in requests)
+    assert engine.prefill_cache_size() == 1 \
+        and engine.decode_cache_size() == 1
+    steady_compiles = log.between(t_run, t_end)
+    assert steady_compiles == 0, (
+        f"mimo: {steady_compiles} compilation(s) after warm-up")
+
+    worst, judged = 0.0, 0
+    for r in requests:
+        seq = list(r.prompt) + list(r.out_tokens)
+        ids = np.zeros(size["max_seq"], np.int32)   # one shape for all:
+        ids[:len(seq)] = seq                        # causal, padding after
+        best, chosen = reference.best_and_chosen(config, params, ids)
+        at = slice(len(r.prompt) - 1, len(seq) - 1)
+        steps = (best[at] - chosen[at]) / np.asarray(
+            [reference.bf16_step(b) for b in best[at]])
+        worst, judged = max(worst, float(steps.max())), judged + len(steps)
+    assert worst <= _TIE_STEPS, (
+        f"mimo: an emitted token lies {worst:.2f} bf16 steps below the "
+        f"reference's best logit (allowed {_TIE_STEPS})")
+
+    ran = dict(_server_lowerings(engine),
+               decode_attn_impl=engine.decode_attn_impl)
+    if not interpret:
+        # an attention kernel of each layer kind and the grouped matmul
+        # (a lowered module holds each distinct kernel once, however
+        # many layers call it)
+        assert ran["decode_attn_impl"] == "pallas" \
+            and ran["decode_mosaic_calls"] >= 3 \
+            and ran["prefill_mosaic_calls"] >= 3, ran
+    rec = {"requests": len(requests), "judged_tokens": judged,
+           "worst_gap_bf16_steps": round(worst, 3),
+           "cold_s": round(cold_s, 2), "rounds": len(rounds),
+           "decode_round_ms": round(statistics.median(
+               s for s, pre, dec in rounds if dec and not pre) * 1e3, 2),
+           "compiles_after_warmup": steady_compiles, "ran": ran}
+    _say(f"  [mimo] {rec['requests']} requests answered; {judged} tokens "
+         f"judged against the float32 reference, worst gap "
+         f"{rec['worst_gap_bf16_steps']} bf16 steps (allowed "
+         f"{_TIE_STEPS}); cold {rec['cold_s']} s; decode round "
+         f"{rec['decode_round_ms']} ms; ran: {ran}")
+    return rec
+
+
 # ------------------------------------------------------------------ kernels
 
 def _close(got, want, atol, rel_bound=_REL):
@@ -723,6 +826,10 @@ def main(argv=None):
          f"{size['pages']} x {size['page_size']}-token pages, "
          f"prefill {size['prefill_len']}")
     record["phases"]["server"] = run_server(size, log, seen, interpret=dry)
+
+    _say("phase server, MiMo family: grouped-query decode over the paged "
+         "pool and the window rings, held experts")
+    record["phases"]["server_mimo"] = run_mimo_server(log, interpret=dry)
 
     _say("phase kernels: Pallas families "
          + ("in interpret mode" if dry else "compiled by Mosaic")

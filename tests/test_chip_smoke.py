@@ -46,7 +46,8 @@ def test_dry_run_passes_is_marked_and_uses_the_placed_cache(tmp_path):
                                 "count": 1}}
     record = json.load(open(tmp_path / "out" / "chip_smoke.json"))
     assert record["dry_run"] is True
-    assert set(record["phases"]) == {"trainer", "server", "kernels"}
+    assert set(record["phases"]) == {"trainer", "server", "server_mimo",
+                                     "kernels"}
     assert record["phases"]["trainer"]["compiles_after_warmup"] == 0
     for engine in ("default", "jnp"):
         assert record["phases"]["server"][engine][

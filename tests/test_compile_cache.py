@@ -164,6 +164,9 @@ def test_activate_placement_rules(tmp_path, monkeypatch):
         real_update(name, value)
 
     prev_dir = jax.config.jax_compilation_cache_dir
+    # a file that ran earlier in this worker may have placed the cache
+    # (any ServingEngine does): start from a process without one
+    real_update("jax_compilation_cache_dir", None)
     monkeypatch.setattr(jax.config, "update", spy)
     try:
         # nothing placed + the opt-out: no cache, nothing touched

@@ -107,3 +107,93 @@ def test_serve_cell_decode_program_feeds_the_kernel_without_a_copy(one_chip):
     # no per-layer slice of the cache is materialised for the kernel
     one_layer = rf"= bf16\[(?:{h},{pages},{ps}|{pages},{ps},{h}),{d}\]"
     assert not re.findall(one_layer, text)
+
+
+# ---- the MiMo family's kernels at the widths serve-mimo-decode runs ----
+
+@pytest.mark.parametrize("n_kv,n,pages,sink", [
+    (4, 24, 1536, False),   # a global layer: 4 KV heads, the paged pool
+    (8, 2, 129, True),      # a window layer: 8 KV heads, rings of 2, sinks
+], ids=["mimo-global", "mimo-window"])
+def test_grouped_decode_kernel_compiles_for_the_v5e(one_chip, n_kv, n,
+                                                    pages, sink):
+    hq, dk, dv, ps, b = 64, 192, 128, 128, 64
+    assert dap.grouped_supported(hq, n_kv, dk, dv, ps, jnp.bfloat16)
+
+    def f(q, k, v, table, base, starts, lengths, *s):
+        return dap.grouped_decode_attention(
+            q, k, v, table, lengths, n_kv=n_kv, page_base=base,
+            starts=starts, sink=s[0] if s else None, impl="pallas",
+            interpret=False)
+
+    args = [_sds(one_chip, (b, hq, dk), jnp.bfloat16),
+            _sds(one_chip, (pages, ps, n_kv * dk), jnp.bfloat16),
+            _sds(one_chip, (pages, ps, n_kv * dv), jnp.bfloat16),
+            _sds(one_chip, (b, n), jnp.int32),
+            _sds(one_chip, (b, n), jnp.int32),
+            _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.int32)]
+    if sink:
+        args.append(_sds(one_chip, (hq,), jnp.float32))
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and dap.GROUPED_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("n_kv,window,sink", [(4, None, False),
+                                              (8, 128, True)],
+                         ids=["mimo-global", "mimo-window"])
+def test_packed_prefill_kernel_compiles_for_the_v5e(one_chip, n_kv, window,
+                                                    sink):
+    from apex_tpu.ops import attention_pallas as ap
+    from apex_tpu.ops.attention import packed_gqa_attention
+
+    hq, dk, dv, S = 64, 192, 128, 2048
+    assert ap.packed_supported(S, dk, dv)
+
+    def f(q, k, v, seg, *s):
+        return packed_gqa_attention(q, k, v, seg, window=window,
+                                    sink=s[0] if s else None,
+                                    impl="pallas", interpret=False)
+
+    args = [_sds(one_chip, (hq, S, dk), jnp.bfloat16),
+            _sds(one_chip, (n_kv, S, dk), jnp.bfloat16),
+            _sds(one_chip, (n_kv, S, dv), jnp.bfloat16),
+            _sds(one_chip, (S,), jnp.int32)]
+    if sink:
+        args.append(_sds(one_chip, (hq,), jnp.float32))
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and ap.PACKED_KERNEL_NAME in text
+
+
+def test_mimo_decode_program_compiles_with_no_copy_of_a_cache(one_chip):
+    """Published widths, 64 slots, pages of 128, one layer of each kind
+    with 16 held experts: the decode program reaches both attention
+    kernels and the three grouped matmuls of an expert layer, and no
+    array of a cache's shape is copied."""
+    import functools
+
+    from apex_tpu.serving import mimo
+
+    slots, ps, pages = 64, 128, 192
+    cfg = mimo.MiMoConfig(
+        vocab_size=2048, max_position_embeddings=1048576,
+        hybrid_layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+        held_experts=(0, 16))
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: mimo.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = sds(jax.eval_shape(
+        lambda: mimo.init_cache(cfg, slots, pages, ps)))
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    decode = functools.partial(mimo.decode_step, cfg=cfg,
+                               decode_impl="pallas", moe_impl="pallas",
+                               interpret=False)
+    text = jax.jit(decode, donate_argnums=(1,)).lower(
+        params, cache, i32(slots), i32(slots), i32(slots, 3072 // ps)
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2 + 3
+    cache_shapes = r"bf16\[(?:192|129),128,(?:768|512|1536|1024)\]"
+    assert not re.findall(rf"= {cache_shapes}\S* copy\(", text)
