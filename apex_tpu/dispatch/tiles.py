@@ -7,10 +7,10 @@ that arithmetic (layer_norm_pallas ``_row_block``, softmax_pallas
 ``_sq_block``, attention_pallas ``_q_block``/``_split_ok``, xent_pallas
 ``_row_block``/``_v_chunk``) and the block size itself was an
 *asserted* heuristic — the one dispatch decision the measured-dispatch
-rule didn't reach. This module is the single implementation all the
-Pallas kernels (the four training families plus the serving
-decode-attention kernel) and the dispatch table's ``params`` payloads
-consult:
+rule didn't reach. This module is the single implementation the four
+training families' Pallas kernels and the dispatch table's ``params``
+payloads consult (the serving decode-attention kernel takes whole pages
+and has no tile to choose):
 
 * ``legal(op, dims, dtype, params)`` — the judge. Empty list = the
   tile lowers (divisibility + VMEM model); non-empty names every
@@ -46,8 +46,6 @@ layer_norm     ``block_rows`` (row block, fwd + bwd)
 softmax        ``block_rows`` (sq block, fwd + bwd)
 lm_head        ``row_block`` (exact row block), ``vmem_budget``
                (bytes — the model cap the row block is sized under)
-decode_        ``block_h`` (heads per grid step of the paged-KV
-attention      serving decode kernel — ISSUE 10)
 =============  =====================================================
 """
 
@@ -82,21 +80,12 @@ XENT_ROW_CAP = 512  # the shipped _ROW_BLOCK cap
 XENT_MIN_VMEM = 1 * 1024 * 1024
 XENT_MAX_VMEM = 16 * 1024 * 1024
 
-# decode attention (ops/decode_attention_pallas.py — the serving
-# q_len=1 kernel over paged K/V, ISSUE 10): per grid step, block_h
-# heads' K and V page blocks [page_size, block_h, head_dim] plus the
-# fp32 online-softmax accumulators stay VMEM-resident. Heads are the
-# block's second-minor axis, so a block is all of h or whole sublane
-# tiles of it; legality is that, divisibility, and the working set.
-DECODE_VMEM_BUDGET = 8 * 1024 * 1024
-
 PARAM_KEYS = {
     "attention": ("block_q", "bwd_block_q", "block_k"),
     "attention_bwd": ("bwd_block_q", "block_k"),
     "layer_norm": ("block_rows",),
     "softmax": ("block_rows",),
     "lm_head": ("row_block", "vmem_budget"),
-    "decode_attention": ("block_h",),
 }
 
 # dims each op's model needs (the same names its dispatch bucket uses)
@@ -106,7 +95,6 @@ DIM_KEYS = {
     "layer_norm": ("rows", "hidden"),
     "softmax": ("b", "h", "sq", "sk"),
     "lm_head": ("n", "v", "h"),
-    "decode_attention": ("b", "h", "pages", "ps", "d"),
 }
 
 _DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4, "float64": 8,
@@ -128,7 +116,7 @@ def env_int(name):
     window is loud, not silently the default shape). The one parser
     behind APEX_ATTN_BLOCK_Q / APEX_LN_BLOCK_ROWS /
     APEX_SOFTMAX_BLOCK_ROWS / APEX_XENT_ROW_BLOCK /
-    APEX_DECODE_ATTN_BLOCK_H / APEX_BENCH_BATCH / APEX_ATTN_SEQ, so
+    APEX_BENCH_BATCH / APEX_ATTN_SEQ, so
     the knob-parsing semantics cannot drift apart."""
     v = os.environ.get(name)
     if v in (None, ""):
@@ -444,72 +432,6 @@ def _xent_legal(dims, dtype, params):
     return problems
 
 
-# ------------------------------------------------------ decode attention
-
-def _decode_sublanes(itembytes):
-    """Rows of one sublane tile at this itemsize (fp32 8, bf16 16,
-    int8 32): what a head block that is not all of h must divide by,
-    heads being the second-minor axis of the kernel's page block."""
-    return SUBLANE * max(1, 4 // itembytes)
-
-
-def decode_vmem_bytes(bh, ps, d, itembytes):
-    """Resident set of one decode-attention grid step: block_h heads'
-    K and V page blocks ``[ps, bh, d]``, each held twice by the
-    pipeline, as they sit in VMEM (heads padded to the sublane tile,
-    head_dim to the lane width), plus the fp32 q rows and (acc, m, l)
-    online-softmax accumulators. The int8 tier's per-(page, head)
-    scale rows are a few KB and not counted."""
-    sub = _decode_sublanes(itembytes)
-    rows = -(-bh // sub) * sub
-    lanes = -(-d // LANE) * LANE
-    return 4 * ps * rows * lanes * itembytes \
-        + 4 * (-(-bh // SUBLANE) * SUBLANE) * (2 * lanes + 2 * LANE)
-
-
-def _decode_head_block_ok(bh, h, itembytes):
-    return h % bh == 0 and (bh == h
-                            or bh % _decode_sublanes(itembytes) == 0)
-
-
-def decode_block_h(h, ps, d, itembytes):
-    """The decode-attention heuristic: the largest legal head block
-    (all of h, or a divisor of h that is whole sublane tiles) whose
-    page working set fits the budget — every grid step costs the same
-    fixed overhead whatever it moves, so fewer, larger steps win
-    (measured at h=20 on the v5e, PERF.md §6 PR 26). 0 when none
-    fits."""
-    for b in range(h, 0, -1):
-        if _decode_head_block_ok(b, h, itembytes) \
-                and decode_vmem_bytes(b, ps, d, itembytes) \
-                <= DECODE_VMEM_BUDGET:
-            return b
-    return 0
-
-
-def _decode_legal(dims, dtype, params):
-    h, ps, d = dims["h"], dims["ps"], dims["d"]
-    bh = params.get("block_h")
-    problems = []
-    if bh is not None:
-        item = itemsize(dtype)
-        if not isinstance(bh, int) or bh < 1:
-            problems.append(f"block_h={bh!r} must be a positive int")
-        elif h % bh:
-            problems.append(f"block_h={bh} does not divide h={h}")
-        elif not _decode_head_block_ok(bh, h, item):
-            problems.append(
-                f"block_h={bh} is neither h={h} nor a multiple of the "
-                f"{_decode_sublanes(item)}-row sublane tile")
-        elif decode_vmem_bytes(bh, ps, d, item) > DECODE_VMEM_BUDGET:
-            problems.append(
-                f"block_h={bh}: page working set "
-                f"{decode_vmem_bytes(bh, ps, d, item)} B "
-                f"exceeds the {DECODE_VMEM_BUDGET} B VMEM budget at "
-                f"ps={ps} d={d}")
-    return problems
-
-
 # ----------------------------------------------------------- the surface
 
 _LEGAL = {
@@ -518,7 +440,6 @@ _LEGAL = {
     "layer_norm": _ln_legal,
     "softmax": _sm_legal,
     "lm_head": _xent_legal,
-    "decode_attention": _decode_legal,
 }
 
 
@@ -578,13 +499,6 @@ def model_vmem_bytes(op, dims, dtype, params=None):
             return None
         h = dims["h"]
         return 6 * bv * h + br * max(8 * h + 8 * bv, 6 * h + 10 * bv)
-    if op == "decode_attention":
-        bh = params.get("block_h") or decode_block_h(
-            dims["h"], dims["ps"], dims["d"], itemsize(dtype))
-        if not bh:
-            return None
-        return decode_vmem_bytes(bh, dims["ps"], dims["d"],
-                                 itemsize(dtype))
     return None
 
 
@@ -631,10 +545,6 @@ def default_params(op, dims, dtype):
             return None
         br = xent_row_block(dims["n"], dims["h"], bv)
         return {"row_block": br} if br else None
-    if op == "decode_attention":
-        bh = decode_block_h(dims["h"], dims["ps"], dims["d"],
-                            itemsize(dtype))
-        return {"block_h": bh} if bh else None
     return None
 
 

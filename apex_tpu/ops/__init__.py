@@ -12,7 +12,7 @@ from apex_tpu.ops.context_parallel import (  # noqa: F401
     ulysses_attention,
 )
 from apex_tpu.ops.decode_attention_pallas import (  # noqa: F401
-    decode_attention,
+    grouped_decode_attention,
 )
 from apex_tpu.ops import layer_norm_pallas  # noqa: F401
 from apex_tpu.ops import softmax_pallas  # noqa: F401

@@ -60,13 +60,12 @@ Knob resolution at engine build (the CLAUDE.md asymmetry):
 * ``spec_decode=`` per-call RAISES on an un-honorable draft length
   (< 1, or deeper than the prefill bucket); the env preference falls
   back per shape.
-* ``decode_impl=`` / ``decode_block_h=`` ride per-call into the
-  decode-attention family on every step (raising semantics live
-  there); None defers to the family's rule (the paged Pallas kernel
-  on a TPU where it supports the cache geometry, the jnp reference
-  otherwise; ``tp > 1`` passes ``"jnp"``). ``engine.decode_attn_impl``
-  / ``.decode_attn_block_h`` say what the decode program was built
-  with.
+* ``decode_impl=`` rides per-call into the decode-attention family on
+  every step (raising semantics live there); None defers to the
+  family's rule (the paged Pallas kernel on a TPU where it supports the
+  cache geometry, the jnp reference otherwise; ``tp > 1`` passes
+  ``"jnp"``). ``engine.decode_attn_impl`` says what the decode program
+  was built with.
 * ``policy=`` per-call unknown policies RAISE
   (``scheduler.resolve_policy``); None defers to ``APEX_SERVE_SCHED``
   (vocabulary ``fifo`` | ``priority``).
@@ -206,7 +205,7 @@ class ServingEngine:
     def __init__(self, cfg, params=None, *, num_slots=4, page_size=16,
                  num_pages=64, max_seq=None, prefill_len=64,
                  prefill_requests=None, weight_quant=None, tp=None,
-                 decode_impl=None, decode_block_h=None, interpret=None,
+                 decode_impl=None, interpret=None,
                  policy=None, sampling=None, spec_decode=None,
                  decode_k=None, prefix_cache=None, overlap=None,
                  admit=None,
@@ -225,14 +224,13 @@ class ServingEngine:
                 tp=tp, weight_quant=weight_quant, kv_quant=kv_quant,
                 kv_swap=kv_swap, prefix_cache=prefix_cache,
                 spec_decode=spec_decode, decode_k=decode_k,
-                overlap=overlap, decode_block_h=decode_block_h))
+                overlap=overlap))
             # (spec_decode takes no per-call "off": its resolved K is
             # zeroed below)
             tp, weight_quant, kv_quant, kv_swap, prefix_cache, decode_k, \
-                overlap, decode_block_h = (opts[name] for name in (
+                overlap = (opts[name] for name in (
                     "tp", "weight_quant", "kv_quant", "kv_swap",
-                    "prefix_cache", "decode_k", "overlap",
-                    "decode_block_h"))
+                    "prefix_cache", "decode_k", "overlap"))
         # the prefill/decode programs are the expensive compiles of a
         # server start: keep them in the persistent cache
         compile_cache.activate()
@@ -283,11 +281,9 @@ class ServingEngine:
         if decode_impl is None and self.tp > 1:
             decode_impl = "jnp"
         self.decode_impl = decode_impl
-        self.decode_block_h = decode_block_h
         self.interpret = interpret
-        # what a family is handed of the three (serving/family.py)
-        self._kernels = family_mod.Kernels(decode_impl, decode_block_h,
-                                           interpret)
+        # what a family is handed of the two (serving/family.py)
+        self._kernels = family_mod.Kernels(decode_impl, interpret)
 
         # generation knobs (ISSUE 13): sampling / speculative decode /
         # prefix cache, each defaulting OFF (measured-dispatch rule)
@@ -465,7 +461,7 @@ class ServingEngine:
         # mesh; the jitted programs below are UNTOUCHED — GSPMD
         # partitions them from these committed input shardings
         # (qkv/h_to_4h column-split on whole heads, attn-dense/
-        # 4h_to_h row-split, cache on its leading head axis), so the
+        # 4h_to_h row-split, cache on its last, heads' axis), so the
         # one-compile contract holds on the mesh and every host-side
         # layer composes unchanged (serving/tp.py docstring).
         self.mesh = tp_mod.mesh_for(self.tp) if self.tp > 1 else None
@@ -508,13 +504,11 @@ class ServingEngine:
                                   kernels=self._kernels)
 
         # which decode-attention program the decode program is built
-        # with ("pallas" | "jnp") and its head block (None on jnp);
-        # the decode.dispatch span carries both
-        self.decode_attn_impl, self.decode_attn_block_h = \
-            family.decode_attention(cfg, self.cache, self._kernels)
+        # with ("pallas" | "jnp"); the decode.dispatch span carries it
+        self.decode_attn_impl = family.decode_attention(
+            cfg, self.cache, self._kernels)
         self._dispatch_attrs = {"prefill": {}, "decode": dict(
-            attn_impl=self.decode_attn_impl,
-            block_h=self.decode_attn_block_h)}
+            attn_impl=self.decode_attn_impl)}
 
         # the decode program: at K=1 the single decode step; at K>1 the
         # ONE lax.scan K-block program replaces it (K is static — at
@@ -550,42 +544,39 @@ class ServingEngine:
                     params, cache, tokens, lengths, page_table, cfg=cfg,
                     qparams=qparams, kernels=self._kernels)
 
+        # the page hops below run over every cache leaf alike: each
+        # carries its page axis FIRST (serving/kv_cache.py), the int8
+        # tier's [pages, h] scales too, so a hop moves a page's codes
+        # AND its scales
         def _copy(cache, src, dst):
-            # one K/V page src -> dst across all layers/heads; src/dst
-            # are traced scalars, so every COW/snapshot hop reuses ONE
+            # one K/V page src -> dst in every layer; src/dst are
+            # traced scalars, so every COW/snapshot hop reuses ONE
             # compiled copy and the donated cache updates in place —
             # an eager .at[].set here would materialize the ENTIRE
-            # cache per copied page. Iterates every cache leaf: the
-            # int8 tier's [L, h, P] scale planes carry their page axis
-            # at axis 2 exactly like the code arrays, so a COW copy
-            # moves a page's codes AND its scale in the same hop.
-            for part in cache:
-                page = jax.lax.dynamic_index_in_dim(
-                    cache[part], src, axis=2, keepdims=False)
-                cache[part] = cache[part].at[:, :, dst].set(page)
-            return cache
+            # cache per copied page
+            return jax.tree.map(lambda leaf: leaf.at[dst].set(leaf[src]),
+                                cache)
 
         def _swap_gather(cache, page_idx):
             # host swap tier (ISSUE 20), device half of swap-OUT: one
-            # victim's pages gathered along every leaf's page axis at
-            # a [max_pages] index row PADDED with null page 0 (zero
-            # codes, zero scale), so this program compiles exactly
-            # once whatever the victim's live page count — the
-            # one-compile contract holds; the host device_get of the
-            # result is the staging copy, never a third serving
-            # program
-            return {name: jnp.take(cache[name], page_idx, axis=2)
-                    for name in cache}
+            # victim's pages gathered from every layer's leaf at a
+            # [max_pages] index row PADDED with null page 0 (zero
+            # codes, zero scale) and stacked [layers, max_pages, ...]
+            # a leaf name, so this program compiles exactly once
+            # whatever the victim's live page count — the one-compile
+            # contract holds; the host device_get of the result is the
+            # staging copy, never a third serving program
+            return {name: jnp.stack([leaf[page_idx] for leaf in leaves])
+                    for name, leaves in cache.items()}
 
-        def _swap_scatter(cache, page_idx, leaves):
+        def _swap_scatter(cache, page_idx, banked):
             # device half of swap-IN: the banked leaves scatter back
             # at the freshly granted pages; the padded tail entries
             # re-write null page 0 with its own zero content — benign,
             # and the program compiles exactly once
-            for name in cache:
-                cache[name] = cache[name].at[:, :, page_idx].set(
-                    leaves[name])
-            return cache
+            return {name: [leaf.at[page_idx].set(banked[name][i])
+                           for i, leaf in enumerate(leaves)]
+                    for name, leaves in cache.items()}
 
         # donate the cache: the scatter-updated pages stay in place
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))

@@ -22,8 +22,9 @@ A family is a :class:`Family` of plain functions:
   its tokens and ``fetch_attrs(extras)`` turns into span attributes;
 * ``decode_block`` (K steps in one dispatch) and
   ``quantize_decode_params``, or None where the family has none;
-* ``decode_attention(cfg, cache, kernels)`` -> ``(impl, block_h)`` for
-  the ``decode.dispatch`` span;
+* ``decode_attention(cfg, cache, kernels)`` -> the impl ("pallas" |
+  "jnp") the decode program is built with, for the ``decode.dispatch``
+  span;
 * ``refused``: the engine options the family cannot honour, by name. A
   per-call demand of one raises at engine build, naming it; an
   environment preference for one is dropped (CLAUDE.md: explicit request
@@ -36,7 +37,7 @@ A family is a :class:`Family` of plain functions:
 
 A family is handed values, never the engine: ``kernels`` is the
 :class:`Kernels` the caller asked for (``decode_impl``,
-``decode_block_h``, ``interpret``). The two ``*_attrs`` functions run
+``interpret``). The two ``*_attrs`` functions run
 only while the span recorder is on.
 """
 
@@ -62,7 +63,6 @@ class Kernels:
     """The caller's per-call demands on a family's attention kernels
     (None: the family's own rule)."""
     decode_impl: Optional[str] = None
-    decode_block_h: Optional[int] = None
     interpret: Optional[bool] = None
 
 
@@ -90,7 +90,7 @@ class Family:
 # argument that switches it off whatever the environment prefers
 OPTIONS_OFF = {"tp": 1, "weight_quant": False, "kv_quant": False,
                "kv_swap": False, "prefix_cache": False, "spec_decode": 0,
-               "decode_k": 1, "overlap": False, "decode_block_h": None}
+               "decode_k": 1, "overlap": False}
 
 
 def settle_options(family, options):
@@ -141,10 +141,10 @@ def _gpt2():
             **dataclasses.asdict(kernels))
 
     def decode_attention(cfg, cache, kernels):
-        _, _, pages, ps, hd = cache["k"].shape
-        return dap.resolved(cfg.num_attention_heads, pages, ps, hd,
-                            cache["k"].dtype, kernels.decode_impl,
-                            kernels.decode_block_h)
+        leaf = cache["k"][0]
+        return dap.grouped_resolved(
+            cfg.num_attention_heads, cfg.num_attention_heads, cfg.head_dim,
+            cfg.head_dim, leaf.shape[1], leaf.dtype, kernels.decode_impl)
 
     return Family(
         name="gpt2", check_config=smodel.check_serving_config,
@@ -180,7 +180,7 @@ def _mimo():
 
     def decode_attention(cfg, cache, kernels):
         return mimo.decode_attention_resolved(
-            cfg, cache, kernels.decode_impl), None
+            cfg, cache, kernels.decode_impl)
 
     def fetch_attrs(extras):
         counts = np.asarray(extras["expert_tokens"])   # [moe layers, held]

@@ -1,20 +1,26 @@
 """Paged KV cache: block-granular allocation as index arithmetic.
 
-Device side: per layer, K and V live as ``[h, num_pages, page_size,
-head_dim]`` arrays stacked over layers into ``[layers, h, num_pages,
-page_size, head_dim]`` — the page axis is a plain array axis, so
-"allocating" a page to a sequence is writing its index into that
-sequence's page-table row and "freeing" it is forgetting the index.
-No reshape, no growing array, no recompile: the decode step's operand
-shapes are fixed for the life of the engine, whatever the scheduler
-does between steps (the ISSUE 10 jaxpr-stability contract, asserted
-by tests/test_serving.py).
+Device side: per layer, K and V live as ``[num_pages, page_size,
+kv_heads * width]`` arrays, one leaf a layer: the page axis is a plain
+array axis, so "allocating" a page to a sequence is writing its index
+into that sequence's page-table row and "freeing" it is forgetting the
+index. No reshape, no growing array, no recompile: the decode step's
+operand shapes are fixed for the life of the engine, whatever the
+scheduler does between steps (the ISSUE 10 jaxpr-stability contract,
+asserted by tests/test_serving.py).
 
-The head axis leads the page axis because the decode-attention
-kernel's BlockSpec tiles heads (``block_h``) while the page block's
-trailing ``(page_size, head_dim)`` dims span their full array axes —
-Mosaic's last-two-dims rule is then satisfied for every legal head
-block (see ops/decode_attention_pallas.py).
+(head, width) is the minor axis: a token's K or V of every head is one
+row, 1280 lanes at GPT-2 large, 768 / 512 at MiMo's global layers, whole
+lane tiles under ``page_size`` rows of whole sublane tiles. Nothing is
+padded; a step scatters ``[tokens, kv_heads * width]`` rows in place;
+the decode kernel (ops/decode_attention_pallas.py) DMAs a page where it
+lies; no program copies a cache (PERF.md §6, PR 27 and PR 28: with
+heads leading pages and 64 columns a row XLA re-laid the whole cache,
+padded 2.4x, at the entry and exit of both programs: 24 of a 36 ms
+decode round). Every leaf carries its page axis FIRST, the int8 tier's
+``[num_pages, kv_heads]`` scales too, so the engine's page hops and the
+host swap tier treat every leaf alike, and tensor-parallel serving
+shards the LAST axis (heads are contiguous blocks of it).
 
 Host side: :class:`PageAllocator` — an explicit free list over pages
 ``1..num_pages-1``. Page 0 is RESERVED as the null page: padded
@@ -28,27 +34,38 @@ import jax.numpy as jnp
 
 def init_cache(num_layers, num_heads, num_pages, page_size, head_dim,
                dtype=jnp.bfloat16, kv_quant=False):
-    """Zeroed cache dict ``{"k", "v"}`` of
-    ``[layers, h, num_pages, page_size, head_dim]`` arrays.
+    """Zeroed cache dict ``{"k", "v"}`` of a model whose layers all keep
+    every token under ``num_heads`` KV heads ``head_dim`` wide (the GPT-2
+    family): each a list with one ``[num_pages, page_size, num_heads *
+    head_dim]`` array a layer, the pool of :func:`init_hybrid_cache`.
 
     ``kv_quant=True`` (the int8 KV tier, ISSUE 20) stores the code
     arrays as int8 and adds per-(page, head) bf16 scale leaves
-    ``{"k_scale", "v_scale"}`` of ``[layers, h, num_pages]`` — pages
-    at axis 2 and heads at axis 1 exactly like the code arrays, so
-    page-copy helpers and the TP ``cache_shardings`` treat every leaf
-    uniformly. Zero scales make the all-zero init exact: a zero scale
-    dequantizes (and quantizes) to exact zeros, which is also what
-    pins null page 0 dead through the codec."""
-    shape = (num_layers, num_heads, num_pages, page_size, head_dim)
+    ``{"k_scale", "v_scale"}``, ``[num_pages, num_heads]`` a layer. Zero
+    scales make the all-zero init exact: a zero scale dequantizes (and
+    quantizes) to exact zeros, which is also what pins null page 0 dead
+    through the codec."""
     if kv_quant:
         from apex_tpu.serving import kv_tier
 
-        cache = {"k": jnp.zeros(shape, kv_tier.CODE_DTYPE),
-                 "v": jnp.zeros(shape, kv_tier.CODE_DTYPE)}
-        cache.update(kv_tier.init_scales(num_layers, num_heads,
-                                         num_pages))
-        return cache
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        dtype = kv_tier.CODE_DTYPE
+    # every layer global: no slot owns a ring, no window kind
+    pool = init_hybrid_cache(
+        (0,) * num_layers, num_pages, 0, page_size, 0,
+        (num_heads, head_dim, head_dim), None, dtype)
+    cache = {"k": pool["global_k"], "v": pool["global_v"]}
+    if kv_quant:
+        cache.update(kv_tier.init_scales(num_layers, num_heads, num_pages))
+    return cache
+
+
+def write_rows(leaf, page, off, rows):
+    """``leaf [pages, page_size, heads * width]`` with ``rows [T, heads,
+    width]`` scattered, one ``[heads * width]`` row a token, at
+    ``(page[t], off[t])``: how every program of both families writes K
+    and V (index arithmetic only; in place under donation)."""
+    return leaf.at[page, off, :].set(
+        rows.reshape(rows.shape[0], -1).astype(leaf.dtype))
 
 
 def pages_needed(tokens, page_size):

@@ -13,14 +13,14 @@ measured-dispatch rule:
   Prefill's in-program page scatter quantizes at write
   (:func:`prefill_scatter_quant`); the decode step re-quantizes the
   single written page read-modify-write (:func:`decode_scatter_quant`);
-  both attention consumers dequantize at read (the jnp gather
-  reference and the Pallas decode kernel, where the scales ride as a
-  second scalar-prefetch-indexed operand — see
-  ops/decode_attention_pallas.py). Null page 0 stays all-zero through
-  the codec: its scale is pinned to 0, and quantizing under a zero
-  scale emits int8 zeros (:func:`inv_scale`). Non-finite inputs are
-  poisoned to 0 before the amax (the PR 8 block-quant NaN-flush
-  precedent — one NaN must not zero a whole page's scale arithmetic).
+  decode attention dequantizes at read (the jnp form of
+  ops/decode_attention_pallas.py, which the tier's pages take: their
+  scales inside the kernel are not written yet). Null page 0 stays
+  all-zero through the codec: its scale is pinned to 0, and quantizing
+  under a zero scale emits int8 zeros (:func:`inv_scale`). Non-finite
+  inputs are poisoned to 0 before the amax (the PR 8 block-quant
+  NaN-flush precedent — one NaN must not zero a whole page's scale
+  arithmetic).
 
 * **host swap tier** (``APEX_SERVE_KV_SWAP`` / ``engine(kv_swap=)``):
   on KV-pressure preemption the victim's live pages copy
@@ -160,37 +160,56 @@ def inv_scale(scale):
                      jnp.zeros_like(s))
 
 
+def _by_head(x, heads):
+    """``[..., rows, heads * width]`` seen as ``[..., rows, heads,
+    width]``: the cache row with its head axis apart, so that a
+    per-(page, head) scale ``[..., heads]`` broadcasts as
+    ``scale[..., None, :, None]``."""
+    return x.reshape(*x.shape[:-1], heads, x.shape[-1] // heads)
+
+
 def quantize(x, scale):
-    """int8 codes of ``x`` under per-leading-dims ``scale`` (broadcast
-    over the trailing ``(page_size, head_dim)`` dims)."""
-    inv = inv_scale(scale)[..., None, None]
-    q = jnp.round(finite(x).astype(jnp.float32) * inv)
-    return jnp.clip(q, -QMAX, QMAX).astype(CODE_DTYPE)
+    """int8 codes of ``x [..., page_size, heads * width]`` under
+    per-(leading dims, head) ``scale [..., heads]``."""
+    inv = inv_scale(scale)[..., None, :, None]
+    q = jnp.round(_by_head(finite(x).astype(jnp.float32),
+                           scale.shape[-1]) * inv)
+    return jnp.clip(q, -QMAX, QMAX).astype(CODE_DTYPE).reshape(x.shape)
 
 
 def dequantize(q, scale, dtype=jnp.float32):
-    """Inverse of :func:`quantize` (per-leading-dims scale broadcast
-    over the trailing two dims)."""
-    return (q.astype(jnp.float32)
-            * scale.astype(jnp.float32)[..., None, None]).astype(dtype)
+    """Inverse of :func:`quantize` (same shapes)."""
+    return (_by_head(q.astype(jnp.float32), scale.shape[-1])
+            * scale.astype(jnp.float32)[..., None, :, None]
+            ).astype(dtype).reshape(q.shape)
 
 
 def init_scales(num_layers, num_heads, num_pages):
-    """Zeroed per-(page, head) scale leaves ``{"k_scale", "v_scale"}``
-    of ``[layers, h, num_pages]`` — the page axis sits at axis 2 like
-    the code arrays', so the engine's page-copy/gather/scatter helpers
-    treat every cache leaf uniformly, and the head axis at axis 1
-    means the TP ``cache_shardings`` head split covers the scales
-    too."""
-    shape = (num_layers, num_heads, num_pages)
-    return {k: jnp.zeros(shape, SCALE_DTYPE) for k in SCALE_KEYS}
+    """Zeroed per-(page, head) scale leaves ``{"k_scale", "v_scale"}``,
+    each a list of one ``[num_pages, num_heads]`` array a layer: the
+    page axis first like the code arrays', so the engine's
+    page-copy/gather/scatter helpers treat every cache leaf alike, and
+    the head axis last, where the TP ``cache_shardings`` split covers
+    the scales too."""
+    return {k: [jnp.zeros((num_pages, num_heads), SCALE_DTYPE)
+                for _ in range(num_layers)] for k in SCALE_KEYS}
+
+
+def _with_layer(cache, layer, part, codes, scales):
+    """``cache`` with one layer's codes and scales of ``part`` replaced
+    (a new dict and new lists: the caller's are left as they were)."""
+    out = dict(cache)
+    for name, leaf in ((part, codes), (part + "_scale", scales)):
+        out[name] = list(cache[name])
+        out[name][layer] = leaf
+    return out
 
 
 def prefill_scatter_quant(cache, layer, part, val, dest_page, dest_off,
                           keep_scale):
     """Quantize-at-write page scatter for the packed prefill program
     (the quant-tier replacement of the plain
-    ``cache[part].at[layer, :, dest_page, dest_off, :].set(...)``).
+    ``cache[part][layer].at[dest_page, dest_off, :].set(...)``).
 
     ``val`` is the layer's fresh K or V rows ``[s, h, d]``;
     ``dest_page``/``dest_off`` the packed rows' page/offset ``[s]``;
@@ -199,7 +218,7 @@ def prefill_scatter_quant(cache, layer, part, val, dest_page, dest_off,
     partially filled page — and 0 for pages freshly granted to this
     prefill, whose stale codes and scale are dead. Functional
     recipe (no data-dependent shapes, so the one-compile contract
-    holds): scatter-max the fresh rows' amax into a per-(head, page)
+    holds): scatter-max the fresh rows' amax into a per-(page, head)
     scale floor, grow each destination page's surviving scale to
     cover it, re-quantize the whole layer under the grown scales
     (ratio 1 for untouched pages — bit-identical codes; ratio 0 for
@@ -207,37 +226,34 @@ def prefill_scatter_quant(cache, layer, part, val, dest_page, dest_off,
     quantize and scatter the fresh rows. Page 0's scale is pinned to
     0, so padded rows (which the packer routes to page 0) quantize to
     exact zeros — the null page stays all-zero through the codec."""
-    q = cache[part]                      # [L, h, P, ps, d] int8
-    sc = cache[part + "_scale"]          # [L, h, P] bf16
-    h, num_pages = q.shape[1], q.shape[2]
+    q = cache[part][layer]               # [P, ps, h*d] int8
+    sc = cache[part + "_scale"][layer]   # [P, h] bf16
+    num_pages, h = sc.shape
     vf = finite(val.astype(jnp.float32))                 # [s, h, d]
     row_amax = jnp.max(jnp.abs(vf), axis=-1)             # [s, h]
-    amax_pages = jnp.zeros((h, num_pages), jnp.float32)
-    amax_pages = amax_pages.at[:, dest_page].max(row_amax.T)
-    old = sc[layer].astype(jnp.float32) * keep_scale[None, :]
+    amax_pages = jnp.zeros((num_pages, h), jnp.float32)
+    amax_pages = amax_pages.at[dest_page].max(row_amax)
+    old = sc.astype(jnp.float32) * keep_scale[:, None]
     new_scale = jnp.maximum(old, amax_pages / QMAX)
-    new_scale = new_scale.at[:, 0].set(0.0)              # null page pin
+    new_scale = new_scale.at[0].set(0.0)                 # null page pin
     ratio = jnp.where(new_scale > 0,
                       old / jnp.where(new_scale > 0, new_scale, 1.0),
                       jnp.zeros_like(new_scale))
-    requant = jnp.clip(jnp.round(q[layer].astype(jnp.float32)
-                                 * ratio[:, :, None, None]),
-                       -QMAX, QMAX)
-    dest_scale = new_scale[:, dest_page]                 # [h, s]
-    rows = jnp.round(vf * inv_scale(dest_scale).T[:, :, None])
+    requant = jnp.clip(jnp.round(_by_head(q.astype(jnp.float32), h)
+                                 * ratio[:, None, :, None]),
+                       -QMAX, QMAX)                      # [P, ps, h, d]
+    rows = jnp.round(vf * inv_scale(new_scale[dest_page])[:, :, None])
     rows = jnp.clip(rows, -QMAX, QMAX)                   # [s, h, d]
-    updated = requant.at[:, dest_page, dest_off, :].set(
-        rows.transpose(1, 0, 2))
-    cache[part] = q.at[layer].set(updated.astype(CODE_DTYPE))
-    cache[part + "_scale"] = sc.at[layer].set(
-        new_scale.astype(SCALE_DTYPE))
-    return cache
+    updated = requant.at[dest_page, dest_off].set(rows)
+    return _with_layer(cache, layer, part,
+                       updated.astype(CODE_DTYPE).reshape(q.shape),
+                       new_scale.astype(SCALE_DTYPE))
 
 
 def decode_scatter_quant(cache, layer, part, val, write_page, write_off):
     """Quantize-at-write for the decode step's single-row scatter: a
     per-page read-modify-write (gather the B written pages — a
-    ``[h, B, ps, d]`` transient, cheap — dequantize, zero the rows at
+    ``[B, ps, h*d]`` transient, cheap — dequantize, zero the rows at
     and beyond the write offset (a freshly granted page arrives with
     ``write_off == 0``, so its stale garbage dies here without any
     alloc-time zeroing), insert the new row, re-derive the page scale
@@ -245,27 +261,22 @@ def decode_scatter_quant(cache, layer, part, val, write_page, write_off):
     ``val`` is ``[B, h, d]``; ``write_page``/``write_off`` ``[B]``
     with inactive lanes routed to page 0 — whose re-derived scale is
     forced to 0, so page 0 is re-written with exact zeros."""
-    q = cache[part]                      # [L, h, P, ps, d] int8
-    sc = cache[part + "_scale"]          # [L, h, P] bf16
-    ps = q.shape[3]
-    pages_q = q[layer][:, write_page]                    # [h, B, ps, d]
-    pscale = sc[layer][:, write_page]                    # [h, B]
-    pf = dequantize(pages_q, pscale)                     # [h, B, ps, d]
-    row_ids = jnp.arange(ps)[None, None, :, None]
-    pf = jnp.where(row_ids < write_off[None, :, None, None], pf,
+    q = cache[part][layer]               # [P, ps, h*d] int8
+    sc = cache[part + "_scale"][layer]   # [P, h] bf16
+    ps, h = q.shape[1], sc.shape[1]
+    pf = _by_head(dequantize(q[write_page], sc[write_page]), h)
+    row_ids = jnp.arange(ps)[None, :, None, None]        # [B, ps, h, d]
+    pf = jnp.where(row_ids < write_off[:, None, None, None], pf,
                    jnp.zeros_like(pf))
-    vf = finite(val.astype(jnp.float32)).transpose(1, 0, 2)  # [h, B, d]
-    pf = pf.at[:, jnp.arange(vf.shape[1]), write_off, :].set(vf)
-    amax = jnp.max(jnp.abs(pf), axis=(-2, -1))           # [h, B]
-    new_scale = jnp.where(write_page[None, :] == 0,
+    vf = finite(val.astype(jnp.float32))                 # [B, h, d]
+    pf = pf.at[jnp.arange(vf.shape[0]), write_off].set(vf)
+    amax = jnp.max(jnp.abs(pf), axis=(1, 3))             # [B, h]
+    new_scale = jnp.where(write_page[:, None] == 0,
                           jnp.zeros_like(amax), amax / QMAX)
-    pq = jnp.clip(jnp.round(pf * inv_scale(new_scale)[..., None, None]),
-                  -QMAX, QMAX).astype(CODE_DTYPE)
-    cache[part] = q.at[layer, :, write_page].set(
-        pq.transpose(1, 0, 2, 3))
-    cache[part + "_scale"] = sc.at[layer, :, write_page].set(
-        new_scale.astype(SCALE_DTYPE).T)
-    return cache
+    pq = quantize(pf.reshape(pf.shape[0], ps, -1), new_scale)
+    return _with_layer(cache, layer, part, q.at[write_page].set(pq),
+                       sc.at[write_page].set(
+                           new_scale.astype(SCALE_DTYPE)))
 
 
 # ---------------------------------------------------------------------------
@@ -278,8 +289,9 @@ class SwappedPages:
     """Host-side copy of one preempted stream's live pages, in wire
     format (bf16 pages plain; int8 codes + bf16 scales under the quant
     tier — the quant layer halves swap bytes too). ``leaves`` maps
-    each cache leaf name to a numpy array whose page axis (axis 2) is
-    padded to the engine's ``max_pages`` with null-page content, so
+    each cache leaf name to a numpy array ``[layers, max_pages, ...]``:
+    every layer's pages, the page axis (axis 1) padded to the engine's
+    ``max_pages`` with null-page content, so
     the device gather/scatter programs compile exactly once. The
     sha1 seals the banked bytes: a corrupt handle (the ``serve_swap``
     chaos site's damage mode) is detected at swap-in and the stream

@@ -310,9 +310,8 @@ def _write_kv(cache, kind, n, page, off, k, v):
     of ``kind``'s arrays at ``(page, off)``, one row a token."""
     with jax.named_scope("kv_write"):
         for part, rows in (("_k", k), ("_v", v)):
-            arr = cache[kind + part][n]
-            cache[kind + part][n] = arr.at[page, off, :].set(
-                rows.reshape(rows.shape[0], -1).astype(arr.dtype))
+            cache[kind + part][n] = kv_cache.write_rows(
+                cache[kind + part][n], page, off, rows)
 
 
 # --------------------------------------------------------------- prefill

@@ -47,6 +47,7 @@ import numpy as np
 from jax import lax
 
 from apex_tpu.dispatch import tiles as _tiles
+from apex_tpu.serving import kv_cache as kv_cache_mod
 from apex_tpu.serving import kv_tier as kv_tier_mod
 from apex_tpu.serving import quant as quant_mod
 from apex_tpu.serving import sampling as sampling_mod
@@ -231,7 +232,8 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     """
     dtype = compute_dtype(cfg)
     hd, n_heads = cfg.head_dim, cfg.num_attention_heads
-    ps = cache["k"].shape[3]
+    cache = {name: list(leaves) for name, leaves in cache.items()}
+    ps = cache["k"][0].shape[1]
     S = ids.shape[0]
 
     word = params["word_embeddings"]
@@ -257,11 +259,10 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     seg2 = seg.astype(jnp.int32)[None, :]
     for i in range(cfg.num_layers):
         def attn(q, k, v, i=i):
-            # scatter this layer's K/V into the paged cache: values
-            # are [S, H, d] as produced (mixed basic/advanced indexing
-            # puts the gathered token axis FIRST) at (page, offset) —
-            # index arithmetic only (the int8 tier routes the same
-            # scatter through the quantize-at-write codec) — then
+            # scatter this layer's K/V into the paged cache: each
+            # token's [H * d] row at (page, offset) of the layer's
+            # leaf — index arithmetic only (the int8 tier routes the
+            # same scatter through the quantize-at-write codec) — then
             # packed causal+segment attention over the full bucket
             nonlocal cache
             with jax.named_scope("kv_write"):
@@ -273,12 +274,7 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
                         cache, i, "v", v, dest_page, dest_off,
                         keep_scale)
                 else:
-                    cache["k"] = cache["k"].at[
-                        i, :, dest_page, dest_off, :].set(
-                        k.astype(cache["k"].dtype))
-                    cache["v"] = cache["v"].at[
-                        i, :, dest_page, dest_off, :].set(
-                        v.astype(cache["v"].dtype))
+                    _write_rows(cache, i, dest_page, dest_off, k, v)
             with jax.named_scope("attend"):
                 ctx = fused_attention(
                     q.transpose(1, 0, 2)[None],
@@ -300,6 +296,14 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     return cache, logits
 
 
+def _write_rows(cache, layer, page, off, k, v):
+    """This layer's ``k``/``v`` ``[rows, H, d]`` into its two leaves at
+    ``(page[r], off[r])``, one row a token."""
+    for part, val in (("k", k), ("v", v)):
+        cache[part][layer] = kv_cache_mod.write_rows(
+            cache[part][layer], page, off, val)
+
+
 def token_rows_to_pages(page_table, token_rows):
     """[S, max_pages] per-token page-table rows (a gather; split out
     so the scatter line above stays readable)."""
@@ -309,8 +313,7 @@ def token_rows_to_pages(page_table, token_rows):
 # ---------------------------------------------------------------- decode
 
 def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
-                qparams=None, decode_impl=None, decode_block_h=None,
-                interpret=None):
+                qparams=None, decode_impl=None, interpret=None):
     """One greedy decode step for every slot (q_len = 1).
 
     tokens/lengths: ``[B]`` — the token to process and the context
@@ -320,16 +323,17 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
     logits [B, vocab])``.
 
     ``qparams`` (from :func:`quantize_decode_params`) switches the
-    decode matmuls to the int8 records; ``decode_impl`` /
-    ``decode_block_h`` ride per-call into the decode-attention family
-    (None = the family's own rule: the Pallas kernel on a TPU where it
-    supports the geometry, the jnp reference otherwise).
+    decode matmuls to the int8 records; ``decode_impl`` rides per-call
+    into the decode-attention family (None = the family's own rule: the
+    Pallas kernel on a TPU where it supports the geometry, the jnp
+    reference otherwise).
     """
     from apex_tpu.ops import decode_attention_pallas as dap
 
     dtype = compute_dtype(cfg)
     hd, n_heads = cfg.head_dim, cfg.num_attention_heads
-    ps = cache["k"].shape[3]
+    cache = {name: list(leaves) for name, leaves in cache.items()}
+    ps = cache["k"][0].shape[1]
     B = tokens.shape[0]
 
     word = params["word_embeddings"]
@@ -352,11 +356,11 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
     quant = kv_tier_mod.is_quantized(cache)
     for i in range(cfg.num_layers):
         def attn(q, k, v, i=i):
-            # append this step's k/v at (page, offset) — the int8 tier
-            # rewrites the touched pages through the per-page RMW
-            # codec — then paged decode attention through the
-            # dispatched fifth family (quantized pages ride with their
-            # per-(page, head) scale planes)
+            # append this step's k/v rows at (page, offset) — the int8
+            # tier rewrites the touched pages through the per-page RMW
+            # codec — then paged decode attention over the layer's
+            # leaves where they lie (quantized pages ride with their
+            # per-(page, head) scales and take the jnp form)
             nonlocal cache
             with jax.named_scope("kv_write"):
                 if quant:
@@ -365,33 +369,15 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
                     cache = kv_tier_mod.decode_scatter_quant(
                         cache, i, "v", v, write_page, write_off)
                 else:
-                    cache["k"] = cache["k"].at[
-                        i, :, write_page, write_off, :].set(
-                        k.astype(cache["k"].dtype))  # [B, H, d] values
-                    cache["v"] = cache["v"].at[
-                        i, :, write_page, write_off, :].set(
-                        v.astype(cache["v"].dtype))
+                    _write_rows(cache, i, write_page, write_off, k, v)
             with jax.named_scope("attend"):
-                # the float cache goes in whole, the layer as an
-                # index: the kernel reads the stacked array where it
-                # lies (a per-layer slice handed to its custom call is
-                # a copy XLA has to make, 72 a round). The int8 tier
-                # hands over its layer's slice: its page-rewrite codec
-                # makes XLA keep the codes in another layout, from
-                # which the whole stacked array would be re-laid for
-                # every layer, and one layer's slice is the bounded cost
-                if quant:
-                    kv = dict(k_pages=cache["k"][i], v_pages=cache["v"][i],
-                              k_scale=cache["k_scale"][i],
-                              v_scale=cache["v_scale"][i])
-                else:
-                    kv = dict(k_pages=cache["k"], v_pages=cache["v"],
-                              layer=i)
-                ctx = dap.decode_attention(
-                    q.astype(dtype), page_table=page_table,
-                    lengths=lengths, sm_scale=1.0 / math.sqrt(hd),
-                    impl=decode_impl, block_h=decode_block_h,
-                    interpret=interpret, **kv)
+                scales = dict(k_scale=cache["k_scale"][i],
+                              v_scale=cache["v_scale"][i]) if quant else {}
+                ctx = dap.grouped_decode_attention(
+                    q.astype(dtype), cache["k"][i], cache["v"][i],
+                    page_table, lengths, n_kv=n_heads,
+                    sm_scale=1.0 / math.sqrt(hd), impl=decode_impl,
+                    interpret=interpret, **scales)
                 return ctx.reshape(B, n_heads * hd).astype(dtype)
 
         x = _trunk_layer(x, params["transformer"][f"layer_{i}"],
@@ -434,8 +420,7 @@ def resolve_decode_k(per_call=None):
 
 def decode_block(params, cache, tokens, lengths, page_table,
                  steps_budget, warm_tokens, warm_steps, lanes=None, *,
-                 k, cfg, qparams=None, decode_impl=None,
-                 decode_block_h=None, interpret=None):
+                 k, cfg, qparams=None, decode_impl=None, interpret=None):
     """K decode steps in ONE dispatch (ISSUE 17): a ``lax.scan`` over
     :func:`decode_step` with in-program per-slot stop detection, so a
     single device round trip amortizes the per-dispatch host cost
@@ -480,8 +465,7 @@ def decode_block(params, cache, tokens, lengths, page_table,
         step_lens = jnp.where(live, lens, 0)
         cache, emitted, logits = decode_step(
             params, cache, tok, step_lens, page_table, cfg=cfg,
-            qparams=qparams, decode_impl=decode_impl,
-            decode_block_h=decode_block_h, interpret=interpret)
+            qparams=qparams, decode_impl=decode_impl, interpret=interpret)
         if lanes is not None:
             temps, top_ks, top_ps, keys, counters = lanes
             ctr = counters + jnp.maximum(j - warm_steps, 0)

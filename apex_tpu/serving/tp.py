@@ -31,10 +31,11 @@ The split (Megatron pairing, whole heads per shard — demands a
 * Embeddings, layernorms, everything else — replicated. The logits
   matmul against the replicated word table is vocab-unsharded (the
   v5e HBM pressure is the 48-layer trunk, not the 50304-row table).
-* KV cache ``[layers, heads, pages, page_size, head_dim]`` — sharded
-  on its LEADING HEAD axis (axis 1): the paged layout leads with
-  heads for exactly this, so each chip holds its own heads' pages
-  and the decode gather never crosses chips.
+* KV cache, ``[pages, page_size, heads * head_dim]`` a layer (and the
+  int8 tier's ``[pages, heads]`` scales) — sharded on its LAST axis:
+  heads are contiguous blocks of it, so each chip holds its own
+  heads' columns of every page and the decode gather never crosses
+  chips.
 
 Knob home (the CLAUDE.md asymmetry): per-call ``ServingEngine(tp=)``
 is a DEMAND — un-honorable values (non-int, tp < 1, tp > visible
@@ -118,10 +119,12 @@ def param_shardings(params, mesh):
 
 
 def cache_shardings(cache, mesh):
-    """NamedSharding tree for the paged KV cache: every array sharded
-    on its leading head axis, ``P(None, TENSOR_AXIS)``."""
-    s = NamedSharding(mesh, P(None, TENSOR_AXIS))
-    return jax.tree.map(lambda _: s, cache)
+    """NamedSharding tree for the paged KV cache: every leaf sharded on
+    its last axis, whose contiguous blocks are whole heads
+    (``[pages, page_size, h * d]`` codes, ``[pages, h]`` scales)."""
+    return jax.tree.map(
+        lambda leaf: NamedSharding(
+            mesh, P(*(None,) * (leaf.ndim - 1), TENSOR_AXIS)), cache)
 
 
 def qparams_shardings(qparams, mesh):

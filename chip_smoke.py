@@ -23,9 +23,10 @@ h=768, 12 heads, vocab 50304) with random weights from a seed:
   plain float32 reference ``perf/references/mimo_v2.py``.
 * kernels  — each Pallas family the default path does not reach (rows
   attention fwd+bwd in both backward structures, with segment ids, with
-  dropout; layer norm; scale-mask softmax; the fused LM head; bf16 and
-  int8 paged decode attention) compiled by Mosaic at the GPT-2-small
-  shape and compared with its ``jnp`` reference.
+  dropout; layer norm; scale-mask softmax; the fused LM head; paged
+  decode attention at the served model's heads and at GPT-2 large's
+  20) compiled by Mosaic at the GPT-2-small shape and compared with its
+  ``jnp`` reference.
 * four chips — when ``jax.device_count() >= 4``: the trainer again on a
   dp=2 x tp=2 mesh, loss trajectory against the one-chip run, memory in
   use on every device.
@@ -396,7 +397,6 @@ def run_server(size, log, seen, interpret):
             "compile_cache": _cache_delta(cache0),
             "ran": dict(_server_lowerings(engine),
                         decode_attn_impl=engine.decode_attn_impl,
-                        decode_attn_block_h=engine.decode_attn_block_h,
                         dispatch=_consulted_since(seen)),
         }
         rec = recs[name]
@@ -673,17 +673,12 @@ def _row_kernel_cases(size, interpret):
 
 def _decode_cases(size, interpret):
     from apex_tpu.ops import decode_attention_pallas as dap
-    from apex_tpu.serving import kv_tier
 
-    b, h = size["slots"], size["heads"]
+    b = size["slots"]
     d = size["hidden"] // size["heads"]
     pages, ps = size["pages"], size["page_size"]
     max_pages = size["max_seq"] // ps
     rs = np.random.RandomState(2)
-    q = jnp.asarray(rs.randn(b, h, d), jnp.bfloat16)
-    kf = rs.randn(h, pages, ps, d).astype(np.float32)
-    vf = rs.randn(h, pages, ps, d).astype(np.float32)
-    kf[:, 0] = vf[:, 0] = 0.0   # the null page
     pt = jnp.asarray(np.stack([
         rs.permutation(np.arange(1, pages))[:max_pages]
         for _ in range(b)]), jnp.int32)
@@ -692,32 +687,25 @@ def _decode_cases(size, interpret):
     lens = jnp.asarray(
         (edge + list(rs.randint(1, max_pages * ps, b)))[:b], jnp.int32)
     scale = 1.0 / math.sqrt(d)
-    assert dap.supported(h, pages, ps, d, jnp.bfloat16)
 
-    k16, v16 = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)
-    yield "decode attention bf16 paged", (
-        lambda q, k, v: dap.decode_attention(
-            q, k, v, pt, lens, sm_scale=scale, impl="pallas",
-            interpret=interpret),
-        lambda q, k, v: dap.decode_attention_reference(
-            q, k, v, pt, lens, scale),
-        (q, k16, v16), [(5e-2,)])
-
-    def scales(x):   # per-(head, page) amax/QMAX in the wire dtype
-        return jnp.asarray(np.max(np.abs(x), axis=(-2, -1)) / kv_tier.QMAX,
-                           kv_tier.SCALE_DTYPE)
-
-    ks, vs = scales(kf), scales(vf)
-    k8 = kv_tier.quantize(jnp.asarray(kf), ks)
-    v8 = kv_tier.quantize(jnp.asarray(vf), vs)
-    assert dap.supported(h, pages, ps, d, jnp.int8)
-    yield "decode attention int8 paged", (
-        lambda q, k, v: dap.decode_attention(
-            q, k, v, pt, lens, sm_scale=scale, k_scale=ks, v_scale=vs,
-            impl="pallas", interpret=interpret),
-        lambda q, k, v: dap.decode_attention_reference(
-            q, k, v, pt, lens, scale, k_scale=ks, v_scale=vs),
-        (q, k8, v8), [(5e-2,)])
+    # the served model's heads, then GPT-2 large's 20 (serve-large-batch:
+    # rows padded 20 -> 24): n_kv = h, every head's banded query against
+    # the whole [page_size, h * d] page
+    for h in dict.fromkeys((size["heads"], 20)):
+        assert interpret or dap.grouped_supported(h, h, d, d, ps,
+                                                  jnp.bfloat16)
+        q = jnp.asarray(rs.randn(b, h, d), jnp.bfloat16)
+        kf = rs.randn(pages, ps, h * d).astype(np.float32)
+        vf = rs.randn(pages, ps, h * d).astype(np.float32)
+        kf[0] = vf[0] = 0.0   # the null page
+        yield f"decode attention bf16 paged, {h} heads", (
+            lambda q, k, v, h=h: dap.grouped_decode_attention(
+                q, k, v, pt, lens, n_kv=h, sm_scale=scale, impl="pallas",
+                interpret=interpret),
+            lambda q, k, v, h=h: dap.grouped_decode_attention_reference(
+                q, k, v, pt, lens, scale, n_kv=h),
+            (q, jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)),
+            [(5e-2,)])
 
 
 def run_kernels(size, log, interpret):

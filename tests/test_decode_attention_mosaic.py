@@ -3,8 +3,8 @@ shapes the chip runs, with no chip: libtpu compiles for a described v5e
 (``jax.experimental.topologies``). Interpret-mode parity is not a
 compile verdict (two kernels passed it for ten PRs and never compiled),
 and what XLA does AROUND the custom call decides the round as much as
-the kernel: the serve cell's decode program has to reach the kernel with
-no copy of the cache beyond the four at entry and exit.
+the kernel: no serving program may copy a KV cache (four whole-cache
+copies were 24 of a 36 ms decode round until PR 28).
 
 One file, one process loads libtpu: the topology is described inside a
 fixture, never at import."""
@@ -40,42 +40,43 @@ def _sds(one_chip, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
 
-@pytest.mark.parametrize("h,b,pages,dtype", [
-    (20, 16, 96, jnp.bfloat16),   # serve-large-batch (gpt2-large)
-    (12, 8, 72, jnp.bfloat16),    # chip_smoke / GPT-2 small
-    (12, 8, 72, jnp.int8),        # the int8 KV tier, one layer's arrays
-], ids=["gpt2-large-bf16", "gpt2-small-bf16", "gpt2-small-int8"])
-def test_kernel_compiles_for_the_v5e(one_chip, h, b, pages, dtype):
+@pytest.mark.parametrize("h,b,pages", [
+    (20, 16, 96),   # serve-large-batch (gpt2-large)
+    (12, 8, 72),    # chip_smoke / GPT-2 small
+], ids=["gpt2-large", "gpt2-small"])
+def test_kernel_compiles_for_the_v5e_at_gpt2_widths(one_chip, h, b, pages):
+    """``n_kv = h``, K and V 64 wide: the whole-page form (every head's
+    banded query against a ``[128, h * 64]`` page, rows padded to a
+    sublane tile)."""
     ps, d, max_pages = 128, 64, 8
-    quant = dtype == jnp.int8
-    assert dap.supported(h, pages, ps, d, dtype)
+    assert dap.grouped_supported(h, h, d, d, ps, jnp.bfloat16)
 
-    def f(q, k, v, pt, ln, *scales):
-        kw = dict(k_scale=scales[0], v_scale=scales[1]) if quant else {}
-        return dap.decode_attention(q, k, v, pt, ln, impl="pallas",
-                                    interpret=False, **kw)
+    def f(q, k, v, pt, ln):
+        return dap.grouped_decode_attention(q, k, v, pt, ln, n_kv=h,
+                                            impl="pallas", interpret=False)
 
-    args = [_sds(one_chip, (b, h, d), jnp.bfloat16),
-            _sds(one_chip, (h, pages, ps, d), dtype),
-            _sds(one_chip, (h, pages, ps, d), dtype),
-            _sds(one_chip, (b, max_pages), jnp.int32),
-            _sds(one_chip, (b,), jnp.int32)]
-    if quant:
-        args += [_sds(one_chip, (h, pages), jnp.bfloat16)] * 2
-    text = jax.jit(f).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text and dap.KERNEL_NAME in text
+    text = jax.jit(f).lower(
+        _sds(one_chip, (b, h, d), jnp.bfloat16),
+        _sds(one_chip, (pages, ps, h * d), jnp.bfloat16),
+        _sds(one_chip, (pages, ps, h * d), jnp.bfloat16),
+        _sds(one_chip, (b, max_pages), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and dap.GROUPED_KERNEL_NAME in text
 
 
-def test_serve_cell_decode_program_feeds_the_kernel_without_a_copy(one_chip):
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serve_cell_program_copies_no_cache_leaf(one_chip, program):
     """GPT-2 large's widths, 16 slots, 96 pages of 128 tokens, four
-    layers of the 36: every layer's kernel takes a bitcast of the
-    scattered cache, and the only whole-cache copies are the two
-    arguments' at entry and the two results' at exit."""
+    layers of the 36: no operation makes an array of a cache leaf's
+    shape by ``copy``, every leaf is aliased input to output, the decode
+    program reaches the kernel in every layer and its temporaries stay
+    far under a leaf's size (8.2 GB of them at full depth before PR
+    28)."""
     from apex_tpu.serving import kv_cache
     from apex_tpu.serving import model as smodel
     from apex_tpu.transformer.testing import TransformerConfig
 
-    layers, h, b, pages, ps, d = 4, 20, 16, 96, 128, 64
+    layers, h, b, pages, ps, d, S = 4, 20, 16, 96, 128, 64, 1024
     cfg = TransformerConfig(
         hidden_size=h * d, num_layers=layers, num_attention_heads=h,
         vocab_size=50304, max_position_embeddings=1024,
@@ -91,22 +92,33 @@ def test_serve_cell_decode_program_feeds_the_kernel_without_a_copy(one_chip):
         lambda: kv_cache.init_cache(layers, h, pages, ps, d)))
     i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
 
-    def decode(params, cache, tokens, lengths, page_table):
-        return smodel.decode_step(params, cache, tokens, lengths,
-                                  page_table, cfg=cfg, decode_impl="pallas",
-                                  interpret=False)
+    if program == "decode":
+        def fn(params, cache, tokens, lengths, page_table):
+            return smodel.decode_step(params, cache, tokens, lengths,
+                                      page_table, cfg=cfg,
+                                      decode_impl="pallas", interpret=False)
+        args = (i32(b), i32(b), i32(b, 1024 // ps))
+    else:
+        def fn(params, cache, ids, positions, seg, rows, page_table, last):
+            return smodel.prefill(params, cache, ids, positions, seg, rows,
+                                  page_table, last, cfg=cfg)
+        args = (i32(S), i32(S), i32(S), i32(S), i32(b + 1, 1024 // ps),
+                i32(b))
 
-    text = jax.jit(decode, donate_argnums=(1,)).lower(
-        params, cache, i32(b), i32(b), i32(b, 1024 // ps)
-    ).compile().as_text()
-    assert text.count("tpu_custom_call") >= layers
-    whole = rf"bf16\[{layers},(?:{h},{pages},{ps}|{pages},{ps},{h}),{d}\]"
-    made = re.findall(rf"= {whole}\S* ([\w-]+)\(", text)
-    assert made.count("copy") == 4, sorted(set(made))
-    assert made.count("bitcast") >= 2 * layers, sorted(set(made))
-    # no per-layer slice of the cache is materialised for the kernel
-    one_layer = rf"= bf16\[(?:{h},{pages},{ps}|{pages},{ps},{h}),{d}\]"
-    assert not re.findall(one_layer, text)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    leaf = rf"bf16\[(?:{pages},{ps}|{pages * ps}),{h * d}\]"
+    made = re.findall(rf"= {leaf}\S* ([\w-]+)\(", text)
+    assert made and "copy" not in made, sorted(set(made))
+    mem = compiled.memory_analysis()
+    leaf_bytes = pages * ps * h * d * 2
+    assert mem.alias_size_in_bytes >= 2 * layers * leaf_bytes
+    if program == "decode":
+        assert text.count("tpu_custom_call") >= layers
+        assert mem.temp_size_in_bytes < leaf_bytes
+        # full depth is 36 layers: under 1 GB of temporaries there
+        assert mem.temp_size_in_bytes * 36 / layers < 1e9
 
 
 # ---- the MiMo family's kernels at the widths serve-mimo-decode runs ----
@@ -197,3 +209,39 @@ def test_mimo_decode_program_compiles_with_no_copy_of_a_cache(one_chip):
     assert text.count("tpu_custom_call") >= 2 + 3
     cache_shapes = r"bf16\[(?:192|129),128,(?:768|512|1536|1024)\]"
     assert not re.findall(rf"= {cache_shapes}\S* copy\(", text)
+
+
+def test_mimo_decode_program_is_the_one_pr27_traced():
+    """The kernel's whole-page form (GPT-2's shapes, PR 28) is a branch
+    on the static ``(group, dk, dv)``; for MiMo's geometry every branch
+    taken is the one taken before it, so ``serve-mimo-decode`` cannot
+    move: the decode program's jaxpr, kernels' bodies included, at
+    published widths (one layer of each kind, 16 held experts) is the
+    text it was at PR 27's commit. Source positions and object addresses
+    are cut out: the lowered module's Mosaic payloads carry line numbers,
+    which move with any edit of the file. A PR that changes MiMo's
+    decode program on purpose records the new digest here."""
+    import functools
+    import hashlib
+
+    from apex_tpu.serving import mimo
+
+    slots, ps, pages = 64, 128, 192
+    cfg = mimo.MiMoConfig(
+        vocab_size=2048, max_position_embeddings=1048576,
+        hybrid_layer_pattern=(0, 1), moe_layer_freq=(0, 1),
+        held_experts=(0, 16))
+    params = jax.eval_shape(
+        lambda: mimo.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: mimo.init_cache(cfg, slots, pages, ps))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    decode = functools.partial(mimo.decode_step, cfg=cfg,
+                               decode_impl="pallas", moe_impl="pallas",
+                               interpret=False)
+    text = str(jax.make_jaxpr(decode)(
+        params, cache, i32(slots), i32(slots), i32(slots, 3072 // ps)))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    text = re.sub(r"/[\w/\.\-]+\.py:\d+", "<src>", text)
+    assert dap.GROUPED_KERNEL_NAME in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "1aa4a6e0d71a3002c5be3234d73b9c8be4de6b8e6b16a80f40e64bd0baa447ae")

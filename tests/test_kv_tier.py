@@ -1,8 +1,8 @@
 """KV-cache memory hierarchy suite (serving/kv_tier.py, ISSUE 20):
 the int8 codec's numeric contract (roundtrip band, non-finite
 poisoning, the null-page-0 invariant, scatter-quantize vs the dense
-reference), dequantize-at-read parity of both decode-attention impls
-across swept tiles, the three-legged ``kv_restore`` resolver, and the
+reference) on the ``[pages, page_size, h * d]`` layout with ``[pages,
+h]`` scales, the three-legged ``kv_restore`` resolver, and the
 engine acceptance — quant greedy parity, swap-restore streams
 token-for-token identical to BOTH the recompute-restored and the
 never-preempted streams (greedy AND sampled), the serve_swap chaos
@@ -27,10 +27,12 @@ from apex_tpu.serving.sampling import SamplingParams
 # ---------------------------------------------------------- the codec
 
 
-def _scales(x):
-    """Per-(leading dims) amax/127 scales over the trailing two dims,
-    in the wire dtype (bf16) — what both scatter paths derive."""
-    amax = np.max(np.abs(np.asarray(x, np.float32)), axis=(-2, -1))
+def _scales(x, h):
+    """Per-(page, head) amax/127 scales ``[..., h]`` of pages ``[...,
+    page_size, h * width]``, in the wire dtype (bf16) — what both
+    scatter paths derive."""
+    x = np.asarray(x, np.float32)
+    amax = np.max(np.abs(x.reshape(*x.shape[:-1], h, -1)), axis=(-3, -1))
     return jnp.asarray(amax / kv_tier.QMAX, kv_tier.SCALE_DTYPE)
 
 
@@ -38,29 +40,31 @@ def _scales(x):
                          ids=["f32", "bf16"])
 def test_roundtrip_stays_in_the_quantization_band(dtype):
     rs = np.random.RandomState(0)
-    x = jnp.asarray(rs.randn(2, 5, 4, 8) * 3.0, dtype)
-    scale = _scales(x)
+    x = jnp.asarray(rs.randn(2, 5, 4, 3 * 8) * 3.0, dtype)  # 3 heads of 8
+    scale = _scales(x, 3)
+    assert scale.shape == (2, 5, 3)
     q = kv_tier.quantize(x, scale)
-    assert q.dtype == kv_tier.CODE_DTYPE
+    assert q.dtype == kv_tier.CODE_DTYPE and q.shape == x.shape
     y = kv_tier.dequantize(q, scale, dtype)
-    assert y.dtype == dtype
-    # error ≤ one code step per page (0.5 rounding + the bf16 scale's
-    # own representation error), measured against the fp32 original
-    band = np.asarray(scale, np.float32)[..., None, None] * 1.0 + 1e-6
+    assert y.dtype == dtype and y.shape == x.shape
+    # error ≤ one code step per (page, head) (0.5 rounding + the bf16
+    # scale's own representation error), against the fp32 original
+    band = np.repeat(np.asarray(scale, np.float32), 8,
+                     axis=-1)[..., None, :] * 1.0 + 1e-6
     err = np.abs(np.asarray(y, np.float32) - np.asarray(x, np.float32))
     assert np.all(err <= band), float(np.max(err - band))
 
 
 def test_nonfinite_inputs_poison_to_zero_codes():
-    x = np.ones((1, 2, 4, 4), np.float32)
+    x = np.ones((1, 2, 4, 2 * 4), np.float32)    # 2 pages, 2 heads of 4
     x[0, 0, 1, 2] = np.nan
-    x[0, 1, 0, 0] = np.inf
+    x[0, 1, 0, 4] = np.inf
     xj = jnp.asarray(x)
-    scale = _scales(kv_tier.finite(xj))
+    scale = _scales(kv_tier.finite(xj), 2)
     q = np.asarray(kv_tier.quantize(xj, scale))
     # the poisoned entries became exact-zero codes, their neighbors
     # quantized normally — one NaN never zeroed (or NaN'd) a page
-    assert q[0, 0, 1, 2] == 0 and q[0, 1, 0, 0] == 0
+    assert q[0, 0, 1, 2] == 0 and q[0, 1, 0, 4] == 0
     assert np.all(q[0, 0, 0] != 0)
     assert np.all(np.isfinite(np.asarray(scale, np.float32)))
 
@@ -71,10 +75,10 @@ def test_zero_scale_is_a_dead_page_not_a_nan_factory():
     assert inv[0] == 0.0 and inv[1] == pytest.approx(0.5)
     # quantizing real content under a zero scale emits exact zeros
     # (the null-page route), and dequantizing returns exact zeros
-    x = jnp.ones((2, 4, 4))
-    z = jnp.zeros((2,), kv_tier.SCALE_DTYPE)
+    x = jnp.ones((2, 4, 2 * 2))
+    z = jnp.zeros((2, 2), kv_tier.SCALE_DTYPE)
     assert np.all(np.asarray(kv_tier.quantize(x, z)) == 0)
-    q = jnp.full((2, 4, 4), 7, kv_tier.CODE_DTYPE)
+    q = jnp.full((2, 4, 2 * 2), 7, kv_tier.CODE_DTYPE)
     assert np.all(np.asarray(kv_tier.dequantize(q, z)) == 0.0)
 
 
@@ -83,48 +87,67 @@ def _quant_cache(layers=1, heads=2, pages=6, ps=4, d=8):
                                kv_quant=True)
 
 
+def _pages(cache, part, layer=0, heads=2):
+    """One layer's dequantized pages as ``[P, ps, h, d]`` and its
+    ``[P, h]`` scales, float32."""
+    got = np.asarray(kv_tier.dequantize(
+        cache[part][layer], cache[part + "_scale"][layer]), np.float32)
+    return got.reshape(*got.shape[:2], heads, -1), \
+        np.asarray(cache[part + "_scale"][layer], np.float32)
+
+
+def test_quant_cache_layout():
+    cache = _quant_cache(layers=3)
+    assert set(cache) == {"k", "v", "k_scale", "v_scale"}
+    for name, leaves in cache.items():
+        assert len(leaves) == 3
+        want = ((6, 2), jnp.dtype(kv_tier.SCALE_DTYPE)) \
+            if name.endswith("_scale") \
+            else ((6, 4, 2 * 8), jnp.dtype(kv_tier.CODE_DTYPE))
+        assert {(a.shape, a.dtype) for a in leaves} == {want}
+    assert kv_tier.is_quantized(cache)
+    assert not kv_tier.is_quantized(kv_cache.init_cache(1, 2, 6, 4, 8))
+
+
 def test_prefill_scatter_quant_matches_dense_and_pins_page0():
     rs = np.random.RandomState(1)
     cache = _quant_cache()
-    ps = 4
     # 6 packed rows: 4 fill page 1, 2 start page 2; rows routed to
     # page 0 are the packer's padding lanes and must stay dead
     val = jnp.asarray(rs.randn(8, 2, 8), jnp.float32)
     dest_page = jnp.asarray([1, 1, 1, 1, 2, 2, 0, 0], jnp.int32)
     dest_off = jnp.asarray([0, 1, 2, 3, 0, 1, 0, 0], jnp.int32)
     keep = jnp.zeros((6,), jnp.float32).at[jnp.asarray([3, 4, 5])].set(1.0)
+    fresh = _quant_cache()
     cache = kv_tier.prefill_scatter_quant(
         cache, 0, "k", val, dest_page, dest_off, keep)
-    got = np.asarray(kv_tier.dequantize(
-        cache["k"][0], cache["k_scale"][0]), np.float32)
+    # the caller's cache is left as it was (a new dict, new lists)
+    assert np.all(np.asarray(fresh["k"][0]) == 0)
+    got, scale = _pages(cache, "k")
     want = np.asarray(val, np.float32)
-    band = np.asarray(cache["k_scale"][0], np.float32) + 1e-6
     for r in range(6):
         p, o = int(dest_page[r]), int(dest_off[r])
-        err = np.abs(got[:, p, o, :] - want[r])
-        assert np.all(err <= band[:, p, None]), (r, float(err.max()))
+        err = np.abs(got[p, o] - want[r])                  # [h, d]
+        assert np.all(err <= scale[p, :, None] + 1e-6), (r, err.max())
     # null page 0 stays all-zero with a pinned-zero scale, even though
     # two padding rows were "scattered" there
-    assert np.all(np.asarray(cache["k"])[0, :, 0] == 0)
-    assert np.all(np.asarray(cache["k_scale"], np.float32)[0, :, 0] == 0)
+    assert np.all(np.asarray(cache["k"][0])[0] == 0)
+    assert np.all(scale[0] == 0)
     # untouched pages never grew a scale
-    assert np.all(np.asarray(cache["k_scale"], np.float32)
-                  [0, :, [3, 4, 5]] == 0)
+    assert np.all(scale[[3, 4, 5]] == 0)
     # a verify re-cover of page 2 (keep=1 there now) preserves page 1
     # verbatim: same scale -> ratio 1 -> bit-identical codes
-    before = np.asarray(cache["k"])[0, :, 1].copy()
+    before = np.asarray(cache["k"][0])[1].copy()
     val2 = jnp.asarray(rs.randn(2, 2, 8) * 0.1, jnp.float32)
     keep2 = jnp.ones((6,), jnp.float32).at[0].set(0.0)
     cache = kv_tier.prefill_scatter_quant(
         cache, 0, "k", val2, jnp.asarray([2, 2], jnp.int32),
         jnp.asarray([2, 3], jnp.int32), keep2)
-    assert np.array_equal(np.asarray(cache["k"])[0, :, 1], before)
+    assert np.array_equal(np.asarray(cache["k"][0])[1], before)
     # the small rows landed without blowing up page 2's earlier rows
-    got2 = np.asarray(kv_tier.dequantize(
-        cache["k"][0], cache["k_scale"][0]), np.float32)
-    err = np.abs(got2[:, 2, :2, :] - want[4:6].transpose(1, 0, 2))
-    band2 = np.asarray(cache["k_scale"], np.float32)[0, :, 2]
-    assert np.all(err <= band2[:, None, None] + 1e-6)
+    got2, scale2 = _pages(cache, "k")
+    err = np.abs(got2[2, :2] - want[4:6])                  # [2, h, d]
+    assert np.all(err <= scale2[2][None, :, None] + 1e-6)
 
 
 def test_decode_scatter_quant_rmw_preserves_and_zeroes():
@@ -140,73 +163,46 @@ def test_decode_scatter_quant_rmw_preserves_and_zeroes():
     cache = kv_tier.decode_scatter_quant(
         cache, 0, "v", new, jnp.asarray([3, 0], jnp.int32),
         jnp.asarray([2, 0], jnp.int32))
-    got = np.asarray(kv_tier.dequantize(
-        cache["v"][0], cache["v_scale"][0]), np.float32)
-    band = np.asarray(cache["v_scale"], np.float32)[0, :, 3] + 1e-6
+    got, scale = _pages(cache, "v")
+    band = scale[3][:, None] + 1e-6                        # [h, 1]
     # earlier rows survived the read-modify-write, the new row landed
     want = np.asarray(seedrows, np.float32)
     for o in range(2):
-        assert np.all(np.abs(got[:, 3, o] - want[o])
-                      <= band[:, None])
-    assert np.all(np.abs(got[:, 3, 2] - np.asarray(new)[0])
-                  <= band[:, None])
+        assert np.all(np.abs(got[3, o] - want[o]) <= band)
+    assert np.all(np.abs(got[3, 2] - np.asarray(new)[0]) <= band)
     # rows at/beyond the write offset were zeroed (stale garbage dies)
-    assert np.all(got[:, 3, 3] == 0)
+    assert np.all(got[3, 3] == 0)
     # the inactive lane re-wrote page 0 with exact zeros
-    assert np.all(np.asarray(cache["v"])[0, :, 0] == 0)
-    assert np.all(np.asarray(cache["v_scale"], np.float32)[0, :, 0] == 0)
-
-
-# -------------------------------- dequantize-at-read attention parity
-
-
-def _attn_data(seed=3, H=4):
-    B, P, PS, D, MAXP = 4, 16, 32, 64, 4
-    rs = np.random.RandomState(seed)
-    q = jnp.asarray(rs.randn(B, H, D), jnp.float32)
-    kf = rs.randn(H, P, PS, D).astype(np.float32)
-    vf = rs.randn(H, P, PS, D).astype(np.float32)
-    kf[:, 0] = vf[:, 0] = 0.0  # null page
-    k_scale, v_scale = _scales(jnp.asarray(kf)), _scales(jnp.asarray(vf))
-    k8 = kv_tier.quantize(jnp.asarray(kf), k_scale)
-    v8 = kv_tier.quantize(jnp.asarray(vf), v_scale)
-    pt = jnp.asarray(np.stack([
-        rs.permutation(np.arange(1, P))[:MAXP] for _ in range(B)]),
-        jnp.int32)
-    lens = jnp.asarray([5, PS, MAXP * PS, 0], jnp.int32)
-    sm = 1.0 / np.sqrt(D)
-    return (q, jnp.asarray(kf), jnp.asarray(vf), k8, v8, k_scale,
-            v_scale, pt, lens, sm)
-
-
-@pytest.mark.parametrize("h,bh", [(4, 4), (64, 32), (64, 64)])
-def test_decode_attention_int8_parity_across_block_h(h, bh):
-    # heads are the page block's second-minor axis: a block is all of
-    # h or whole 32-row int8 sublane tiles of it
-    (q, kf, vf, k8, v8, ks, vs, pt, lens, sm) = _attn_data(H=h)
-    ref8 = dap.decode_attention_reference(q, k8, v8, pt, lens, sm,
-                                          k_scale=ks, v_scale=vs)
-    got = dap.decode_attention_pallas(q, k8, v8, pt, lens, sm,
-                                      k_scale=ks, v_scale=vs,
-                                      block_h=bh, interpret=True)
-    # kernel vs jnp reference: same dequantize-at-read math -> tight
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref8),
-                               atol=1e-4)
-    # int8 tier vs the float cache: inside the quantization band
-    reff = dap.decode_attention_reference(q, kf, vf, pt, lens, sm)
-    np.testing.assert_allclose(np.asarray(ref8), np.asarray(reff),
-                               atol=0.12)
-    # the fully-masked lane still produces exact zeros
-    assert np.all(np.asarray(got)[3] == 0.0)
+    assert np.all(np.asarray(cache["v"][0])[0] == 0)
+    assert np.all(scale[0] == 0)
+    # the layer not written is the array it was
+    two = _quant_cache(layers=2)
+    out = kv_tier.decode_scatter_quant(
+        two, 1, "v", new, jnp.asarray([3, 0], jnp.int32),
+        jnp.asarray([2, 0], jnp.int32))
+    assert out["v"][0] is two["v"][0] and out["k"] is two["k"]
 
 
 def test_int8_pages_without_scales_raise():
-    (q, _, _, k8, v8, ks, vs, pt, lens, sm) = _attn_data()
+    """Codes are meaningless without their scales: the dispatched call
+    refuses int8 pages that come with one scale or none (the attention
+    parity of the tier is tests/test_decode_attention_pallas.py's)."""
+    h, P, PS, D = 2, 6, 4, 8
+    rs = np.random.RandomState(3)
+    q = jnp.asarray(rs.randn(2, h, D), jnp.float32)
+    kf = jnp.asarray(rs.randn(P, PS, h * D), jnp.float32)
+    ks = _scales(kf, h)
+    k8 = kv_tier.quantize(kf, ks)
+    pt = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
+    lens = jnp.asarray([6, 2], jnp.int32)
     with pytest.raises(ValueError, match="come as a pair"):
-        dap.decode_attention(q, k8, v8, pt, lens, sm_scale=sm,
-                             k_scale=ks)
+        dap.grouped_decode_attention(q, k8, k8, pt, lens, n_kv=h,
+                                     k_scale=ks)
     with pytest.raises(ValueError, match="int8"):
-        dap.decode_attention(q, k8, v8, pt, lens, sm_scale=sm)
+        dap.grouped_decode_attention(q, k8, k8, pt, lens, n_kv=h)
+    out = dap.grouped_decode_attention(q, k8, k8, pt, lens, n_kv=h,
+                                       k_scale=ks, v_scale=ks)
+    assert out.shape == (2, h, D) and np.all(np.isfinite(np.asarray(out)))
 
 
 # ------------------------------------------- the kv_restore resolver

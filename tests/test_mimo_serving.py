@@ -400,7 +400,7 @@ def test_prefill_does_not_depend_on_where_its_trunk_stops(
 @pytest.mark.parametrize("option,value", [
     ("tp", 2), ("weight_quant", True), ("kv_quant", True),
     ("kv_swap", True), ("prefix_cache", True), ("spec_decode", 2),
-    ("decode_k", 2), ("overlap", True), ("decode_block_h", 4)])
+    ("decode_k", 2), ("overlap", True)])
 def test_mimo_family_refuses_by_name_what_it_cannot_honour(option, value):
     cfg = T.toy_config()
     with pytest.raises(ValueError, match=f"mimo .*{option}="):

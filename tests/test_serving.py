@@ -133,6 +133,103 @@ def test_decode_matches_prefill_per_dtype(setup, atol, name, request):
                                    err_msg=f"{name} step {i}")
 
 
+# ------------------------- the cache layout and where its rows land
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["float", "int8"])
+def test_cache_is_one_page_major_leaf_a_layer(kv_quant):
+    """K and V: one ``[pages, page_size, h * d]`` array a layer, page
+    axis first in every leaf (the int8 tier's ``[pages, h]`` scales
+    too): the pool of the two-kind cache with every layer global."""
+    from apex_tpu.serving import kv_cache
+
+    cache = init_cache(3, 4, 10, 8, 16, jnp.bfloat16, kv_quant=kv_quant)
+    assert set(cache) == ({"k", "v", "k_scale", "v_scale"} if kv_quant
+                          else {"k", "v"})
+    for name, leaves in cache.items():
+        assert len(leaves) == 3
+        want = (10, 4) if name.endswith("_scale") else (10, 8, 4 * 16)
+        assert all(a.shape == want and not np.asarray(a, np.float32).any()
+                   for a in leaves)
+    assert cache["k"][0].dtype == (jnp.int8 if kv_quant else jnp.bfloat16)
+    pool = kv_cache.init_hybrid_cache((0, 0, 0), 10, 0, 8, 0, (4, 16, 16),
+                                      None, cache["k"][0].dtype)
+    assert [a.shape for a in pool["global_k"]] \
+        == [a.shape for a in cache["k"]]
+    assert not pool["window_k"] and not pool["window_v"]
+
+
+def test_decode_step_writes_one_row_a_lane_and_idle_lanes_page_zero(
+        f32_setup):
+    """A decode step scatters each active lane's K/V of every head as
+    ONE row at ``(page, offset)`` of every layer's leaf; an inactive
+    lane's write lands on null page 0 and nothing else moves."""
+    cfg, params = f32_setup
+    ps, pages = 8, 6
+    cache = init_cache(cfg.num_layers, cfg.num_attention_heads, pages, ps,
+                       cfg.head_dim, jnp.float32)
+    before = jax.tree.map(np.asarray, cache)
+    # lane 0: position 10 -> table entry 1 (page 4), row 2; lane 1 idle
+    pt = jnp.asarray([[2, 4, 0], [5, 3, 0]], jnp.int32)
+    out, toks, _ = smodel.decode_step(
+        params, cache, jnp.asarray([7, 9], jnp.int32),
+        jnp.asarray([11, 0], jnp.int32), pt, cfg=cfg)
+    assert int(toks[1]) == 0
+    for part in ("k", "v"):
+        assert isinstance(out[part], list) and cache[part] is not out[part]
+        for layer in range(cfg.num_layers):
+            got = np.asarray(out[part][layer])
+            assert got.shape == (pages, ps, cfg.hidden_size)
+            assert np.abs(got[4, 2]).min() > 0        # every head's columns
+            changed = np.argwhere(
+                (got != before[part][layer]).any(axis=-1))
+            assert {tuple(c) for c in changed} <= {(4, 2), (0, 0)}
+            assert (4, 2) in {tuple(c) for c in changed}
+
+
+def test_prefill_rows_land_at_page_and_offset_padding_on_page_zero(
+        f32_setup):
+    """The packed prefill scatters token ``t`` of a request as row
+    ``t % page_size`` of page ``table[t // page_size]``; padding rows go
+    to page 0; each row is the decode step's row for the same token
+    (both are ``k.reshape(rows, h * d)`` of one shared trunk)."""
+    cfg, params = f32_setup
+    ps, pages, S, n = 8, 6, 16, 11
+    cache = init_cache(cfg.num_layers, cfg.num_attention_heads, pages, ps,
+                       cfg.head_dim, jnp.float32)
+    prompt = [int(t) for t in np.random.RandomState(0).randint(0, 128, n)]
+    real = (np.arange(S) < n).astype(np.int32)
+    ids = np.zeros(S, np.int32)
+    ids[:n] = prompt
+    pt = np.asarray([[3, 1, 0], [0, 0, 0]], np.int32)   # row 1: null spare
+    filled, _ = smodel.prefill(
+        params, cache, jnp.asarray(ids),
+        jnp.asarray(np.arange(S, dtype=np.int32) * real), jnp.asarray(real),
+        jnp.asarray(1 - real), jnp.asarray(pt),
+        jnp.asarray([n - 1], jnp.int32), cfg=cfg)
+    for part in ("k", "v"):
+        for layer in range(cfg.num_layers):
+            got = np.asarray(filled[part][layer])
+            live = {(3, r) for r in range(8)} | {(1, r) for r in range(3)}
+            written = {tuple(c) for c in np.argwhere(got.any(axis=-1))}
+            assert live <= written <= live | {(0, 0)}, (part, layer)
+    # the same 11th token through a decode step over the first 10's pages
+    short, _ = smodel.prefill(
+        params, cache, jnp.asarray(ids * (np.arange(S) < n - 1)),
+        jnp.asarray(np.arange(S, dtype=np.int32) * (np.arange(S) < n - 1)),
+        jnp.asarray((np.arange(S) < n - 1).astype(np.int32)),
+        jnp.asarray((np.arange(S) >= n - 1).astype(np.int32)),
+        jnp.asarray(pt), jnp.asarray([n - 2], jnp.int32), cfg=cfg)
+    stepped, _, _ = smodel.decode_step(
+        params, short, jnp.asarray([prompt[-1]], jnp.int32),
+        jnp.asarray([n], jnp.int32), jnp.asarray(pt[:1]), cfg=cfg)
+    for part in ("k", "v"):
+        for layer in range(cfg.num_layers):
+            np.testing.assert_allclose(
+                np.asarray(stepped[part][layer])[1, 2],
+                np.asarray(filled[part][layer])[1, 2], atol=2e-5)
+
+
 def test_allocator_invariants_under_churn():
     alloc = PageAllocator(32)
     rs = np.random.RandomState(1)
