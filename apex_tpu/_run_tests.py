@@ -50,14 +50,16 @@ SUITES = {
                 "test_router.py", "test_router_chaos.py",
                 "test_mimo_serving.py"],
     "api_parity": ["test_api_parity_round3.py"],
-    "harness": ["test_run_tests.py", "test_bench_contract.py",
-                "test_chip_smoke.py",
+    "harness": ["test_run_tests.py", "test_chip_smoke.py",
                 "test_compile_cache.py", "test_resilience.py",
-                "test_apexlint.py"],
+                "test_fault_sites.py", "test_import_arrows.py",
+                "test_docs_guard.py", "test_apexlint.py"],
+    "benchmark": ["test_benchmark_rehearsal_train.py",
+                  "test_benchmark_rehearsal_serve.py"],
     "telemetry": ["test_telemetry.py", "test_bench_labels.py",
                   "test_dispatch.py", "test_dispatch_tiles.py",
                   "test_costs.py", "test_window_report.py",
-                  "test_flight.py", "test_spans.py"],
+                  "test_spans.py"],
     "api_audit": ["test_noop_knob_audit.py"],
     "checkpoint": ["test_checkpoint.py", "test_checkpoint_durable.py",
                    "test_checkpoint_chaos.py", "test_resume_parity.py"],

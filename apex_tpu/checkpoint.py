@@ -25,11 +25,10 @@ atomic (tmp + rename + content-hash manifest), whose restores walk
 backward past torn/corrupt/stale files, and whose saves can run on a
 background thread off the step critical path (``APEX_CKPT_ASYNC``;
 default SYNC until the overhead A/B lands — the measured-dispatch
-rule). The relay grants ~50-minute windows and wedges without warning
-(PERF.md §6); everything a healthy window computes must survive the
-wedge that follows it. The format is self-contained (numpy bytes +
-JSON manifest, no orbax dependency) so an emergency restore never
-depends on the optional stack.
+rule). Whatever a run computed must survive the kill that follows
+it. The format is self-contained (numpy bytes + JSON manifest, no
+orbax dependency) so an emergency restore never depends on the
+optional stack.
 """
 
 import hashlib
@@ -516,9 +515,9 @@ def restore_durable(directory, template, step=None):
 
 
 def resume_provenance(writer, template, expect_meta=None):
-    """The ONE resume entry for the harnesses (bench.py --resume,
-    profile_gpt): restore the newest valid checkpoint and build the
-    provenance block check_bench_labels check 5 polices.
+    """The ONE resume entry for the harnesses (profile_gpt): restore
+    the newest valid checkpoint and build the provenance block
+    check_bench_labels check 5 polices.
 
     Returns ``(restored_state, step0, resumed_from)`` —
     ``(None, 0, None)`` when no valid checkpoint exists or when

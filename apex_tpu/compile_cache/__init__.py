@@ -26,12 +26,9 @@ Pieces:
   (``examples.transformer.pretrain.main``, ``ServingEngine``) and by
   the harnesses; idempotent.
 * :func:`snapshot` — ``{enabled, dir, hits, misses, warm_age_s}`` read
-  from JAX's own config, stamped into bench.py's JSON line, every
-  ledger record and ``chip_smoke.py``'s phases, so a number can prove
-  whether it was taken compile-free.
-* :func:`warm` — AOT ``jit(...).lower(*args).compile()`` of a program
-  without executing it (``APEX_WARM_ONLY=1`` switches bench.py and the
-  Tracer-based harnesses into this compile-only mode).
+  from JAX's own config, stamped into every ledger record and
+  ``chip_smoke.py``'s phases, so a number can prove whether it was
+  taken compile-free.
 
 Cache reuse never changes the measured program (the key is the lowered
 module plus compile options; execution is identical).
@@ -78,15 +75,6 @@ def cache_dir():
     if not jax.config.jax_enable_compilation_cache:
         return None
     return jax.config.jax_compilation_cache_dir or None
-
-
-def warm_only():
-    """True when this invocation should only COMPILE the measured
-    programs (populating the cache), never run/time them
-    (``APEX_WARM_ONLY=1`` — set by ``benchmarks/warm_cache.py``)."""
-    from apex_tpu.dispatch.tiles import env_flag
-
-    return env_flag("APEX_WARM_ONLY")
 
 
 def _on_event(event, **kw):
@@ -159,30 +147,6 @@ def snapshot():
         "warm_age_s": _newest_entry_age_s(directory) if directory
         else None,
     }
-
-
-def warm(fn, args):
-    """AOT-compile ``fn`` (a ``jax.jit``-wrapped callable) at ``args``
-    — concrete arrays or ``jax.ShapeDtypeStruct`` avals — WITHOUT
-    executing it, populating the persistent cache.
-
-    Returns ``(info, compiled)``: ``info`` is ``{"seconds", "hits",
-    "misses", "cached"}`` where the hit/miss deltas cover exactly this
-    compile and ``cached`` is True when the executable came out of the
-    cache (the warm was already done); ``compiled`` is the AOT
-    ``jax.stages.Compiled`` (its ``output_shardings`` let a caller warm
-    follow-on keys, e.g. a donated-state rebind). Raises on compile
-    failure: a warm driver must report a program it could not warm, not
-    swallow it.
-    """
-    h0, m0 = _counters["hits"], _counters["misses"]
-    t0 = time.perf_counter()
-    compiled = fn.lower(*args).compile()
-    dt = time.perf_counter() - t0
-    dh = _counters["hits"] - h0
-    dm = _counters["misses"] - m0
-    return ({"seconds": round(dt, 3), "hits": dh, "misses": dm,
-             "cached": dh > 0 and dm == 0}, compiled)
 
 
 def _reset_for_tests():

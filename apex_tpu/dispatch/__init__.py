@@ -14,9 +14,8 @@ validates the citation and the knob pins mechanically, in tier-1.
 Consulted at trace time by the five Pallas op families
 (attention/rows, layer-norm, scale-mask softmax, fused LM head, and
 the serving decode-attention kernel), the FusedLAMB ``impl``
-structure, the trunk remat policy, the grad-comm scheme, and
-bench.py's batch ladder — strictly BELOW any explicit signal. The precedence at
-every call site is:
+structure, the trunk remat policy and the grad-comm scheme — strictly
+BELOW any explicit signal. The precedence at every call site is:
 
     per-call knob  >  process-wide setter  >  table entry  >  built-in
 
@@ -25,10 +24,9 @@ and the CLAUDE.md asymmetry is preserved: a table entry is a measured
 silently, like a process-wide setter), never a demand — only per-call
 knobs raise on un-honorable requests.
 
-Table entries are produced by ``benchmarks/autotune_steps.py`` (one
-budgeted pass over the queued step-level A/Bs) and are keyed by
-backend, so the committed CPU-measured demonstration rows can never
-leak into TPU dispatch.
+Table entries are keyed by backend, so the committed CPU-measured
+demonstration rows can never leak into TPU dispatch.
+``benchmarks/sweep_kv_restore.py`` is the one writer of the table.
 
 File format — one JSON object per line::
 
@@ -38,8 +36,8 @@ File format — one JSON object per line::
      "measured": {...}, "rung": "gpt_rows"}
 
 Entries may additionally carry a ``params`` payload — the per-shape
-TILE geometry measured for the chosen kernel (``benchmarks/
-autotune_tiles.py``), its own citation riding inside::
+TILE geometry measured for the chosen kernel, its own citation riding
+inside::
 
     "params": {"value": {"block_q": 256}, "ledger": "lg-...",
                "pins": {"APEX_ATTN_BLOCK_Q": "256"},
@@ -84,7 +82,8 @@ from apex_tpu.dispatch import tiles
 # Pallas kernel vs the XLA-fused jnp path; "lm_head" is the fused
 # linear-CE head vs materialized logits; "lamb" is FusedLAMB's compute
 # structure; "remat" the trunk recompute granularity; "bench_batch"
-# bench.py's default batch (choice is the batch as a string);
+# a train batch size (choice is the batch as a string; no code reads
+# it, the committed row goes with the table);
 # "grad_comm" the DDP gradient-sync algorithm
 # (apex_tpu.parallel.collectives: int8 block quantization and/or the
 # hierarchical two-stage reduction), keyed on the flat payload size.
@@ -118,8 +117,8 @@ REQUIRED_FIELDS = ("op", "bucket", "dtype", "backend", "choice", "ledger")
 _cache = {}  # path -> (mtime_ns, size, entries, problems)
 # trace-time consult log: (op, bucket, dtype, backend) -> choice (None =
 # miss). The pin-the-label rule's answer to data-driven dispatch: a
-# harness can't state its knob pins alone any more — bench.py and
-# Tracer.flush_ledger stamp snapshot() so every measurement records
+# harness can't state its knob pins alone any more —
+# Tracer.flush_ledger stamps snapshot() so every measurement records
 # exactly which table entries resolved its unpinned choices.
 _consults = {}
 
@@ -279,8 +278,8 @@ def consulted():
 
 
 def snapshot():
-    """The dispatch telemetry block stamped into bench.py's JSON line
-    and every ledger record (Tracer.flush_ledger): ``{enabled, table,
+    """The dispatch telemetry block stamped into every ledger record
+    (Tracer.flush_ledger): ``{enabled, table,
     consulted}`` — the mechanical record of which table entries drove
     this run's unpinned choices."""
     return {"enabled": dispatch_enabled(), "table": table_path(),

@@ -20,10 +20,9 @@ and has no tile to choose):
   ships today, exported so sweeps can label (and keep, under the flip
   margin) the incumbent. The heuristics themselves are UNCHANGED: the
   kernels now call these functions instead of private copies.
-* ``candidates(op, dims, dtype)`` — the legal sweep set for
-  ``benchmarks/autotune_tiles.py``: every enumerated tile passes
-  ``legal``, so a sweep never submits a program Mosaic rejects
-  mid-window.
+* ``candidates(op, dims, dtype)`` — the legal sweep set: every
+  enumerated tile passes ``legal``, so a sweep never submits a
+  program Mosaic rejects.
 * ``parse_bucket`` / ``validate_payload`` — the checker surface
   (``tools/check_bench_labels.py`` check 4): a committed ``params``
   payload must be legal under this model at its entry's bucket dims.
@@ -115,8 +114,7 @@ def env_int(name):
     env_choice/env_float, so a mistyped pin on a scarce collection
     window is loud, not silently the default shape). The one parser
     behind APEX_ATTN_BLOCK_Q / APEX_LN_BLOCK_ROWS /
-    APEX_SOFTMAX_BLOCK_ROWS / APEX_XENT_ROW_BLOCK /
-    APEX_BENCH_BATCH / APEX_ATTN_SEQ, so
+    APEX_SOFTMAX_BLOCK_ROWS / APEX_XENT_ROW_BLOCK / APEX_ATTN_SEQ, so
     the knob-parsing semantics cannot drift apart."""
     v = os.environ.get(name)
     if v in (None, ""):

@@ -118,7 +118,7 @@ def _resolve_pallas(x_shape, n_norm_axes, use_pallas, dtype=None):
     from apex_tpu.dispatch import tiles
 
     if _on_cpu() and tiles.env_flag("APEX_PALLAS_INTERPRET"):
-        # the CPU leg of a pinned pallas A/B (autotune_steps --smoke):
+        # the CPU leg of a pinned pallas A/B:
         # run the kernel in interpret mode instead of silently falling
         # back to jnp — a "pallas" label over a jnp run is label drift
         return True, True, tile_pref

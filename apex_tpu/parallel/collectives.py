@@ -127,7 +127,7 @@ def _table_choice(nelems):
     """The dispatch-table consult for op "grad_comm" (the tier strictly
     BELOW per-call knobs and the process-wide setters/env, per the PR-3
     precedence): keyed on the flat payload size, fed by the
-    ``benchmarks/profile_comm.py`` A/B rungs in autotune_steps. None =
+    ``benchmarks/profile_comm.py`` A/B rows. None =
     miss (built-in default: off). Only call sites that know their flat
     payload consult (``allreduce_tree``/``ef_init`` pass ``nelems``);
     the ZeRO optimizers resolve WITHOUT a table consult — their

@@ -43,7 +43,7 @@ Generation subsystem (ISSUE 13), three cooperating layers:
   prompt is PREFILLED ONCE per engine.
 
 All three default OFF per the measured-dispatch rule — the device
-A/Bs are queued in PERF.md §2 behind ``APEX_SERVE_BENCH=1``;
+A/Bs have not been run;
 correctness (greedy parity, per-request determinism, refcount/COW
 invariants, two-program stability) is pinned on CPU by
 tests/test_serving_generation.py.

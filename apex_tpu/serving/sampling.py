@@ -27,8 +27,7 @@ explicit request ≠ preference) > ``set_sampling`` setter >
 ``APEX_SERVE_SAMPLING`` env preference > built-in OFF. Default OFF per
 the measured-dispatch rule: with sampling compiled in, even all-greedy
 batches pay the sort/top-p ops, so the decode program only grows them
-when asked (the sampling-vs-greedy decode A/B is queued in PERF.md §2
-behind ``APEX_SERVE_BENCH=1``).
+when asked (the sampling-vs-greedy decode A/B has not been run).
 """
 
 import dataclasses

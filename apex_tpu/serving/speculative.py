@@ -28,8 +28,8 @@ Knob (the CLAUDE.md asymmetry): per-call ``spec_decode=K`` at engine
 build RAISES when un-honorable (K < 1, or K+1 deeper than the prefill
 bucket); the ``APEX_SPEC_DECODE`` env is a preference — 0/unset is
 off, garbage warns once and is ignored. Default OFF per the
-measured-dispatch rule (the verify-vs-decode device A/B is queued in
-PERF.md §2 behind ``APEX_SERVE_BENCH=1``); correctness — speculative
+measured-dispatch rule (the verify-vs-decode device A/B has not been
+run); correctness — speculative
 output ≡ non-speculative greedy token-for-token — is pinned on CPU by
 tests/test_serving_generation.py.
 """

@@ -1,4 +1,4 @@
-"""Run ledger: one structured JSONL record per bench/profile invocation.
+"""Run ledger: one structured JSONL record per profile invocation.
 
 Every measurement harness appends a record — git SHA, APEX_* knob pins,
 measured dispatch overhead, scan length K, relay-degradation stamp,
@@ -13,8 +13,8 @@ Record ids are content hashes (``lg-`` + sha1 of the canonical record
 sans ``id``), so a record edited after the fact no longer matches its
 own id — the checker flags that too.
 
-Writes are best-effort and NEVER raise: bench.py's one-JSON-line
-contract must survive a read-only checkout. Smoke-mode runs
+Writes are best-effort and NEVER raise: a harness must survive a
+read-only checkout. Smoke-mode runs
 (``APEX_BENCH_SMOKE=1``) skip the write unless ``APEX_TELEMETRY_LEDGER``
 explicitly points somewhere — CPU sanity numbers do not belong in the
 measurement ledger.
@@ -44,7 +44,7 @@ def ledger_path():
 
 def knob_pins(env=None):
     """Every ``APEX_*`` env var, sorted — the process-wide knob pins.
-    Per-call knobs (e.g. bench.py's ``config`` dict) ride in ``extra``."""
+    Per-call knobs ride in ``extra``."""
     env = os.environ if env is None else env
     return {k: env[k] for k in sorted(env) if k.startswith("APEX_")}
 
@@ -54,17 +54,13 @@ def knob_pins(env=None):
 # counters, retry budgets) — everything else an APEX_* pin names shapes
 # the measured program, and a resumed timing row whose pins drifted
 # from the checkpoint's is mixing two configs under one label. Shared
-# by bench.py's resume provenance and check_bench_labels check 5 so the
+# by checkpoint.resume_provenance and check_bench_labels check 5 so the
 # two can never disagree about what counts as drift.
 INFRA_KNOB_PREFIXES = (
-    "APEX_CKPT_", "APEX_BENCH_ATTEMPT", "APEX_BENCH_TIMEOUT",
-    "APEX_BENCH_RETRY_WAIT", "APEX_BENCH_INNER", "APEX_BENCH_BASELINE",
+    "APEX_CKPT_",
     "APEX_TELEMETRY_LEDGER", "APEX_TELEMETRY_PATH",
-    "APEX_COMPILE_CACHE", "APEX_WARM_ONLY", "APEX_WARM_TIMEOUT",
-    "APEX_PROBE_", "APEX_FAULT_PLAN", "APEX_COLLECT_MANIFEST",
-    "APEX_COST_ANALYSIS", "APEX_SERVE_BENCH",
-    "APEX_FLIGHT_",  # flight recorder / supervisor (ISSUE 16): where
-                     # beats land + reap thresholds — never the program
+    "APEX_COMPILE_CACHE", "APEX_FAULT_PLAN",
+    "APEX_COST_ANALYSIS",
 )
 
 
@@ -119,8 +115,7 @@ def make_record(harness, platform, dispatch_overhead_ms, k, relay=None,
 
     ``relay`` is the degradation stamp: ``{"degraded": bool|None,
     "kind": str|None}`` — None/None when the harness has no detector
-    (most profile harnesses; bench.py fills in its MFU-envelope
-    verdict)."""
+    (every profile harness today)."""
     rec = {
         "ts": round(time.time(), 3) if ts is None else ts,
         "harness": harness,
@@ -487,56 +482,9 @@ def validate_record(rec):
         # zero-loss failover or a prefix-affinity hit-rate delta no
         # fleet produced — same teeth as the slo block.
         problems += [f"router: {p}" for p in _validate_router(rt)]
-    fr = rec.get("flight_reap")
-    if fr is not None:
-        # the supervisor's reap stamp (apex_tpu.resilience.flight_watch,
-        # ISSUE 16): a malformed one could claim a rung was reaped for
-        # heartbeat silence when it actually ran out its cap (or vice
-        # versa) — the window account would mis-bill the reclaimed
-        # minutes. Verdict/reason vocabularies come from the resilience
-        # classifier so the two can never drift.
-        from apex_tpu import resilience as _resilience
-
-        if not isinstance(fr, dict):
-            problems.append("flight_reap is not a dict")
-        else:
-            if not (isinstance(fr.get("row"), str) and fr["row"]):
-                problems.append(
-                    "flight_reap.row does not name the reaped row")
-            if fr.get("verdict") not in _resilience.INFLIGHT_VERDICTS:
-                problems.append(
-                    f"flight_reap.verdict {fr.get('verdict')!r} is not a "
-                    f"classified in-flight verdict "
-                    f"{_resilience.INFLIGHT_VERDICTS}")
-            if fr.get("reason") not in ("silence", "cap", "signal"):
-                problems.append(
-                    f"flight_reap.reason {fr.get('reason')!r} is not one "
-                    f"of ('silence', 'cap', 'signal')")
-            for field in ("silence_s", "timeout_s", "elapsed_s"):
-                v = fr.get(field)
-                if not (isinstance(v, (int, float))
-                        and not isinstance(v, bool) and v >= 0):
-                    problems.append(
-                        f"flight_reap.{field} is not a non-negative "
-                        f"number")
-            nb = fr.get("beats")
-            if not (isinstance(nb, int) and not isinstance(nb, bool)
-                    and nb >= 0):
-                problems.append(
-                    "flight_reap.beats is not a non-negative int")
-            age = fr.get("age_s")
-            if age is not None and (not isinstance(age, (int, float))
-                                    or isinstance(age, bool) or age < 0):
-                problems.append(
-                    "flight_reap.age_s is not a non-negative number "
-                    "or null")
-            lp = fr.get("last_phase")
-            if lp is not None and not isinstance(lp, str):
-                problems.append(
-                    "flight_reap.last_phase is not a string or null")
     rf = rec.get("resumed_from")
     if rf is not None:
-        # resume provenance (bench.py --resume / profile_gpt): rides
+        # resume provenance (profile_gpt under APEX_CKPT_RESUME): rides
         # INSIDE the content-hashed id; check_bench_labels check 5
         # pin-matches citations of resumed records
         if not isinstance(rf, dict):
@@ -601,10 +549,6 @@ def _summary_line(rec):
     cost = rec.get("cost")
     if isinstance(cost, dict) and cost.get("peak_hbm_bytes"):
         marks.append(f"peak_hbm={cost['peak_hbm_bytes'] / 2 ** 20:.0f}MiB")
-    fr = rec.get("flight_reap")
-    if isinstance(fr, dict):
-        marks.append(f"reaped:{fr.get('row', '?')}"
-                     f"({fr.get('reason', '?')}/{fr.get('verdict', '?')})")
     return (f"{rec.get('id', '?'):14s} {when}  "
             f"{str(rec.get('harness', '?')):22s} "
             f"{str(rec.get('platform', '?')):4s} "
@@ -677,13 +621,6 @@ def main(argv=None):
                       f"attainment={att_s} "
                       f"goodput={s.get('goodput_tok_s')} tok/s "
                       f"ttft_p99={s.get('ttft_p99_ms')}ms [{tid}]")
-        # newest flight heartbeat (ISSUE 16): when a flight dir is
-        # armed the ledger status also answers "is anything alive
-        # RIGHT NOW" — newest beat's phase + age
-        from apex_tpu.telemetry import flight as _flight
-
-        if _flight.enabled():
-            print(f"  {_flight.status_line()}")
         return 1 if problems else 0
     if args.cmd == "tail":
         # n<=0 prints nothing (records[-0:] would be the WHOLE ledger)

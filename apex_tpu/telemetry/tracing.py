@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from apex_tpu.telemetry import flight
-
 
 def sync(x):
     """Wait for device execution by fetching one element."""
@@ -45,10 +43,7 @@ def sync(x):
 
 
 def _overhead_program(k):
-    """The jitted calibration scan — module-level so the warm path
-    (benchmarks/warm_cache.py via bench.py's APEX_WARM_ONLY mode) can
-    AOT-compile the EXACT program measure_dispatch_overhead will
-    dispatch: same function, same HLO, same persistent-cache key."""
+    """The jitted calibration scan."""
     def run(c, eps):
         def body(c, _):
             return c + eps, ()
@@ -127,12 +122,6 @@ class Span:
 
     def format_row(self, peak_flops=None, width=28, ms_prec=2):
         """The harness table row (name, ms, optional TF/s + MFU)."""
-        if self.seconds is None and self.error is None \
-                and self.extra.get("warm_only"):
-            w = self.extra.get("warm", {})
-            return (f"{self.name:{width}s} warmed "
-                    f"(compile {w.get('seconds', '?')}s, "
-                    f"cached={w.get('cached')})")
         if self.seconds is None:
             return f"{self.name:{width}s} FAILED: {self.error}"
         extra = ""
@@ -157,34 +146,15 @@ class Tracer:
     """Calibrated timing context for one harness run.
 
     Calibrates the per-dispatch overhead once (``overhead=`` injects a
-    pre-measured value — e.g. bench.py measures before compiling), then
+    pre-measured value), then
     times rows via :meth:`scan_time` / :meth:`time_call`; spans
     accumulate for :meth:`flush_ledger`.
     """
 
     def __init__(self, k, overhead=None, peak_flops=None):
         self.k = int(k)
-        if overhead is not None:
-            self.overhead = float(overhead)
-        else:
-            from apex_tpu import compile_cache
-
-            if compile_cache.warm_only():
-                # compile-only contract: never execute the calibration
-                # dispatches (4 timed round trips) in a warm pass
-                # — the measurement would go unused (nothing is timed,
-                # flush_ledger is skipped). AOT-warm its cache key
-                # instead, so the scored run's calibration compile is
-                # also a cache read.
-                try:
-                    sds = jax.ShapeDtypeStruct((), jnp.float32)
-                    compile_cache.warm(_overhead_program(self.k),
-                                       (sds, sds))
-                except Exception:
-                    pass
-                self.overhead = 0.0
-            else:
-                self.overhead = measure_dispatch_overhead(self.k)
+        self.overhead = float(overhead) if overhead is not None \
+            else measure_dispatch_overhead(self.k)
         self.peak_flops = device_peak_flops() if peak_flops is None \
             else peak_flops
         self.spans = []
@@ -202,11 +172,10 @@ class Tracer:
                       comm_ms=None):
         """Attribution block for one measured program (cost_analysis /
         memory_analysis via apex_tpu.telemetry.costs): ``compiled`` is
-        the free-harvest path (the warm mode already paid for the AOT
-        object); otherwise one extra host-side ``call.lower`` trace,
-        compiled only where that is a persistent-cache read — never a
-        second cold compile. The first captured block becomes the
-        run-level ``self.cost``."""
+        an AOT object the caller already holds; otherwise one extra
+        host-side ``call.lower`` trace, compiled only where that is a
+        persistent-cache read — never a second cold compile. The first
+        captured block becomes the run-level ``self.cost``."""
         from apex_tpu import compile_cache
         from apex_tpu.telemetry import costs
 
@@ -239,57 +208,7 @@ class Tracer:
         (the eps chain).
         ``on_fail="span"`` records a failed row instead of raising (the
         sweep-harness pattern: one unlowered config must not kill the
-        window's remaining rows).
-
-        Under ``APEX_WARM_ONLY=1`` (the warm-start path,
-        ``apex_tpu.compile_cache``) the row is only AOT-COMPILED —
-        ``call.lower(*warm_args).compile()`` populates the persistent
-        cache without executing or timing anything; the returned Span
-        has ``seconds=None`` and a ``warm`` extra. Non-jitted callables
-        fall back to one executed warm dispatch."""
-        from apex_tpu import compile_cache
-
-        if compile_cache.warm_only():
-            try:
-                warm_cost = None
-                # flight beats (ISSUE 16): host-side appends, no trace
-                # interaction — the supervisor sees "compiling" live
-                flight.beat("compile_start", span=name)
-                if hasattr(call, "lower"):
-                    info, compiled = compile_cache.warm(call, warm_args)
-                    if capture_cost:
-                        # free harvest: the warm already paid for the
-                        # Compiled object (bench's warm path does the
-                        # same — predicted peak HBM before any dispatch)
-                        warm_cost = self._capture_cost(
-                            call, warm_args, flops_per_iter,
-                            compiled=compiled, comm=comm,
-                            comm_compression=comm_compression,
-                            host_ms=host_ms, comm_ms=comm_ms)
-                else:
-                    sync_out(call(*warm_args))
-                    info = {"executed": True}
-                flight.beat("compile_done", span=name)
-                span = Span(name, None, None, self.k, self.overhead,
-                            flops_per_iter=flops_per_iter,
-                            extra=dict(extra or {}, warm_only=True,
-                                       warm=info,
-                                       **({"cost": warm_cost}
-                                          if warm_cost else {})))
-            except Exception as e:
-                if on_fail != "span":
-                    raise
-                span = Span(name, None, None, self.k, self.overhead,
-                            flops_per_iter=flops_per_iter,
-                            error=f"{type(e).__name__}: {str(e)[:100]}",
-                            extra=dict(extra or {}, warm_only=True))
-            self.spans.append(span)
-            return span
-        # flight beats (ISSUE 16) bracket the phases a supervisor needs
-        # to tell "compiling" from "dispatched, waiting on the fetch":
-        # host-side file appends outside the timed region (the dispatch
-        # beat lands BEFORE t0), never touching the traced program
-        flight.beat("compile_start", span=name)
+        window's remaining rows)."""
         try:
             sync_out(call(*warm_args))
         except Exception as e:
@@ -301,12 +220,9 @@ class Tracer:
                         extra=dict(extra or {}))
             self.spans.append(span)
             return span
-        flight.beat("compile_done", span=name)
-        flight.beat("dispatch", span=name)
         t0 = time.perf_counter()
         sync_out(call(*timed_args))
         total = time.perf_counter() - t0
-        flight.beat("fetch", span=name)
         span_extra = dict(extra or {})
         if capture_cost:
             # AFTER the timed region: the lower/compile are host work
@@ -349,18 +265,13 @@ class Tracer:
                      path=None):
         """Append this run (calibration + every span) as one ledger
         record; returns the record id (None when the write was skipped
-        or failed — see ledger.append_record). Warm-only runs
-        (``APEX_WARM_ONLY=1``) write nothing: a compile pass is not a
-        measurement and must not look like one in the ledger. Every
+        or failed — see ledger.append_record). Every
         written record is stamped with the compile-cache telemetry
         block, so a PERF.md row can prove whether its numbers were
         taken compile-free."""
         from apex_tpu import compile_cache, dispatch
         from apex_tpu.telemetry import ledger
 
-        if compile_cache.warm_only():
-            return None
-        flight.beat("flush", harness=harness)
         if platform is None:
             platform = jax.devices()[0].platform
         from apex_tpu.telemetry import costs

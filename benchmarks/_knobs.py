@@ -1,7 +1,6 @@
 """Shared kernel-dispatch env knobs for the step-level A/B harnesses.
 
-One implementation consumed by both ``benchmarks/profile_gpt.py`` and
-``bench.py`` so the knob semantics cannot drift between them:
+Consumed by ``benchmarks/profile_gpt.py``:
 
 * ``APEX_ATTN_IMPL={flash|rows}`` — process-wide attention kernel
   (``ops.attention.set_default_impl``).

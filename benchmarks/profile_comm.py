@@ -5,9 +5,7 @@ found_inf-gated ZeRO-free fused Adam — apex_tpu.transformer.testing
 .minimal) over a data-parallel mesh with the grad sync routed through
 ``apex_tpu.parallel.collectives``: the program whose algorithm the
 ``APEX_GRAD_COMPRESS`` / ``APEX_HIER_ALLREDUCE`` knobs select.
-``benchmarks/autotune_steps.py`` pins one variant per subprocess
-(off / int8 / hier / int8_hier) and the winner lands as the
-per-payload-size "grad_comm" dispatch-table entry.
+One variant per process: off / int8 / hier / int8_hier.
 
 Honest-label notes (PERF.md §0):
 
@@ -73,9 +71,7 @@ devices = jax.devices()
 N = len(devices)
 
 # pp=1 / tp=1: every device goes to dp — this harness measures the dp
-# grad sync, nothing else. Shapes mirror what autotune_steps'
-# "grad_comm" group keys its payload bucket on (tests assert the
-# mirror).
+# grad sync, nothing else.
 S = 32 if SMOKE else 512
 M, MBS = 2, (2 if SMOKE else 4)
 cfg = TransformerConfig(

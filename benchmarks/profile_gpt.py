@@ -30,9 +30,6 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")  # force-CPU tiny sanity mode
 
 from benchmarks._timing import Tracer  # noqa: E402
-from apex_tpu.telemetry import flight  # noqa: E402
-
-flight.beat("proc_start")  # ISSUE 16: no-op unless APEX_FLIGHT_DIR
 
 from apex_tpu.amp.scaler import LossScaler
 from apex_tpu.dispatch import tiles as _tiles
@@ -42,16 +39,15 @@ from apex_tpu.transformer.testing import GPTModel, TransformerConfig
 
 # Step-level halves of the kernel head-to-heads (profile_attention /
 # profile_xent / profile_layernorm): APEX_ATTN_IMPL, APEX_FUSED_LM_HEAD,
-# APEX_LN_PALLAS — shared semantics with bench.py via benchmarks/_knobs
+# APEX_LN_PALLAS — resolved by benchmarks/_knobs
 from benchmarks._knobs import (apply_dispatch_knobs, fused_head_requested,
                                remat_granularity)
 
 apply_dispatch_knobs()
 FUSED_HEAD = fused_head_requested()
 REMAT = remat_granularity()
-# Autotune rung mode (benchmarks/autotune_steps.py): measure ONLY the
-# FULL-train-step row — an A/B pass pays for one number per rung inside
-# a budgeted window, not the whole component table.
+# Measure ONLY the FULL-train-step row — an A/B pass pays for one
+# number, not the whole component table.
 ONLY_STEP = _tiles.env_flag("APEX_GPT_ONLY_STEP")
 
 B, S = (2, 128) if SMOKE else (8, 1024)
@@ -87,7 +83,6 @@ params = jax.jit(shmap(
     2))(ids, pos)
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
 TRACER = Tracer(K)
-flight.beat("backend_init")  # Tracer measured overhead => backend is up
 print(f"params: {n_params/1e6:.1f}M   (method: {K}-step lax.scan, 1 dispatch,"
       f" dispatch overhead {TRACER.overhead_ms:.1f} ms subtracted)")
 
@@ -213,13 +208,11 @@ make_step = make_train_step(model)
 # stamped into this run's ledger record, so check_bench_labels check 5
 # can police citations) and the advanced state is committed after the
 # row. Restore/save sit entirely outside the Tracer's timed region.
-from apex_tpu import compile_cache as _cc  # noqa: E402
-
 step_carry0 = (params, opt_state, scaler.init())
 CKPT_EXTRA = {}
 _ckpt_writer, _ckpt_rng = None, jax.random.PRNGKey(0)
 _gpt_step0 = 0
-if os.environ.get("APEX_CKPT_DIR") and not _cc.warm_only():
+if os.environ.get("APEX_CKPT_DIR"):
     from apex_tpu import checkpoint as _ckpt_mod
     from apex_tpu.telemetry import ledger as _tledger
 
@@ -229,8 +222,8 @@ if os.environ.get("APEX_CKPT_DIR") and not _cc.warm_only():
         _tmpl = {"params": step_carry0[0], "opt": step_carry0[1],
                  "scaler": step_carry0[2], "rng": _ckpt_rng}
         # checkpoint.resume_provenance: the ONE restore+provenance
-        # implementation shared with bench.py (check 5 depends on the
-        # exact resumed_from shape); the meta guard refuses
+        # implementation (check 5 depends on the exact resumed_from
+        # shape); the meta guard refuses
         # cross-config resumes the batch-independent state tree
         # cannot (e.g. a b=16 checkpoint under this b=8 run)
         _restored, _gpt_step0, _prov = _ckpt_mod.resume_provenance(
@@ -243,7 +236,7 @@ if os.environ.get("APEX_CKPT_DIR") and not _cc.warm_only():
 
 # the headline row captures its attribution block (flops/HBM/peak-HBM
 # floors — apex_tpu.telemetry.costs): one extra host trace after the
-# timed region, free in warm mode, smoke-off like the ledger
+# timed region, smoke-off like the ledger
 from apex_tpu.telemetry import costs as _costs  # noqa: E402
 
 # ...and its TRAINING overlap_bound inputs (ROADMAP 4d, ISSUE 14):
@@ -252,17 +245,16 @@ from apex_tpu.telemetry import costs as _costs  # noqa: E402
 # the per-step collective payload over the ICI envelope (the size-1
 # single-chip tp axis moves nothing and is filtered, the
 # training_comm_bytes rule). Both strictly OUTSIDE the Tracer's timed
-# region; skipped in warm mode (nothing measured there).
+# region.
 OVERLAP_HOST_MS = OVERLAP_COMM = OVERLAP_COMM_MS = None
-if _costs.enabled(default=not SMOKE) and not _cc.warm_only():
+if _costs.enabled(default=not SMOKE):
     from jax import lax as _olax
 
     from apex_tpu.overlap import prefetch as _prefetch
 
     try:
         # exactly what a per-step feed moves: the int32 ids/labels
-        # (pos is loop-invariant — never re-staged; same rule as
-        # bench.py so the two headline harnesses stamp one claim)
+        # (pos is loop-invariant — never re-staged)
         OVERLAP_HOST_MS = _prefetch.staging_seconds(
             (np.asarray(ids), np.asarray(labels))) * 1e3
     except Exception:
@@ -289,8 +281,7 @@ t_step = scan_time("FULL train step", make_step,
                    capture_cost=_costs.enabled(default=not SMOKE),
                    comm=OVERLAP_COMM, host_ms=OVERLAP_HOST_MS,
                    comm_ms=OVERLAP_COMM_MS)
-if t_step:  # None under APEX_WARM_ONLY (compile-only, nothing timed)
-    print(f"{'':28s} -> {B*S/t_step:.0f} tok/s")
+print(f"{'':28s} -> {B*S/t_step:.0f} tok/s")
 
 if _ckpt_writer is not None:
     # commit the advanced TrainState (one additional K-step scan — the
@@ -422,8 +413,7 @@ if not SMOKE or _tiles.env_flag("APEX_BENCH_DROPOUT_SMOKE"):
         t_d = scan_time(f"FULL step {_label}", make_dstep,
                         (_dparams, _dopt, scaler.init()),
                         (ids, pos, labels), flops_per_iter=model_flops_fb)
-        if t_d:  # None under APEX_WARM_ONLY
-            print(f"{'':28s} -> {B*S/t_d:.0f} tok/s")
+        print(f"{'':28s} -> {B*S/t_d:.0f} tok/s")
 
 # one ledger record for the whole run: calibration + every span above
 TRACER.flush_ledger("profile_gpt", extra=dict({

@@ -10,9 +10,7 @@ achieved HBM bandwidth against the analytic floor:
   SGD:   read g, p, buf; write p, buf    ->  5 fp32 passes
 
 Every run flushes one ledger record (spans per optimizer row incl. the
-"FusedLAMB 1pass" A/B rung plus ``n_params``), so
-``benchmarks/autotune_steps.py`` can cash the LAMB structure decision
-into a dispatch-table entry citing the record id.
+"FusedLAMB 1pass" A/B rung plus ``n_params``).
 
 Results recorded in PERF.md §2/§6.
 Run:  PYTHONPATH=/root/repo:$PYTHONPATH python benchmarks/profile_optimizers.py
@@ -35,7 +33,6 @@ SMOKE = smoke_mode("APEX_BENCH_SMOKE")  # force-CPU tiny sanity mode
 
 from benchmarks._timing import Span, Tracer, bench_k, sync  # noqa: E402
 
-from apex_tpu import compile_cache  # noqa: E402
 from apex_tpu.optimizers.fused_adam import fused_adam  # noqa: E402
 from apex_tpu.optimizers.fused_lamb import fused_lamb  # noqa: E402
 from apex_tpu.optimizers.fused_sgd import fused_sgd  # noqa: E402
@@ -79,15 +76,6 @@ def bench(name, tx, passes):
     f = jax.jit(run, donate_argnums=(0, 1))
     traffic = passes * 4 * n
     floor = traffic / HBM
-    if compile_cache.warm_only():
-        # warm-start pass (APEX_WARM_ONLY=1): AOT-compile only
-        info, _ = compile_cache.warm(
-            f, (p0, state0, jnp.float32(0.0), grads))
-        span = Span(name, None, None, K, TRACER.overhead,
-                    extra={"warm_only": True, "warm": info})
-        TRACER.spans.append(span)
-        print(span.format_row(width=12))
-        return
     p1, s1, out = f(p0, state0, jnp.float32(0.0), grads)
     sync(out)
     # apexlint: disable=APX004 — donated warm/timed pattern on Tracer's own calibration (the timed args ARE the warm call's outputs — time_call cannot express it)
@@ -117,8 +105,7 @@ bench("FusedLAMB", fused_lamb(1e-3, impl="two_pass"), 7)
 # the per-leaf loop's many small norm reductions are the suspect — the
 # one_pass impl does ONE segment_sum sweep instead. Same state layout,
 # so the row is directly comparable; both rows pin impl= per call so
-# the labels can't drift whatever the table/env says, and
-# autotune_steps.py turns the pair into the dispatch-table "lamb" entry.
+# the labels can't drift whatever the table/env says.
 bench("FusedLAMB 1pass", fused_lamb(1e-3, impl="one_pass"), 7)
 bench("FusedSGD", fused_sgd(1e-2, momentum=0.9), 5)
 

@@ -3,11 +3,10 @@
 Three rows per run, one per overlap path, each measured UNDER THE
 RESOLVED KNOBS and pinned back into the environment before the ledger
 write (the profile_serving check-8 discipline, here check 10), so the
-A/B is two rungs of ``run_all_tpu.sh`` — ``overlap_base`` (everything
-off: terminal grad sync, synchronous feed, serial serving loop) vs
-``overlap_on`` (``APEX_OVERLAP_GRAD=bucketed APEX_PREFETCH=2
-APEX_SERVE_OVERLAP=1``) — whose records differ ONLY in the pinned
-schedule:
+A/B is two runs — everything off (terminal grad sync, synchronous
+feed, serial serving loop) vs ``APEX_OVERLAP_GRAD=bucketed
+APEX_PREFETCH=2 APEX_SERVE_OVERLAP=1`` — whose records differ ONLY in
+the pinned schedule:
 
 * **dp grad sync step** — the §0 Tracer K-scan of the minimal-GPT
   data-parallel train step (the profile_comm program) under the
@@ -33,9 +32,8 @@ prefetch, serve}`` + ``collective_schedule`` verdicts;
 pins disagree with the claim. All defaults OFF (measured-dispatch
 rule; PERF.md §2 queues the device rows).
 
-Run on the real TPU via ``run_all_tpu.sh`` (rows ``overlap_base`` /
-``overlap_on``); ``--smoke`` / ``APEX_BENCH_SMOKE=1`` is the CPU
-sanity mode (8 virtual devices). AOT-warmed by ``warm_cache.py``.
+``--smoke`` / ``APEX_BENCH_SMOKE=1`` is the CPU sanity mode (8 virtual
+devices).
 """
 
 import os
@@ -69,7 +67,6 @@ from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from benchmarks._timing import Tracer, bench_k, sync  # noqa: E402
 
-from apex_tpu import compile_cache  # noqa: E402
 from apex_tpu import overlap as overlap_mod  # noqa: E402
 from apex_tpu.overlap import prefetch as prefetch_mod  # noqa: E402
 from apex_tpu.serving import ServingEngine, synthetic_trace  # noqa: E402
@@ -88,7 +85,6 @@ from apex_tpu.transformer.testing.minimal import (  # noqa: E402
 )
 
 K = bench_k(SMOKE)
-WARM_ONLY = compile_cache.warm_only()
 
 # ---------------------------------------------------------------- pins
 # Resolve every overlap knob ONCE, pin the resolved values back into
@@ -247,29 +243,22 @@ def _feed_step(w, ids):
 feed_step = jax.jit(_feed_step)
 
 PIPE_MS = STAGE_MS = None
-if not WARM_ONLY:
-    try:
-        STAGE_MS = prefetch_mod.staging_seconds(feed_batches[0]) * 1e3
-        # warm the feed step off the clock (compile + one dispatch)
-        sync(feed_step(emb, jax.device_put(feed_batches[0])))
-        # apexlint: disable=APX004 — host-clocked feed loop: the staging serialization is the measured quantity; the device rows ride Tracer
-        t0 = time.perf_counter()
-        for staged in prefetch_mod.prefetch(iter(feed_batches)):
-            sync(feed_step(emb, staged))
-        # apexlint: disable=APX004 — host-clocked feed loop: the staging serialization is the measured quantity; the device rows ride Tracer
-        PIPE_MS = (time.perf_counter() - t0) / N_BATCHES * 1e3
-        print(f"{'input pipeline [depth=' + str(PREFETCH_DEPTH) + ']':28s}"
-              f" {PIPE_MS:8.2f} ms/batch over {N_BATCHES} dispatches "
-              f"(staging {STAGE_MS:.2f} ms/batch)")
-    except Exception as e:
-        print(f"profile_overlap: input pipeline row failed "
-              f"({type(e).__name__}: {str(e)[:80]})")
-else:
-    # warm mode: AOT-compile the feed step's cache key; nothing timed
-    try:
-        compile_cache.warm(feed_step, (emb, jnp.asarray(feed_batches[0])))
-    except Exception:
-        pass
+try:
+    STAGE_MS = prefetch_mod.staging_seconds(feed_batches[0]) * 1e3
+    # warm the feed step off the clock (compile + one dispatch)
+    sync(feed_step(emb, jax.device_put(feed_batches[0])))
+    # apexlint: disable=APX004 — host-clocked feed loop: the staging serialization is the measured quantity; the device rows ride Tracer
+    t0 = time.perf_counter()
+    for staged in prefetch_mod.prefetch(iter(feed_batches)):
+        sync(feed_step(emb, staged))
+    # apexlint: disable=APX004 — host-clocked feed loop: the staging serialization is the measured quantity; the device rows ride Tracer
+    PIPE_MS = (time.perf_counter() - t0) / N_BATCHES * 1e3
+    print(f"{'input pipeline [depth=' + str(PREFETCH_DEPTH) + ']':28s}"
+          f" {PIPE_MS:8.2f} ms/batch over {N_BATCHES} dispatches "
+          f"(staging {STAGE_MS:.2f} ms/batch)")
+except Exception as e:
+    print(f"profile_overlap: input pipeline row failed "
+          f"({type(e).__name__}: {str(e)[:80]})")
 
 # ------------------------------------------------- serving replay row
 # The profile_serving trace replay under the resolved engine schedule
@@ -285,64 +274,63 @@ scfg = TransformerConfig(
     apply_query_key_layer_scaling=False, bf16=True)
 SERVE_MS = None
 serving_block = None
-if not WARM_ONLY:
-    try:
-        # warm the serving program set BEFORE the clock (PERF.md §6
-        # warm-start discipline): a scratch engine runs a 2-request
-        # mini trace so the prefill/decode/page-copy compiles land in
-        # the persistent compile cache — the measured engine's own jit
-        # compiles are then cache reads on BOTH rungs, instead of
-        # overlap_base paying a cold remote compile inside its wall
-        # that overlap_on would read back out of the cache
-        scratch = ServingEngine(scfg, num_slots=4, page_size=8,
-                                num_pages=48, max_seq=64,
-                                prefill_len=32)
-        warm_trace, _ = synthetic_trace(
-            seed=1, n_requests=2, vocab=scfg.vocab_size, prompt_lo=4,
-            prompt_hi=8, new_lo=2, new_hi=4, mean_interarrival=0.5)
-        scratch.run_trace(warm_trace)
-        replay = ServingEngine(scfg, params=scratch.params,
-                               num_slots=4, page_size=8,
-                               num_pages=48, max_seq=64, prefill_len=32)
-        assert replay.overlap == SERVE_OVERLAP, (
-            replay.overlap, SERVE_OVERLAP)
-        trace, trace_id = synthetic_trace(
-            seed=7, n_requests=8 if SMOKE else 24, vocab=scfg.vocab_size,
-            prompt_lo=4, prompt_hi=16, new_lo=4, new_hi=24,
-            mean_interarrival=0.5)
-        # apexlint: disable=APX004 — host-clocked serving replay: the host slice is the measured quantity (profile_serving rule)
-        t0 = time.perf_counter()
-        done = replay.run_trace(trace)
-        # apexlint: disable=APX004 — host-clocked serving replay: the host slice is the measured quantity (profile_serving rule)
-        wall = time.perf_counter() - t0
-        SERVE_MS = wall / max(1, replay.decode_steps) * 1e3
-        host_ms = max(0.0, (wall - replay.device_dispatch_s)
-                      / max(1, replay.decode_steps) * 1e3)
-        serving_block = {
-            "tokens_per_s": round(replay.tokens_generated / wall, 2),
-            "scan_tokens_per_s": None,
-            "p50_ms": None, "p99_ms": None,
-            "trace_id": trace_id, "kv_pages": 48,
-            "requests": len(done),
-            "decode_steps": replay.decode_steps,
-            "spec_acceptance_rate": None, "draft_len": None,
-            "prefix_hit_rate": None,
-            # the replay's measured host slice per round: it belongs
-            # to THIS tiny serving program, so it rides here — never
-            # attached to the grad row's cost block, whose floor
-            # describes a different program (profile_serving owns the
-            # same-program floor/host pairing for the real serving
-            # stack)
-            "host_ms_per_round": round(host_ms, 3),
-        }
-        print(f"{'serving replay [' + ('overlap' if SERVE_OVERLAP else 'serial') + ']':28s}"
-              f" {SERVE_MS:8.2f} ms/round, host slice "
-              f"{host_ms:.2f} ms/round over {replay.decode_steps} "
-              f"round(s) [{trace_id}]")
-        assert replay.decode_cache_size() == 1
-    except Exception as e:
-        print(f"profile_overlap: serving replay row failed "
-              f"({type(e).__name__}: {str(e)[:80]})")
+try:
+    # warm the serving program set BEFORE the clock (PERF.md §6
+    # warm-start discipline): a scratch engine runs a 2-request
+    # mini trace so the prefill/decode/page-copy compiles land in
+    # the persistent compile cache — the measured engine's own jit
+    # compiles are then cache reads on BOTH rungs, instead of
+    # overlap_base paying a cold remote compile inside its wall
+    # that overlap_on would read back out of the cache
+    scratch = ServingEngine(scfg, num_slots=4, page_size=8,
+                            num_pages=48, max_seq=64,
+                            prefill_len=32)
+    warm_trace, _ = synthetic_trace(
+        seed=1, n_requests=2, vocab=scfg.vocab_size, prompt_lo=4,
+        prompt_hi=8, new_lo=2, new_hi=4, mean_interarrival=0.5)
+    scratch.run_trace(warm_trace)
+    replay = ServingEngine(scfg, params=scratch.params,
+                           num_slots=4, page_size=8,
+                           num_pages=48, max_seq=64, prefill_len=32)
+    assert replay.overlap == SERVE_OVERLAP, (
+        replay.overlap, SERVE_OVERLAP)
+    trace, trace_id = synthetic_trace(
+        seed=7, n_requests=8 if SMOKE else 24, vocab=scfg.vocab_size,
+        prompt_lo=4, prompt_hi=16, new_lo=4, new_hi=24,
+        mean_interarrival=0.5)
+    # apexlint: disable=APX004 — host-clocked serving replay: the host slice is the measured quantity (profile_serving rule)
+    t0 = time.perf_counter()
+    done = replay.run_trace(trace)
+    # apexlint: disable=APX004 — host-clocked serving replay: the host slice is the measured quantity (profile_serving rule)
+    wall = time.perf_counter() - t0
+    SERVE_MS = wall / max(1, replay.decode_steps) * 1e3
+    host_ms = max(0.0, (wall - replay.device_dispatch_s)
+                  / max(1, replay.decode_steps) * 1e3)
+    serving_block = {
+        "tokens_per_s": round(replay.tokens_generated / wall, 2),
+        "scan_tokens_per_s": None,
+        "p50_ms": None, "p99_ms": None,
+        "trace_id": trace_id, "kv_pages": 48,
+        "requests": len(done),
+        "decode_steps": replay.decode_steps,
+        "spec_acceptance_rate": None, "draft_len": None,
+        "prefix_hit_rate": None,
+        # the replay's measured host slice per round: it belongs
+        # to THIS tiny serving program, so it rides here — never
+        # attached to the grad row's cost block, whose floor
+        # describes a different program (profile_serving owns the
+        # same-program floor/host pairing for the real serving
+        # stack)
+        "host_ms_per_round": round(host_ms, 3),
+    }
+    print(f"{'serving replay [' + ('overlap' if SERVE_OVERLAP else 'serial') + ']':28s}"
+          f" {SERVE_MS:8.2f} ms/round, host slice "
+          f"{host_ms:.2f} ms/round over {replay.decode_steps} "
+          f"round(s) [{trace_id}]")
+    assert replay.decode_cache_size() == 1
+except Exception as e:
+    print(f"profile_overlap: serving replay row failed "
+          f"({type(e).__name__}: {str(e)[:80]})")
 
 # --------------------------------------------------------- the record
 # the claim block check 10 pin-matches: resolved values, one knob set

@@ -27,8 +27,6 @@ The record PINS both fleet knobs — ``APEX_ROUTE_POLICY`` and
 (tools/check_bench_labels.py check 12: block and pins must agree both
 directions), so every router row is citable by construction.
 
-Run on the real TPU behind ``APEX_SERVE_BENCH=1`` (the
-``serving_router`` rung, dead-last in run_all_tpu.sh);
 ``--smoke`` / ``APEX_BENCH_SMOKE=1`` is the CPU sanity mode that also
 produced the committed CPU-mesh hit-rate numbers in PERF.md §2.
 """
@@ -49,11 +47,6 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")
 
 from benchmarks._timing import Tracer  # noqa: E402
-from apex_tpu.telemetry import flight  # noqa: E402
-
-flight.beat("proc_start")  # no-op unless APEX_FLIGHT_DIR
-
-from apex_tpu import compile_cache  # noqa: E402
 from apex_tpu.dispatch import tiles as _tiles  # noqa: E402
 from apex_tpu.serving import ServingEngine, synthetic_trace  # noqa: E402
 from apex_tpu.serving import lifecycle  # noqa: E402
@@ -106,7 +99,6 @@ os.environ["APEX_SERVE_PREFIX_CACHE"] = "1" if PREFIX else "0"
 params = smodel.init_gpt_params(cfg)
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
 TRACER = Tracer(K)
-flight.beat("backend_init")
 print(f"router: {n_params / 1e6:.1f}M params x {N_REPLICAS} replicas "
       f"(shared), {SLOTS} slots, {PAGES} pages x {PS} each, "
       f"policy={POLICY}, arrivals={ARRIVALS} "
@@ -143,16 +135,6 @@ def make_trace(arrival, *, seed=7):
         mean_interarrival=0.5, arrival=arrival,
         system_prompt=sys_prompt)
 
-
-if compile_cache.warm_only():
-    # compile-only pass: build one fleet + run one short trace so the
-    # prefill/decode programs land in the persistent cache, then exit
-    # (flush_ledger writes nothing in warm mode)
-    fleet = build_fleet(1, prefix=True)
-    trace, _ = make_trace(ARRIVALS)
-    Router(fleet, policy=POLICY).run_trace(trace[:2])
-    TRACER.flush_ledger("profile_router")
-    sys.exit(0)
 
 import time  # noqa: E402
 

@@ -37,14 +37,12 @@ knob — ``APEX_SERVE_WEIGHT_QUANT``,
 RESOLVED values before the write, so every serving row is citable
 under ``tools/check_bench_labels.py`` by construction.
 
-Run on the real TPU (dead-last in run_all_tpu.sh behind
-``APEX_SERVE_BENCH=1`` — the still-owed training headlines outrank
-it); ``--smoke`` / ``APEX_BENCH_SMOKE=1`` is the CPU sanity mode.
-AOT-warmed by ``benchmarks/warm_cache.py`` when the rung is armed.
+``--smoke`` / ``APEX_BENCH_SMOKE=1`` is the CPU sanity mode.
 """
 
 import os
 import sys
+import time
 
 if "--smoke" in sys.argv[1:]:
     os.environ["APEX_BENCH_SMOKE"] = "1"
@@ -60,11 +58,6 @@ from benchmarks._smoke import smoke_mode  # noqa: E402
 SMOKE = smoke_mode("APEX_BENCH_SMOKE")
 
 from benchmarks._timing import Tracer  # noqa: E402
-from apex_tpu.telemetry import flight  # noqa: E402
-
-flight.beat("proc_start")  # ISSUE 16: no-op unless APEX_FLIGHT_DIR
-
-from apex_tpu import compile_cache  # noqa: E402
 from apex_tpu.dispatch import tiles as _tiles  # noqa: E402
 from apex_tpu.serving import (  # noqa: E402
     ServingEngine,
@@ -120,9 +113,7 @@ os.environ["APEX_SERVE_SCHED"] = POLICY
 # draft length, sampling, prefix cache — resolved once, pinned back
 # into the env BEFORE the engines build (they re-resolve from these
 # very pins), so the record's knobs name exactly the programs the
-# replay ran. The rungs ride run_all_tpu.sh's dead-last serving rows
-# (serving_sampling / serving_spec / serving_prefix) and their A/Bs
-# are queued in PERF.md §2.
+# replay ran.
 SPEC_K = spec_mod.resolve_k()
 os.environ["APEX_SPEC_DECODE"] = str(SPEC_K)
 SAMPLING = sampling_mod.resolve()
@@ -144,8 +135,7 @@ os.environ["APEX_SERVE_OVERLAP"] = "1" if SERVE_OVERLAP else "0"
 # watchdog — resolved once and pinned back BEFORE the engines build
 # (they re-resolve from these pins), so the record's knobs name
 # exactly the admission/preemption/recovery behavior the replay ran
-# under. The shed-vs-tail overload A/B under the diurnal trace rides
-# run_all_tpu.sh's `serving_resilience` rung (PERF.md §2).
+# under.
 from apex_tpu.serving import resilience as serve_res  # noqa: E402
 
 ADMIT = serve_res.resolve_admit()
@@ -210,7 +200,6 @@ engine = ServingEngine(cfg, num_slots=SLOTS, page_size=PS,
 IMPL = engine.decode_attn_impl
 n_params = sum(x.size for x in jax.tree_util.tree_leaves(engine.params))
 TRACER = Tracer(K)
-flight.beat("backend_init")  # Tracer measured overhead => backend is up
 print(f"serving: {n_params / 1e6:.1f}M params, {SLOTS} slots, "
       f"{PAGES} pages x {PS}, quant={'int8' if WQ else 'off'}, "
       f"kv={'int8' if KV_QUANT else 'off'}"
@@ -278,154 +267,149 @@ if span.seconds:
     print(f"{'':28s} -> {scan_tps:.0f} tok/s (scan upper line)")
 
 # ----------------------------- row 2: trace replay + the slo block
-serving_block = None
-slo_block = None
-if not compile_cache.warm_only():
-    import time
+n_req = 6 if SMOKE else 32
+# with the prefix cache armed, the trace models the workload the
+# cache exists for: one shared system prompt per fleet (content-
+# hashed into the tr- id, so the label names the prepended trace)
+sys_prompt = None
+if PREFIX:
+    # span one full page + a partial tail so BOTH sharing modes
+    # (by-reference full pages, copy-on-write tail) are measured
+    sys_len = PS + PS // 2
+    sys_prompt = [int(t) for t in np.random.RandomState(123)
+                  .randint(0, cfg.vocab_size, sys_len)]
+new_hi = min(24, MAX_SEQ - 32)
+prompt_hi = min(24, PRE_LEN // 2)
+if sys_prompt:
+    # the prepended system prompt rides inside the same max_seq /
+    # prefill_len budgets — shrink the drawn part so no request
+    # can overflow the per-slot page table
+    prompt_hi = max(4, min(prompt_hi,
+                           MAX_SEQ - new_hi - len(sys_prompt),
+                           PRE_LEN - len(sys_prompt)))
+trace, trace_id = synthetic_trace(
+    seed=7, n_requests=n_req, vocab=cfg.vocab_size,
+    prompt_lo=4, prompt_hi=prompt_hi,
+    new_lo=4, new_hi=new_hi,
+    mean_interarrival=0.5, arrival=ARRIVALS,
+    system_prompt=sys_prompt)
+# lifecycle collection ON for the replay engine only (the scan
+# row above measured the device program, not host bookkeeping);
+# reset to the env default right after the ctor captured the gate
+lifecycle.enable()
+try:
+    replay = ServingEngine(cfg, params=engine.params,
+                           num_slots=SLOTS, page_size=PS,
+                           num_pages=PAGES, max_seq=MAX_SEQ,
+                           prefill_len=PRE_LEN, policy=POLICY)
+finally:
+    lifecycle.reset_enabled()
+# apexlint: disable=APX004 — host-clocked SLO replay: the host wall IS the measured quantity (slo block); the decode headline rides Tracer
+t0 = time.perf_counter()
+done = replay.run_trace(trace)
+# apexlint: disable=APX004 — host-clocked SLO replay: the host wall IS the measured quantity (slo block); the decode headline rides Tracer
+wall = time.perf_counter() - t0
+lats = sorted((r.finish_wall - r.enqueue_wall) * 1e3 for r in done
+              if r.finish_wall and r.enqueue_wall)
+p50 = lats[len(lats) // 2]
+p99 = lats[min(len(lats) - 1, int(len(lats) * 0.99))]
+replay_tps = replay.tokens_generated / wall
+gen = replay.generation_stats()
 
-    n_req = 6 if SMOKE else 32
-    # with the prefix cache armed, the trace models the workload the
-    # cache exists for: one shared system prompt per fleet (content-
-    # hashed into the tr- id, so the label names the prepended trace)
-    sys_prompt = None
-    if PREFIX:
-        # span one full page + a partial tail so BOTH sharing modes
-        # (by-reference full pages, copy-on-write tail) are measured
-        sys_len = PS + PS // 2
-        sys_prompt = [int(t) for t in np.random.RandomState(123)
-                      .randint(0, cfg.vocab_size, sys_len)]
-    new_hi = min(24, MAX_SEQ - 32)
-    prompt_hi = min(24, PRE_LEN // 2)
-    if sys_prompt:
-        # the prepended system prompt rides inside the same max_seq /
-        # prefill_len budgets — shrink the drawn part so no request
-        # can overflow the per-slot page table
-        prompt_hi = max(4, min(prompt_hi,
-                               MAX_SEQ - new_hi - len(sys_prompt),
-                               PRE_LEN - len(sys_prompt)))
-    trace, trace_id = synthetic_trace(
-        seed=7, n_requests=n_req, vocab=cfg.vocab_size,
-        prompt_lo=4, prompt_hi=prompt_hi,
-        new_lo=4, new_hi=new_hi,
-        mean_interarrival=0.5, arrival=ARRIVALS,
-        system_prompt=sys_prompt)
-    # lifecycle collection ON for the replay engine only (the scan
-    # row above measured the device program, not host bookkeeping);
-    # reset to the env default right after the ctor captured the gate
-    lifecycle.enable()
-    try:
-        replay = ServingEngine(cfg, params=engine.params,
-                               num_slots=SLOTS, page_size=PS,
-                               num_pages=PAGES, max_seq=MAX_SEQ,
-                               prefill_len=PRE_LEN, policy=POLICY)
-    finally:
-        lifecycle.reset_enabled()
-    # apexlint: disable=APX004 — host-clocked SLO replay: the host wall IS the measured quantity (slo block); the decode headline rides Tracer
-    t0 = time.perf_counter()
-    done = replay.run_trace(trace)
-    # apexlint: disable=APX004 — host-clocked SLO replay: the host wall IS the measured quantity (slo block); the decode headline rides Tracer
-    wall = time.perf_counter() - t0
-    lats = sorted((r.finish_wall - r.enqueue_wall) * 1e3 for r in done
-                  if r.finish_wall and r.enqueue_wall)
-    p50 = lats[len(lats) // 2]
-    p99 = lats[min(len(lats) - 1, int(len(lats) * 0.99))]
-    replay_tps = replay.tokens_generated / wall
-    gen = replay.generation_stats()
+def _r4(v):
+    return None if v is None else round(v, 4)
 
-    def _r4(v):
-        return None if v is None else round(v, 4)
-
-    serving_block = {
-        "tokens_per_s": round(replay_tps, 2),
-        "scan_tokens_per_s": None if scan_tps is None
-        else round(scan_tps, 2),
-        "p50_ms": round(p50, 2), "p99_ms": round(p99, 2),
-        "trace_id": trace_id, "kv_pages": PAGES,
-        "requests": len(done),
-        "decode_steps": replay.decode_steps,
-        # decode_steps counts DISPATCHES (the ~65 ms relay unit);
-        # tokens/dispatch is the K-block amortization the
-        # serving_multitok rung (ISSUE 17) exists to measure
-        "tokens_generated": replay.tokens_generated,
-        # generation economics (ISSUE 13): None-when-disabled —
-        # degradation, never omission (check 8 refuses a non-None
-        # rate whose selecting knob is unpinned or off)
-        "spec_acceptance_rate": _r4(gen["spec_acceptance_rate"]),
-        "draft_len": _r4(gen["draft_len"]),
-        "prefix_hit_rate": _r4(gen["prefix_hit_rate"]),
-    }
-    # KV-tier economics (ISSUE 20): None-when-disabled like the
-    # generation rates above — check 8 refuses a non-None value
-    # whose selecting knob is unpinned or off
-    serving_block.update({k: (_r4(v) if k == "swap_rate" else v)
-                          for k, v in replay.kv_tier_rates().items()})
-    print(f"{'trace replay':28s} {len(done)} req, "
-          f"{replay.tokens_generated} tok in {wall:.2f}s -> "
-          f"{replay_tps:.0f} tok/s, p50 {p50:.1f} ms, p99 {p99:.1f} ms "
-          f"[{trace_id}]")
-    gen_bits = []
-    if serving_block["spec_acceptance_rate"] is not None:
-        gen_bits.append(
-            f"spec acceptance {serving_block['spec_acceptance_rate']:.0%}"
-            f" over {replay.verify_calls} verify call(s), mean draft "
-            f"{serving_block['draft_len']:g}")
-    if serving_block["prefix_hit_rate"] is not None:
-        gen_bits.append(
-            f"prefix hit {serving_block['prefix_hit_rate']:.0%}")
-    if gen_bits:
-        print(f"{'generation':28s} {', '.join(gen_bits)}")
-    assert replay.decode_cache_size() == 1, (
-        "decode step recompiled during the trace — the scheduler "
-        "changed a shape (jaxpr-stability contract broken)")
-    assert replay.prefill_cache_size() <= 1, (
-        "prefill program compiled more than once — a speculative "
-        "verify batch took a third compiled program (ISSUE 13 "
-        "contract broken)")
-    order_problems = replay.events.validate_order()
-    assert not order_problems, (
-        "lifecycle event-order invariant broken", order_problems)
-    slo_block = lifecycle.slo_block(
-        done, wall, ttft_ms=SLO_TTFT_MS, tpot_ms=SLO_TPOT_MS,
-        arrival_process=ARRIVALS,
-        offered_load=sched_mod.offered_load(trace),
-        log=replay.events, resilience=replay.resilience_rates(),
-        decode_block_k=replay.decode_k)
-    print(f"{'slo (' + ARRIVALS + ')':28s} "
-          f"ttft p50/p99 {slo_block['ttft_p50_ms']}/"
-          f"{slo_block['ttft_p99_ms']} ms, per-token p50/p99 "
-          f"{slo_block['per_token_p50_ms']}/"
-          f"{slo_block['per_token_p99_ms']} ms, goodput "
-          f"{slo_block['goodput_tok_s']} tok/s, attainment "
-          f"{slo_block['slo_attainment']:.0%} "
-          f"(ttft<={SLO_TTFT_MS:g}ms tpot<={SLO_TPOT_MS:g}ms), "
-          f"qmax={slo_block['max_queue_depth']} "
-          f"kv_hw={slo_block['kv_page_high_water']}/{PAGES}")
-    res_bits = []
-    if slo_block["shed_rate"] is not None:
-        res_bits.append(f"shed {slo_block['shed_rate']:.0%}")
-    if slo_block["preempt_rate"] is not None:
-        res_bits.append(f"preempt {slo_block['preempt_rate']:.0%}")
-    if slo_block["degraded_rounds"] is not None:
-        res_bits.append(
-            f"degraded rounds {slo_block['degraded_rounds']}")
-    if res_bits:
-        print(f"{'resilience':28s} {', '.join(res_bits)} "
-              f"(admit={ADMIT or 'off'}, {len(replay.rejected)} "
-              f"rejected)")
-    # the measured host slice of the serving loop, per decode round
-    # (run wall minus device dispatch time) -> the cost block's
-    # overlap_bound stamp: what perfect host/device overlap
-    # (ROADMAP 4c) could hide behind the decode dispatch
-    if replay.decode_steps:
-        host_ms = max(0.0, (wall - replay.device_dispatch_s)
-                      / replay.decode_steps * 1e3)
-        base = TRACER.cost if TRACER.cost is not None \
-            else _costs.null_block()
-        TRACER.cost = _costs.attach_overlap(base, host_ms=host_ms)
-        ob = TRACER.cost["overlap_bound"]
-        print(f"{'overlap bound':28s} host {ob['host_ms']:.2f} "
-              f"ms/step vs compute floor "
-              f"{'?' if ob['compute_floor_ms'] is None else ob['compute_floor_ms']} ms")
+serving_block = {
+    "tokens_per_s": round(replay_tps, 2),
+    "scan_tokens_per_s": None if scan_tps is None
+    else round(scan_tps, 2),
+    "p50_ms": round(p50, 2), "p99_ms": round(p99, 2),
+    "trace_id": trace_id, "kv_pages": PAGES,
+    "requests": len(done),
+    "decode_steps": replay.decode_steps,
+    # decode_steps counts DISPATCHES (the ~65 ms relay unit);
+    # tokens/dispatch is the K-block amortization the
+    # serving_multitok rung (ISSUE 17) exists to measure
+    "tokens_generated": replay.tokens_generated,
+    # generation economics (ISSUE 13): None-when-disabled —
+    # degradation, never omission (check 8 refuses a non-None
+    # rate whose selecting knob is unpinned or off)
+    "spec_acceptance_rate": _r4(gen["spec_acceptance_rate"]),
+    "draft_len": _r4(gen["draft_len"]),
+    "prefix_hit_rate": _r4(gen["prefix_hit_rate"]),
+}
+# KV-tier economics (ISSUE 20): None-when-disabled like the
+# generation rates above — check 8 refuses a non-None value
+# whose selecting knob is unpinned or off
+serving_block.update({k: (_r4(v) if k == "swap_rate" else v)
+                      for k, v in replay.kv_tier_rates().items()})
+print(f"{'trace replay':28s} {len(done)} req, "
+      f"{replay.tokens_generated} tok in {wall:.2f}s -> "
+      f"{replay_tps:.0f} tok/s, p50 {p50:.1f} ms, p99 {p99:.1f} ms "
+      f"[{trace_id}]")
+gen_bits = []
+if serving_block["spec_acceptance_rate"] is not None:
+    gen_bits.append(
+        f"spec acceptance {serving_block['spec_acceptance_rate']:.0%}"
+        f" over {replay.verify_calls} verify call(s), mean draft "
+        f"{serving_block['draft_len']:g}")
+if serving_block["prefix_hit_rate"] is not None:
+    gen_bits.append(
+        f"prefix hit {serving_block['prefix_hit_rate']:.0%}")
+if gen_bits:
+    print(f"{'generation':28s} {', '.join(gen_bits)}")
+assert replay.decode_cache_size() == 1, (
+    "decode step recompiled during the trace — the scheduler "
+    "changed a shape (jaxpr-stability contract broken)")
+assert replay.prefill_cache_size() <= 1, (
+    "prefill program compiled more than once — a speculative "
+    "verify batch took a third compiled program (ISSUE 13 "
+    "contract broken)")
+order_problems = replay.events.validate_order()
+assert not order_problems, (
+    "lifecycle event-order invariant broken", order_problems)
+slo_block = lifecycle.slo_block(
+    done, wall, ttft_ms=SLO_TTFT_MS, tpot_ms=SLO_TPOT_MS,
+    arrival_process=ARRIVALS,
+    offered_load=sched_mod.offered_load(trace),
+    log=replay.events, resilience=replay.resilience_rates(),
+    decode_block_k=replay.decode_k)
+print(f"{'slo (' + ARRIVALS + ')':28s} "
+      f"ttft p50/p99 {slo_block['ttft_p50_ms']}/"
+      f"{slo_block['ttft_p99_ms']} ms, per-token p50/p99 "
+      f"{slo_block['per_token_p50_ms']}/"
+      f"{slo_block['per_token_p99_ms']} ms, goodput "
+      f"{slo_block['goodput_tok_s']} tok/s, attainment "
+      f"{slo_block['slo_attainment']:.0%} "
+      f"(ttft<={SLO_TTFT_MS:g}ms tpot<={SLO_TPOT_MS:g}ms), "
+      f"qmax={slo_block['max_queue_depth']} "
+      f"kv_hw={slo_block['kv_page_high_water']}/{PAGES}")
+res_bits = []
+if slo_block["shed_rate"] is not None:
+    res_bits.append(f"shed {slo_block['shed_rate']:.0%}")
+if slo_block["preempt_rate"] is not None:
+    res_bits.append(f"preempt {slo_block['preempt_rate']:.0%}")
+if slo_block["degraded_rounds"] is not None:
+    res_bits.append(
+        f"degraded rounds {slo_block['degraded_rounds']}")
+if res_bits:
+    print(f"{'resilience':28s} {', '.join(res_bits)} "
+          f"(admit={ADMIT or 'off'}, {len(replay.rejected)} "
+          f"rejected)")
+# the measured host slice of the serving loop, per decode round
+# (run wall minus device dispatch time) -> the cost block's
+# overlap_bound stamp: what perfect host/device overlap
+# (ROADMAP 4c) could hide behind the decode dispatch
+if replay.decode_steps:
+    host_ms = max(0.0, (wall - replay.device_dispatch_s)
+                  / replay.decode_steps * 1e3)
+    base = TRACER.cost if TRACER.cost is not None \
+        else _costs.null_block()
+    TRACER.cost = _costs.attach_overlap(base, host_ms=host_ms)
+    ob = TRACER.cost["overlap_bound"]
+    print(f"{'overlap bound':28s} host {ob['host_ms']:.2f} "
+          f"ms/step vs compute floor "
+          f"{'?' if ob['compute_floor_ms'] is None else ob['compute_floor_ms']} ms")
 
 rid = TRACER.flush_ledger("profile_serving", extra={
     "serving": serving_block,

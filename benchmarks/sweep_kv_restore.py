@@ -26,10 +26,8 @@ same stream length) keeps the +1-token-per-round drift fair, and an
 assert pins every cycle of a bucket inside ONE pow2 bucket so the
 committed key names exactly the lengths measured.
 
-CPU demonstration sweep: entries land backend-keyed ``"cpu"`` (the
-same capability-demonstration class as the autotune_tiles CPU
-entries); the TPU A/B at serving shapes is queued in PERF.md §2 and
-rides run_all_tpu.sh's ``serving_kv_swap`` rung.
+CPU demonstration sweep: entries land backend-keyed ``"cpu"``; the TPU
+A/B at serving shapes has not been run.
 
 Usage::
 
@@ -50,7 +48,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-# table-blind measurement (the autotune_steps convention): the sweep
+# table-blind measurement: the sweep
 # measures the two built-in paths, not yesterday's table — and the
 # committed entry pins APEX_DISPATCH=off so the citation can be
 # audited against exactly that
@@ -191,8 +189,8 @@ def main(argv=None):
 
 
 def _upsert(table_path, entry):
-    """Replace-or-append the entry for its key (the autotune_steps
-    convention: corrupt lines kept verbatim, atomic replace)."""
+    """Replace-or-append the entry for its key (corrupt lines kept
+    verbatim, atomic replace)."""
     key = (entry["op"], entry["bucket"], entry["dtype"],
            entry["backend"])
     lines = []

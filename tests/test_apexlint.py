@@ -10,11 +10,8 @@ Three surfaces under test:
    run against a scaffolded mini-repo, plus pragma accounting
    (APX000: reasonless and unknown-rule pragmas are findings;
    unused pragmas are reported, never failures).
-3. **The gates** — the CLI rc convention (0 clean / 1 findings /
-   2 crash-as-finding), the ``--json`` machine line, and both
-   collection shells refusing to arm on a dirty lint
-   (``APEX_APEXLINT_ROOT`` fixture redirect — the APEX_PROBE_*
-   isolation pattern).
+3. **The gate** — the CLI rc convention (0 clean / 1 findings /
+   2 crash-as-finding) and the ``--json`` machine line.
 
 No jax needed anywhere here: the linter is stdlib+AST by design.
 """
@@ -204,19 +201,6 @@ def test_apx003_infra_prefix_coverage_and_staleness(tmp_path):
     assert len(msgs) == 1 and "APEX_GONE_" in msgs[0], msgs
 
 
-def test_apx003_counts_shell_uses(tmp_path):
-    api = SCAFFOLD_API.replace(
-        "| `APEX_DOCED=1` | documented fixture knob |",
-        "| `APEX_DOCED=1` | documented fixture knob |\n"
-        "| `APEX_SHELL_ONLY=1` | read by the collection shell |")
-    root = make_tree(tmp_path, {
-        "benchmarks/run_all_tpu.sh":
-            '#!/bin/bash\nif [ -n "${APEX_SHELL_ONLY:-}" ]; then echo y; fi\n',
-    }, api_md=api)
-    report = run(root, rules=["APX003"])
-    assert not rule_findings(report, "APX003"), report.render()
-
-
 def test_apx003_missing_markers_is_a_finding(tmp_path):
     root = make_tree(tmp_path, api_md="# no markers here\n")
     report = run(root, rules=["APX003"])
@@ -321,21 +305,6 @@ def test_apx006_resolves_relative_imports(tmp_path):
         report.render()
 
 
-def test_apx003_shell_comment_mention_is_not_a_use(tmp_path):
-    api = SCAFFOLD_API.replace(
-        "| `APEX_DOCED=1` | documented fixture knob |",
-        "| `APEX_DOCED=1` | documented fixture knob |\n"
-        "| `APEX_COMMENTED` | named only in a shell comment |")
-    root = make_tree(tmp_path, {
-        "benchmarks/run_all_tpu.sh":
-            "#!/bin/bash\n# APEX_COMMENTED is prose, not a use\n",
-    }, api_md=api)
-    report = run(root, rules=["APX003"])
-    msgs = [f.msg for f in rule_findings(report, "APX003")]
-    assert any("APEX_COMMENTED" in m and "never read" in m
-               for m in msgs), msgs
-
-
 # ---------------------------------------------------------------------------
 # pragma machinery (APX000 + accounting)
 # ---------------------------------------------------------------------------
@@ -378,7 +347,7 @@ def test_pragma_accounting_in_json(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# 3. CLI + shell gates
+# 3. CLI
 # ---------------------------------------------------------------------------
 
 def test_cli_json_machine_line_on_repo():
@@ -437,69 +406,3 @@ def test_cli_rejects_unknown_rule_id():
         cwd=REPO, env=env, capture_output=True, text=True, timeout=120)
     assert out.returncode == 2, out.stdout + out.stderr
     assert "unknown rule id" in out.stderr
-
-
-def _shell_env(tmp_path, lint_root):
-    return dict(
-        os.environ,
-        APEX_APEXLINT_ROOT=lint_root,
-        APEX_PROBE_DRYRUN="1",
-        APEX_PROBE_PIDFILE=str(tmp_path / "probe.pid"),
-        APEX_PROBE_DISARM=str(tmp_path / "probe.disarm"),
-        APEX_PROBE_STATE=str(tmp_path / "probe.state"),
-    )
-
-
-def test_probe_shell_refuses_to_arm_on_dirty_lint(tmp_path):
-    dirty = make_tree(tmp_path / "tree", {
-        "apex_tpu/v.py": "apx001_violation.py"})
-    out = subprocess.run(
-        ["bash", os.path.join(REPO, "benchmarks", "probe_and_collect.sh")],
-        env=_shell_env(tmp_path, dirty),
-        capture_output=True, text=True, timeout=180)
-    assert out.returncode == 2, out.stdout + out.stderr
-    assert "REFUSING TO ARM" in out.stderr and "apexlint" in out.stderr
-
-
-def test_probe_shell_arms_on_clean_lint(tmp_path):
-    clean = make_tree(tmp_path / "tree")
-    out = subprocess.run(
-        ["bash", os.path.join(REPO, "benchmarks", "probe_and_collect.sh")],
-        env=_shell_env(tmp_path, clean),
-        capture_output=True, text=True, timeout=180)
-    assert out.returncode == 0, out.stdout + out.stderr
-    assert "ARM OK (dryrun)" in out.stdout
-
-
-def test_run_all_shell_refuses_on_dirty_lint(tmp_path):
-    dirty = make_tree(tmp_path / "tree", {
-        "apex_tpu/v.py": "apx001_violation.py"})
-    env = dict(os.environ, APEX_APEXLINT_ROOT=dirty)
-    out = subprocess.run(
-        ["bash", os.path.join(REPO, "benchmarks", "run_all_tpu.sh"),
-         str(tmp_path / "out")],
-        env=env, capture_output=True, text=True, timeout=180)
-    assert out.returncode == 2, out.stdout + out.stderr
-    assert "REFUSING TO COLLECT" in out.stderr and "APX001" in out.stderr
-
-
-def test_redirect_cannot_neuter_the_gate(tmp_path):
-    """A leftover APEX_APEXLINT_ROOT export must never arm a REAL
-    pass, even when the fixture tree lints clean — the stale-test-env
-    bypass class the APEX_FAULT_PLAN refusal also guards."""
-    clean = make_tree(tmp_path / "tree")
-    env = dict(os.environ, APEX_APEXLINT_ROOT=clean)
-    out = subprocess.run(
-        ["bash", os.path.join(REPO, "benchmarks", "run_all_tpu.sh"),
-         str(tmp_path / "out")],
-        env=env, capture_output=True, text=True, timeout=180)
-    assert out.returncode == 2, out.stdout + out.stderr
-    assert "test-only" in out.stderr
-    # probe shell: same refusal for a non-dryrun arm
-    probe_env = _shell_env(tmp_path, clean)
-    del probe_env["APEX_PROBE_DRYRUN"]
-    out = subprocess.run(
-        ["bash", os.path.join(REPO, "benchmarks", "probe_and_collect.sh")],
-        env=probe_env, capture_output=True, text=True, timeout=180)
-    assert out.returncode == 2, out.stdout + out.stderr
-    assert "REFUSING TO ARM" in out.stderr and "test-only" in out.stderr

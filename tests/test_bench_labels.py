@@ -239,11 +239,12 @@ def test_resumed_record_with_pin_drift_is_refused(tmp_path):
 
 
 def test_infra_knob_difference_is_not_pin_drift(tmp_path):
-    """Paths/attempt counters (ledger.INFRA_KNOB_PREFIXES) legitimately
-    differ between the saving and the resuming run — not drift."""
+    """Paths and arming switches (ledger.INFRA_KNOB_PREFIXES)
+    legitimately differ between the saving and the resuming run — not
+    drift."""
     rec = _resumed_record(
-        {"APEX_CKPT_RESUME": "1", "APEX_BENCH_ATTEMPT": "2"},
-        {"APEX_BENCH_TIMEOUT": "900"})
+        {"APEX_CKPT_RESUME": "1", "APEX_TELEMETRY_LEDGER": "/tmp/b.jsonl"},
+        {"APEX_COMPILE_CACHE": "off"})
     lpath = tmp_path / "ledger.jsonl"
     lpath.write_text(json.dumps(rec, sort_keys=True) + "\n")
     perf = tmp_path / "PERF.md"

@@ -189,6 +189,34 @@ def test_async_commits_drain_on_flush(tmp_path):
     _assert_tree_equal(restored, state)
 
 
+def test_commit_now_bypasses_a_stalled_async_queue(tmp_path, monkeypatch):
+    """The emergency-save path (a SIGTERM handler's last act): with the
+    background writer stalled inside step 1's commit and step 2 queued
+    behind it, ``commit_now`` lands step 3 from the calling thread
+    without touching the queue, once the writer's commit (bounded by
+    one commit, under the writer lock) is through; nothing queued is
+    lost and the newest restore is the emergency one."""
+    stall = 0.4
+    monkeypatch.setenv("APEX_FAULT_PLAN", json.dumps([
+        {"site": "ckpt_commit", "kind": "hang", "seconds": stall,
+         "match_ctx": {"phase": "serialized", "step": 1}}]))
+    state = _state()
+    w = ckpt.DurableCheckpointer(tmp_path, max_to_keep=5,
+                                 async_save=True, queue_size=2)
+    w.save(1, state)   # the worker stalls in this commit
+    w.save(2, state)   # waits in the queue
+    manifest = w.commit_now(3, jax.device_get(state), meta={"why": "term"})
+    assert manifest["step"] == 3
+    assert 3 in w.all_steps()            # on disk before any flush
+    w.flush()
+    assert w.all_steps() == [1, 2, 3]
+    assert w.snapshot()["errors"] == 0
+    w.close()
+    restored, m = ckpt.restore_durable(str(tmp_path), state)
+    assert m["step"] == 3 and m["meta"]["why"] == "term"
+    _assert_tree_equal(restored, state)
+
+
 def test_async_bounded_queue_applies_backpressure(tmp_path, monkeypatch):
     """A serializer that cannot keep up BLOCKS the caller (bounded
     queue) instead of growing host memory or dropping checkpoints:
@@ -246,11 +274,11 @@ def test_async_commit_error_is_telemetry_not_crash(tmp_path,
 def test_enabled_checkpointing_is_jaxpr_byte_identical(monkeypatch,
                                                        tmp_path):
     """The zero-cost rule for the durability layer: the writer lives
-    entirely at the scan boundary (host side), so tracing the bench
+    entirely at the scan boundary (host side), so tracing the
     training step with checkpointing armed — writer constructed, a
     save committed — yields a jaxpr byte-identical to the
     checkpointing-disabled trace."""
-    import bench
+    from tests.one_step import make_one_step
     from tests.test_telemetry import _bench_fixture
 
     (model, scaler, tx, params, opt_state, scaler_state,
@@ -261,13 +289,13 @@ def test_enabled_checkpointing_is_jaxpr_byte_identical(monkeypatch,
 
     telemetry.disable()
     monkeypatch.delenv("APEX_CKPT_DIR", raising=False)
-    want = str(jax.make_jaxpr(bench.make_one_step(model, scaler, tx))(
+    want = str(jax.make_jaxpr(make_one_step(model, scaler, tx))(
         *args))
 
     monkeypatch.setenv("APEX_CKPT_DIR", str(tmp_path))
     w = ckpt.DurableCheckpointer(tmp_path, async_save=False)
     w.save(1, {"params": params, "opt": opt_state})
-    got = str(jax.make_jaxpr(bench.make_one_step(model, scaler, tx))(
+    got = str(jax.make_jaxpr(make_one_step(model, scaler, tx))(
         *args))
     assert got == want, \
         "enabled checkpointing changed the training step's jaxpr"
@@ -275,7 +303,7 @@ def test_enabled_checkpointing_is_jaxpr_byte_identical(monkeypatch,
 
 def test_snapshot_block_shape_matches_ledger_validation(tmp_path):
     """The writer's telemetry block passes the ledger's checkpoint-
-    block validation — the schema bench.py stamps into records."""
+    block validation — the schema profile_gpt stamps into records."""
     from apex_tpu.telemetry import ledger
 
     w = ckpt.DurableCheckpointer(tmp_path, async_save=False)

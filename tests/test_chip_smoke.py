@@ -1,10 +1,9 @@
-"""chip_smoke.py and bench.py refuse to stand in for the chip.
+"""chip_smoke.py refuses to stand in for the chip.
 
 The chip check itself only runs through the builder's chip tool; what
 the sandbox can pin is everything around it: the dry run walks the same
 control flow and says it is a dry run, a run without a TPU fails and
-prints no result (chip_smoke.py) or no throughput (bench.py), the script
-alone in a directory fails, and a compile cache placed from outside with
+prints no result, the script alone in a directory fails, and a compile cache placed from outside with
 ``JAX_COMPILATION_CACHE_DIR`` is the one the run uses.
 """
 
@@ -78,17 +77,3 @@ def test_alone_in_a_directory_it_fails(tmp_path):
     assert out.returncode != 0
     assert "apex_tpu" in out.stderr
     assert _json_lines(out.stdout) == []
-
-
-def test_bench_without_smoke_needs_the_chip(tmp_path):
-    env = _env(APEX_TELEMETRY_LEDGER=str(tmp_path / "ledger.jsonl"),
-               APEX_BENCH_BASELINE=str(tmp_path / "baseline.json"))
-    for k in ("APEX_BENCH_SMOKE", "APEX_BENCH_INNER", "APEX_WARM_ONLY"):
-        env.pop(k, None)
-    out = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         env=env, capture_output=True, text=True,
-                         timeout=300)
-    assert out.returncode != 0
-    assert "no TPU" in out.stderr
-    assert out.stdout.strip() == ""        # no line, no throughput
-    assert not os.path.exists(tmp_path / "ledger.jsonl")

@@ -19,7 +19,7 @@ mesh) — no TPU window required:
   tracks inside the tolerance band;
 * the ledger/checker/report plumbing for the ``comm_compression``
   cost-block stamp (costs.validate, check_bench_labels check 7,
-  window_report comm rows, the profile_comm/autotune rung wiring).
+  window_report comm rows).
 """
 
 import json
@@ -678,56 +678,6 @@ def test_window_report_comm_rows():
     assert row["bytes_per_axis"] == {"dp": 120.0}
     assert row["scheme"] == "int8"
     assert row["uncompressed_bytes_per_axis"] == {"dp": 470.0}
-
-
-def test_grad_comm_rung_group_registered():
-    from benchmarks.autotune_steps import rung_groups, shape_info
-
-    for smoke in (True, False):
-        groups = {g["name"]: g for g in rung_groups(smoke)}
-        g = groups["grad_comm"]
-        assert g["op"] == "grad_comm"
-        assert g["harness"] == "profile_comm"
-        assert g["metric"] == "dp grad sync step"
-        assert set(g["variants"]) == {"off", "int8", "hier", "int8_hier"}
-        assert g["variants"]["int8_hier"] == {
-            "APEX_GRAD_COMPRESS": "int8", "APEX_HIER_ALLREDUCE": "1"}
-        assert g["dims"] == {"n": shape_info(smoke)["comm_payload"]}
-    # the op is in the dispatch vocabulary (table entries validate)
-    assert dispatch.OP_CHOICES["grad_comm"] == (
-        "off", "int8", "hier", "int8_hier")
-
-
-def test_grad_comm_payload_bucket_mirrors_harness():
-    """The autotune group's payload dims must land in the SAME pow2
-    bucket as the param tree profile_comm actually builds (the
-    'dims mirror what the harness builds' convention, enforced).
-    eval_shape only — nothing compiles."""
-    from benchmarks.autotune_steps import shape_info
-    from apex_tpu.transformer.parallel_state import (
-        PIPELINE_AXIS, DATA_AXIS, TENSOR_AXIS)
-    from apex_tpu.transformer.testing.minimal import (
-        TransformerConfig, make_gpt_fns, toy_batch)
-
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1, 1),
-                (PIPELINE_AXIS, DATA_AXIS, TENSOR_AXIS))
-    # profile_comm's SMOKE cfg, verbatim
-    S = 32
-    cfg = TransformerConfig(
-        hidden_size=64, num_layers=2, num_attention_heads=4,
-        vocab_size=128, max_position_embeddings=S,
-        hidden_dropout=0.0, attention_dropout=0.0, bf16=True,
-        apply_query_key_layer_scaling=False)
-    _, init_params = make_gpt_fns(cfg, 1)
-    b = toy_batch(cfg.vocab_size, 2, 2, S)
-    f = shard_map(
-        lambda ids, labels: init_params(
-            jax.random.PRNGKey(0), {"ids": ids[0], "labels": labels[0]}),
-        mesh=mesh, in_specs=(P(), P()), out_specs=P(), check_vma=False)
-    shapes = jax.eval_shape(f, b["ids"], b["labels"])
-    n = sum(x.size for x in jax.tree_util.tree_leaves(shapes))
-    assert dispatch.bucket(n=n) == \
-        dispatch.bucket(n=shape_info(True)["comm_payload"])
 
 
 @pytest.mark.slow  # one real harness subprocess (~60-90s on this box)

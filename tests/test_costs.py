@@ -63,7 +63,7 @@ def test_build_peak_hbm_from_memory_analysis():
 
 def test_build_cpu_platform_has_no_roofline():
     """No committed envelope off-TPU: floors and bound stay None (the
-    same rule as bench.py's mfu=None on CPU)."""
+    same rule as the benchmark's mfu=None on CPU)."""
     block = costs.build(xla_flops=1e9, hbm_bytes=1e6, steps=1,
                         device_kind="cpu", source="lowered")
     assert block["peak_flops"] is None

@@ -5,8 +5,7 @@ setter > table entry > built-in default), the explicit-request-raises /
 preference-falls-back asymmetry, table-miss and corrupt-line fallback,
 and — the acceptance bar — that a table entry REALLY changes the traced
 program end-to-end for every consulting op family (LN, softmax,
-attention, LM head, remat, LAMB), plus the autotune driver's
-winner/resume/budget/hysteresis logic against a stubbed measurer.
+attention, LM head, remat, LAMB).
 """
 
 import importlib
@@ -205,7 +204,7 @@ def test_attention_table_flip_changes_traced_program(tmp_path, monkeypatch):
                   "float32", "rows"))
     table_jx = _jx(f, q)
     # the CPU-measured table choice runs the rows kernel in interpret
-    # mode — the way it was measured (autotune --smoke)
+    # mode — the way it was measured
     assert "pallas_call" in table_jx
 
 
@@ -411,152 +410,6 @@ def test_lamb_table_flip_and_precedence(tmp_path, monkeypatch):
     assert jx_of(fused_lamb(1e-3, impl="two_pass")) == default_jx
 
 
-# ------------------------- autotune driver ----------------------------------
-
-def _seed_ledger(tmp_path, n=1):
-    recs = [ledger.make_record("profile_gpt", "cpu", 0.5, 2, knobs={},
-                               git="abc", ts=float(i)) for i in range(n)]
-    path = tmp_path / "ledger.jsonl"
-    path.write_text("".join(json.dumps(r, sort_keys=True) + "\n"
-                            for r in recs))
-    return [r["id"] for r in recs], str(path)
-
-
-def _fake_measure(values):
-    """Stub for autotune_steps._measure: rung.variant -> value (ms or
-    tokens/s per the group's unit), all citing the seeded ledger id."""
-
-    def measure(group, vname, venv, ctx):
-        key = f"{group['name']}.{vname}"
-        if key not in values:
-            return None
-        unit = "tokens/s" if group.get("metric") == "tokens_per_sec" \
-            else "ms"
-        return {"value": values[key], "unit": unit,
-                "ledger": values.get("_ledger"),
-                "pins": dict(venv) if isinstance(venv, dict) else {},
-                "n_params": 1000}
-    return measure
-
-
-def test_autotune_writes_winner_and_resumes(tmp_path, monkeypatch):
-    from benchmarks import autotune_steps as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    table = tmp_path / "table.jsonl"
-    vals = {"gpt_rows.flash": 50.0, "gpt_rows.rows": 40.0,
-            "_ledger": ids[0]}
-    monkeypatch.setattr(at, "_measure", _fake_measure(vals))
-    rc = at.main(["--smoke", "--only", "gpt_rows", "--table", str(table),
-                  "--ledger", lpath])
-    assert rc == 0
-    entries, problems = dispatch.load_table(str(table))
-    assert problems == [] and len(entries) == 1
-    e = next(iter(entries.values()))
-    assert e["choice"] == "rows" and e["ledger"] == ids[0]
-    assert e["pins"] == {"APEX_ATTN_IMPL": "rows"}
-    assert e["measured"]["flash"]["value"] == 50.0
-
-    # second invocation: the cashed rung is SKIPPED (resume contract) —
-    # a measurer that explodes proves no measurement ran
-    def boom(*a, **kw):
-        raise AssertionError("re-measured a cashed rung")
-
-    monkeypatch.setattr(at, "_measure", boom)
-    rc = at.main(["--smoke", "--only", "gpt_rows", "--table", str(table),
-                  "--ledger", lpath])
-    assert rc == 0
-
-    # ...but a STALE entry (ledger id no longer resolves) re-runs
-    stale = dict(e, ledger="lg-ffffffffff")
-    table.write_text(json.dumps(stale) + "\n")
-    dispatch._reset_for_tests()
-    monkeypatch.setattr(at, "_measure", _fake_measure(vals))
-    assert at.main(["--smoke", "--only", "gpt_rows", "--table", str(table),
-                    "--ledger", lpath]) == 0
-    entries, _ = dispatch.load_table(str(table))
-    assert next(iter(entries.values()))["ledger"] == ids[0]
-
-
-def test_autotune_flip_margin_keeps_default(tmp_path, monkeypatch):
-    from benchmarks import autotune_steps as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    table = tmp_path / "table.jsonl"
-    # rows ahead by 1% — inside the hysteresis margin
-    vals = {"gpt_rows.flash": 50.0, "gpt_rows.rows": 49.5,
-            "_ledger": ids[0]}
-    monkeypatch.setattr(at, "_measure", _fake_measure(vals))
-    assert at.main(["--smoke", "--only", "gpt_rows", "--table", str(table),
-                    "--ledger", lpath]) == 0
-    entries, _ = dispatch.load_table(str(table))
-    assert next(iter(entries.values()))["choice"] == "flash"
-
-
-def test_autotune_budget_drops_are_loud(tmp_path, monkeypatch, capsys):
-    from benchmarks import autotune_steps as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    table = tmp_path / "table.jsonl"
-    monkeypatch.setattr(at, "_measure", _fake_measure(
-        {"gpt_rows.flash": 50.0, "gpt_rows.rows": 40.0, "_ledger": ids[0]}))
-    rc = at.main(["--smoke", "--only", "gpt_rows,gpt_ln_pallas",
-                  "--table", str(table), "--ledger", lpath,
-                  "--budget-s", "0"])
-    out = capsys.readouterr().out
-    assert rc == 1  # dropped rungs are a nonzero exit, not a silent cap
-    assert "BUDGET DROPPED" in out
-
-
-def test_autotune_failed_variant_is_not_an_entry(tmp_path, monkeypatch):
-    from benchmarks import autotune_steps as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    table = tmp_path / "table.jsonl"
-    monkeypatch.setattr(at, "_measure", _fake_measure({"_ledger": ids[0]}))
-    rc = at.main(["--smoke", "--only", "gpt_rows", "--table", str(table),
-                  "--ledger", lpath])
-    assert rc == 1
-    entries, _ = dispatch.load_table(str(table))
-    assert entries == {}
-
-
-@pytest.mark.slow
-def test_autotune_smoke_end_to_end(tmp_path):
-    """The real thing, two rungs: subprocess harness runs on CPU, table
-    entries written with resolving ledger ids, second invocation resumes
-    (skips both rungs without re-measuring)."""
-    import os
-    import subprocess
-    import sys
-    import time
-
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(REPO, "benchmarks", "autotune_steps.py")
-    table = tmp_path / "table.jsonl"
-    lpath = tmp_path / "ledger.jsonl"
-    args = [sys.executable, script, "--smoke", "--only",
-            "gpt_ln_pallas,lamb_one_pass", "--table", str(table),
-            "--ledger", str(lpath), "--repeats", "1",
-            "--out", str(tmp_path / "logs")]
-    env = dict(os.environ)
-    out = subprocess.run(args, capture_output=True, text=True,
-                         timeout=420, env=env)
-    assert out.returncode == 0, out.stdout + out.stderr
-    entries, problems = dispatch.load_table(str(table))
-    assert problems == [] and len(entries) == 2, out.stdout
-    ids = {r["id"] for r in ledger.read_ledger(str(lpath))}
-    for e in entries.values():
-        assert e["ledger"] in ids
-    # resume: the second invocation must skip both rungs, fast
-    t0 = time.time()
-    out2 = subprocess.run(args, capture_output=True, text=True,
-                          timeout=120, env=env)
-    assert out2.returncode == 0, out2.stdout + out2.stderr
-    assert out2.stdout.count("— skip") == 2, out2.stdout
-    assert time.time() - t0 < 60
-
-
 # ------------------------- tool integration ---------------------------------
 
 def test_check_tool_validates_table(tmp_path):
@@ -602,7 +455,7 @@ def test_committed_table_validates_against_committed_ledger():
     (the full check also runs in test_bench_labels.py)."""
     entries, problems = dispatch.load_table(dispatch.default_path())
     assert problems == []
-    assert len(entries) >= 6  # the six autotune rung groups, CPU-measured
+    assert len(entries) >= 6  # six step-level groups, CPU-measured
     recs = ledger.read_ledger()
     by_id = {r.get("id"): r for r in recs}
     for e in entries.values():
@@ -611,27 +464,3 @@ def test_committed_table_validates_against_committed_ledger():
     # end-to-end: the bench_batch rung's measured amortization win
     assert any(e["op"] == "bench_batch" and e["choice"] != "2"
                for e in entries.values())
-
-
-def test_committed_bench_batch_entry_drives_bench(monkeypatch):
-    """The committed flip reaches the consuming program: bench.py's CPU
-    smoke batch is table-driven (b=4, the measured amortization win)
-    unless pinned or the table is off — the traced program genuinely
-    changes with the table."""
-    import os
-    import sys
-
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, REPO)
-    import bench
-    from apex_tpu.transformer.testing import TransformerConfig
-
-    cfg = TransformerConfig(hidden_size=128, num_layers=2,
-                            num_attention_heads=4, vocab_size=512,
-                            max_position_embeddings=128)
-    assert bench._default_batch(cfg, 2, s=128) == 4  # committed entry
-    monkeypatch.setenv("APEX_DISPATCH", "off")
-    assert bench._default_batch(cfg, 2, s=128) == 2  # built-in default
-    monkeypatch.delenv("APEX_DISPATCH")
-    monkeypatch.setenv("APEX_BENCH_BATCH", "8")
-    assert bench._default_batch(cfg, 2, s=128) == 8  # env pin wins

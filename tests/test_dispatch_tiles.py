@@ -1,9 +1,7 @@
 """Tile-parameter dispatch (ISSUE 5): table ``params`` payloads,
 the shared tile-validity model's checker surface, the consult log, the
 jaxpr-level proof that an unpinned consult re-tiles every consuming op
-family, check 4 of tools/check_bench_labels.py, and the
-autotune_tiles driver's winner/resume/budget/hysteresis logic against
-a stubbed measurer.
+family, and check 4 of tools/check_bench_labels.py.
 """
 
 import importlib
@@ -117,7 +115,7 @@ def test_runtime_value_skips_malformed_payloads():
 
 
 def test_validate_params_citation_and_pins():
-    rec = ledger.make_record("autotune_tiles", "cpu", 0.5, 2,
+    rec = ledger.make_record("tile_sweep", "cpu", 0.5, 2,
                              knobs={"APEX_DISPATCH": "off"}, git="abc",
                              ts=1.0)
     by_id = {rec["id"]: rec}
@@ -358,7 +356,7 @@ def test_check_tool_validates_params_payloads(tmp_path):
     subprocess CLI path is already covered by test_dispatch.py)."""
     from tools import check_bench_labels as tool
 
-    rec = ledger.make_record("autotune_tiles", "cpu", 0.5, 2,
+    rec = ledger.make_record("tile_sweep", "cpu", 0.5, 2,
                              knobs={"APEX_DISPATCH": "off"}, git="abc",
                              ts=1.0)
     lpath = tmp_path / "ledger.jsonl"
@@ -408,172 +406,3 @@ def test_committed_table_params_validate():
     for e in with_params:
         assert e["backend"] == "cpu"  # never leaks into TPU dispatch
         assert dispatch.validate_params(e, by_id) == [], e
-
-
-# ------------------------------------------------ autotune_tiles driver
-
-def _seed_ledger(tmp_path, n=1):
-    recs = [ledger.make_record("autotune_tiles", "cpu", 0.5, 2,
-                               knobs={"APEX_DISPATCH": "off"}, git="abc",
-                               ts=float(i)) for i in range(n)]
-    path = tmp_path / "ledger.jsonl"
-    path.write_text("".join(json.dumps(r, sort_keys=True) + "\n"
-                            for r in recs))
-    return [r["id"] for r in recs], str(path)
-
-
-def _fake_runner(values, ledger_id):
-    """Stub for autotune_tiles.run_candidate: params-tuple -> ms."""
-
-    def runner(group, params, smoke, ledger_path, timeout, log_dir, tag):
-        key = (group["op"], tuple(sorted(params.items())))
-        if key not in values:
-            return None
-        return {"value": values[key], "unit": "ms", "params": params,
-                "ledger": ledger_id}
-    return runner
-
-
-def test_autotune_tiles_winner_resume_and_hysteresis(tmp_path,
-                                                     monkeypatch):
-    from benchmarks import autotune_tiles as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    table = tmp_path / "table.jsonl"
-    g = at.sweep_groups(True)[1]  # layer_norm rows=1024 hidden=256
-    cands = tiles.candidates(g["op"], g["dims"], g["dtype"], 3)
-    # challenger wins by > flip margin
-    vals = {(g["op"], tuple(sorted(c.items()))): 10.0 + i
-            for i, c in enumerate(cands)}
-    best_key = (g["op"], tuple(sorted(cands[-1].items())))
-    vals[best_key] = 5.0
-    rc = at.main(["--smoke", "--only", "layer_norm", "--table",
-                  str(table), "--ledger", lpath],
-                 runner=_fake_runner(vals, ids[0]))
-    assert rc == 0
-    entries, problems = dispatch.load_table(str(table))
-    assert problems == []
-    e = entries[(g["op"], dispatch.bucket(**g["dims"]), g["dtype"],
-                 "cpu")]
-    assert e["choice"] == "pallas"
-    assert e["params"]["value"] == cands[-1]
-    assert e["params"]["ledger"] == ids[0]
-    assert e["params"]["pins"] == {"APEX_DISPATCH": "off"}
-
-    # resume: cashed groups are SKIPPED (an exploding runner proves it)
-    def boom(*a, **kw):
-        raise AssertionError("re-measured a cashed tile rung")
-
-    rc = at.main(["--smoke", "--only", "layer_norm", "--table",
-                  str(table), "--ledger", lpath], runner=boom)
-    assert rc == 0
-
-    # hysteresis: a 1% challenger keeps the heuristic incumbent
-    table2 = tmp_path / "table2.jsonl"
-    vals2 = {(g["op"], tuple(sorted(c.items()))): 10.0 for c in cands}
-    vals2[best_key] = 9.95
-    rc = at.main(["--smoke", "--only", "layer_norm", "--table",
-                  str(table2), "--ledger", lpath],
-                 runner=_fake_runner(vals2, ids[0]))
-    assert rc == 0
-    entries, _ = dispatch.load_table(str(table2))
-    e = next(e for e in entries.values() if "params" in e)
-    assert e["params"]["value"] == cands[0]  # the heuristic tile
-
-
-def test_autotune_tiles_preserves_step_level_choice(tmp_path,
-                                                    monkeypatch):
-    """An existing entry for the key keeps its step-level choice and
-    citation; the sweep only attaches params — and refuses to attach
-    params to an entry whose choice is NOT the swept kernel."""
-    from benchmarks import autotune_tiles as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-    g = at.sweep_groups(True)[1]
-    cands = tiles.candidates(g["op"], g["dims"], g["dtype"], 3)
-    vals = {(g["op"], tuple(sorted(c.items()))): 10.0 for c in cands}
-    runner = _fake_runner(vals, ids[0])
-
-    # case 1: existing pallas-choice entry — params attach, choice kept
-    table = tmp_path / "table.jsonl"
-    prior = _entry(g["op"], g["dims"], g["dtype"], "pallas",
-                   ledger_id=ids[0], rung="gpt_ln_pallas")
-    table.write_text(json.dumps(prior) + "\n")
-    dispatch._reset_for_tests()
-    assert at.main(["--smoke", "--only", "layer_norm", "--table",
-                    str(table), "--ledger", lpath], runner=runner) == 0
-    entries, _ = dispatch.load_table(str(table))
-    e = next(iter(entries.values()))
-    assert e["rung"] == "gpt_ln_pallas" and e["ledger"] == ids[0]
-    assert e["params"]["value"] == cands[0]
-
-    # case 2: existing jnp-choice entry — sweep does NOT attach
-    table2 = tmp_path / "table2.jsonl"
-    prior2 = _entry(g["op"], g["dims"], g["dtype"], "jnp",
-                    ledger_id=ids[0])
-    table2.write_text(json.dumps(prior2) + "\n")
-    dispatch._reset_for_tests()
-    assert at.main(["--smoke", "--only", "layer_norm", "--table",
-                    str(table2), "--ledger", lpath], runner=runner) == 1
-    entries, _ = dispatch.load_table(str(table2))
-    assert "params" not in next(iter(entries.values()))
-
-
-def test_autotune_tiles_budget_drops_are_loud(tmp_path, capsys):
-    from benchmarks import autotune_tiles as at
-
-    ids, lpath = _seed_ledger(tmp_path)
-
-    def boom(*a, **kw):
-        raise AssertionError("no child may launch at budget 0")
-
-    rc = at.main(["--smoke", "--table", str(tmp_path / "t.jsonl"),
-                  "--ledger", lpath, "--budget-s", "0"], runner=boom)
-    out = capsys.readouterr().out
-    assert rc == 1
-    assert "BUDGET DROPPED" in out
-    for g in at.sweep_groups(True):
-        assert f"{g['op']}/{dispatch.bucket(**g['dims'])}" in out
-
-
-def test_autotune_tiles_refuses_committed_table_under_fault_plan(
-        monkeypatch):
-    from benchmarks import autotune_tiles as at
-
-    monkeypatch.setenv("APEX_FAULT_PLAN", json.dumps(
-        [{"site": "autotune_budget", "kind": "set_budget",
-          "budget_s": 0}]))
-    with pytest.raises(SystemExit, match="refusing to write"):
-        at.main(["--smoke"])
-
-
-@pytest.mark.slow
-def test_autotune_tiles_smoke_end_to_end(tmp_path):
-    """The real thing, one family: child subprocesses on CPU, a params
-    payload with resolving ledger ids, resume on re-run."""
-    import os
-    import subprocess
-    import sys
-
-    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    script = os.path.join(REPO, "benchmarks", "autotune_tiles.py")
-    table = tmp_path / "table.jsonl"
-    lpath = tmp_path / "ledger.jsonl"
-    args = [sys.executable, script, "--smoke", "--only", "layer_norm",
-            "--table", str(table), "--ledger", str(lpath),
-            "--max-candidates", "2", "--out", str(tmp_path / "logs")]
-    env = dict(os.environ)
-    out = subprocess.run(args, capture_output=True, text=True,
-                         timeout=420, env=env)
-    assert out.returncode == 0, out.stdout + out.stderr
-    entries, problems = dispatch.load_table(str(table))
-    assert problems == [] and len(entries) == 2, out.stdout
-    ids = {r["id"] for r in ledger.read_ledger(str(lpath))}
-    by_id = {r["id"]: r for r in ledger.read_ledger(str(lpath))}
-    for e in entries.values():
-        assert e["params"]["ledger"] in ids
-        assert dispatch.validate_params(e, by_id) == [], e
-    out2 = subprocess.run(args, capture_output=True, text=True,
-                          timeout=120, env=env)
-    assert out2.returncode == 0, out2.stdout + out2.stderr
-    assert out2.stdout.count("— skip") == 2, out2.stdout

@@ -80,7 +80,7 @@ def test_sample_tokens_semantics():
     key = sampling_mod.request_key(7)
 
     def draw(temps, top_ks, top_ps, keys, counters,
-             active=(True,) * 4):
+             active=(True,) * 4, logits=logits):
         return np.asarray(sampling_mod.sample_tokens(
             logits, jnp.asarray(temps, jnp.float32),
             jnp.asarray(top_ks, jnp.int32),
@@ -106,11 +106,16 @@ def test_sample_tokens_semantics():
                     [ctr] * 4)
         for lane in range(4):
             assert toks[lane] in top5[lane], (ctr, lane)
-    # lane-position independence: lane value depends on (key, counter)
-    # only — the RNG determinism property at op level
-    a = draw([0.9] * 4, [0] * 4, [1.0] * 4, [key] * 4, [3, 0, 0, 0])
+    # lane-position independence: over the same logits a lane's token
+    # depends on (key, counter) only — the RNG determinism property at
+    # op level (every lane is given row 0: rows that differ draw
+    # different tokens under one key, as they should)
+    same = jnp.tile(logits[:1], (4, 1))
+    a = draw([0.9] * 4, [0] * 4, [1.0] * 4, [key] * 4, [3, 0, 0, 0],
+             logits=same)
     b = draw([0.9] * 4, [0] * 4, [1.0] * 4,
-             [np.zeros(2, np.uint32), key, key, key], [0, 3, 5, 3])
+             [np.zeros(2, np.uint32), key, key, key], [0, 3, 5, 3],
+             logits=same)
     assert a[0] == b[1] == b[3]
     # inactive lanes return 0
     toks = draw([0.0] * 4, [0] * 4, [1.0] * 4, zero, [0] * 4,

@@ -3,7 +3,6 @@ rule (disabled telemetry leaves the jitted GPT training step's jaxpr
 byte-identical), ledger schema + content-hash ids, and the shared
 Tracer. All CPU-tier (the conftest 8-device CPU mesh), fast."""
 
-import json
 import os
 import sys
 
@@ -19,6 +18,7 @@ sys.path.insert(0, REPO)
 from apex_tpu import telemetry
 from apex_tpu.telemetry import ledger, metrics
 from apex_tpu.telemetry.tracing import Tracer
+from tests.one_step import make_one_step
 
 
 @pytest.fixture(autouse=True)
@@ -152,9 +152,9 @@ def test_stateful_optimizer_stashes_grad_stats():
 
 class _TinyLM:
     """Stand-in with GPTModel's apply signature: embed → logits → CE per
-    token. bench.make_one_step's telemetry branch is model-independent,
+    token. make_one_step's telemetry branch is model-independent,
     so byte-identity of the step jaxpr proven on this model IS the
-    zero-cost property of the instrumented bench step; the GPTModel
+    zero-cost property of the instrumented step; the GPTModel
     variant below re-proves it on the flagship model where the
     container's jax supports tracing it (the TPU host; this container's
     jax predates lax.axis_size — the seed's pre-existing skew)."""
@@ -189,8 +189,8 @@ def _bench_fixture(vocab=64, hidden=16, b=2, s=16):
 
 
 def _reference_step_fn(model, scaler, tx):
-    """Frozen copy of the pre-telemetry (HEAD) bench.py step body — the
-    uninstrumented program every pinned measurement ran."""
+    """Frozen copy of the step body from before the telemetry branch —
+    the uninstrumented program."""
 
     def reference_step(params, opt_state, scaler_state, ids, pos, labels):
         def loss_fn(p):
@@ -214,11 +214,10 @@ def _reference_step_fn(model, scaler, tx):
 
 
 def test_disabled_telemetry_jaxpr_is_byte_identical():
-    """The acceptance gate: with telemetry disabled, bench.py's
-    instrumented training step traces to a jaxpr byte-identical to the
+    """The acceptance gate: with telemetry disabled, the instrumented
+    training step (tests/one_step.py) traces to a jaxpr byte-identical to the
     uninstrumented (pre-telemetry HEAD) step — observability adds zero
     cost to pinned measurements."""
-    import bench
 
     (model, scaler, tx, params, opt_state, scaler_state,
      ids, pos, labels) = _bench_fixture()
@@ -226,7 +225,7 @@ def test_disabled_telemetry_jaxpr_is_byte_identical():
 
     args = (params, opt_state, scaler_state, ids, pos, labels)
     telemetry.disable()
-    one_step = bench.make_one_step(model, scaler, tx)
+    one_step = make_one_step(model, scaler, tx)
     got = str(jax.make_jaxpr(one_step)(*args))
     want = str(jax.make_jaxpr(reference_step)(*args))
     assert got == want, "disabled telemetry changed the step's jaxpr"
@@ -236,7 +235,7 @@ def test_disabled_telemetry_jaxpr_is_byte_identical():
     # NB a FRESH closure: jax caches traces per function object, so
     # re-tracing the same one_step would return the disabled jaxpr.
     telemetry.enable()
-    one_step = bench.make_one_step(model, scaler, tx)
+    one_step = make_one_step(model, scaler, tx)
     enabled_jaxpr = str(jax.make_jaxpr(one_step)(*args))
     assert enabled_jaxpr != want
     _, _, _, _, aux = one_step(*args)
@@ -245,12 +244,10 @@ def test_disabled_telemetry_jaxpr_is_byte_identical():
 
 
 def test_disabled_telemetry_jaxpr_gpt_model():
-    """The same byte-identity on the flagship GPTModel step bench.py
-    actually measures. The model needs a bound tensor-parallel axis
+    """The same byte-identity on the flagship GPTModel step. The model needs a bound tensor-parallel axis
     (shard_map) to trace; where this container's jax predates the APIs
     the model uses (the seed's pre-existing version skew), skip — the
     _TinyLM variant above still pins the mechanism."""
-    import bench
     from apex_tpu.amp.scaler import LossScaler
     from apex_tpu.optimizers.fused_adam import fused_adam
 
@@ -291,24 +288,23 @@ def test_disabled_telemetry_jaxpr_gpt_model():
 
     telemetry.disable()
     got = str(jax.make_jaxpr(
-        shmap(bench.make_one_step(model, scaler, tx), 6))(*args))
+        shmap(make_one_step(model, scaler, tx), 6))(*args))
     want = str(jax.make_jaxpr(
         shmap(_reference_step_fn(model, scaler, tx), 6))(*args))
     assert got == want, "disabled telemetry changed the GPT step's jaxpr"
 
 
 def test_aux_stacks_through_scan_and_flushes(tmp_path):
-    """The bench.py main() protocol minus the shard_map wrapper: the
-    enabled step's aux scalars stack across the K-iteration training
+    """A K-iteration training scan around the step: the enabled
+    step's aux scalars stack across the K-iteration training
     scan, fetch as [K] arrays, and flush to the metrics sink one row
     per step."""
-    import bench
     from jax import lax
 
     (model, scaler, tx, params, opt_state, scaler_state,
      ids, pos, labels) = _bench_fixture()
     telemetry.enable()
-    one_step = bench.make_one_step(model, scaler, tx)
+    one_step = make_one_step(model, scaler, tx)
     iters = 3
 
     def run(params, opt_state, scaler_state, eps, ids, pos, labels):
@@ -341,7 +337,7 @@ def test_aux_stacks_through_scan_and_flushes(tmp_path):
     # disabled: the same scan carries no aux at all (fresh closures —
     # jax caches traces per function object)
     telemetry.disable()
-    one_step = bench.make_one_step(model, scaler, tx)
+    one_step = make_one_step(model, scaler, tx)
 
     def run_disabled(params, opt_state, scaler_state, eps, ids, pos,
                      labels):
@@ -362,12 +358,11 @@ def test_aux_stacks_through_scan_and_flushes(tmp_path):
 def test_disabled_aux_is_empty_pytree():
     """aux=None contributes no outputs: scan/jit treat the 5-tuple step
     exactly like the old 4-tuple one."""
-    import bench
 
     (model, scaler, tx, params, opt_state, scaler_state,
      ids, pos, labels) = _bench_fixture()
     telemetry.disable()
-    one_step = bench.make_one_step(model, scaler, tx)
+    one_step = make_one_step(model, scaler, tx)
     out = one_step(params, opt_state, scaler_state, ids, pos, labels)
     assert out[4] is None
     assert jax.tree_util.tree_leaves(out[4]) == []
@@ -495,37 +490,3 @@ def test_timing_reexports():
     assert callable(_timing.sync)
     assert callable(_timing.measure_dispatch_overhead)
     assert _timing.bench_k(True) == 2
-
-
-def test_bench_json_fields_in_fabricated_timeout_record():
-    """The watchdog's fabricated timeout record carries the structured
-    timed_out/relay_degraded stamps the lazy cap and the driver key on."""
-    import bench
-    import subprocess
-
-    class FakeProc:
-        returncode = None
-
-        def communicate(self, timeout=None):
-            if timeout is not None and not getattr(self, "_killed", False):
-                raise subprocess.TimeoutExpired("bench", timeout)
-            return "", None
-
-        def terminate(self):
-            self._killed = True
-
-        def kill(self):
-            self._killed = True
-
-    state = {"child": None}
-    orig = subprocess.Popen
-    subprocess.Popen = lambda *a, **kw: FakeProc()
-    os.environ["APEX_BENCH_TIMEOUT"] = "1"
-    try:
-        line, rec, rc = bench._attempt_once(state)
-    finally:
-        subprocess.Popen = orig
-        del os.environ["APEX_BENCH_TIMEOUT"]
-    assert rc is None
-    assert rec["timed_out"] is True and rec["relay_degraded"] is True
-    assert "error" in rec and json.loads(line) == rec

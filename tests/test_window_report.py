@@ -1,6 +1,6 @@
-"""tools/window_report.py — the window-economics reporter. The
-ledger/manifest/probe summaries get synthetic fixtures so the test
-doesn't chase the live ledger. Jax-free and subprocess-free (the tool
+"""tools/window_report.py — the ledger-economics reporter. The
+ledger summaries get synthetic fixtures so the test doesn't chase the
+live ledger. Jax-free and subprocess-free (the tool
 itself never touches a backend)."""
 
 import contextlib
@@ -13,7 +13,6 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from apex_tpu.resilience import manifest as manifest_mod
 from apex_tpu.telemetry import costs, ledger
 
 _spec = importlib.util.spec_from_file_location(
@@ -22,22 +21,7 @@ wr = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(wr)
 
 
-def test_header_only_log_is_no_output(tmp_path):
-    """A run that wedged right after calibration leaves a banner plus
-    the Tracer header ("dispatch overhead 82.6 ms subtracted") and no
-    measured rows — the report must call that dead slot no-output, not
-    a productive "table" (the header's "ms" must not count as a row)."""
-    log = tmp_path / "wedged.log"
-    log.write_text(
-        "WARNING:2026-08-01 09:00:00,123:jax._src.xla_bridge:794: ...\n"
-        "params: 124.5M   (method: 32-step lax.scan, 1 dispatch, "
-        "dispatch overhead 82.6 ms subtracted)\n")
-    entry = wr.parse_log(str(log))
-    assert entry["rows"] == 0
-    assert entry["verdict"] == "no-output"
-
-
-# ------------------------------------------- ledger / manifest / probe
+# ------------------------------------------------------------ ledger
 
 
 def _seed(path, **extra):
@@ -77,36 +61,6 @@ def test_committed_ledger_is_summarizable():
     assert led["records"] >= 34
     assert led["injected"] == 0
     assert "bench" in led["by_harness"]
-
-
-# ------------------------------------------- manifest + probe summaries
-
-
-def test_manifest_and_probe_summaries(tmp_path):
-    man = str(tmp_path / "manifest.json")
-    manifest_mod.record(man, "bench_first", "healthy", rc=0)
-    summary = wr.manifest_summary(man)
-    assert "bench_first" in summary["cashed"]
-    assert summary["verdicts"]["bench_first"] == "healthy"
-    assert set(summary["owed"]) | set(summary["cashed"]) >= set(
-        manifest_mod.PASS_ROWS)
-
-    probe = tmp_path / "probe_state.json"
-    probe.write_text(json.dumps(
-        {"ts": 1754000000.0, "verdict": "healthy", "rc": 0,
-         "detail": "value=102196"}))
-    ps = wr.probe_summary(str(probe))
-    assert ps["verdict"] == "healthy" and "at" in ps
-
-    # degradation, never a crash: missing probe file is None, garbage
-    # manifest is an error entry — and both print
-    assert wr.probe_summary(str(tmp_path / "nope.json")) is None
-    bad = tmp_path / "bad.json"
-    bad.write_text("[1, 2")
-    buf = io.StringIO()
-    wr.print_report({"manifest": wr.manifest_summary(str(bad)),
-                     "probe": wr.probe_summary(str(bad))}, out=buf)
-    assert "unreadable" in buf.getvalue()
 
 
 def test_empty_round_is_a_report_not_an_error(tmp_path):

@@ -59,8 +59,8 @@ def cli():
     except SystemExit:
         raise
     except Exception as e:  # crash-as-finding: rc 2, message, no
-        # traceback — tier-1 and the shells see a loud structured
-        # failure either way. Under --json the stdout contract stays
+        # traceback — tier-1 sees a loud structured failure either
+        # way. Under --json the stdout contract stays
         # one parseable line; otherwise the crash goes to stderr.
         msg = f"CRASH: apexlint error: {type(e).__name__}: {e}"
         if "--json" in sys.argv[1:]:

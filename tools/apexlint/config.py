@@ -14,20 +14,11 @@ SCOPE_BENCH = ("benchmarks",)
 # "outside tests": the shipped package, the harnesses, and the tools —
 # examples/ are reference-ported torch demos, out of knob scope
 SCOPE_NONTEST = ("apex_tpu", "benchmarks", "tools",
-                 "bench.py", "__graft_entry__.py")
+                 "__graft_entry__.py")
 # citation-bearing docstrings (APX005) live everywhere code does
 SCOPE_CITED = ("apex_tpu", "benchmarks", "tools",
-               "bench.py", "__graft_entry__.py")
+               "__graft_entry__.py")
 
-SHELLS = ("benchmarks/run_all_tpu.sh", "benchmarks/probe_and_collect.sh")
-# APX004 monotonic-home extension (ISSUE 16): the only non-benchmark
-# files allowed to call time.monotonic/monotonic_ns — the beat stamp,
-# its one other emitter, and the supervisor that ages beats
-MONOTONIC_HOMES = (
-    "apex_tpu/telemetry/flight.py",
-    "apex_tpu/telemetry/tracing.py",
-    "apex_tpu/resilience/flight_watch.py",
-)
 API_MD = "docs/API.md"
 LEDGER_PY = "apex_tpu/telemetry/ledger.py"
 KNOB_TABLE_BEGIN = "<!-- apexlint: knob-table begin -->"
@@ -80,15 +71,6 @@ DESIGNATED_READERS = (
     ("apex_tpu/contrib/fmha/fmha.py", "APEX_FMHA_DROPOUT",
      "validated raise at first use: the escape hatch is an explicit "
      "request, not a preference"),
-    ("apex_tpu/resilience/__init__.py", "APEX_BENCH_*",
-     "the §6 timeout-envelope home; zero is a legal value here (chaos "
-     "pins RETRY_WAIT=0) which the positive-only env_int cannot "
-     "express"),
-    ("apex_tpu/resilience/probe.py", "APEX_PROBE_STATE",
-     "CLI state-path default (path, not a typed value)"),
-    ("apex_tpu/resilience/manifest.py", "APEX_PROBE_STATE",
-     "CLI --probe-state default (probe_and_collect.sh exports it per "
-     "round)"),
     ("apex_tpu/telemetry/costs.py", "APEX_COST_ANALYSIS",
      "tri-state hard-on/hard-off/unset-follows-harness"),
     ("apex_tpu/optimizers/fused_lamb.py", "APEX_LAMB_IMPL",
@@ -98,8 +80,8 @@ DESIGNATED_READERS = (
      "APEX_PP_IMPL",
      "merged with per-call impl= then validated with a raise — a "
      "typo'd knob must not pass silently"),
-    # harness-side owners: bench.py / the profile drivers are the
-    # arming + label-pinning sites the records are stamped from
+    # harness-side owners: the profile drivers are the arming +
+    # label-pinning sites the records are stamped from
     ("benchmarks/_knobs.py", "APEX_REMAT",
      "the documented one-home resolver for the step-harness pins "
      "(validated raise)"),
@@ -109,36 +91,9 @@ DESIGNATED_READERS = (
      "one-home resolver; tri-state 1/0/unset"),
     ("benchmarks/_knobs.py", "APEX_FUSED_LM_HEAD",
      "one-home resolver; tri-state 1/0/unset"),
-    ("bench.py", "APEX_CKPT_DIR",
+    ("benchmarks/profile_gpt.py", "APEX_CKPT_DIR",
      "durability arming path, consumed host-side before any trace "
      "(checkpoint.py owns the other APEX_CKPT_* semantics)"),
-    ("bench.py", "APEX_BENCH_BASELINE",
-     "baseline-store path redirect (the chaos-test hook)"),
-    ("bench.py", "APEX_ATTN_IMPL",
-     "label pin: the scored line stamps the raw pin it ran under "
-     "(_knobs.apply_dispatch_knobs already validated it)"),
-    ("bench.py", "APEX_LN_PALLAS",
-     "label pin (tri-state mirror of _knobs)"),
-    ("benchmarks/profile_gpt.py", "APEX_CKPT_DIR",
-     "durability arming path (same pattern as bench.py)"),
-    ("benchmarks/warm_cache.py", "APEX_COLLECT_MANIFEST",
-     "manifest-path handoff from probe_and_collect.sh"),
-    # flight recorder + supervisor (ISSUE 16)
-    ("apex_tpu/telemetry/flight.py", "APEX_FLIGHT_*",
-     "the recorder itself: dir path + row label, read per-beat (unset "
-     "= disabled is the whole zero-cost contract — a typed helper "
-     "would be a second home)"),
-    ("apex_tpu/telemetry/flight.py", "APEX_BENCH_ATTEMPT",
-     "beats auto-stamp the watchdog's attempt index; raw int parse "
-     "because a beat must NEVER raise on a malformed value"),
-    ("apex_tpu/resilience/flight_watch.py", "APEX_FLIGHT_*",
-     "supervisor clock thresholds: zero and fractional seconds are "
-     "legal (chaos tests pin seconds-scale silence), which the "
-     "positive-int helpers cannot express; plus the pool-restore "
-     "marker handoff from run_all_tpu.sh"),
-    ("tools/window_report.py", "APEX_FLIGHT_DIR",
-     "CLI --flight default (probe_and_collect.sh exports it per "
-     "round) — path, not a typed value"),
 )
 
 # ---------------------------------------------------------------------------
@@ -148,7 +103,6 @@ DESIGNATED_READERS = (
 
 STDLIB_ONLY_CLAIMED = (
     "apex_tpu/resilience/",
-    "apex_tpu/telemetry/flight.py",
     "apex_tpu/dispatch/tiles.py",
     "apex_tpu/dispatch/__init__.py",
     "apex_tpu/serving/scheduler.py",

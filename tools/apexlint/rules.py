@@ -290,17 +290,6 @@ def apx003(repo, config, report, reference_root=None):
                 if name and name.startswith("APEX_"):
                     used.setdefault(name, (ctx.path,
                                            getattr(node, "lineno", 1)))
-    for shell in config.SHELLS:
-        if not repo.exists(shell):
-            continue
-        for i, line in enumerate(repo.read_text(shell).splitlines(),
-                                 start=1):
-            if line.lstrip().startswith("#"):
-                # a comment naming a knob is prose, not a use — else a
-                # stale mention would mask the no-op-row direction
-                continue
-            for m in APEX_NAME_RE.finditer(line):
-                used.setdefault(m.group(0), (shell, i))
 
     for name in sorted(set(used) - set(documented)):
         if any(name.startswith(p) for p in prefixes):
@@ -366,52 +355,6 @@ def apx004(repo, config, report, reference_root=None):
                     "timing rules have ONE implementation "
                     "(apex_tpu.telemetry.tracing); use Tracer/Span, or "
                     "pragma with the reason this is not a measured row"))
-    # monotonic-home extension (ISSUE 16): outside benchmarks/ (the
-    # stricter full scan above), ``time.monotonic``/``monotonic_ns``
-    # may only be called from the flight-recorder homes
-    # (config.MONOTONIC_HOMES) — the beat stamp and the supervisor's
-    # aging clock are a cross-process contract (CLOCK_MONOTONIC is
-    # system-wide), and a third clock site could silently age beats
-    # against a different rule than classify_inflight applies.
-    mono_attrs = {"monotonic", "monotonic_ns"}
-    homes = set(getattr(config, "MONOTONIC_HOMES", ()))
-    for ctx in repo.ctxs(config.SCOPE_NONTEST):
-        if ctx.path in homes:
-            continue
-        if any(ctx.path == p or ctx.path.startswith(p + "/")
-               for p in config.SCOPE_BENCH):
-            continue  # already covered by the full _TIME_ATTRS scan
-        time_aliases = {"time"}
-        direct = set()
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.Import):
-                for a in node.names:
-                    if a.name == "time":
-                        time_aliases.add(a.asname or "time")
-            elif isinstance(node, ast.ImportFrom) and node.module == "time":
-                for a in node.names:
-                    if a.name in mono_attrs:
-                        direct.add(a.asname or a.name)
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            f = node.func
-            what = None
-            if isinstance(f, ast.Attribute):
-                if f.attr in mono_attrs and isinstance(f.value, ast.Name) \
-                        and f.value.id in time_aliases:
-                    what = f"time.{f.attr}()"
-            elif isinstance(f, ast.Name) and f.id in direct:
-                what = f"{f.id}()"
-            if what:
-                findings.append(Finding(
-                    "APX004", ctx.path, node.lineno,
-                    f"{what} outside the flight/tracing monotonic homes "
-                    f"({', '.join(sorted(homes))}) — beat stamps and "
-                    "their aging share ONE clock contract (ISSUE 16); "
-                    "emit a flight.beat / use the resilience classifier, "
-                    "or pragma with the reason this clock is not aging "
-                    "heartbeats"))
     return findings
 
 

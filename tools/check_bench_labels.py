@@ -34,8 +34,7 @@ Checks:
    runtime lookups skip a corrupt line and fall back, but here it is a
    finding).
 4. **Tile params payloads** — every entry carrying a ``params``
-   payload (the per-shape tile geometry from
-   ``benchmarks/autotune_tiles.py``) must be LEGAL under the shared
+   payload (the per-shape tile geometry) must be LEGAL under the shared
    tile model (``apex_tpu.dispatch.tiles``: VMEM working set +
    (8, 128)-divisibility at the entry's bucket dims — a committed tile
    must lower), cite a resolving, un-injected ``params.ledger``
@@ -44,7 +43,7 @@ Checks:
    kernel heuristic; here it is a finding, so corruption cannot
    persist in the committed table.
 5. **Resume provenance** — a cited record carrying ``resumed_from``
-   (bench.py ``--resume`` / profile_gpt: the run restored a
+   (profile_gpt under ``APEX_CKPT_RESUME``: the run restored a
    checkpointed TrainState and continued) must pin-match: the
    measurement pins saved in the checkpoint
    (``resumed_from.pins``, filtered by

@@ -1,127 +1,30 @@
 #!/usr/bin/env python
-"""Window economics: one per-round timeline from the collection artifacts.
+"""Ledger economics: one account of ``benchmarks/ledger.jsonl``.
 
-Rounds 4-5 got exactly ONE 50-minute relay window and no record of where
-its minutes went — the §6 ordering lessons (bench-first, small-HBM-first,
-warm-before-measure) were reconstructed from prose afterwards. This tool
-aggregates the round's durable artifacts into one account:
+Aggregates the run ledger into one report: per-record verdicts,
+compile-cache hit/miss totals, cost-block coverage, the measured-MFU vs
+MFU-bound attribution gap, the ``overlap_bound`` column (compute floor
+vs comm+host — ROADMAP 4d), and the SERVING ECONOMICS section (ISSUE
+11): per-trace SLO attainment, goodput vs the decode-scan throughput
+line, and queue/KV-page occupancy from the ``serving``/``slo`` blocks.
 
-* the **run ledger** (``benchmarks/ledger.jsonl``) — per-record verdicts,
-  compile-cache hit/miss totals (the warm-start proof-of-work), cost-block
-  coverage, the measured-MFU vs MFU-bound attribution gap, the
-  ``overlap_bound`` column (compute floor vs comm+host — ROADMAP 4d),
-  and the SERVING ECONOMICS section (ISSUE 11): per-trace SLO
-  attainment, goodput vs the decode-scan throughput line, and
-  queue/KV-page occupancy from the ``serving``/``slo`` blocks;
-* the **flight recorder** (``apex_tpu.telemetry.flight``, ISSUE 16) —
-  when a round carries heartbeat streams (``--flight``), they are the
-  PRIMARY timeline: exact per-process compile / dispatch->fetch minute
-  attribution from phase beats (monotonic deltas, not banner
-  inference), per-row totals, and the supervisor's reap account
-  (``flight_reap`` ledger records: minutes reclaimed from
-  heartbeat-silent wedges). The raw-log banner timeline below stays as
-  the fallback for rounds that predate the recorder;
-* a **raw log directory** (e.g. ``benchmarks/device_logs_r05``) — every
-  harness log's dated backend-init banner(s) anchor the fallback
-  timeline: starts, attempt counts, per-log verdicts (via the shared
-  resilience classifier) and the minutes each slot consumed before the
-  next program started;
-* the **collection manifest** (``manifest.json``) — rows cashed vs owed;
-* the **probe state** — the last stamped probe verdict.
+    python tools/window_report.py [--ledger PATH] [--json]
 
-``--watch`` turns the report into a live status loop: newest heartbeat
-(phase + age), recent beats, probe verdict and the manifest account,
-re-rendered every ``--interval`` seconds.
-
-Runnable today against the committed round-5 artifacts::
-
-    python tools/window_report.py --logs benchmarks/device_logs_r05
-
-Exit status 0 when the report was produced (an empty round is a report,
-not an error); 1 only on unreadable inputs. ``--json`` appends ONE
-machine-readable JSON line (the driver-interface idiom) after the text.
+Exit status 0 when the report was produced (an empty ledger is a
+report, not an error); 1 only on unreadable inputs. ``--json`` appends
+ONE machine-readable JSON line after the text.
 """
 
 import argparse
 import datetime
 import json
 import os
-import re
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from apex_tpu import resilience  # noqa: E402
-from apex_tpu.telemetry import flight as flight_mod  # noqa: E402
 from apex_tpu.telemetry import ledger as ledger_mod  # noqa: E402
-
-# the dated backend-init banner every harness log opens with — the one
-# wall-clock anchor the raw logs carry
-BANNER_RE = re.compile(
-    r"^WARNING:(\d{4}-\d{2}-\d{2} \d{2}:\d{2}:\d{2}),\d+:"
-    r"jax\._src\.xla_bridge")
-ROW_RE = re.compile(r"\d+\.\d+ ms")
-
-
-def parse_log(path):
-    """One log's timeline entry: banner timestamps (each = one backend
-    init, i.e. one attempt/process), measured-row count, and the
-    verdict of its last JSON line (the shared classifier) or a
-    table/no-output heuristic for Tracer harnesses."""
-    with open(path, errors="replace") as f:
-        text = f.read()
-    starts = [datetime.datetime.strptime(m.group(1), "%Y-%m-%d %H:%M:%S")
-              for m in map(BANNER_RE.match, text.splitlines()) if m]
-    # a measured table row, NOT the Tracer header ("... dispatch
-    # overhead 75.8 ms subtracted)") every harness prints before its
-    # first row — a run that wedged right after calibration must read
-    # no-output, not "table"
-    rows = sum(1 for line in text.splitlines()
-               if ROW_RE.search(line) and "dispatch overhead" not in line)
-    _, rec = resilience.last_json(text)
-    if rec is not None:
-        verdict = resilience.classify(rec)
-    elif rows:
-        # a table-printing harness: rows landed (exit status is not in
-        # the log, so this is the optimistic read the manifest's
-        # probe-state gate exists to police)
-        verdict = "table"
-    else:
-        # banner only: the §10b wedge signature (fresh compile hung in
-        # the remote-compile helper)
-        verdict = "no-output"
-    return {
-        "name": os.path.basename(path),
-        "starts": starts,
-        "attempts": max(1, len(starts)) if (starts or text.strip()) else 0,
-        "rows": rows,
-        "verdict": verdict,
-        "value": (rec or {}).get("value"),
-        "mfu": (rec or {}).get("mfu"),
-    }
-
-
-def logs_timeline(logs_dir):
-    """Sorted per-log timeline + slot minutes: each log's slot runs from
-    its first banner to the NEXT log's first banner (the raw logs carry
-    start anchors, not end anchors — the gap IS where the minutes
-    went). The last slot's cost is unknowable from the logs alone."""
-    entries = []
-    for name in sorted(os.listdir(logs_dir)):
-        if not name.endswith(".log"):
-            continue
-        entries.append(parse_log(os.path.join(logs_dir, name)))
-    timed = sorted((e for e in entries if e["starts"]),
-                   key=lambda e: e["starts"][0])
-    for i, e in enumerate(timed):
-        if i + 1 < len(timed):
-            dt = timed[i + 1]["starts"][0] - e["starts"][0]
-            e["slot_minutes"] = round(dt.total_seconds() / 60.0, 1)
-        else:
-            e["slot_minutes"] = None
-    return entries, timed
-
 
 def ledger_summary(records):
     """Aggregate the ledger's side of the account: per-harness counts,
@@ -259,179 +162,11 @@ def _fmt_ts(ts):
         "%Y-%m-%d %H:%M:%S")
 
 
-def manifest_summary(path):
-    try:
-        from apex_tpu.resilience import manifest as manifest_mod
-
-        data = manifest_mod.load(path)
-        rows = data.get("rows", {}) if isinstance(data, dict) else {}
-        cashed = sorted(manifest_mod.cashed_rows(path))
-        owed = [r for r in manifest_mod.PASS_ROWS if r not in cashed]
-        return {"cashed": cashed, "owed": owed,
-                "verdicts": {name: (entry or {}).get("verdict")
-                             for name, entry in sorted(rows.items())}}
-    except Exception as e:
-        return {"error": f"{type(e).__name__}: {e}"}
-
-
-def probe_summary(path):
-    try:
-        with open(path) as f:
-            state = json.load(f)
-        if not isinstance(state, dict):
-            return {"error": "probe state is not a JSON object"}
-        out = {"verdict": state.get("verdict"), "rc": state.get("rc"),
-               "detail": state.get("detail")}
-        if isinstance(state.get("ts"), (int, float)):
-            out["at"] = _fmt_ts(state["ts"])
-        return out
-    except FileNotFoundError:
-        return None
-    except Exception as e:
-        return {"error": f"{type(e).__name__}: {e}"}
-
-
-def _flight_process(beats):
-    """One process's beat stream -> exact minute attribution. Durations
-    are MONOTONIC deltas between phase beats of the same pid (the §0
-    concern — wall clocks can step — does not apply to mono stamps),
-    so compile and dispatch->fetch minutes are measured, not inferred
-    from banner gaps."""
-    beats = [b for b in beats
-             if isinstance(b.get("mono"), (int, float))
-             and not isinstance(b.get("mono"), bool)]
-    if not beats:
-        return None
-    compile_s = measure_s = 0.0
-    pend_compile = pend_dispatch = None
-    attempts = 0
-    phases = {}
-    label = None
-    for b in beats:
-        ph = b.get("phase")
-        phases[ph] = phases.get(ph, 0) + 1
-        if b.get("label"):
-            label = b["label"]
-        if ph == "compile_start":
-            pend_compile = b["mono"]
-        elif ph == "compile_done" and pend_compile is not None:
-            compile_s += max(0.0, b["mono"] - pend_compile)
-            pend_compile = None
-        elif ph == "dispatch":
-            pend_dispatch = b["mono"]
-        elif ph == "fetch" and pend_dispatch is not None:
-            measure_s += max(0.0, b["mono"] - pend_dispatch)
-            pend_dispatch = None
-        elif ph == "attempt_start":
-            attempts += 1
-    ts = [b.get("ts") for b in beats
-          if isinstance(b.get("ts"), (int, float))]
-    return {
-        "pid": beats[0].get("pid"),
-        "label": label,
-        "start": _fmt_ts(min(ts)) if ts else None,
-        "last_beat": _fmt_ts(max(ts)) if ts else None,
-        "minutes": round((beats[-1]["mono"] - beats[0]["mono"]) / 60.0, 2),
-        "beats": len(beats),
-        "last_phase": beats[-1].get("phase"),
-        "compile_open": pend_compile is not None,  # died mid-compile
-        "compile_minutes": round(compile_s / 60.0, 2),
-        "measure_minutes": round(measure_s / 60.0, 2),
-        "attempts": attempts,
-        "phases": phases,
-    }
-
-
-def flight_summary(flight_dir, records=()):
-    """The PRIMARY timeline (ISSUE 16): per-process phase accounts from
-    the heartbeat streams, per-row totals, and the supervisor's reap
-    account from ``flight_reap`` ledger records (minutes a silent wedge
-    would have burnt vs what it actually got)."""
-    all_beats = flight_mod.read_beats(flight_dir)
-    by_pid = {}
-    for b in all_beats:
-        by_pid.setdefault(b.get("pid"), []).append(b)
-    procs = [p for p in (_flight_process(bs) for bs in by_pid.values())
-             if p is not None]
-    procs.sort(key=lambda p: (p["start"] or "", p["pid"] or 0))
-    by_label = {}
-    for pr in procs:
-        row = by_label.setdefault(pr["label"] or "?", {
-            "processes": 0, "minutes": 0.0, "compile_minutes": 0.0,
-            "measure_minutes": 0.0})
-        row["processes"] += 1
-        for k in ("minutes", "compile_minutes", "measure_minutes"):
-            row[k] = round(row[k] + pr[k], 2)
-    reaps = []
-    reclaimed = 0.0
-    for rec in records:
-        fr = rec.get("flight_reap")
-        if not isinstance(fr, dict):
-            continue
-        saved_s = max(0.0, (fr.get("timeout_s") or 0)
-                      - (fr.get("elapsed_s") or 0))
-        reaps.append({
-            "id": rec.get("id"), "row": fr.get("row"),
-            "reason": fr.get("reason"), "verdict": fr.get("verdict"),
-            "elapsed_s": fr.get("elapsed_s"),
-            "timeout_s": fr.get("timeout_s"),
-            "last_phase": fr.get("last_phase"),
-            "reclaimed_minutes": round(saved_s / 60.0, 1),
-        })
-        reclaimed += saved_s
-    ts = [b.get("ts") for b in all_beats
-          if isinstance(b.get("ts"), (int, float))]
-    window = None
-    if ts:
-        window = {"start": _fmt_ts(min(ts)),
-                  "last_activity": _fmt_ts(max(ts)),
-                  "minutes": round((max(ts) - min(ts)) / 60.0, 1)}
-    return {
-        "dir": flight_dir,
-        "window": window,
-        "processes": procs,
-        "by_label": by_label,
-        "reaps": reaps,
-        "reclaimed_minutes": round(reclaimed / 60.0, 1),
-    }
-
-
-def build_report(ledger_path=None, logs_dir=None, manifest_path=None,
-                 probe_state=None, flight_dir=None):
+def build_report(ledger_path=None):
     report = {}
-    records = []
     if ledger_path and os.path.exists(ledger_path):
-        records = ledger_mod.read_ledger(ledger_path)
-        report["ledger"] = ledger_summary(records)
-    if flight_dir and os.path.isdir(flight_dir):
-        fl = flight_summary(flight_dir, records)
-        if fl["processes"] or fl["reaps"]:
-            report["flight"] = fl
-    if logs_dir:
-        entries, timed = logs_timeline(logs_dir)
-        window = None
-        if timed:
-            t0 = timed[0]["starts"][0]
-            t1 = max(e["starts"][-1] for e in timed)
-            window = {
-                "start": t0.strftime("%Y-%m-%d %H:%M:%S"),
-                "last_activity": t1.strftime("%Y-%m-%d %H:%M:%S"),
-                "minutes": round((t1 - t0).total_seconds() / 60.0, 1),
-            }
-        report["logs"] = {
-            "dir": logs_dir,
-            "window": window,
-            "timeline": [{k: (v if k != "starts" else
-                              [s.strftime("%H:%M:%S") for s in v])
-                          for k, v in e.items()}
-                         for e in (timed or entries)],
-            "unanchored": [e["name"] for e in entries
-                           if not e["starts"]],
-        }
-    if manifest_path:
-        report["manifest"] = manifest_summary(manifest_path)
-    if probe_state:
-        report["probe"] = probe_summary(probe_state)
+        report["ledger"] = ledger_summary(
+            ledger_mod.read_ledger(ledger_path))
     return report
 
 
@@ -618,127 +353,8 @@ def print_report(report, out=None):
                     bits = ", ".join(
                         f"{k}={v:.0%}" for k, v in sorted(hr.items()))
                     p(f"      prefix hit-rate by policy: {bits}")
-    fl = report.get("flight")
-    if fl:
-        p(f"flight: {fl['dir']} (primary timeline — exact phase "
-          f"minutes from heartbeats)")
-        w = fl["window"]
-        if w:
-            p(f"  window: {w['start']} .. {w['last_activity']} "
-              f"({w['minutes']} min of recorded activity)")
-        for pr in fl["processes"]:
-            start = (pr["start"] or "?").split(" ")[-1]
-            extra = ""
-            if pr["attempts"]:
-                extra += f"  {pr['attempts']} attempt(s)"
-            if pr["compile_open"]:
-                extra += "  DIED MID-COMPILE"
-            p(f"  {start}  {str(pr['label'] or '?'):26s} "
-              f"{pr['minutes']:6.1f} min  compile {pr['compile_minutes']:g}"
-              f" min  dispatch->fetch {pr['measure_minutes']:g} min  "
-              f"last={pr['last_phase']} pid={pr['pid']}{extra}")
-        if fl["by_label"]:
-            p("  per-row totals:")
-            for name in sorted(fl["by_label"]):
-                row = fl["by_label"][name]
-                p(f"    {name:26s} {row['minutes']:6.1f} min across "
-                  f"{row['processes']} process(es)  (compile "
-                  f"{row['compile_minutes']:g}, dispatch->fetch "
-                  f"{row['measure_minutes']:g})")
-        for r in fl["reaps"]:
-            p(f"  reap {r['id']} row={r['row']}: {r['reason']} "
-              f"(verdict={r['verdict']}) after {r['elapsed_s']}s of a "
-              f"{r['timeout_s']}s cap — reclaimed "
-              f"{r['reclaimed_minutes']} min (last phase "
-              f"{r['last_phase']})")
-        if fl["reaps"]:
-            p(f"  reclaimed by early reap: {fl['reclaimed_minutes']} min")
-    logs = report.get("logs")
-    if logs:
-        fallback = " (fallback timeline — banner inference)" \
-            if report.get("flight") else ""
-        p(f"logs: {logs['dir']}{fallback}")
-        w = logs["window"]
-        if w:
-            p(f"  window: {w['start']} .. {w['last_activity']} "
-              f"({w['minutes']} min of anchored activity)")
-        for e in logs["timeline"]:
-            starts = e.get("starts") or []
-            slot = (f"{e['slot_minutes']:5.1f} min"
-                    if e.get("slot_minutes") is not None else "  end   ")
-            extra = ""
-            if e.get("value") is not None:
-                extra = f" value={e['value']}"
-                if e.get("mfu") is not None:
-                    extra += f" mfu={e['mfu']}"
-            elif e.get("rows"):
-                extra = f" {e['rows']} row(s)"
-            p(f"  {starts[0] if starts else '--:--:--'}  "
-              f"{e['name']:26s} {slot}  {e['attempts']} attempt(s)  "
-              f"{e['verdict']}{extra}")
-        if logs["unanchored"]:
-            p(f"  unanchored (no dated banner): "
-              f"{', '.join(logs['unanchored'])}")
-    man = report.get("manifest")
-    if man:
-        if "error" in man:
-            p(f"manifest: unreadable ({man['error']})")
-        else:
-            p(f"manifest: {len(man['cashed'])} cashed / "
-              f"{len(man['owed'])} owed")
-            if man["cashed"]:
-                p(f"  cashed: {', '.join(man['cashed'])}")
-            if man["owed"]:
-                p(f"  owed:   {', '.join(man['owed'])}")
-    probe = report.get("probe")
-    if probe is not None:
-        if "error" in probe:
-            p(f"probe: unreadable ({probe['error']})")
-        else:
-            p(f"probe: last verdict {probe.get('verdict')} "
-              f"at {probe.get('at', '?')} ({probe.get('detail', '')})")
     if not report:
         p("nothing to report (no readable inputs)")
-
-
-def watch_once(flight_dir, manifest_path=None, probe_state=None,
-               out=None):
-    """One frame of the live status view: newest heartbeat (phase +
-    age), the last few beats, probe verdict, manifest account."""
-    out = out or sys.stdout
-    p = lambda s="": print(s, file=out)  # noqa: E731
-    p(flight_mod.status_line(flight_dir))
-    beats = flight_mod.read_beats(flight_dir)
-    for b in beats[-5:]:
-        ts = b.get("ts")
-        when = (_fmt_ts(ts).split(" ")[-1]
-                if isinstance(ts, (int, float))
-                and not isinstance(ts, bool) else "?")
-        bits = [f"  {when}  {str(b.get('phase', '?')):14s} "
-                f"pid={b.get('pid', '?')}"]
-        if b.get("label"):
-            bits.append(f"row={b['label']}")
-        if b.get("attempt") is not None:
-            bits.append(f"attempt={b['attempt']}")
-        p(" ".join(bits))
-    if probe_state:
-        probe = probe_summary(probe_state)
-        if probe is None:
-            p("probe: no state file yet")
-        elif "error" in probe:
-            p(f"probe: unreadable ({probe['error']})")
-        else:
-            p(f"probe: last verdict {probe.get('verdict')} "
-              f"at {probe.get('at', '?')} ({probe.get('detail', '')})")
-    if manifest_path:
-        man = manifest_summary(manifest_path)
-        if "error" in man:
-            p(f"manifest: unreadable ({man['error']})")
-        else:
-            p(f"manifest: {len(man['cashed'])} cashed / "
-              f"{len(man['owed'])} owed"
-              + (f" (owed: {', '.join(man['owed'])})"
-                 if man["owed"] else ""))
 
 
 def main(argv=None):
@@ -746,54 +362,12 @@ def main(argv=None):
     ap.add_argument("--ledger",
                     default=os.path.join(REPO, "benchmarks",
                                          "ledger.jsonl"))
-    ap.add_argument("--logs", default=None,
-                    help="raw harness log directory "
-                         "(e.g. benchmarks/device_logs_r05)")
-    ap.add_argument("--manifest", default=None,
-                    help="collection manifest.json (cashed/owed rows)")
-    ap.add_argument("--probe-state", default=None,
-                    help="probe state file (last stamped verdict)")
-    ap.add_argument("--flight", default=None,
-                    help="flight-recorder heartbeat dir (ISSUE 16) — "
-                         "the primary timeline when present "
-                         "(default: APEX_FLIGHT_DIR)")
-    ap.add_argument("--watch", action="store_true",
-                    help="live status loop: newest heartbeat + probe + "
-                         "manifest, re-rendered every --interval s")
-    ap.add_argument("--interval", type=float, default=10.0,
-                    help="seconds between --watch frames")
-    ap.add_argument("--iterations", type=int, default=0,
-                    help="stop --watch after N frames (0 = until ^C)")
     ap.add_argument("--json", action="store_true",
                     help="append one machine-readable JSON line")
     args = ap.parse_args(argv)
 
-    flight_dir = args.flight or os.environ.get("APEX_FLIGHT_DIR")
-    if args.watch:
-        if not flight_dir:
-            print("FAIL: --watch needs a flight dir "
-                  "(--flight or APEX_FLIGHT_DIR)")
-            return 1
-        import time as _time
-
-        n = 0
-        try:
-            while True:
-                watch_once(flight_dir, manifest_path=args.manifest,
-                           probe_state=args.probe_state)
-                n += 1
-                if args.iterations and n >= args.iterations:
-                    return 0
-                _time.sleep(max(0.1, args.interval))
-                print()
-        except KeyboardInterrupt:
-            return 0
-
     try:
-        report = build_report(ledger_path=args.ledger, logs_dir=args.logs,
-                              manifest_path=args.manifest,
-                              probe_state=args.probe_state,
-                              flight_dir=flight_dir)
+        report = build_report(ledger_path=args.ledger)
     except (OSError, ValueError) as e:
         print(f"FAIL: {e}")
         return 1
