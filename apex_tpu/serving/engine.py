@@ -248,6 +248,8 @@ class ServingEngine:
         # round's admission stops at one dispatch's tokens
         self._admit_tokens = self.prefill_len \
             if self.family.one_prefill_a_round else None
+        # the row counts the ONE prefill program can stop its trunk at
+        self._trunk_rows = self.family.prefill_rows(self.prefill_len)
         self.params = params if params is not None \
             else self.family.init_params(cfg, seed)
 
@@ -974,15 +976,19 @@ class ServingEngine:
 
     # ----------------------------------------------------------- prefill
 
-    def _sample_first_tokens(self, logits_rows, slot_indices):
-        """First-token selection off prefill logits ``[R, vocab]`` for
-        the admitted slots — the SAME lane semantics as the decode
-        program's in-graph sampling (counter 0, the request's own
-        key), run eagerly between dispatches."""
+    def _sample_first_tokens(self, logits, at, slot_indices):
+        """First-token selection off the prefill program's gathered
+        logits ``[G, vocab]``, rows ``at`` of which are the admitted
+        slots' — the SAME lane semantics as the decode program's
+        in-graph sampling (counter 0, the request's own key), run
+        eagerly between dispatches. The greedy pick runs over all ``G``
+        rows whatever the batch holds (one program, the verify's, not
+        one a batch size) and the host keeps rows ``at``."""
         sch = self.scheduler
         if not self.sampling:
             return np.asarray(jnp.argmax(
-                logits_rows.astype(jnp.float32), axis=-1))
+                logits.astype(jnp.float32), axis=-1))[at]
+        logits_rows = logits[at]
         temps, top_ks, top_ps, keys, counters = \
             sampling_mod.batch_lanes(
                 [sch.slots[si].request for si in slot_indices])
@@ -1113,7 +1119,8 @@ class ServingEngine:
                                    (len(fed) - 1) // self.page_size + 1):
                         if j < len(pages):
                             keep[pages[j]] = 0.0
-            sp.set(tokens=cursor)
+            sp.set(tokens=cursor, trunk_rows=next(
+                R for R in self._trunk_rows if cursor <= R))
         t0 = time.perf_counter()
         with spans.span("prefill.stage"):
             args = [self.params, self.cache, jnp.asarray(ids),
@@ -1214,8 +1221,8 @@ class ServingEngine:
             self.prefill_batches += 1
             with spans.span("prefill.fetch"):
                 # rows r*W hold each request's last-prompt-token logits
-                sel = logits[np.arange(len(batch)) * self._gather_w]
-                next_toks = self._sample_first_tokens(sel, batch)
+                next_toks = self._sample_first_tokens(
+                    logits, np.arange(len(batch)) * self._gather_w, batch)
                 wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
             with spans.span("prefill.commit"):

@@ -20,6 +20,10 @@ A family is a :class:`Family` of plain functions:
   qparams, kernels)`` -> ``(cache, next_tokens, logits[, extras])``;
   ``extras`` is a dict of small device arrays the round fetches with
   its tokens and ``fetch_attrs(extras)`` turns into span attributes;
+* ``prefill_rows(S)``: the row counts at which the family's prefill
+  program can stop its trunk for ``S`` packed rows (ascending, ending
+  in ``S``); the engine names the one a batch will take on the
+  ``prefill.pack`` span;
 * ``decode_block`` (K steps in one dispatch) and
   ``quantize_decode_params``, or None where the family has none;
 * ``decode_attention(cfg, cache, kernels)`` -> the impl ("pallas" |
@@ -74,6 +78,7 @@ class Family:
     cache_dtype: Callable
     init_cache: Callable
     prefill: Callable
+    prefill_rows: Callable
     decode_step: Callable
     decode_attention: Callable
     decode_block: Optional[Callable] = None
@@ -84,6 +89,18 @@ class Family:
     # admission stops at one prefill dispatch's tokens a round, the rest
     # of the queue waits for the next (scheduler.admit's token_budget)
     one_prefill_a_round: bool = False
+
+
+def prefill_rows(S, tile=8):
+    """The row counts at which a family's ONE prefill program can stop
+    its trunk: the packed ``S`` and its exact halvings down to an eighth,
+    as far as they stay whole multiples of ``tile`` (8: a sublane tile;
+    a family whose attention kernel wants more says so), and always
+    ``S`` itself, so every ``S`` has a count. A packed batch's tokens
+    lie first, so a batch of ``n`` tokens runs the trunk on the smallest
+    count that holds it (a ``lax.switch`` inside the program): one
+    program, whose work follows what the round packed."""
+    return tuple(S >> j for j in (3, 2, 1) if S % (tile << j) == 0) + (S,)
 
 
 # what each refusable option looks like when it is OFF, as the per-call
@@ -150,7 +167,8 @@ def _gpt2():
         name="gpt2", check_config=smodel.check_serving_config,
         init_params=smodel.init_gpt_params,
         cache_dtype=smodel.compute_dtype, init_cache=init_cache,
-        prefill=prefill, decode_step=decode_step,
+        prefill=prefill, prefill_rows=smodel.trunk_rows,
+        decode_step=decode_step,
         decode_block=decode_block, decode_attention=decode_attention,
         quantize_decode_params=smodel.quantize_decode_params)
 
@@ -201,7 +219,8 @@ def _mimo():
         init_params=mimo.init_params,
         cache_dtype=lambda cfg: jnp.dtype(cfg.cache_dtype),
         init_cache=init_cache,
-        prefill=prefill, decode_step=decode_step,
+        prefill=prefill, prefill_rows=prefill_rows,
+        decode_step=decode_step,
         decode_attention=decode_attention, fetch_attrs=fetch_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
         one_prefill_a_round=True)

@@ -43,6 +43,7 @@ import numpy as np
 from jax import lax
 
 from apex_tpu.serving import kv_cache
+from apex_tpu.serving.family import prefill_rows
 from apex_tpu.transformer import moe as moe_mod
 
 
@@ -316,15 +317,6 @@ def _write_kv(cache, kind, n, page, off, k, v):
 
 # --------------------------------------------------------------- prefill
 
-def prefill_rows(S):
-    """The row counts at which the prefill program can stop: the packed
-    ``S`` and its halvings down to an eighth, as far as they stay whole
-    sublane tiles. A packed batch's tokens lie first, so a batch of
-    ``n`` tokens runs the trunk on the smallest count that holds it: one
-    program, whose work follows what the round packed."""
-    return tuple(S >> j for j in (3, 2, 1, 0) if S >> j and (S >> j) % 8 == 0)
-
-
 def prefill(params, cache, ids, positions, seg, token_rows, page_table,
             last_idx, *, cfg, attn_impl=None, moe_impl=None, interpret=None):
     """One packed prompt batch through the trunk, filling both caches
@@ -336,7 +328,7 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     to the null page. Returns ``(cache, logits [G, vocab] float32)``.
 
     The trunk runs on the first ``R`` of the ``S`` packed rows, ``R`` the
-    smallest of :func:`prefill_rows` that holds the batch's tokens (a
+    smallest of ``family.prefill_rows`` that holds the batch's tokens (a
     ``lax.switch`` on their count: the rows behind them are padding,
     which no token attends to and no expert serves, so results do not
     depend on ``R``). Each layer's K and V rows come out of the branch

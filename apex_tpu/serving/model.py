@@ -51,6 +51,7 @@ from apex_tpu.serving import kv_cache as kv_cache_mod
 from apex_tpu.serving import kv_tier as kv_tier_mod
 from apex_tpu.serving import quant as quant_mod
 from apex_tpu.serving import sampling as sampling_mod
+from apex_tpu.serving.family import prefill_rows
 
 
 def check_serving_config(cfg):
@@ -210,6 +211,17 @@ def _trunk_layer(x, lp, qr, cfg, attn):
 
 # --------------------------------------------------------------- prefill
 
+def trunk_rows(S):
+    """The row counts the prefill program's trunk can run on for ``S``
+    packed rows (``family.prefill_rows``): where ``fused_attention``
+    takes its flash kernel at ``S`` every count is a length it takes it
+    at too (whole 128-row blocks), so no count falls to the dense
+    path."""
+    from apex_tpu.ops.attention import flash_supported
+
+    return prefill_rows(S, 128 if flash_supported(S, S) else 8)
+
+
 def prefill(params, cache, ids, positions, seg, token_rows, page_table,
             last_idx, keep_scale=None, *, cfg):
     """One packed prompt batch through the trunk, filling the cache.
@@ -217,9 +229,11 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     ids/positions/seg/token_rows: ``[S_pack]`` — token values, their
     within-request positions, segment ids (0 = padding, 1..R real),
     and each token's row into ``page_table`` (padding rows point at
-    the all-null spare row). page_table: ``[R_rows, max_pages]``.
+    the all-null spare row). A batch's tokens lie FIRST, the padding
+    behind them. page_table: ``[R_rows, max_pages]``.
     last_idx: ``[G]`` flat pack indices to gather logits at (inactive
-    entries 0 — callers mask). Plain prefill gathers one index per
+    entries 0 — callers mask; every entry lies below the batch's
+    token count). Plain prefill gathers one index per
     request (its last prompt token); the SPECULATIVE VERIFY dispatch
     of this same program (ISSUE 13) gathers K+1 indices per request —
     the pending-token + draft positions whose greedy chain decides
@@ -229,76 +243,133 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     rows whose scale must survive, 0 = fresh or null) — required by
     and only consumed on the int8 KV tier (``kv_tier.is_quantized``),
     where the scatter routes through the quantize-at-write codec.
-    """
+
+    The trunk (embedding, every layer, final norm) runs on the first
+    ``R`` of the ``S`` packed rows, ``R`` the smallest of
+    :func:`trunk_rows` that holds the batch's tokens: a ``lax.switch``
+    on the count of ``seg > 0`` inside the ONE program, so a short
+    batch does not pay for a full dispatch. The rows behind the tokens
+    are padding: no token attends to them (segment 0), no ``last_idx``
+    gathers them and their K/V lands on the null page, so the logits
+    and every live page do not depend on ``R``. Each layer's K and V
+    rows leave the branch padded back to ``S`` and are written behind
+    the switch (:func:`_write_behind`), so no branch carries a cache: a
+    ``cond`` that holds the 72 leaves copies each (PERF.md §5, PR 30)."""
     dtype = compute_dtype(cfg)
     hd, n_heads = cfg.head_dim, cfg.num_attention_heads
-    cache = {name: list(leaves) for name, leaves in cache.items()}
     ps = cache["k"][0].shape[1]
     S = ids.shape[0]
 
-    word = params["word_embeddings"]
-    with jax.named_scope("embed"):
-        x = jnp.take(word, ids, axis=0) \
-            + jnp.take(params["embedding"]["position_embeddings"],
-                       positions, axis=0)
-        x = x.astype(dtype)
-
-        dest_page = jnp.take_along_axis(
-            token_rows_to_pages(page_table, token_rows),
-            (positions // ps)[:, None], axis=1)[:, 0]
-        dest_off = positions % ps
-
-    quant = kv_tier_mod.is_quantized(cache)
-    if quant and keep_scale is None:
+    if kv_tier_mod.is_quantized(cache) and keep_scale is None:
         raise ValueError(
             "prefill on a quantized cache needs the keep_scale row — "
             "requantizing without it would zero surviving pages")
 
     from apex_tpu.ops import fused_attention
 
-    seg2 = seg.astype(jnp.int32)[None, :]
-    for i in range(cfg.num_layers):
-        def attn(q, k, v, i=i):
-            # scatter this layer's K/V into the paged cache: each
-            # token's [H * d] row at (page, offset) of the layer's
-            # leaf — index arithmetic only (the int8 tier routes the
-            # same scatter through the quantize-at-write codec) — then
-            # packed causal+segment attention over the full bucket
-            nonlocal cache
-            with jax.named_scope("kv_write"):
-                if quant:
-                    cache = kv_tier_mod.prefill_scatter_quant(
-                        cache, i, "k", k, dest_page, dest_off,
-                        keep_scale)
-                    cache = kv_tier_mod.prefill_scatter_quant(
-                        cache, i, "v", v, dest_page, dest_off,
-                        keep_scale)
-                else:
-                    _write_rows(cache, i, dest_page, dest_off, k, v)
-            with jax.named_scope("attend"):
-                ctx = fused_attention(
-                    q.transpose(1, 0, 2)[None],
-                    k.transpose(1, 0, 2)[None],
-                    v.transpose(1, 0, 2)[None], causal=True,
-                    sm_scale=1.0 / math.sqrt(hd),
-                    segment_ids=(seg2, seg2))
-                return ctx[0].transpose(1, 0, 2).reshape(S, n_heads * hd)
+    word = params["word_embeddings"]
 
-        x = _trunk_layer(x, params["transformer"][f"layer_{i}"], {},
-                         cfg, attn)
+    def trunk_on(R):
+        # one jitted function a branch: its 36 calls are ONE trace and
+        # one lowering (of the flash kernel too), not 36 of each
+        @jax.jit
+        def layer(x, lp, seg2):
+            kv = []   # this layer's k and v rows, padded to S
 
-    with jax.named_scope("final_norm"):
-        x = _layer_norm(x, params["transformer"]["final_layernorm"],
-                        cfg.layernorm_epsilon)
+            def attn(q, k, v):
+                # packed causal+segment attention over the branch's
+                # rows; the K/V rows go out to be written
+                kv.extend(jnp.pad(a.reshape(R, n_heads * hd),
+                                  ((0, S - R), (0, 0))) for a in (k, v))
+                with jax.named_scope("attend"):
+                    ctx = fused_attention(
+                        q.transpose(1, 0, 2)[None],
+                        k.transpose(1, 0, 2)[None],
+                        v.transpose(1, 0, 2)[None], causal=True,
+                        sm_scale=1.0 / math.sqrt(hd),
+                        segment_ids=(seg2, seg2))
+                    return ctx[0].transpose(1, 0, 2).reshape(
+                        R, n_heads * hd)
+
+            return _trunk_layer(x, lp, {}, cfg, attn), tuple(kv)
+
+        def branch(ids, positions, seg):
+            ids, positions = ids[:R], positions[:R]
+            seg2 = seg[:R].astype(jnp.int32)[None, :]
+            with jax.named_scope("embed"):
+                x = jnp.take(word, ids, axis=0) \
+                    + jnp.take(params["embedding"]["position_embeddings"],
+                               positions, axis=0)
+                x = x.astype(dtype)
+            written = []   # every layer's (k, v), in layer order
+            for i in range(cfg.num_layers):
+                x, kv = layer(x, params["transformer"][f"layer_{i}"], seg2)
+                written.append(kv)
+            with jax.named_scope("final_norm"):
+                x = _layer_norm(x, params["transformer"]["final_layernorm"],
+                                cfg.layernorm_epsilon)
+            with jax.named_scope("lm_head"):
+                return jnp.take(x, last_idx, axis=0), written
+
+        return branch
+
+    rows = trunk_rows(S)
+    tokens = jnp.sum((seg > 0).astype(jnp.int32))
+    x_last, written = lax.switch(
+        sum((tokens > R).astype(jnp.int32) for R in rows[:-1]),
+        [trunk_on(R) for R in rows], ids, positions, seg)
+
+    with jax.named_scope("embed"):
+        dest_page = jnp.take_along_axis(
+            token_rows_to_pages(page_table, token_rows),
+            (positions // ps)[:, None], axis=1)[:, 0]
+        dest_off = positions % ps
+    # the scope the decode program's write has inside ``_trunk_layer``
+    with jax.named_scope("layer"), jax.named_scope("attn"), \
+            jax.named_scope("kv_write"):
+        cache = _write_behind(cache, written, dest_page, dest_off, tokens,
+                              keep_scale, rows[0])
     with jax.named_scope("lm_head"):
-        x_last = jnp.take(x, last_idx, axis=0)
         logits = _mm(x_last, word, dtype)
     return cache, logits
 
 
+def _write_behind(cache, written, page, off, tokens, keep_scale, chunk):
+    """Every layer's K/V rows (``written``: a ``(k, v)`` of ``[S, H * d]``
+    a layer) scattered into the paged cache at ``(page[t], off[t])`` —
+    index arithmetic only. A scatter costs by its rows (GPT-2 large on a
+    v5e: 1.4 ms for 128 rows into each of the 72 leaves, whatever the
+    chunk; 1,024 rows at once were ~5 ms of every dispatch, PERF.md §5),
+    so the rows are written ``chunk`` at a time and only the chunks that
+    hold tokens: a loop of ``ceil(tokens / chunk)`` trips that carries
+    the leaves in place. The int8 tier's quantize-at-write codec
+    re-scales whole pages by what a dispatch writes to them, so it
+    takes the ``S`` rows in one call."""
+    cache = {name: list(leaves) for name, leaves in cache.items()}
+    if kv_tier_mod.is_quantized(cache):
+        n_heads = cache["k_scale"][0].shape[1]
+        for i, kv in enumerate(written):
+            for part, val in zip("kv", kv):
+                cache = kv_tier_mod.prefill_scatter_quant(
+                    cache, i, part, val.reshape(val.shape[0], n_heads, -1),
+                    page, off, keep_scale)
+        return cache
+
+    def write_chunk(c, cache):
+        cache = {name: list(leaves) for name, leaves in cache.items()}
+        cut = lambda a: lax.dynamic_slice_in_dim(      # noqa: E731
+            a, c * chunk, chunk)
+        for i, (k, v) in enumerate(written):
+            _write_rows(cache, i, cut(page), cut(off), cut(k), cut(v))
+        return cache
+
+    return lax.fori_loop(0, (tokens + chunk - 1) // chunk, write_chunk,
+                         cache)
+
+
 def _write_rows(cache, layer, page, off, k, v):
-    """This layer's ``k``/``v`` ``[rows, H, d]`` into its two leaves at
-    ``(page[r], off[r])``, one row a token."""
+    """This layer's ``k``/``v`` (``[rows, H, d]`` or ``[rows, H * d]``)
+    into its two leaves at ``(page[r], off[r])``, one row a token."""
     for part, val in (("k", k), ("v", v)):
         cache[part][layer] = kv_cache_mod.write_rows(
             cache[part][layer], page, off, val)
@@ -354,34 +425,47 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
 
     ql = qparams["layers"] if qparams is not None else None
     quant = kv_tier_mod.is_quantized(cache)
-    for i in range(cfg.num_layers):
-        def attn(q, k, v, i=i):
+
+    # one jitted function for every layer: its 36 calls are ONE trace and
+    # one lowering (of the decode kernel too), not 36 of each; XLA
+    # inlines the calls, so the compiled program is what it was
+    @jax.jit
+    def layer(x, lp, qr, leaves):
+        one = {name: [leaf] for name, leaf in leaves.items()}
+
+        def attn(q, k, v):
             # append this step's k/v rows at (page, offset) — the int8
             # tier rewrites the touched pages through the per-page RMW
             # codec — then paged decode attention over the layer's
             # leaves where they lie (quantized pages ride with their
             # per-(page, head) scales and take the jnp form)
-            nonlocal cache
+            nonlocal one
             with jax.named_scope("kv_write"):
                 if quant:
-                    cache = kv_tier_mod.decode_scatter_quant(
-                        cache, i, "k", k, write_page, write_off)
-                    cache = kv_tier_mod.decode_scatter_quant(
-                        cache, i, "v", v, write_page, write_off)
+                    for part, val in (("k", k), ("v", v)):
+                        one = kv_tier_mod.decode_scatter_quant(
+                            one, 0, part, val, write_page, write_off)
                 else:
-                    _write_rows(cache, i, write_page, write_off, k, v)
+                    _write_rows(one, 0, write_page, write_off, k, v)
             with jax.named_scope("attend"):
-                scales = dict(k_scale=cache["k_scale"][i],
-                              v_scale=cache["v_scale"][i]) if quant else {}
+                scales = dict(k_scale=one["k_scale"][0],
+                              v_scale=one["v_scale"][0]) if quant else {}
                 ctx = dap.grouped_decode_attention(
-                    q.astype(dtype), cache["k"][i], cache["v"][i],
+                    q.astype(dtype), one["k"][0], one["v"][0],
                     page_table, lengths, n_kv=n_heads,
                     sm_scale=1.0 / math.sqrt(hd), impl=decode_impl,
                     interpret=interpret, **scales)
                 return ctx.reshape(B, n_heads * hd).astype(dtype)
 
-        x = _trunk_layer(x, params["transformer"][f"layer_{i}"],
-                         ql[i] if ql is not None else {}, cfg, attn)
+        x = _trunk_layer(x, lp, qr, cfg, attn)
+        return x, {name: leaf for name, (leaf,) in one.items()}
+
+    for i in range(cfg.num_layers):
+        x, leaves = layer(x, params["transformer"][f"layer_{i}"],
+                          ql[i] if ql is not None else {},
+                          {name: cache[name][i] for name in cache})
+        for name, leaf in leaves.items():
+            cache[name][i] = leaf
 
     with jax.named_scope("final_norm"):
         x = _layer_norm(x, params["transformer"]["final_layernorm"],

@@ -64,19 +64,26 @@ def test_kernel_compiles_for_the_v5e_at_gpt2_widths(one_chip, h, b, pages):
     assert "tpu_custom_call" in text and dap.GROUPED_KERNEL_NAME in text
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_serve_cell_program_copies_no_cache_leaf(one_chip, program):
-    """GPT-2 large's widths, 16 slots, 96 pages of 128 tokens, four
-    layers of the 36: no operation makes an array of a cache leaf's
-    shape by ``copy``, every leaf is aliased input to output, the decode
-    program reaches the kernel in every layer and its temporaries stay
+@pytest.mark.parametrize("program,layers", [("decode", 4), ("prefill", 36)])
+def test_serve_cell_program_copies_no_cache_leaf(one_chip, monkeypatch,
+                                                 program, layers):
+    """GPT-2 large's widths, 16 slots, 96 pages of 128 tokens: no
+    operation makes an array of a cache leaf's shape by ``copy``, every
+    leaf is aliased input to output, the decode program (four layers of
+    the 36) reaches the kernel in every layer and its temporaries stay
     far under a leaf's size (8.2 GB of them at full depth before PR
-    28)."""
+    28). The prefill program at FULL depth and with the attention kernel
+    the chip takes: a ``cond`` that carries the cache copies no leaf at
+    four layers and every leaf five times at 36 (PR 30), so its four
+    trunks carry none, the write behind them is a loop that holds the
+    leaves in place, no leaf goes through VMEM and back (``copy-done``),
+    and the temporaries stay near the K/V rows that leave the switch."""
+    from apex_tpu.ops import attention
     from apex_tpu.serving import kv_cache
     from apex_tpu.serving import model as smodel
     from apex_tpu.transformer.testing import TransformerConfig
 
-    layers, h, b, pages, ps, d, S = 4, 20, 16, 96, 128, 64, 1024
+    h, b, pages, ps, d, S = 20, 16, 96, 128, 64, 1024
     cfg = TransformerConfig(
         hidden_size=h * d, num_layers=layers, num_attention_heads=h,
         vocab_size=50304, max_position_embeddings=1024,
@@ -99,6 +106,11 @@ def test_serve_cell_program_copies_no_cache_leaf(one_chip, program):
                                       decode_impl="pallas", interpret=False)
         args = (i32(b), i32(b), i32(b, 1024 // ps))
     else:
+        # the host platform is the CPU: steer ``fused_attention`` onto
+        # the flash kernel the chip takes at these lengths
+        monkeypatch.setattr(attention, "_tpu_available", lambda: True)
+        assert smodel.trunk_rows(S) == (128, 256, 512, 1024)
+
         def fn(params, cache, ids, positions, seg, rows, page_table, last):
             return smodel.prefill(params, cache, ids, positions, seg, rows,
                                   page_table, last, cfg=cfg)
@@ -119,6 +131,13 @@ def test_serve_cell_program_copies_no_cache_leaf(one_chip, program):
         assert mem.temp_size_in_bytes < leaf_bytes
         # full depth is 36 layers: under 1 GB of temporaries there
         assert mem.temp_size_in_bytes * 36 / layers < 1e9
+    else:
+        assert len(re.findall(r" conditional\(", text)) == 1
+        assert text.count("tpu_custom_call") >= 4 * layers
+        assert "copy-done" not in made, sorted(set(made))
+        # 2 * 36 * [1024, 1280] bf16 of K/V rows out of the switch are
+        # 0.19 GB; a second set of leaves would be 2.27
+        assert mem.temp_size_in_bytes < 0.6e9
 
 
 # ---- the MiMo family's kernels at the widths serve-mimo-decode runs ----

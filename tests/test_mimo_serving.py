@@ -348,7 +348,7 @@ def test_packed_prefill_attention_has_no_backward():
 # ------------------------------------------- the prefill's row counts
 
 def test_prefill_row_counts_are_halvings_in_whole_tiles():
-    from apex_tpu.serving.mimo import prefill_rows
+    from apex_tpu.serving.family import prefill_rows
 
     assert prefill_rows(2048) == (256, 512, 1024, 2048)
     assert prefill_rows(32) == (8, 16, 32) and prefill_rows(8) == (8,)

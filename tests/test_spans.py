@@ -185,7 +185,7 @@ def test_one_round_yields_the_documented_span_tree(params):
     schedule, pack = children[0], children[1]
     assert schedule.attrs == {"evicted": 0, "admitted": 3,
                               "queue_depth": 0}
-    assert pack.attrs == {"rows": 3, "tokens": 5 + 6 + 7}
+    assert pack.attrs == {"rows": 3, "tokens": 5 + 6 + 7, "trunk_rows": 32}
     # a round that only decodes has no prefill span
     spans.clear()
     engine.step()
