@@ -429,3 +429,174 @@ def grouped_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     return grouped_decode_attention_reference(
         q, k_pages, v_pages, page_table, lengths, sm_scale,
         k_scale=k_scale, v_scale=v_scale, **kw)
+
+
+# ------------------------------------------------ latent pages (one row a
+# token, no head axis)
+#
+# Latent attention (serving/axk1.py) keeps ONE row a token a layer:
+# ``rank`` columns of normalised compressed KV, then the rotated key
+# dims that every head shares. In the absorbed decode form every query
+# head meets that same row: its score is ``q . row`` over the whole
+# width, and the value it sums is the row's first ``rank`` columns. So
+# a page is ONE operand, fetched once, and serves as K and as V:
+#
+#   q            [b, hq, width]     ``q_nope W_uk^T`` ‖ rotated ``q_pe``
+#   latent_pages [pages, page_size, width]   one layer (kv_cache.py)
+#   out          [b, hq, rank]      ``sum p c_kv``, before ``W_uv``
+#
+# The page walk (scalar-prefetched table, bases and lengths; an entry at
+# or past ``length`` skipped) and the online softmax step are the
+# grouped kernel's own; every layer keeps every token, so no ``starts``.
+
+LATENT_KERNEL_NAME = "latent_decode_attention"
+
+
+def latent_supported(hq, width, rank, page_size, dtype=None):
+    """Whether Mosaic takes the latent kernel: query heads in whole
+    sublane tiles, a row of whole lane tiles whose value columns start
+    at column 0, a bfloat16 or float32 page (double-buffered) with the
+    accumulators inside VMEM."""
+    if dtype is not None and jnp.dtype(dtype) not in (
+            jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return False
+    itembytes = tiles.itemsize(dtype) if dtype is not None else 4
+    return (hq % 8 == 0 and page_size % 8 == 0 and width % 128 == 0
+            and rank % 8 == 0 and rank <= width
+            and 2 * page_size * width * itembytes + 16 * hq * rank
+            <= 8 * 2 ** 20)
+
+
+def _latent_kernel(pt_ref, base_ref, len_ref, q_ref, kv_ref, o_ref,
+                   acc_scr, m_scr, l_scr, *, scale, ps, n_iter, rank):
+    """One (slot, table entry) step: the page ``[ps, width]`` meets every
+    head's query whole (scores ``[hq, ps]``), and its first ``rank``
+    columns are what the probabilities sum."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, jnp.float32(NEG_INF))
+        l_scr[...] = jnp.zeros_like(l_scr)
+
+    base, length = base_ref[i, j], len_ref[i]
+
+    @pl.when(base < length)
+    def _page():
+        pos = base + lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        masked = pos >= length                                   # [1, ps]
+        s = lax.dot_general(q_ref[...], kv_ref[...],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s = jnp.where(masked, jnp.float32(NEG_INF), s * jnp.float32(scale))
+        p, alpha = _softmax_step(s, masked, m_scr, l_scr, 0, s.shape[0])
+        value = kv_ref[:, :rank]                              # [ps, rank]
+        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
+            p.astype(value.dtype), value, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_iter - 1)
+    def _finish():
+        l = l_scr[...]
+        o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(
+            o_ref.dtype)
+
+
+def latent_decode_attention_pallas(q, latent_pages, page_table, lengths,
+                                   sm_scale, *, rank, page_base=None,
+                                   interpret=False):
+    """The latent decode kernel (layouts above)."""
+    b, hq, width = q.shape
+    ps = latent_pages.shape[1]
+    n = page_table.shape[1]
+    if latent_pages.shape[2] != width or rank > width:
+        raise ValueError(
+            f"latent_decode_attention: q {q.shape} and latent pages "
+            f"{latent_pages.shape} do not share a row of {rank} value "
+            f"columns")
+    if not interpret and not latent_supported(hq, width, rank, ps,
+                                              latent_pages.dtype):
+        raise ValueError(
+            f"latent_decode_attention_pallas: unsupported geometry "
+            f"hq={hq} width={width} rank={rank} ps={ps} "
+            f"{latent_pages.dtype}")
+    page_base, _ = _context_view(page_table, ps, page_base)
+    kern = functools.partial(_latent_kernel, scale=float(sm_scale), ps=ps,
+                             n_iter=n, rank=rank)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n),
+            in_specs=[
+                pl.BlockSpec((None, hq, width),
+                             lambda i, j, pt, base, ln: (i, 0, 0)),
+                pl.BlockSpec((None, ps, width),
+                             lambda i, j, pt, base, ln: (pt[i, j], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, hq, rank),
+                                   lambda i, j, pt, base, ln: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((hq, rank), jnp.float32),
+                pltpu.VMEM((hq, 1), jnp.float32),
+                pltpu.VMEM((hq, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hq, rank), q.dtype),
+        interpret=interpret,
+        name=LATENT_KERNEL_NAME,
+    )(page_table.astype(jnp.int32), page_base, lengths.astype(jnp.int32),
+      q, latent_pages)
+
+
+def latent_decode_attention_reference(q, latent_pages, page_table, lengths,
+                                      sm_scale, *, rank, page_base=None):
+    """The jnp form of the latent kernel (the CPU, an unsupported
+    geometry or page dtype): gather each slot's pages, mask at and past
+    ``length``, exact float32 softmax. Inactive slots return 0."""
+    b, hq, width = q.shape
+    ps = latent_pages.shape[1]
+    rows = latent_pages[page_table].reshape(b, -1, width).astype(
+        jnp.float32)                                   # [b, n * ps, width]
+    masked = _outside_context(page_table, ps, lengths,
+                              page_base)[:, None, :]            # [b, 1, S]
+    s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), rows,
+                   precision=lax.Precision.HIGHEST) * jnp.float32(sm_scale)
+    out = jnp.einsum("bhs,bsr->bhr", masked_softmax(s, masked, None),
+                     rows[..., :rank], precision=lax.Precision.HIGHEST)
+    return out.astype(q.dtype)
+
+
+def latent_resolved(hq, width, rank, page_size, dtype, impl=None):
+    """The impl a :func:`latent_decode_attention` call runs with: a
+    per-call demand, else the kernel on a TPU where the geometry and the
+    pages' dtype are supported, else the jnp form."""
+    if impl is not None:
+        if impl not in ("jnp", "pallas"):
+            raise ValueError(f"unknown decode-attention impl {impl!r}")
+        return impl
+    if jax.default_backend() == "tpu" and latent_supported(
+            hq, width, rank, page_size, dtype):
+        return "pallas"
+    return "jnp"
+
+
+def latent_decode_attention(q, latent_pages, page_table, lengths, *, rank,
+                            sm_scale, page_base=None, impl=None,
+                            interpret=None):
+    """Dispatched decode attention over latent pages (layouts above).
+    ``impl`` is a per-call demand ("jnp" | "pallas"; "pallas" compiled on
+    an unsupported geometry raises); ``interpret`` defaults to True on
+    the CPU platform only."""
+    hq, width = q.shape[1:]
+    kw = dict(rank=rank, page_base=page_base)
+    if latent_resolved(hq, width, rank, latent_pages.shape[1],
+                       latent_pages.dtype, impl) == "pallas":
+        if interpret is None:
+            interpret = jax.devices()[0].platform == "cpu"
+        return latent_decode_attention_pallas(
+            q, latent_pages, page_table, lengths, sm_scale,
+            interpret=interpret, **kw)
+    return latent_decode_attention_reference(
+        q, latent_pages, page_table, lengths, sm_scale, **kw)

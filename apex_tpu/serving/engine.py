@@ -641,6 +641,13 @@ class ServingEngine:
                 self.num_slots, self.num_pages, self.page_size,
                 self._cache_dtype, self.kv_quant)))
 
+    @property
+    def kernels(self):
+        """The :class:`family.Kernels` the engine's programs were built
+        with (``decode_impl``, ``interpret``): what a caller hands one
+        of the family's public functions to run it as the rounds did."""
+        return self._kernels
+
     def decode_cache_size(self):
         """jit-cache entry count of the decode step — the
         jaxpr-stability assertion surface (must stay 1 whatever the

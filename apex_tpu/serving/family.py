@@ -37,7 +37,11 @@ A family is a :class:`Family` of plain functions:
   or None;
 * ``one_prefill_a_round``: the serial round admits at most one prefill
   dispatch's tokens (``prefill_len``); the rest of the queue waits a
-  round, so the decode lanes are never held for a second dispatch.
+  round, so the decode lanes are never held for a second dispatch;
+* ``model_type``, ``config_from_dict``: the published ``model_type``
+  whose ``config.json`` the family reads, and the constructor of its
+  config object from such a dict (:func:`config_from_dict` finds the
+  family by the first); None where a family has no published dict form.
 
 A family is handed values, never the engine: ``kernels`` is the
 :class:`Kernels` the caller asked for (``decode_impl``,
@@ -89,6 +93,10 @@ class Family:
     # admission stops at one prefill dispatch's tokens a round, the rest
     # of the queue waits for the next (scheduler.admit's token_budget)
     one_prefill_a_round: bool = False
+    # the published model_type the family reads and the constructor of
+    # its config object from a dict of the published keys
+    model_type: Optional[str] = None
+    config_from_dict: Optional[Callable] = None
 
 
 def prefill_rows(S, tile=8):
@@ -101,6 +109,22 @@ def prefill_rows(S, tile=8):
     count that holds it (a ``lax.switch`` inside the program): one
     program, whose work follows what the round packed."""
     return tuple(S >> j for j in (3, 2, 1) if S % (tile << j) == 0) + (S,)
+
+
+def switch_on_rows(rows, trunk_on, ids, positions, seg):
+    """Run ``trunk_on(R)(ids, positions, seg)`` for the smallest ``R`` of
+    ``rows`` (a family's :func:`prefill_rows`) that holds the packed
+    batch's tokens (``seg > 0``; they lie first): ONE ``lax.switch`` on
+    their count, every branch returning the same shapes. No branch
+    carries a cache: a family writes its K/V (or latent) rows behind the
+    switch."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    tokens = jnp.sum((seg > 0).astype(jnp.int32))
+    return lax.switch(
+        sum((tokens > R).astype(jnp.int32) for R in rows[:-1]),
+        [trunk_on(R) for R in rows], ids, positions, seg)
 
 
 # what each refusable option looks like when it is OFF, as the per-call
@@ -175,6 +199,16 @@ def _gpt2():
 
 # ------------------------------------------------------------------- MiMo
 
+def _expert_attrs(extras):
+    """``engine.round``'s expert counters, from a decode program's
+    ``expert_tokens [moe layers, held]`` (MiMo's and A.X-K1's)."""
+    counts = np.asarray(extras["expert_tokens"])
+    return dict(experts_touched=int((counts > 0).sum()),
+                experts_held=int(counts.size),
+                expert_tokens_max=int(counts.max(initial=0)),
+                expert_tokens_sum=int(counts.sum()))
+
+
 def _mimo():
     import jax.numpy as jnp
 
@@ -200,13 +234,6 @@ def _mimo():
         return mimo.decode_attention_resolved(
             cfg, cache, kernels.decode_impl)
 
-    def fetch_attrs(extras):
-        counts = np.asarray(extras["expert_tokens"])   # [moe layers, held]
-        return dict(experts_touched=int((counts > 0).sum()),
-                    experts_held=int(counts.size),
-                    expert_tokens_max=int(counts.max(initial=0)),
-                    expert_tokens_sum=int(counts.sum()))
-
     def round_attrs(cfg, scheduler):
         # what the round's decode read of each kind of state: pages of
         # the pool that hold context (not the reserved ones), ring pages
@@ -221,17 +248,75 @@ def _mimo():
         init_cache=init_cache,
         prefill=prefill, prefill_rows=prefill_rows,
         decode_step=decode_step,
-        decode_attention=decode_attention, fetch_attrs=fetch_attrs,
+        decode_attention=decode_attention, fetch_attrs=_expert_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
-        one_prefill_a_round=True)
+        one_prefill_a_round=True, model_type="mimo_v2",
+        config_from_dict=mimo.MiMoConfig.from_dict)
 
 
-_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo}
+# ------------------------------------------------------------------ A.X-K1
+
+def _axk1():
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import axk1
+
+    def init_cache(cfg, geometry):
+        return axk1.init_cache(cfg, geometry.num_pages, geometry.page_size,
+                               geometry.cache_dtype)
+
+    def prefill(params, cache, ids, positions, seg, token_rows, page_table,
+                last_idx, keep_scale=None, *, cfg, kernels):
+        return axk1.prefill(params, cache, ids, positions, seg, token_rows,
+                            page_table, last_idx, cfg=cfg,
+                            interpret=kernels.interpret)
+
+    def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+                    qparams, kernels):
+        return axk1.decode_step(params, cache, tokens, lengths, page_table,
+                                cfg=cfg, decode_impl=kernels.decode_impl,
+                                interpret=kernels.interpret)
+
+    def decode_attention(cfg, cache, kernels):
+        return axk1.decode_attention_resolved(
+            cfg, cache, kernels.decode_impl)
+
+    def round_attrs(cfg, scheduler):
+        # pages of the pool that hold context: each is one latent page a
+        # layer, read once by the round's decode
+        live, _ = scheduler.context_pages()
+        return dict(latent_pages_live=live)
+
+    return Family(
+        name="axk1", check_config=axk1.check_config,
+        init_params=axk1.init_params,
+        cache_dtype=lambda cfg: jnp.dtype(cfg.cache_dtype),
+        init_cache=init_cache,
+        prefill=prefill, prefill_rows=prefill_rows,
+        decode_step=decode_step,
+        decode_attention=decode_attention, fetch_attrs=_expert_attrs,
+        round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
+        one_prefill_a_round=True, model_type="axk1",
+        config_from_dict=axk1.AXK1Config.from_dict)
+
+
+_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo, "axk1": _axk1}
 
 
 @functools.lru_cache(maxsize=None)
 def _built(name):
     return _BUILDERS[name]()   # imports the family's modules on first use
+
+
+def config_from_dict(d):
+    """The config object of a configuration dict with the published key
+    names, from the family that reads its ``model_type``: what
+    :func:`family_of` and ``ServingEngine`` take."""
+    known = {f.model_type: f for f in map(_built, _BUILDERS) if f.model_type}
+    if d.get("model_type") not in known:
+        raise ValueError(f"no serving family reads model_type "
+                         f"{d.get('model_type')!r} (known: {sorted(known)})")
+    return known[d["model_type"]].config_from_dict(d)
 
 
 def family_of(cfg):

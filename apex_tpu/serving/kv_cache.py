@@ -12,7 +12,8 @@ asserted by tests/test_serving.py).
 (head, width) is the minor axis: a token's K or V of every head is one
 row, 1280 lanes at GPT-2 large, 768 / 512 at MiMo's global layers, whole
 lane tiles under ``page_size`` rows of whole sublane tiles. Nothing is
-padded; a step scatters ``[tokens, kv_heads * width]`` rows in place;
+padded (a latent row apart: :func:`init_latent_cache`); a step scatters
+``[tokens, kv_heads * width]`` rows in place;
 the decode kernel (ops/decode_attention_pallas.py) DMAs a page where it
 lies; no program copies a cache (PERF.md §6, PR 27 and PR 28: with
 heads leading pages and 64 columns a row XLA re-laid the whole cache,
@@ -59,6 +60,34 @@ def init_cache(num_layers, num_heads, num_pages, page_size, head_dim,
     return cache
 
 
+def latent_row_width(width):
+    """Columns of a latent page's row: ``width`` padded to whole lane
+    tiles of 128 (576 -> 640)."""
+    return -(-int(width) // 128) * 128
+
+
+def init_latent_cache(num_layers, num_pages, page_size, width,
+                      dtype=jnp.bfloat16):
+    """Zeroed cache ``{"latent"}`` of a model with latent attention
+    (serving/axk1.py): a third kind of state, ONE row a token a layer
+    with no head axis, ``width`` live columns (the normalised compressed
+    KV, then the rotated key dims every head shares) that serve as K and
+    as V. One ``[num_pages, page_size, latent_row_width(width)]`` leaf a
+    layer, pages of the same pool, allocator and page table as
+    :func:`init_cache`'s.
+
+    The row is padded to whole lane tiles with zero columns that stay
+    zero (:func:`write_latent_rows`). Unpadded, the TPU
+    keeps a ``[pages, 128, 576]`` array position-minor (576 is 4.5 lane
+    tiles, 128 is one) and every row scatter and every kernel call then
+    copies the whole leaf to row-major and back (two 207 MB copies a
+    layer a program at the cell's size: the compile-only verdict of
+    tests/test_decode_attention_mosaic.py; PERF.md §6, PR 31)."""
+    return {"latent": [
+        jnp.zeros((num_pages, page_size, latent_row_width(width)), dtype)
+        for _ in range(num_layers)]}
+
+
 def write_rows(leaf, page, off, rows):
     """``leaf [pages, page_size, heads * width]`` with ``rows [T, heads,
     width]`` scattered, one ``[heads * width]`` row a token, at
@@ -66,6 +95,26 @@ def write_rows(leaf, page, off, rows):
     and V (index arithmetic only; in place under donation)."""
     return leaf.at[page, off, :].set(
         rows.reshape(rows.shape[0], -1).astype(leaf.dtype))
+
+
+def write_latent_rows(leaf, page, off, rows):
+    """:func:`write_rows` for a latent leaf: ``rows [T, width]`` padded
+    with zero columns to the leaf's whole lane tiles."""
+    return write_rows(leaf, page, off, jnp.pad(
+        rows, ((0, 0), (0, leaf.shape[2] - rows.shape[1]))))
+
+
+def pool_view(page_table, positions, lengths, page_size):
+    """What a decode kernel walks of the paged pool: ``(table [b, n],
+    page_base [b, n])``. Past a slot's last page (the one holding
+    ``positions[i]``) the table repeats it: an unchanged block index is
+    not fetched again, and its base (``lengths[i]``) says "skip"."""
+    last = positions // page_size
+    j = jnp.arange(page_table.shape[1], dtype=jnp.int32)[None, :]
+    table = jnp.take_along_axis(
+        page_table, jnp.minimum(j, last[:, None]), axis=1)
+    base = jnp.where(j <= last[:, None], j * page_size, lengths[:, None])
+    return table, base
 
 
 def pages_needed(tokens, page_size):

@@ -43,7 +43,7 @@ import numpy as np
 from jax import lax
 
 from apex_tpu.serving import kv_cache
-from apex_tpu.serving.family import prefill_rows
+from apex_tpu.serving.family import prefill_rows, switch_on_rows
 from apex_tpu.transformer import moe as moe_mod
 
 
@@ -221,13 +221,15 @@ def _rotary(x, positions, base, rot):
 def moe_ffn(inner, lp, cfg, valid=None, moe_impl=None, interpret=None):
     """The expert layer of one block as both programs run it (scopes
     ``route`` and ``experts`` under the caller's ``layer/moe``): route
-    ``inner [T, hidden]`` over all experts, sum over the chosen experts
-    that are held. Returns ``(y [T, hidden], tokens per held expert)``.
+    ``inner [T, hidden]`` over all experts (a layer with no
+    ``router_bias`` takes the plain top-k of its scores), sum over the
+    chosen experts that are held. Returns ``(y [T, hidden], tokens per
+    held expert)``.
     Public because the benchmark's judge holds THIS function, on the
     engine's weights, to the plain reference's expert layer."""
     with jax.named_scope("route"):
         experts, weights = moe_mod.route_sigmoid_topk(
-            inner, lp["router"], lp["router_bias"],
+            inner, lp["router"], lp.get("router_bias"),
             cfg.num_experts_per_tok, cfg.norm_topk_prob,
             cfg.routed_scaling_factor or 1.0)
     with jax.named_scope("experts"):
@@ -274,10 +276,8 @@ def _layer(x, lp, cfg, window, is_moe, positions, valid, attn, moe_impl,
                 x = x + y
         else:
             with jax.named_scope("mlp"):
-                gate = _mm(inner, lp["w_gate"]).astype(jnp.float32)
-                up = _mm(inner, lp["w_up"]).astype(jnp.float32)
-                x = x + _mm((jax.nn.silu(gate) * up).astype(x.dtype),
-                            lp["w_down"])
+                x = x + moe_mod.gated_mlp(inner, lp["w_gate"], lp["w_up"],
+                                          lp["w_down"])
     return x, counts
 
 
@@ -375,11 +375,8 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
 
         return branch
 
-    rows = prefill_rows(S)
-    tokens = jnp.sum((seg > 0).astype(jnp.int32))
-    last, written = lax.switch(
-        sum((tokens > R).astype(jnp.int32) for R in rows[:-1]),
-        [trunk_on(R) for R in rows], ids, positions, seg)
+    last, written = switch_on_rows(prefill_rows(S), trunk_on, ids,
+                                   positions, seg)
 
     with jax.named_scope("embed"):
         g_page = jnp.take_along_axis(
@@ -432,14 +429,8 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
         g_off = jnp.where(active, positions % ps, 0)
         w_page, w_off = kv_cache.ring_write(
             jnp.arange(B, dtype=jnp.int32), positions, active, ring, ps)
-        # past a slot's last page the table repeats it: an unchanged
-        # block index is not fetched again, and its base says "skip"
-        n_tab = page_table.shape[1]
-        last = positions // ps
-        j = jnp.arange(n_tab, dtype=jnp.int32)[None, :]
-        g_table = jnp.take_along_axis(
-            page_table, jnp.minimum(j, last[:, None]), axis=1)
-        g_base = jnp.where(j <= last[:, None], j * ps, lengths[:, None])
+        g_table, g_base = kv_cache.pool_view(page_table, positions,
+                                             lengths, ps)
         w_table = kv_cache.ring_table(B, ring)
         w_base, w_start = kv_cache.ring_view(lengths, ring, ps,
                                              cfg.sliding_window)

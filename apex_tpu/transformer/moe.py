@@ -230,20 +230,40 @@ def route_sigmoid_topk(x, router_w, router_bias, k, norm_topk=True,
     """Sigmoid top-k routing with a selection-only correction bias
     (the ``noaux_tc`` method, one group).
 
-    x: [T, H]; router_w: [E, H]; router_bias: [E]. Scores are
+    x: [T, H]; router_w: [E, H]; router_bias: [E], or None for a plain
+    top-k of the scores (a model trained without the bias). Scores are
     ``sigmoid(x router_w^T)`` in float32; the top ``k`` of ``score +
     bias`` are chosen; the weights are the chosen SCORES (the bias
-    selects and never weighs), divided by their sum when ``norm_topk``.
-    Returns ``(experts [T, k] int32, weights [T, k] float32)``."""
+    selects and never weighs), divided by their sum when ``norm_topk``,
+    times ``scaling``. Returns ``(experts [T, k] int32, weights [T, k]
+    float32)``."""
     logits = lax.dot_general(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, experts = lax.top_k(scores + router_bias.astype(jnp.float32), k)
+    _, experts = lax.top_k(scores if router_bias is None else
+                           scores + router_bias.astype(jnp.float32), k)
     w = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), w * scaling
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """A plain SwiGLU ``(silu(x w_gate) * x w_up) w_down`` on ``x [T,
+    H]``: float32 accumulation, the gate's product in float32, results
+    in x's dtype. A dense layer's MLP, and the SHARED expert a serving
+    family adds to :func:`held_experts_mlp`'s partial sum: every chip of
+    an expert-parallel deployment computes it alike, so across the
+    shares it counts once."""
+    def mm(a, w):
+        return lax.dot_general(a, w, (((a.ndim - 1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32
+                               ).astype(a.dtype)
+
+    gate = mm(x, w_gate).astype(jnp.float32)
+    up = mm(x, w_up).astype(jnp.float32)
+    return mm((jax.nn.silu(gate) * up).astype(x.dtype), w_down)
 
 
 GMM_TILES = (128, 1024, 1024)   # rows, contraction, columns of a step

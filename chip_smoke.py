@@ -21,6 +21,11 @@ h=768, 12 heads, vocab 50304) with random weights from a seed:
   decode and prefill kernels, held experts through the grouped matmul,
   all compiled by Mosaic on the chip), greedy tokens judged against the
   plain float32 reference ``perf/references/mimo_v2.py``.
+* server, A.X-K1 family — the same again over the rehearse twin of
+  ``perf/configs/axk1-ep16.json`` (the latent cache, attention expanded
+  per head in prefill and absorbed over latent pages in decode, the
+  latent decode kernel compiled by Mosaic on the chip, a shared expert
+  beside the held ones), judged against ``perf/references/axk1.py``.
 * kernels  — each Pallas family the default path does not reach (rows
   attention fwd+bwd in both backward structures, with segment ids, with
   dropout; layer norm; scale-mask softmax; the fused LM head; paged
@@ -431,41 +436,56 @@ def run_server(size, log, seen, interpret):
     return recs
 
 
-# ------------------------------------------------------- server, MiMo family
+# ------------------------------------- server, the MiMo and A.X-K1 families
 
 MIMO = dict(slots=32, page_size=16, pages=96, max_seq=256, prefill_len=256,
             requests=6, prompt=(8, 120), new_tokens=(20, 40))
 MIMO_DRY = dict(MIMO, slots=4, pages=48, requests=3, new_tokens=(6, 12))
+# the rehearse twin of each configuration, its plain reference, and the
+# distinct Mosaic kernels its decode / prefill programs must reach on
+# the chip (a lowered module holds each distinct kernel once, however
+# many layers call it): MiMo an attention kernel of each layer kind and
+# the grouped matmul, A.X-K1 the latent (packed) kernel and the grouped
+# matmul
+FAMILY_TWINS = {
+    "mimo": ("mimo-v2.5-ep16.json", "mimo_v2.py", 3),
+    "axk1": ("axk1-ep16.json", "axk1.py", 2),
+}
 
 
-def run_mimo_server(log, interpret):
-    """The MiMo-V2 family through the same ``ServingEngine`` path at the
-    rehearse size of ``perf/configs/mimo-v2.5-ep16.json`` (published K
-    192 / V 128 head widths, 32 heads on 2 and 4 KV heads, window 16,
-    16 experts top-4 of which 4 are held): on the chip both attention
-    kernels and the grouped expert matmul are compiled by Mosaic; the
-    engine's greedy tokens are judged against the plain float32
-    reference ``perf/references/mimo_v2.py`` (within ``_TIE_STEPS``
-    bfloat16 steps of its best logit at every position)."""
+def run_family_server(log, interpret, name):
+    """A model family other than GPT-2 through the same ``ServingEngine``
+    path, at the rehearse size of its configuration under
+    ``perf/configs/`` (``mimo``: published K 192 / V 128 head widths, 32
+    heads on 2 and 4 KV heads, window 16, 16 experts top-4 of which 4
+    are held; ``axk1``: latent attention with a 96-wide cache row in its
+    expanded and absorbed forms, YaRN, 4 of 16 experts top-4 and a
+    shared one): on the chip its attention kernels and the grouped
+    expert matmul are compiled by Mosaic; the engine's greedy tokens are
+    judged against the family's plain float32 reference under
+    ``perf/references/`` (within ``_TIE_STEPS`` bfloat16 steps of its
+    best logit at every position)."""
     import importlib.util
 
-    from apex_tpu.serving import ServingEngine, mimo
+    from apex_tpu.serving import ServingEngine
+    from apex_tpu.serving import family as family_mod
     from apex_tpu.serving.scheduler import Request
 
-    with open(os.path.join(ROOT, "perf", "configs",
-                           "mimo-v2.5-ep16.json")) as f:
+    config_file, reference_file, kernels = FAMILY_TWINS[name]
+    with open(os.path.join(ROOT, "perf", "configs", config_file)) as f:
         config = json.load(f)
     config.update(config.pop("rehearse"))
     config["held_experts"] = tuple(config["held_experts"])
     spec = importlib.util.spec_from_file_location(
-        "mimo_v2_reference",
-        os.path.join(ROOT, "perf", "references", "mimo_v2.py"))
+        name + "_reference",
+        os.path.join(ROOT, "perf", "references", reference_file))
     reference = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(reference)
 
     size = MIMO_DRY if interpret else MIMO
-    cfg = mimo.MiMoConfig.from_dict(config)
-    params = mimo.init_params(cfg, jax.random.PRNGKey(0))
+    cfg = family_mod.config_from_dict(config)
+    params = family_mod.family_of(cfg).init_params(
+        cfg, jax.random.PRNGKey(0))
     t0 = time.perf_counter()
     engine = ServingEngine(
         cfg, params=params, num_slots=size["slots"],
@@ -490,7 +510,7 @@ def run_mimo_server(log, interpret):
         and engine.decode_cache_size() == 1
     steady_compiles = log.between(t_run, t_end)
     assert steady_compiles == 0, (
-        f"mimo: {steady_compiles} compilation(s) after warm-up")
+        f"{name}: {steady_compiles} compilation(s) after warm-up")
 
     worst, judged = 0.0, 0
     for r in requests:
@@ -503,25 +523,22 @@ def run_mimo_server(log, interpret):
             [reference.bf16_step(b) for b in best[at]])
         worst, judged = max(worst, float(steps.max())), judged + len(steps)
     assert worst <= _TIE_STEPS, (
-        f"mimo: an emitted token lies {worst:.2f} bf16 steps below the "
+        f"{name}: an emitted token lies {worst:.2f} bf16 steps below the "
         f"reference's best logit (allowed {_TIE_STEPS})")
 
     ran = dict(_server_lowerings(engine),
                decode_attn_impl=engine.decode_attn_impl)
     if not interpret:
-        # an attention kernel of each layer kind and the grouped matmul
-        # (a lowered module holds each distinct kernel once, however
-        # many layers call it)
         assert ran["decode_attn_impl"] == "pallas" \
-            and ran["decode_mosaic_calls"] >= 3 \
-            and ran["prefill_mosaic_calls"] >= 3, ran
+            and ran["decode_mosaic_calls"] >= kernels \
+            and ran["prefill_mosaic_calls"] >= kernels, ran
     rec = {"requests": len(requests), "judged_tokens": judged,
            "worst_gap_bf16_steps": round(worst, 3),
            "cold_s": round(cold_s, 2), "rounds": len(rounds),
            "decode_round_ms": round(statistics.median(
                s for s, pre, dec in rounds if dec and not pre) * 1e3, 2),
            "compiles_after_warmup": steady_compiles, "ran": ran}
-    _say(f"  [mimo] {rec['requests']} requests answered; {judged} tokens "
+    _say(f"  [{name}] {rec['requests']} requests answered; {judged} tokens "
          f"judged against the float32 reference, worst gap "
          f"{rec['worst_gap_bf16_steps']} bf16 steps (allowed "
          f"{_TIE_STEPS}); cold {rec['cold_s']} s; decode round "
@@ -817,7 +834,12 @@ def main(argv=None):
 
     _say("phase server, MiMo family: grouped-query decode over the paged "
          "pool and the window rings, held experts")
-    record["phases"]["server_mimo"] = run_mimo_server(log, interpret=dry)
+    record["phases"]["server_mimo"] = run_family_server(log, dry, "mimo")
+
+    _say("phase server, A.X-K1 family: latent attention (expanded in "
+         "prefill, absorbed over the latent pages in decode), a shared "
+         "expert beside the held ones")
+    record["phases"]["server_axk1"] = run_family_server(log, dry, "axk1")
 
     _say("phase kernels: Pallas families "
          + ("in interpret mode" if dry else "compiled by Mosaic")

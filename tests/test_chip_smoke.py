@@ -46,7 +46,7 @@ def test_dry_run_passes_is_marked_and_uses_the_placed_cache(tmp_path):
     record = json.load(open(tmp_path / "out" / "chip_smoke.json"))
     assert record["dry_run"] is True
     assert set(record["phases"]) == {"trainer", "server", "server_mimo",
-                                     "kernels"}
+                                     "server_axk1", "kernels"}
     assert record["phases"]["trainer"]["compiles_after_warmup"] == 0
     for engine in ("default", "jnp"):
         assert record["phases"]["server"][engine][

@@ -264,3 +264,131 @@ def test_mimo_decode_program_is_the_one_pr27_traced():
     assert dap.GROUPED_KERNEL_NAME in text
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "1aa4a6e0d71a3002c5be3234d73b9c8be4de6b8e6b16a80f40e64bd0baa447ae")
+
+
+# ---- the A.X-K1 family's kernels at the widths serve-axk1-longprompt runs ----
+
+LATENT = dict(hq=64, rank=512, live=576, ps=128, slots=32, pages=1408,
+              table=5120 // 128)
+
+
+@pytest.mark.parametrize("width,copied", [(640, False), (576, True)],
+                         ids=["padded-640", "unpadded-576"])
+def test_latent_decode_kernel_compiles_and_only_a_padded_row_lies_still(
+        one_chip, monkeypatch, width, copied):
+    """A row scatter then the latent kernel over 1,408 pages of 128
+    rows. With the row padded to 640 columns (whole lane tiles) the leaf
+    is aliased and nothing of its shape is copied. With the 576 live
+    columns alone the TPU keeps the array position-minor (576 is 4.5
+    lane tiles, 128 is one) and copies the whole leaf to row-major for
+    the scatter and the kernel and back: why ``kv_cache.
+    init_latent_cache`` pads."""
+    g = LATENT
+    assert dap.latent_supported(g["hq"], width, g["rank"], g["ps"],
+                                jnp.bfloat16) == (width % 128 == 0)
+    # Mosaic itself takes a 576-column page; the rule refuses it for
+    # what XLA does around the call
+    monkeypatch.setattr(dap, "latent_supported", lambda *a, **k: True)
+
+    def f(leaf, rows, page, off, q, table, lengths):
+        leaf = leaf.at[page, off, :].set(rows)
+        return leaf, dap.latent_decode_attention(
+            q, leaf, table, lengths, rank=g["rank"], sm_scale=0.13,
+            impl="pallas", interpret=False)
+
+    b = g["slots"]
+    compiled = jax.jit(f, donate_argnums=(0,)).lower(
+        _sds(one_chip, (g["pages"], g["ps"], width), jnp.bfloat16),
+        _sds(one_chip, (b, width), jnp.bfloat16),
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (b, g["hq"], width), jnp.bfloat16),
+        _sds(one_chip, (b, g["table"]), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and dap.LATENT_KERNEL_NAME in text
+    made = re.findall(rf"= bf16\[{g['pages']},{g['ps']},{width}\]\S* "
+                      rf"([\w-]+)\(", text)
+    assert ("copy" in made) == copied, sorted(set(made))
+    leaf_bytes = g["pages"] * g["ps"] * width * 2
+    if copied:
+        assert compiled.memory_analysis().temp_size_in_bytes >= leaf_bytes
+    else:
+        assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes / 8
+
+
+def test_packed_prefill_kernel_compiles_with_a_kv_head_a_query_head(one_chip):
+    """The expanded form of latent attention: as many KV heads as query
+    heads, K 192 and V 128 wide, a 4,096-row pack."""
+    from apex_tpu.ops import attention_pallas as ap
+    from apex_tpu.ops.attention import packed_gqa_attention
+
+    hq, dk, dv, S = 64, 192, 128, 4096
+    assert ap.packed_supported(S, dk, dv)
+
+    def f(q, k, v, seg):
+        return packed_gqa_attention(q, k, v, seg, sm_scale=0.13087,
+                                    impl="pallas", interpret=False)
+
+    text = jax.jit(f).lower(
+        _sds(one_chip, (hq, S, dk), jnp.bfloat16),
+        _sds(one_chip, (hq, S, dk), jnp.bfloat16),
+        _sds(one_chip, (hq, S, dv), jnp.bfloat16),
+        _sds(one_chip, (S,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and ap.PACKED_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_axk1_program_compiles_with_no_copy_of_a_latent_leaf(one_chip,
+                                                             program):
+    """Published widths, the cell's 32 slots and 1,408 pages of 128,
+    layer 0 (dense) and one expert layer with 12 held experts and the
+    shared one: the decode program reaches the latent kernel in both
+    layers and the three grouped matmuls, the prefill program (1,024
+    packed rows: four trunks behind one switch) the packed kernel; every
+    leaf is aliased input to output and no array of a leaf's shape is
+    copied."""
+    import functools
+
+    from apex_tpu.serving import axk1
+
+    g = LATENT
+    cfg = axk1.AXK1Config(vocab_size=2048, num_hidden_layers=2,
+                          held_experts=(0, 12))
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: axk1.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = sds(jax.eval_shape(
+        lambda: axk1.init_cache(cfg, g["pages"], g["ps"])))
+    assert cache["latent"][0].shape == (g["pages"], g["ps"], 640)
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    b, S = g["slots"], 1024
+    if program == "decode":
+        fn = functools.partial(axk1.decode_step, cfg=cfg,
+                               decode_impl="pallas", moe_impl="pallas",
+                               interpret=False)
+        args = (i32(b), i32(b), i32(b, g["table"]))
+    else:
+        fn = functools.partial(axk1.prefill, cfg=cfg, attn_impl="pallas",
+                               moe_impl="pallas", interpret=False)
+        args = (i32(S), i32(S), i32(S), i32(S), i32(b + 1, g["table"]),
+                i32(b))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    made = re.findall(rf"= bf16\[{g['pages']},{g['ps']},640\]\S* "
+                      rf"([\w-]+)\(", text)
+    assert made and not {"copy", "copy-done"} & set(made), sorted(set(made))
+    mem = compiled.memory_analysis()
+    leaf_bytes = g["pages"] * g["ps"] * 640 * 2
+    assert mem.alias_size_in_bytes >= 2 * leaf_bytes
+    if program == "decode":
+        assert text.count(dap.LATENT_KERNEL_NAME) >= 1
+        assert text.count("tpu_custom_call") >= 2 + 3
+        assert mem.temp_size_in_bytes < leaf_bytes / 4
+    else:
+        assert len(re.findall(r" conditional\(", text)) == 1
+        assert text.count("tpu_custom_call") >= 4 * (2 + 3)

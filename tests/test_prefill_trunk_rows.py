@@ -261,3 +261,46 @@ def test_mimo_prefill_program_is_the_one_before_prefill_rows_moved(
     text = re.sub(r"/[\w/\.\-]+\.py:\d+", "<src>", text)
     assert text.count("cond[") >= 1
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+# ----------------------------------- GPT-2's programs are the parent's text
+
+@pytest.mark.parametrize("program,digest", [
+    ("decode",
+     "7659bf9e24a7d074d40d072816331943ceed1cc4e5c172864cada41a527a5228"),
+    ("prefill",
+     "28cff0de0006b221c77a33e8e6ade14298f0968455d27e29885d0e31d401b3de"),
+])
+def test_gpt2_programs_are_the_ones_before_the_latent_cache(program, digest):
+    """PR 31 gave the cache a third kind of state (a latent row in a
+    lane-padded leaf, written through ``kv_cache.write_latent_rows``)
+    and the seam a third family; ``write_rows`` is the parent's, so both
+    of GPT-2's programs' jaxprs at
+    GPT-2 large's widths (two layers, the serve cell's geometry) are the
+    text they were at PR 30's commit. Source positions and addresses cut
+    out, as above. A PR that changes them on purpose records the new
+    digests."""
+    from apex_tpu.serving import kv_cache
+
+    h, b, pages, ps, d, rows, layers = 20, 16, 96, 128, 64, 1024, 2
+    cfg = TransformerConfig(
+        hidden_size=h * d, num_layers=layers, num_attention_heads=h,
+        vocab_size=50304, max_position_embeddings=1024, hidden_dropout=0.0,
+        attention_dropout=0.0, apply_query_key_layer_scaling=False,
+        bf16=True)
+    params = jax.eval_shape(lambda: smodel.init_gpt_params(cfg, 0))
+    cache = jax.eval_shape(
+        lambda: kv_cache.init_cache(layers, h, pages, ps, d))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode":
+        fn = functools.partial(smodel.decode_step, cfg=cfg,
+                               decode_impl="pallas", interpret=False)
+        args = (i32(b), i32(b), i32(b, 1024 // ps))
+    else:
+        fn = functools.partial(smodel.prefill, cfg=cfg)
+        args = (i32(rows), i32(rows), i32(rows), i32(rows),
+                i32(b + 1, 1024 // ps), i32(b))
+    text = str(jax.make_jaxpr(fn)(params, cache, *args))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    text = re.sub(r"/[\w/\.\-]+\.py:\d+", "<src>", text)
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
