@@ -410,8 +410,7 @@ def _write_rows(cache, i, page, off, row):
 def prefill(params, cache, ids, positions, seg, token_rows, page_table,
             last_idx, *, cfg, attn_impl=None, moe_impl=None, interpret=None):
     """One packed prompt batch through the trunk, filling the latent
-    cache (arguments as ``mimo.prefill``). Returns ``(cache, logits [G,
-    vocab] float32)``.
+    cache (arguments and returns as ``mimo.prefill``).
 
     The trunk runs on the first ``R`` of the ``S`` packed rows, ``R`` the
     smallest of ``family.prefill_rows`` that holds the batch's tokens
@@ -438,15 +437,16 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
 
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], ids, axis=0)
-            x, _ = _trunk(params, cfg, x, positions, seg > 0, attend_of,
-                          moe_impl, interpret)
+            x, counts = _trunk(params, cfg, x, positions, seg > 0,
+                               attend_of, moe_impl, interpret)
             return (jnp.take(x, jnp.minimum(last_idx, R - 1), axis=0),
-                    [jnp.pad(row, ((0, S - R), (0, 0))) for row in written])
+                    [jnp.pad(row, ((0, S - R), (0, 0))) for row in written],
+                    counts)
 
         return branch
 
-    last, written = switch_on_rows(prefill_rows(S), trunk_on, ids,
-                                   positions, seg)
+    last, written, counts = switch_on_rows(prefill_rows(S), trunk_on, ids,
+                                           positions, seg)
     with jax.named_scope("embed"):
         page = jnp.take_along_axis(
             jnp.take(page_table, token_rows, axis=0),
@@ -457,7 +457,7 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
             _write_rows(cache, i, page, off, row)
     with jax.named_scope("lm_head"):
         logits = _logits(last, params["head"])
-    return cache, logits
+    return cache, logits, {"expert_tokens": counts}
 
 
 # ---------------------------------------------------------------- decode

@@ -610,6 +610,9 @@ class ServingEngine:
         # attributes they and the family's own counts give the round
         self._decode_extras = None
         self._round_attrs = {}
+        # the same of the last prefill dispatch, beside the trunk rows
+        # it ran on (``prefill.fetch``'s attributes)
+        self._prefill_extras = (None, 0)
         # wall seconds inside swap-tier staging copies (device_get at
         # swap-out + scatter at swap-in) — the host-copy clock the
         # kv_restore crossover sweep measures against the replay
@@ -1126,8 +1129,8 @@ class ServingEngine:
                                    (len(fed) - 1) // self.page_size + 1):
                         if j < len(pages):
                             keep[pages[j]] = 0.0
-            sp.set(tokens=cursor, trunk_rows=next(
-                R for R in self._trunk_rows if cursor <= R))
+            trunk_rows = next(R for R in self._trunk_rows if cursor <= R)
+            sp.set(tokens=cursor, trunk_rows=trunk_rows)
         t0 = time.perf_counter()
         with spans.span("prefill.stage"):
             args = [self.params, self.cache, jnp.asarray(ids),
@@ -1138,16 +1141,18 @@ class ServingEngine:
                 args.append(jnp.asarray(keep))
 
         def call():
-            cache, logits = self._prefill_fn(*args)
+            out = self._prefill_fn(*args)
+            cache, logits = out[0], out[1]
             if self.recover:
                 # fetch INSIDE the watchdog: the sync on the gathered
                 # logits is where a wedged round actually blocks
                 logits = np.asarray(logits)
-            return cache, logits
+            return cache, logits, (out[2] if len(out) > 2 else None)
 
         # state adopted only after a clean return: a timed-out round's
         # late result can never overwrite the recovered engine
-        self.cache, logits = self._dispatch(phase, call)
+        self.cache, logits, extras = self._dispatch(phase, call)
+        self._prefill_extras = (extras, trunk_rows)
         return logits, t0
 
     def _replay_prefill(self, resumed):
@@ -1226,10 +1231,14 @@ class ServingEngine:
                     for si in batch]
             logits, t0 = self._packed_call(rows)
             self.prefill_batches += 1
-            with spans.span("prefill.fetch"):
+            with spans.span("prefill.fetch") as sp:
                 # rows r*W hold each request's last-prompt-token logits
                 next_toks = self._sample_first_tokens(
                     logits, np.arange(len(batch)) * self._gather_w, batch)
+                extras, trunk_rows = self._prefill_extras
+                if extras is not None and spans.enabled():
+                    sp.set(**self.family.prefill_attrs(
+                        self.cfg, extras, trunk_rows))
                 wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
             with spans.span("prefill.commit"):

@@ -15,7 +15,11 @@ A family is a :class:`Family` of plain functions:
   the engine's :class:`Geometry` (slots, pages, page size, cache dtype,
   ``kv_quant``);
 * ``prefill(params, cache, ids, positions, seg, token_rows, page_table,
-  last_idx, keep_scale, *, cfg, kernels)`` -> ``(cache, logits)``;
+  last_idx, keep_scale, *, cfg, kernels)`` -> ``(cache, logits[,
+  extras])``; ``extras`` as ``decode_step``'s, turned into attributes of
+  the ``prefill.fetch`` span by ``prefill_attrs(cfg, extras,
+  trunk_rows)`` (``trunk_rows``: the row count the dispatch's trunk ran
+  on);
 * ``decode_step(params, cache, tokens, lengths, page_table, *, cfg,
   qparams, kernels)`` -> ``(cache, next_tokens, logits[, extras])``;
   ``extras`` is a dict of small device arrays the round fetches with
@@ -45,8 +49,8 @@ A family is a :class:`Family` of plain functions:
 
 A family is handed values, never the engine: ``kernels`` is the
 :class:`Kernels` the caller asked for (``decode_impl``,
-``interpret``). The two ``*_attrs`` functions run
-only while the span recorder is on.
+``interpret``). The ``*_attrs`` functions run only while the span
+recorder is on.
 """
 
 import dataclasses
@@ -88,6 +92,7 @@ class Family:
     decode_block: Optional[Callable] = None
     quantize_decode_params: Optional[Callable] = None
     fetch_attrs: Optional[Callable] = None
+    prefill_attrs: Optional[Callable] = None
     round_attrs: Optional[Callable] = None
     refused: Tuple[str, ...] = ()
     # admission stops at one prefill dispatch's tokens a round, the rest
@@ -209,6 +214,23 @@ def _expert_attrs(extras):
                 expert_tokens_sum=int(counts.sum()))
 
 
+def _held_row_attrs(cfg, extras, trunk_rows):
+    """``prefill.fetch``'s account of the expert layers' row bound
+    (``transformer.moe.held_experts_mlp``), from a prefill program's
+    ``expert_tokens``: the largest count of held assignments a layer
+    saw, the rows the bound gives the dispatch's trunk (by the function
+    the program takes it from), and how many layers were over it and so
+    worked on every row."""
+    from apex_tpu.transformer.moe import held_row_bound
+
+    counts = np.asarray(extras["expert_tokens"])
+    held = counts.sum(axis=1)
+    rows = held_row_bound(trunk_rows, cfg.num_experts_per_tok,
+                          counts.shape[1], cfg.n_routed_experts)
+    return dict(held_rows_max=int(held.max(initial=0)), expert_rows=rows,
+                expert_rows_full=int((held > rows).sum()))
+
+
 def _mimo():
     import jax.numpy as jnp
 
@@ -249,6 +271,7 @@ def _mimo():
         prefill=prefill, prefill_rows=prefill_rows,
         decode_step=decode_step,
         decode_attention=decode_attention, fetch_attrs=_expert_attrs,
+        prefill_attrs=_held_row_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
         one_prefill_a_round=True, model_type="mimo_v2",
         config_from_dict=mimo.MiMoConfig.from_dict)
@@ -295,6 +318,7 @@ def _axk1():
         prefill=prefill, prefill_rows=prefill_rows,
         decode_step=decode_step,
         decode_attention=decode_attention, fetch_attrs=_expert_attrs,
+        prefill_attrs=_held_row_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
         one_prefill_a_round=True, model_type="axk1",
         config_from_dict=axk1.AXK1Config.from_dict)

@@ -236,7 +236,8 @@ def moe_ffn(inner, lp, cfg, valid=None, moe_impl=None, interpret=None):
         return moe_mod.held_experts_mlp(
             inner, experts, weights, lp["w_gate"], lp["w_up"],
             lp["w_down"], cfg.held_experts[0], valid=valid,
-            impl=moe_impl, interpret=interpret)
+            impl=moe_impl, interpret=interpret,
+            num_experts=lp["router"].shape[0])
 
 
 def _layer(x, lp, cfg, window, is_moe, positions, valid, attn, moe_impl,
@@ -325,7 +326,9 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
     tells a padding token from a slot's). Global layers write every
     token at its page-table page; window layers write each segment's
     last ``sliding_window`` tokens into its slot's ring and send the rest
-    to the null page. Returns ``(cache, logits [G, vocab] float32)``.
+    to the null page. Returns ``(cache, logits [G, vocab] float32,
+    {"expert_tokens": [moe layers, held] int32})``: how many of the
+    batch's assignments each held expert received.
 
     The trunk runs on the first ``R`` of the ``S`` packed rows, ``R`` the
     smallest of ``family.prefill_rows`` that holds the batch's tokens (a
@@ -366,17 +369,17 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
 
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], ids, axis=0)
-            x, _ = _trunk(params, cfg, x, positions, seg > 0, attn_of,
-                          moe_impl, interpret)
+            x, counts = _trunk(params, cfg, x, positions, seg > 0, attn_of,
+                               moe_impl, interpret)
             pad = lambda rows: jnp.pad(                      # noqa: E731
                 rows.reshape(R, -1), ((0, S - R), (0, 0)))
             return (jnp.take(x, jnp.minimum(last_idx, R - 1), axis=0),
-                    [(pad(k), pad(v)) for k, v in written])
+                    [(pad(k), pad(v)) for k, v in written], counts)
 
         return branch
 
-    last, written = switch_on_rows(prefill_rows(S), trunk_on, ids,
-                                   positions, seg)
+    last, written, counts = switch_on_rows(prefill_rows(S), trunk_on, ids,
+                                           positions, seg)
 
     with jax.named_scope("embed"):
         g_page = jnp.take_along_axis(
@@ -399,7 +402,7 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
         seen[window] += 1
     with jax.named_scope("lm_head"):
         logits = _logits(last, params["head"])
-    return cache, logits
+    return cache, logits, {"expert_tokens": counts}
 
 
 # ---------------------------------------------------------------- decode

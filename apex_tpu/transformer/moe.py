@@ -21,6 +21,7 @@ Parity is tested against a single-device reference on the CPU mesh
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import jax
@@ -316,44 +317,77 @@ def grouped_matmul(lhs, rhs, group_sizes, *, impl=None, interpret=None):
                    interpret=interpret).astype(lhs.dtype)
 
 
-def held_experts_mlp(x, experts, weights, w_gate, w_up, w_down, first,
-                     valid=None, impl=None, interpret=None):
-    """The partial MoE sum of the experts this chip holds; dropless.
+# the row bound engages where it spares this many 128-row tiles of the
+# T * k: each engaged layer adds a second branch to its program, ~0.2 s
+# of a warm set-up a layer a trunk on the v5e (PERF.md section 6), which
+# only a prefill trunk of ~2,048 rows or more (at top 8, 1/16 held) pays
+# back; under it the layer gains 0 - 1 ms a dispatch
+HELD_ROWS_SPARED_MIN = 96
 
-    x: [T, H]; experts/weights: [T, k] from :func:`route_sigmoid_topk`
-    (over ALL experts); w_gate, w_up: [count, H, F], w_down: [count, F,
-    H], the held experts ``first .. first + count - 1``; valid: [T]
-    bool or None: rows that are tokens (a packed batch's padding and an
-    empty decode lane are not: they get 0 and cost no expert a row).
-    Returns ``(y [T, H], tokens_per_held [count] int32)``: ``y[t]`` is the weighted
-    sum over t's chosen experts that are held (zero when none is) and
-    the count is how many assignments each held expert received.
 
-    The T x k assignments are sorted with the held experts' first and
-    in expert order, so the grouped matmul's groups start at row 0 and
-    no tile is visited past the last held row. Every assignment keeps
-    its row whoever holds its expert: no capacity, nothing dropped."""
-    T, H = x.shape
-    k = experts.shape[1]
-    count = w_gate.shape[0]
+def held_row_bound(T, k, count, num_experts):
+    """How many of the ``T * k`` assignment rows :func:`held_experts_mlp`
+    works on when the held experts are ``count`` of ``num_experts``:
+    twice the ``T * k * count / num_experts`` a uniform router sends
+    here, in whole row tiles of the grouped matmul. ``T * k`` itself
+    (every row: no bound) where the held experts are all there are
+    (``num_experts`` None or ``count``) and where the bound would spare
+    fewer than ``HELD_ROWS_SPARED_MIN`` row tiles (a decode round's few
+    lanes, a short prefill trunk). The engine calls it with a prefill
+    dispatch's trunk rows for the ``prefill.fetch`` span's
+    ``expert_rows``."""
+    total, tile = T * k, GMM_TILES[0]
+    if num_experts is None or count >= num_experts:
+        return total
+    rows = -(-2 * total * count // (num_experts * tile)) * tile
+    return rows if total - rows >= HELD_ROWS_SPARED_MIN * tile else total
+
+
+def _held_assignments(experts, first, count, valid):
+    """``(local [T*k], is_held [T*k])``: each assignment's index among
+    the held experts, and whether it has one (and its row is a token)."""
     local = experts.reshape(-1) - first                     # [T*k]
     is_held = (local >= 0) & (local < count)
     if valid is not None:
-        is_held = is_held & jnp.repeat(valid, k)
-    order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
-    tokens_per_held = jnp.sum(
+        is_held = is_held & jnp.repeat(valid, experts.shape[1])
+    return local, is_held
+
+
+def _tokens_per_held(local, is_held, count):
+    """How many assignments each of the ``count`` held experts has."""
+    return jnp.sum(
         jax.nn.one_hot(jnp.where(is_held, local, count), count + 1,
                        dtype=jnp.int32), axis=0)[:count]
-    rows = jnp.take(x, order // k, axis=0)                  # [T*k, H]
+
+
+def _grouped_mlp(rows, w_gate, w_up, w_down, group_sizes, impl, interpret):
+    """The experts' SwiGLU over rows grouped by expert from row 0 (scope
+    ``gmm``): three grouped matmuls, the gate's product in float32. Rows
+    past the last group are undefined, as :func:`grouped_matmul`'s."""
     with jax.named_scope("gmm"):
-        gate = grouped_matmul(rows, w_gate, tokens_per_held, impl=impl,
+        gate = grouped_matmul(rows, w_gate, group_sizes, impl=impl,
                               interpret=interpret)
-        up = grouped_matmul(rows, w_up, tokens_per_held, impl=impl,
+        up = grouped_matmul(rows, w_up, group_sizes, impl=impl,
                             interpret=interpret)
         inner = (jax.nn.silu(gate.astype(jnp.float32))
-                 * up.astype(jnp.float32)).astype(x.dtype)
-        out = grouped_matmul(inner, w_down, tokens_per_held, impl=impl,
-                             interpret=interpret)
+                 * up.astype(jnp.float32)).astype(rows.dtype)
+        return grouped_matmul(inner, w_down, group_sizes, impl=impl,
+                              interpret=interpret)
+
+
+def _every_row(x, weights, local, is_held, w_gate, w_up, w_down, impl,
+               interpret):
+    """:func:`held_experts_mlp` over all ``T * k`` assignment rows,
+    whoever holds their expert: ``(y, tokens_per_held)`` for any
+    routing."""
+    T, H = x.shape
+    k = weights.shape[1]
+    count = w_gate.shape[0]
+    order = jnp.argsort(jnp.where(is_held, local, count), stable=True)
+    tokens_per_held = _tokens_per_held(local, is_held, count)
+    rows = jnp.take(x, order // k, axis=0)                  # [T*k, H]
+    out = _grouped_mlp(rows, w_gate, w_up, w_down, tokens_per_held, impl,
+                       interpret)
     # each assignment's row back beside its token (a gather through the
     # inverse permutation), rows of absent experts selected away
     inverse = jnp.argsort(order)
@@ -363,3 +397,106 @@ def held_experts_mlp(x, experts, weights, w_gate, w_up, w_down, first,
                           back.astype(jnp.float32), 0.0)
                 * w[..., None], axis=1)
     return y.astype(x.dtype), tokens_per_held
+
+
+def _held_rows(x, weights, local, is_held, tokens_per_held, w_gate, w_up,
+               w_down, rows, impl, interpret):
+    """``y`` of :func:`held_experts_mlp` from the first ``rows`` sorted
+    assignments alone, which hold every held one (the caller has seen
+    ``sum(tokens_per_held) <= rows``): their rows gathered, the grouped
+    matmuls over them, each output row times its own routing weight and
+    summed into its token, both in float32 (a scatter-add over rows
+    sorted by token: the inverse permutation and the ``[T*k, H]`` gather
+    back of :func:`_every_row` go)."""
+    T = x.shape[0]
+    k = weights.shape[1]
+    count = w_gate.shape[0]
+    order = jnp.argsort(jnp.where(is_held, local, count),
+                        stable=True)[:rows]
+    token = order // k
+    out = _grouped_mlp(jnp.take(x, token, axis=0), w_gate, w_up, w_down,
+                       tokens_per_held, impl, interpret)      # [rows, H]
+    # rows at or past the last held one are undefined (and their order
+    # entries some unheld assignment's): selected away, sent to a token
+    # past the end, where they sort last and the sum drops them. The
+    # weighted float32 rows are brought into token order BEFORE the
+    # scatter-add, as an array of their own: on the v5e XLA sorts an
+    # unsorted scatter's rows itself at 2.5 x the time, and a scatter
+    # whose updates it computes in place (the product fused into it)
+    # runs at 3 x (PERF.md section 6)
+    live = jnp.arange(rows) < jnp.sum(tokens_per_held)
+    weighted = jnp.where(
+        live[:, None],
+        out.astype(jnp.float32)
+        * jnp.take(weights.reshape(-1), order)[:, None], 0.0)
+    token = jnp.where(live, token, T)
+    by_token = jnp.argsort(token)
+    y = jax.ops.segment_sum(
+        jnp.take(weighted, by_token, axis=0), jnp.take(token, by_token),
+        num_segments=T, indices_are_sorted=True)
+    return y.astype(x.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("first", "rows", "impl",
+                                             "interpret"))
+def _held_rows_or_every_row(x, experts, weights, valid, w_gate, w_up,
+                            w_down, *, first, rows, impl, interpret):
+    """:func:`held_experts_mlp` where its row bound engages: ONE
+    ``lax.cond`` on the held assignments' count, which carries rows,
+    routing and the expert matrices in and ``y`` out. A ``jit`` of its
+    own so that a program traces it once for all its layers of one
+    shape."""
+    count = w_gate.shape[0]
+    local, is_held = _held_assignments(experts, first, count, valid)
+    tokens_per_held = _tokens_per_held(local, is_held, count)
+    y = lax.cond(
+        jnp.sum(tokens_per_held) <= rows,
+        lambda: _held_rows(x, weights, local, is_held, tokens_per_held,
+                           w_gate, w_up, w_down, rows, impl, interpret),
+        lambda: _every_row(x, weights, local, is_held, w_gate, w_up, w_down,
+                           impl, interpret)[0])
+    return y, tokens_per_held
+
+
+def held_experts_mlp(x, experts, weights, w_gate, w_up, w_down, first,
+                     valid=None, impl=None, interpret=None,
+                     num_experts=None):
+    """The partial MoE sum of the experts this chip holds; dropless.
+
+    x: [T, H]; experts/weights: [T, k] from :func:`route_sigmoid_topk`
+    (over ALL experts); w_gate, w_up: [count, H, F], w_down: [count, F,
+    H], the held experts ``first .. first + count - 1``; valid: [T]
+    bool or None: rows that are tokens (a packed batch's padding and an
+    empty decode lane are not: they get 0 and cost no expert a row);
+    num_experts: the router's width (None: the held experts are all).
+    Returns ``(y [T, H], tokens_per_held [count] int32)``: ``y[t]`` is the weighted
+    sum over t's chosen experts that are held (zero when none is) and
+    the count is how many assignments each held expert received.
+
+    The T x k assignments are sorted with the held experts' first and
+    in expert order, so the grouped matmul's groups start at row 0 and
+    no tile is visited past the last held row. Every assignment keeps
+    its row whoever holds its expert: no capacity, nothing dropped.
+
+    The work around the grouped matmul follows the rows that are held.
+    Where :func:`held_row_bound` gives fewer rows than ``T * k`` (a
+    long prefill trunk under a cut layer: twice a uniform router's share),
+    ONE ``lax.cond`` on the held count, which is on the device before
+    anything is gathered, picks between two forms of the same sum: at or
+    under the bound only the first ``rows`` sorted assignments are
+    gathered, multiplied and summed into their tokens; over it (a router
+    that sends this chip more than twice its share) all ``T * k`` are, as
+    everywhere the bound does not engage. Either way the layer is
+    dropless and the float32 sum holds the same terms; only their order
+    within a token may differ."""
+    T = x.shape[0]
+    k = experts.shape[1]
+    count = w_gate.shape[0]
+    rows = held_row_bound(T, k, count, num_experts)
+    if rows < T * k:
+        return _held_rows_or_every_row(
+            x, experts, weights, valid, w_gate, w_up, w_down, first=first,
+            rows=rows, impl=impl, interpret=interpret)
+    local, is_held = _held_assignments(experts, first, count, valid)
+    return _every_row(x, weights, local, is_held, w_gate, w_up, w_down,
+                      impl, interpret)

@@ -102,3 +102,31 @@ def compare(tap, requests, ref_logits_of):
             errors.append(float(np.abs(
                 tap.rows[(req.rid, pos)] - ref[pos]).max()))
     return np.asarray(errors), scale
+
+
+def long_prompt_run(cfg, params, ref_logits_of):
+    """A 300-token prompt, then a 20-token one, through an engine whose
+    prefill packs 512 rows: the first dispatch's trunk runs on 512 rows
+    (x top 4 = 2,048 assignment rows with 4 of 16 experts held: the
+    expert layers' row bound, 1,024, engages), the second on 64 (256
+    rows: it does not). Returns ``(errors against the reference, the two
+    dispatches' prefill.fetch attributes)``."""
+    import time
+
+    from apex_tpu.serving import ServingEngine
+    from apex_tpu.serving.scheduler import Request
+    from apex_tpu.telemetry import spans
+
+    engine = ServingEngine(cfg, params=params, num_slots=2, page_size=4,
+                           num_pages=256, max_seq=512, prefill_len=512)
+    tap = LogitsTap(engine)
+    rs = np.random.RandomState(11)
+    requests = [Request(rid=i, prompt=rs.randint(0, 512, n).tolist(),
+                        max_new_tokens=3) for i, n in enumerate((300, 20))]
+    t0 = time.perf_counter()
+    for request in requests:
+        serve(engine, [request])
+    assert (tap._prefill._cache_size(), tap._decode._cache_size()) == (1, 1)
+    fetches = [r.attrs for r in spans.snapshot(t0)
+               if r.name == "prefill.fetch"]
+    return compare(tap, requests, ref_logits_of)[0], fetches

@@ -114,6 +114,27 @@ def test_engine_spans_carry_the_expert_and_latent_counts(bf16_run):
     assert dispatch and dispatch[-1].attrs["attn_impl"] == "jnp"   # the CPU
 
 
+def test_prefill_fetch_span_carries_the_row_bound_account(monkeypatch):
+    """The expert layers' row bound (``moe.held_row_bound``) engages in
+    a 512-row trunk and not in a 64-row one; either way the float32
+    program stays on the reference, and ``prefill.fetch`` says what the
+    bound was, how near the held assignments came, and that no layer
+    took the form over every row (the toy routes uniformly)."""
+    monkeypatch.setattr(moe, "HELD_ROWS_SPARED_MIN", 8)   # toy sizes
+    cfg = A.toy_config(cache_dtype="float32")
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    A.toy_params(cfg))
+    errors, (long, short) = T.long_prompt_run(
+        cfg, params, lambda seq: ref.logits(cfg.to_dict(), params, seq))
+    assert len(errors) == 2 * 3 and errors.max() <= F32_TOL, errors.max()
+    assert long["expert_rows"] == moe.held_row_bound(512, 4, 4, 16) == 1024
+    assert short["expert_rows"] == 64 * 4              # every row: no bound
+    for attrs, tokens in ((long, 300), (short, 20)):
+        assert 0 < attrs["held_rows_max"] <= tokens * 4
+        assert attrs["expert_rows_full"] == 0
+    assert long["held_rows_max"] < long["expert_rows"]
+
+
 # ------------------------------------------------------ negative controls
 
 CONTROLS = {

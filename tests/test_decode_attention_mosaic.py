@@ -343,8 +343,9 @@ def test_axk1_program_compiles_with_no_copy_of_a_latent_leaf(one_chip,
     """Published widths, the cell's 32 slots and 1,408 pages of 128,
     layer 0 (dense) and one expert layer with 12 held experts and the
     shared one: the decode program reaches the latent kernel in both
-    layers and the three grouped matmuls, the prefill program (1,024
-    packed rows: four trunks behind one switch) the packed kernel; every
+    layers and the three grouped matmuls (and holds no conditional: its
+    expert layer takes every row), the prefill program (4,096 packed
+    rows: four trunks behind one switch) the packed kernel; every
     leaf is aliased input to output and no array of a leaf's shape is
     copied."""
     import functools
@@ -365,7 +366,7 @@ def test_axk1_program_compiles_with_no_copy_of_a_latent_leaf(one_chip,
         lambda: axk1.init_cache(cfg, g["pages"], g["ps"])))
     assert cache["latent"][0].shape == (g["pages"], g["ps"], 640)
     i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
-    b, S = g["slots"], 1024
+    b, S = g["slots"], 4096      # the cell's packed rows
     if program == "decode":
         fn = functools.partial(axk1.decode_step, cfg=cfg,
                                decode_impl="pallas", moe_impl="pallas",
@@ -389,6 +390,15 @@ def test_axk1_program_compiles_with_no_copy_of_a_latent_leaf(one_chip,
         assert text.count(dap.LATENT_KERNEL_NAME) >= 1
         assert text.count("tpu_custom_call") >= 2 + 3
         assert mem.temp_size_in_bytes < leaf_bytes / 4
+        assert " conditional(" not in text
     else:
-        assert len(re.findall(r" conditional\(", text)) == 1
+        # the trunk's switch, and in each trunk whose expert layer's row
+        # bound engages (2,048 and 4,096 rows) that layer's ONE cond
+        from apex_tpu.serving.family import prefill_rows
+        from apex_tpu.transformer.moe import held_row_bound
+
+        bounded = sum(held_row_bound(R, 8, 12, 192) < R * 8
+                      for R in prefill_rows(S))
+        assert bounded == 2
+        assert len(re.findall(r" conditional\(", text)) == 1 + bounded
         assert text.count("tpu_custom_call") >= 4 * (2 + 3)

@@ -106,6 +106,26 @@ def test_engine_spans_carry_the_expert_and_cache_counts(bf16_run):
     assert a["global_pages_live"] >= a["window_pages"] >= 2
 
 
+def test_prefill_fetch_span_carries_the_row_bound_account(bound_at_toy_sizes):
+    """The expert layers' row bound (``moe.held_row_bound``) engages in
+    a 512-row trunk and not in a 64-row one; either way the float32
+    program stays on the reference, and ``prefill.fetch`` says what the
+    bound was, how near the held assignments came, and that no layer
+    took the form over every row (the toy routes uniformly)."""
+    cfg = T.toy_config(cache_dtype="float32")
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    T.toy_params(cfg))
+    errors, (long, short) = T.long_prompt_run(
+        cfg, params, lambda seq: ref.logits(cfg.to_dict(), params, seq))
+    assert len(errors) == 2 * 3 and errors.max() <= F32_TOL, errors.max()
+    assert long["expert_rows"] == moe.held_row_bound(512, 4, 4, 16) == 1024
+    assert short["expert_rows"] == 64 * 4              # every row: no bound
+    for attrs, tokens in ((long, 300), (short, 20)):
+        assert 0 < attrs["held_rows_max"] <= tokens * 4
+        assert attrs["expert_rows_full"] == 0
+    assert long["held_rows_max"] < long["expert_rows"]
+
+
 CONTROLS = {
     "sink_dropped": (dict(add_swa_attention_sink_bias=False), None),
     "window_7_instead_of_8": (dict(sliding_window=7), None),
@@ -243,6 +263,164 @@ def test_grouped_matmul_kernel_matches_ragged_dot_in_interpret_mode():
                                            interpret=True)
     np.testing.assert_array_equal(counts, counts2)
     np.testing.assert_allclose(kernel, plain, atol=1e-6)
+
+
+# -------------------------------------------------- the held rows' bound
+#
+# 512 tokens x top 4 of 16 experts with 4 held (4..7): 2,048 assignment
+# rows, of which a uniform router sends 512 here; the bound is twice
+# that, 1,024 rows, and spares 8 row tiles, so the layer's cond engages.
+
+BOUND = dict(T=512, k=4, E=16, first=4, count=4, rows=1024)
+
+
+@pytest.fixture
+def bound_at_toy_sizes(monkeypatch):
+    """The program engages the bound where it spares 96 row tiles (a
+    2,048-row trunk at top 8); the toy layers spare 8."""
+    monkeypatch.setattr(moe, "HELD_ROWS_SPARED_MIN", 8)
+
+
+def _bound_case(routing, dtype=jnp.float32, seed=5):
+    """``(x, experts, weights, w_gate, w_up, w_down, valid)`` with
+    ``routing``'s number of held assignments: ``uniform`` ~512,
+    ``skewed`` 1,536 (over the bound), ``exactly`` 1,024 (the bound),
+    ``none`` 0, ``padding`` 3 held a token as ``skewed`` but only 340
+    rows are tokens (1,020: padding must not count)."""
+    b = BOUND
+    rs = np.random.RandomState(seed)
+    held = np.arange(b["first"], b["first"] + b["count"])
+    others = np.setdiff1d(np.arange(b["E"]), held)
+    n_held = {"uniform": None, "skewed": 3, "exactly": 2, "none": 0,
+              "padding": 3}[routing]
+    experts = np.empty((b["T"], b["k"]), np.int32)
+    for t in range(b["T"]):
+        if n_held is None:
+            experts[t] = rs.permutation(b["E"])[:b["k"]]
+        else:
+            experts[t] = rs.permutation(np.concatenate(
+                [rs.permutation(held)[:n_held],
+                 rs.permutation(others)[:b["k"] - n_held]]))
+    weights = rs.uniform(0.1, 1.0, (b["T"], b["k"])).astype(np.float32)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    H, F = 128, 64
+    x = jax.random.normal(keys[0], (b["T"], H), dtype)
+    mats = [(jax.random.normal(key, shape) * 0.05).astype(dtype)
+            for key, shape in zip(keys[1:], [(b["count"], H, F),
+                                             (b["count"], H, F),
+                                             (b["count"], F, H)])]
+    valid = jnp.arange(b["T"]) < 340 if routing == "padding" else None
+    return (x, jnp.asarray(experts), jnp.asarray(weights), *mats, valid)
+
+
+def _both_forms(case, **kernel):
+    """The layer with every row (no router width given: today's path)
+    and with the bound engaged."""
+    *args, valid = case
+    kw = dict(valid=valid, **kernel)
+    return (moe.held_experts_mlp(*args, BOUND["first"], **kw),
+            moe.held_experts_mlp(*args, BOUND["first"], **kw,
+                                 num_experts=BOUND["E"]))
+
+
+@pytest.mark.parametrize("T_,k,count,E,rows", [
+    (4096, 8, 12, 192, 4096), (2048, 8, 12, 192, 2048),
+    (2048, 8, 16, 256, 2048),                  # long prefill trunks: T*k / 8
+    (1024, 8, 12, 192, 8192), (512, 8, 12, 192, 4096),
+    (256, 8, 16, 256, 2048),                   # spare under 96 tiles: every row
+    (64, 8, 12, 192, 512), (32, 8, 12, 192, 256),
+    (64, 8, 16, 256, 512),                     # decode lanes: every row
+    (4096, 8, 192, 192, 32768), (4096, 8, 16, None, 32768),   # uncut
+    (4096, 8, 100, 192, 32768),                # twice the share is all
+    (8192, 4, 4, 16, 16384), (8200, 4, 3, 16, 12416),   # whole row tiles
+])
+def test_held_row_bound_is_twice_the_uniform_share_in_row_tiles(
+        T_, k, count, E, rows):
+    assert moe.held_row_bound(T_, k, count, E) == rows
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "pallas"])
+@pytest.mark.parametrize("routing", ["uniform", "skewed", "exactly", "none",
+                                     "padding"])
+def test_bounded_layer_equals_the_layer_over_every_row(bound_at_toy_sizes, routing, impl):
+    case = _bound_case(routing)
+    (y_all, n_all), (y, n) = _both_forms(case, impl=impl, interpret=True)
+    held = {"skewed": 1536, "exactly": BOUND["rows"], "none": 0,
+            "padding": 3 * 340}.get(routing)
+    if held is not None:
+        assert int(n_all.sum()) == held
+    else:
+        assert 400 < int(n_all.sum()) < 640
+    np.testing.assert_array_equal(n, n_all)
+    assert routing == "none" or float(jnp.abs(y_all).max()) > 0.01
+    # float32: the same terms in another order
+    np.testing.assert_allclose(y, y_all, atol=1e-6)
+    if routing == "padding":
+        assert not np.asarray(y[340:]).any()
+    if routing == "none":
+        assert not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "pallas"])
+def test_bounded_layer_in_bfloat16_equals_the_layer_over_every_row(bound_at_toy_sizes, impl):
+    case = _bound_case("uniform", jnp.bfloat16)
+    (y_all, n_all), (y, n) = _both_forms(case, impl=impl, interpret=True)
+    np.testing.assert_array_equal(n, n_all)
+    assert y.dtype == jnp.bfloat16 and float(jnp.abs(y_all).max()) > 0.01
+    # both round ONE float32 sum of the same bfloat16 rows to bfloat16
+    np.testing.assert_allclose(y.astype(jnp.float32),
+                               y_all.astype(jnp.float32), atol=2 ** -9)
+
+
+@pytest.mark.parametrize("routing,poisoned", [
+    ("uniform", "_every_row"), ("exactly", "_every_row"),
+    ("none", "_every_row"), ("padding", "_every_row"),
+    ("skewed", "_held_rows")])
+def test_the_count_on_the_device_picks_the_branch(bound_at_toy_sizes,
+                                                  monkeypatch, routing,
+                                                  poisoned):
+    """The branch that must NOT run is made to answer NaN: at or under
+    the bound (padding not counted) the held rows' form runs, over it
+    the form over every row, so nothing is ever dropped."""
+    *args, valid = _bound_case(routing)
+    want, _ = moe.held_experts_mlp(*args, BOUND["first"], valid=valid)
+    real = getattr(moe, poisoned)
+
+    def nan(*a, **kw):
+        out = real(*a, **kw)
+        if isinstance(out, tuple):
+            return jnp.full_like(out[0], jnp.nan), out[1]
+        return jnp.full_like(out, jnp.nan)
+
+    monkeypatch.setattr(moe, poisoned, nan)
+    moe._held_rows_or_every_row.clear_cache()
+    try:
+        got, _ = moe.held_experts_mlp(*args, BOUND["first"], valid=valid,
+                                      num_experts=BOUND["E"])
+    finally:
+        monkeypatch.undo()
+        moe._held_rows_or_every_row.clear_cache()
+    np.testing.assert_allclose(got, want, atol=1e-6)
+
+
+@pytest.mark.parametrize("T_,count,E,conds", [
+    (32, 16, 256, 0), (64, 16, 256, 0),        # MiMo's decode: T*k 256, 512
+    (32, 12, 192, 0), (64, 12, 192, 0),        # A.X-K1's
+    (2048, 16, 16, 0), (2048, 16, None, 0),    # an uncut layer
+    (1024, 16, 256, 0), (512, 12, 192, 0),     # short prefill trunks
+    (2048, 16, 256, 1), (4096, 12, 192, 1),    # long ones
+])
+def test_only_a_long_cut_prefill_layer_holds_a_cond(T_, count, E, conds):
+    k, H, F = 8, 32, 16
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda x, e, w, g, u, d: moe.held_experts_mlp(
+            x, e, w, g, u, d, 0, impl="ragged_dot", num_experts=E))(
+        f32(T_, H), jax.ShapeDtypeStruct((T_, k), jnp.int32), f32(T_, k),
+        f32(count, H, F), f32(count, H, F), f32(count, F, H)))
+    assert text.count(" cond[") == conds
+    # ... inside a jit of its own, traced once for a program's layers
+    assert ("name=_held_rows_or_every_row" in text) == bool(conds)
 
 
 # ------------------------------------------------------------ the kernels

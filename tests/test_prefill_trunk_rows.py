@@ -224,22 +224,25 @@ def test_the_program_holds_one_switch_whose_branches_run_fewer_rows(params):
     assert outside == [len(args[-1])]          # the lm_head, on [G] rows
 
 
-# ------------------------------------- MiMo's program is the parent's text
+# ----------------------------------------- MiMo's prefill program, pinned
 
 @pytest.mark.parametrize("rows,kernels,digest", [
     (2048, dict(attn_impl="pallas", moe_impl="pallas", interpret=False),
-     "ba062c8f9a90da6890e8f8287b6a3f1c3428deecb8dea8b03ad99dcc8d42585f"),
+     "9c782d2c6232c3f0625c2023c7870791c5476c1f2aa9202b1658ee033e2f8ca7"),
     (128, {},
-     "d150a2165e5c95708268fecf123434de89cb98fe543134461bc8405bdd911733"),
+     "2fbd87d8ed8be3320938020b699d0a5829f09536f7272d3ed33241d70ad15a17"),
 ], ids=["cell-2048", "rehearsal-128"])
-def test_mimo_prefill_program_is_the_one_before_prefill_rows_moved(
+def test_mimo_prefill_program_is_the_one_pr32_measured(
         rows, kernels, digest):
-    """``prefill_rows`` moved from ``serving/mimo.py`` to the family seam
-    (PR 30); the MiMo prefill's jaxpr at published widths (one layer of
-    each kind, 16 held experts) is the text it was at PR 29's commit, at
-    the cell's 2,048 packed rows with the kernels the chip takes and at
-    the rehearsal's 128 with the forms the CPU takes. Source positions
-    and object addresses are cut out, as in
+    """The MiMo prefill's jaxpr at published widths (one layer of each
+    kind, 16 held experts), at the cell's 2,048 packed rows with the
+    kernels the chip takes and at the rehearsal's 128 with the forms the
+    CPU takes, is the text PR 32 measured: PR 29's (which ``prefill_rows``
+    moving to the family seam in PR 30 left alone) plus the expert
+    counts it now returns and, in the 2,048-row trunk, the expert
+    layer's ONE cond on the held assignments (``moe.held_row_bound``;
+    the shorter trunks and the 128-row program hold none). Source
+    positions and object addresses are cut out, as in
     ``test_decode_attention_mosaic``'s digest of the decode program. A
     PR that changes MiMo's prefill on purpose records the new digests."""
     from apex_tpu.serving import mimo
