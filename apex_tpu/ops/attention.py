@@ -251,10 +251,11 @@ def masked_softmax(s, masked, sink=None):
     return e / jnp.where(tot > 0, tot, 1.0)
 
 
-def _packed_gqa_dense(q, k, v, seg, sm_scale, window, sink):
+def _packed_gqa_dense(q, k, v, seg, sm_scale, window, sink, selected=None):
     """The jnp form of the packed grouped-query prefill: float32
     softmax over causal, same-segment (and, with ``window``, the last
-    ``window`` positions) keys, the sink logit in the denominator."""
+    ``window`` positions; with ``selected``, the chosen) keys, the sink
+    logit in the denominator."""
     hq, S, dk = q.shape
     n_kv = k.shape[0]
     qg = q.reshape(n_kv, hq // n_kv, S, dk)
@@ -264,6 +265,8 @@ def _packed_gqa_dense(q, k, v, seg, sm_scale, window, sink):
     masked = (col > row) | (seg[:, None] != seg[None, :])
     if window is not None:
         masked = masked | (row - col >= window)
+    if selected is not None:
+        masked = masked | (selected == 0)
     if sink is not None:
         sink = sink.astype(jnp.float32).reshape(n_kv, hq // n_kv, 1, 1)
     p = masked_softmax(s, masked, sink).astype(v.dtype)
@@ -326,3 +329,127 @@ def packed_gqa_attention(q, k, v, segment_ids, *, sm_scale=None,
         interpret = _on_cpu()
     return _packed_gqa(q, k, v, segment_ids.astype(jnp.int32),
                        float(sm_scale), window, sink, impl, bool(interpret))
+
+
+# ------------------------- attention over SELECTED keys (packed prefill)
+#
+# A layer with a learned sparse selection (serving/dots3.py) scores every
+# (query, key) pair with a small indexer, keeps each query's ``k`` best
+# keys, and attends over those alone. Three steps, each with a jnp form:
+# the scores ``[S, S]`` (a kernel that never holds the per-head scores),
+# the selection (the k-th largest score of every row, found exactly by
+# bisection on the scores' bit patterns: 32 passes of compare-and-count,
+# no sort; ties at it broken as a sort breaks them), and the packed kernel
+# under the selection's mask.
+
+def _index_scores_dense(q_idx, w, k_idx, seg):
+    """The jnp form of ``attention_pallas.packed_index_scores_pallas``,
+    a head at a time (``[S, S]`` float32 is all that is ever held)."""
+    S = q_idx.shape[1]
+
+    def head(acc, qw):
+        q, wh = qw
+        s = jnp.einsum("qd,kd->qk", q, k_idx,
+                       preferred_element_type=jnp.float32)
+        return acc + jnp.maximum(s, 0.0) * wh[:, None], None
+
+    acc, _ = jax.lax.scan(head, jnp.zeros((S, S), jnp.float32),
+                          (q_idx, w.astype(jnp.float32).T))
+    row, col = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    masked = (col > row) | (seg[:, None] != seg[None, :])
+    return jnp.where(masked, jnp.float32(-1e30), acc)
+
+
+def packed_index_scores(q_idx, w, k_idx, segment_ids, *, impl=None,
+                        interpret=None):
+    """The indexer's scores over ONE packed sequence: ``q_idx [hi, S,
+    di]``, ``w [S, hi]`` (float32 head weights, the scale folded in),
+    ``k_idx [S, di]`` -> ``[S, S]`` float32 ``sum_h w[t, h] relu(q_idx[h,
+    t] . k_idx[j])``, -1e30 where ``j > t`` or the segments differ.
+    ``impl`` / ``interpret`` as :func:`packed_gqa_attention`."""
+    from apex_tpu.ops import attention_pallas as ap
+
+    if impl is not None and impl not in ("jnp", "pallas"):
+        raise ValueError(f"unknown packed-attention impl {impl!r}")
+    hi, S, di = q_idx.shape
+    if impl is None:
+        impl = "pallas" if _tpu_available() \
+            and ap.index_scores_supported(S, hi, di) else "jnp"
+    seg = segment_ids.astype(jnp.int32)
+    if impl == "pallas":
+        if interpret is None:
+            interpret = _on_cpu()
+        return ap.packed_index_scores_pallas(q_idx, w, k_idx, seg,
+                                             interpret=bool(interpret))
+    return _index_scores_dense(q_idx, w, k_idx, seg)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def kth_largest_bits(bits, k):
+    """The ``k``-th largest of every row of ``bits [..., n]`` uint32,
+    exactly: its bits are settled from the top, each by one count of the
+    row's entries at or over the candidate."""
+    def settle(i, found):
+        cand = found | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = jnp.sum((bits >= cand[..., None]).astype(jnp.int32), axis=-1)
+        return jnp.where(count >= k, cand, found)
+
+    return jax.lax.fori_loop(0, 32, settle,
+                             jnp.zeros(bits.shape[:-1], jnp.uint32))
+
+
+def select_keys(scores, k):
+    """``[S, S]`` int8: 1 where a key is among its query's ``k`` largest
+    ``scores`` (:func:`packed_index_scores`; every unmasked key of a
+    query with ``k`` or fewer). Exact, and ``lax.top_k``'s choice: of the
+    keys that TIE with the k-th largest the first by index are kept (a
+    count along the row, taken only where some row holds such a tie;
+    ``-0.0`` ties with ``0.0``: a query whose every index head scores a
+    key under zero gives it exactly that)."""
+    scores = jnp.where(scores == 0, 0.0, scores)
+    bits = _ordered_bits(scores)
+    kth = kth_largest_bits(bits, k)[:, None]
+    live = scores > -1e29
+
+    def by_threshold():
+        return bits >= kth
+
+    def first_of_the_tied():
+        above, tied = bits > kth, bits == kth
+        room = k - jnp.sum(above.astype(jnp.int32), axis=-1, keepdims=True)
+        return above | (tied & (jnp.cumsum(tied.astype(jnp.int32), axis=-1)
+                                <= room))
+
+    over = jnp.sum((live & (bits >= kth)).astype(jnp.int32), axis=-1) > k
+    return (lax.cond(jnp.any(over), first_of_the_tied, by_threshold)
+            & live).astype(jnp.int8)
+
+
+def selected_attention(q, k, v, segment_ids, selected, *, sm_scale=None,
+                       impl=None, interpret=None):
+    """:func:`packed_gqa_attention` (no window, no sink) restricted to
+    the keys ``selected [S, S]`` (:func:`select_keys`) marks nonzero;
+    forward only."""
+    from apex_tpu.ops import attention_pallas as ap
+
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if impl is not None and impl not in ("jnp", "pallas"):
+        raise ValueError(f"unknown packed-attention impl {impl!r}")
+    if impl is None:
+        impl = "pallas" if _tpu_available() and ap.packed_supported(
+            q.shape[1], q.shape[2], v.shape[2]) else "jnp"
+    seg = segment_ids.astype(jnp.int32)
+    if impl == "pallas":
+        if interpret is None:
+            interpret = _on_cpu()
+        return ap.packed_gqa_attention_pallas(
+            q, k, v, seg, float(sm_scale), selected=selected,
+            interpret=bool(interpret))
+    return _packed_gqa_dense(q, k, v, seg, float(sm_scale), None, None,
+                             selected)

@@ -1032,7 +1032,9 @@ def packed_supported(S, dk, dv):
 
 
 def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
-                   blk, n_k, window, has_sink):
+                   blk, n_k, window, has_sink, has_sel=False):
+    if has_sel:
+        sel_ref, *rest = rest
     if has_sink:
         sink_ref, o_ref, acc_scr, m_scr, l_scr = rest
     else:
@@ -1063,6 +1065,8 @@ def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
         masked = (col > row) | (sq_ref[...] != skv_ref[...])
         if window is not None:
             masked = masked | (row - col >= window)
+        if has_sel:
+            masked = masked | (sel_ref[...].astype(jnp.int32) == 0)
         s = jnp.where(masked, jnp.float32(-1e30), s * jnp.float32(scale))
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -1082,8 +1086,11 @@ def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
 
 
 def packed_gqa_attention_pallas(q, k, v, seg, sm_scale, *, window=None,
-                                sink=None, interpret=False):
-    """The packed grouped-query prefill kernel (layouts above)."""
+                                sink=None, selected=None, interpret=False):
+    """The packed grouped-query prefill kernel (layouts above).
+    ``selected [S, S]`` int8 (a layer that selects its keys:
+    :func:`packed_index_scores_pallas`, ``attention.select_keys``): a
+    query attends only where it is nonzero, besides the other masks."""
     hq, S, dk = q.shape
     n_kv, dv = k.shape[0], v.shape[2]
     blk = packed_block(S)
@@ -1113,11 +1120,18 @@ def packed_gqa_attention_pallas(q, k, v, seg, sm_scale, *, window=None,
     ]
     seg = seg.astype(jnp.int32)
     operands = [q, k, v, seg[:, None], seg[None, :]]
+    kw = {}
+    if selected is not None:
+        in_specs.append(pl.BlockSpec(
+            (blk, blk), lambda h, iq, ik: (iq, k_block(iq, ik))))
+        operands.append(selected.astype(jnp.int8))
+        kw["has_sel"] = True
     if has_sink:
         in_specs.append(pl.BlockSpec((None, 1, 1), lambda h, iq, ik: (h, 0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(hq, 1, 1))
     kern = functools.partial(_packed_kernel, scale=float(sm_scale), blk=blk,
-                             n_k=n_blk, window=window, has_sink=has_sink)
+                             n_k=n_blk, window=window, has_sink=has_sink,
+                             **kw)
     return pl.pallas_call(
         kern,
         grid=(hq, n_blk, n_blk),
@@ -1130,3 +1144,87 @@ def packed_gqa_attention_pallas(q, k, v, seg, sm_scale, *, window=None,
         interpret=interpret,
         name=PACKED_KERNEL_NAME,
     )(*operands)
+
+
+# --------------------------------------- the indexer's scores (prefill)
+#
+# A layer that SELECTS its keys (serving/dots3.py) scores every (query,
+# key) pair of a packed batch with a small indexer before it attends:
+#
+#   q_idx [hi, S, di], w [S, hi] float32, k_idx [S, di], seg [S]
+#     -> [S, S] float32:  sum_h w[t, h] relu(q_idx[h, t] . k_idx[j]),
+#        -1e30 where j > t or the segments differ
+#
+# Grid (q block, k block); a step holds every head's queries of its q
+# block and sums the heads' rectified, weighted scores of one k block in
+# registers, so no [S, S, hi] array exists. A k block wholly above the
+# diagonal is neither fetched nor computed.
+
+INDEX_SCORES_KERNEL_NAME = "packed_index_scores"
+_INDEX_BQ, _INDEX_BK = 128, 512
+
+
+def index_blocks(S):
+    """``(q block, k block)`` of the index kernel; ``(0, 0)`` where S
+    does not divide into whole lane tiles."""
+    bq, bk = min(_INDEX_BQ, S), min(_INDEX_BK, S)
+    return (bq, bk) if S % bq == 0 and S % bk == 0 and bq % 128 == 0 \
+        else (0, 0)
+
+
+def index_scores_supported(S, hi, di):
+    return index_blocks(S)[0] != 0 and di % 128 == 0 and hi <= 128
+
+
+def _index_scores_kernel(q_ref, w_ref, k_ref, sq_ref, skv_ref, o_ref, *,
+                         bq, bk, hi):
+    iq, ik = pl.program_id(0), pl.program_id(1)
+    live = ik * bk <= iq * bq + bq - 1
+
+    @pl.when(jnp.logical_not(live))
+    def _above():
+        o_ref[...] = jnp.full_like(o_ref, jnp.float32(-1e30))
+
+    @pl.when(live)
+    def _block():
+        k = k_ref[...]
+        w = w_ref[...]
+        acc = jnp.zeros((bq, bk), jnp.float32)
+        for h in range(hi):
+            s = lax.dot_general(q_ref[h], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w[:, h:h + 1]
+        row = iq * bq + lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        col = ik * bk + lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        masked = (col > row) | (sq_ref[...] != skv_ref[...])
+        o_ref[...] = jnp.where(masked, jnp.float32(-1e30), acc)
+
+
+def packed_index_scores_pallas(q_idx, w, k_idx, seg, *, interpret=False):
+    """The prefill index kernel (layouts above)."""
+    hi, S, di = q_idx.shape
+    bq, bk = index_blocks(S)
+    if bq == 0 or (not interpret
+                   and not index_scores_supported(S, hi, di)):
+        raise ValueError(f"packed_index_scores_pallas: unsupported "
+                         f"q {q_idx.shape} k {k_idx.shape}")
+
+    def k_block(iq, ik):   # a block above the diagonal is not fetched
+        return jnp.minimum(ik, (iq * bq + bq - 1) // bk)
+
+    seg = seg.astype(jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_index_scores_kernel, bq=bq, bk=bk, hi=hi),
+        grid=(S // bq, S // bk),
+        in_specs=[
+            pl.BlockSpec((hi, bq, di), lambda iq, ik: (0, iq, 0)),
+            pl.BlockSpec((bq, hi), lambda iq, ik: (iq, 0)),
+            pl.BlockSpec((bk, di), lambda iq, ik: (k_block(iq, ik), 0)),
+            pl.BlockSpec((bq, 1), lambda iq, ik: (iq, 0)),
+            pl.BlockSpec((1, bk), lambda iq, ik: (0, k_block(iq, ik))),
+        ],
+        out_specs=pl.BlockSpec((bq, bk), lambda iq, ik: (iq, ik)),
+        out_shape=jax.ShapeDtypeStruct((S, S), jnp.float32),
+        interpret=interpret,
+        name=INDEX_SCORES_KERNEL_NAME,
+    )(q_idx, w.astype(jnp.float32), k_idx, seg[:, None], seg[None, :])

@@ -447,7 +447,10 @@ def grouped_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
 #
 # The page walk (scalar-prefetched table, bases and lengths; an entry at
 # or past ``length`` skipped) and the online softmax step are the
-# grouped kernel's own; every layer keeps every token, so no ``starts``.
+# grouped kernel's own. A pool keeps every token and gives no ``starts``;
+# a latent RING (kv_cache.init_latent_ring: window layers whose state is
+# a latent row) gives the first visible position of each slot, and the
+# kernel then carries it as a fourth scalar operand.
 
 LATENT_KERNEL_NAME = "latent_decode_attention"
 
@@ -467,11 +470,15 @@ def latent_supported(hq, width, rank, page_size, dtype=None):
             <= 8 * 2 ** 20)
 
 
-def _latent_kernel(pt_ref, base_ref, len_ref, q_ref, kv_ref, o_ref,
-                   acc_scr, m_scr, l_scr, *, scale, ps, n_iter, rank):
+def _latent_kernel(pt_ref, base_ref, *rest, scale, ps, n_iter, rank,
+                   has_start=False):
     """One (slot, table entry) step: the page ``[ps, width]`` meets every
     head's query whole (scores ``[hq, ps]``), and its first ``rank``
     columns are what the probabilities sum."""
+    if has_start:
+        start_ref, len_ref, q_ref, kv_ref, o_ref, acc_scr, m_scr, l_scr = rest
+    else:
+        len_ref, q_ref, kv_ref, o_ref, acc_scr, m_scr, l_scr = rest
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -481,11 +488,17 @@ def _latent_kernel(pt_ref, base_ref, len_ref, q_ref, kv_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
 
     base, length = base_ref[i, j], len_ref[i]
+    live = base < length
+    if has_start:
+        start = start_ref[i]
+        live = live & (base + ps > start)
 
-    @pl.when(base < length)
+    @pl.when(live)
     def _page():
         pos = base + lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         masked = pos >= length                                   # [1, ps]
+        if has_start:
+            masked = masked | (pos < start)
         s = lax.dot_general(q_ref[...], kv_ref[...],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -505,7 +518,7 @@ def _latent_kernel(pt_ref, base_ref, len_ref, q_ref, kv_ref, o_ref,
 
 def latent_decode_attention_pallas(q, latent_pages, page_table, lengths,
                                    sm_scale, *, rank, page_base=None,
-                                   interpret=False):
+                                   starts=None, interpret=False):
     """The latent decode kernel (layouts above)."""
     b, hq, width = q.shape
     ps = latent_pages.shape[1]
@@ -522,21 +535,27 @@ def latent_decode_attention_pallas(q, latent_pages, page_table, lengths,
             f"hq={hq} width={width} rank={rank} ps={ps} "
             f"{latent_pages.dtype}")
     page_base, _ = _context_view(page_table, ps, page_base)
+    scalars = [page_table.astype(jnp.int32), page_base,
+               lengths.astype(jnp.int32)]
+    kw = {}
+    if starts is not None:
+        scalars.insert(2, starts.astype(jnp.int32))
+        kw["has_start"] = True
     kern = functools.partial(_latent_kernel, scale=float(sm_scale), ps=ps,
-                             n_iter=n, rank=rank)
+                             n_iter=n, rank=rank, **kw)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(b, n),
             in_specs=[
                 pl.BlockSpec((None, hq, width),
-                             lambda i, j, pt, base, ln: (i, 0, 0)),
+                             lambda i, j, *_: (i, 0, 0)),
                 pl.BlockSpec((None, ps, width),
-                             lambda i, j, pt, base, ln: (pt[i, j], 0, 0)),
+                             lambda i, j, pt, *_: (pt[i, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec((None, hq, rank),
-                                   lambda i, j, pt, base, ln: (i, 0, 0)),
+                                   lambda i, j, *_: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((hq, rank), jnp.float32),
                 pltpu.VMEM((hq, 1), jnp.float32),
@@ -546,21 +565,22 @@ def latent_decode_attention_pallas(q, latent_pages, page_table, lengths,
         out_shape=jax.ShapeDtypeStruct((b, hq, rank), q.dtype),
         interpret=interpret,
         name=LATENT_KERNEL_NAME,
-    )(page_table.astype(jnp.int32), page_base, lengths.astype(jnp.int32),
-      q, latent_pages)
+    )(*scalars, q, latent_pages)
 
 
 def latent_decode_attention_reference(q, latent_pages, page_table, lengths,
-                                      sm_scale, *, rank, page_base=None):
+                                      sm_scale, *, rank, page_base=None,
+                                      starts=None):
     """The jnp form of the latent kernel (the CPU, an unsupported
     geometry or page dtype): gather each slot's pages, mask at and past
-    ``length``, exact float32 softmax. Inactive slots return 0."""
+    ``length`` (and before ``starts``), exact float32 softmax. Inactive
+    slots return 0."""
     b, hq, width = q.shape
     ps = latent_pages.shape[1]
     rows = latent_pages[page_table].reshape(b, -1, width).astype(
         jnp.float32)                                   # [b, n * ps, width]
-    masked = _outside_context(page_table, ps, lengths,
-                              page_base)[:, None, :]            # [b, 1, S]
+    masked = _outside_context(page_table, ps, lengths, page_base,
+                              starts)[:, None, :]               # [b, 1, S]
     s = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32), rows,
                    precision=lax.Precision.HIGHEST) * jnp.float32(sm_scale)
     out = jnp.einsum("bhs,bsr->bhr", masked_softmax(s, masked, None),
@@ -583,14 +603,17 @@ def latent_resolved(hq, width, rank, page_size, dtype, impl=None):
 
 
 def latent_decode_attention(q, latent_pages, page_table, lengths, *, rank,
-                            sm_scale, page_base=None, impl=None,
-                            interpret=None):
-    """Dispatched decode attention over latent pages (layouts above).
+                            sm_scale, page_base=None, starts=None,
+                            impl=None, interpret=None):
+    """Dispatched decode attention over latent pages (layouts above;
+    ``starts [b]``: the first visible position of a slot, for a ring).
     ``impl`` is a per-call demand ("jnp" | "pallas"; "pallas" compiled on
     an unsupported geometry raises); ``interpret`` defaults to True on
     the CPU platform only."""
     hq, width = q.shape[1:]
     kw = dict(rank=rank, page_base=page_base)
+    if starts is not None:
+        kw["starts"] = starts
     if latent_resolved(hq, width, rank, latent_pages.shape[1],
                        latent_pages.dtype, impl) == "pallas":
         if interpret is None:
@@ -600,3 +623,133 @@ def latent_decode_attention(q, latent_pages, page_table, lengths, *, rank,
             interpret=interpret, **kw)
     return latent_decode_attention_reference(
         q, latent_pages, page_table, lengths, sm_scale, **kw)
+
+
+# -------------------------------------------- the indexer's scores (decode)
+#
+# A layer that SELECTS the rows it attends to (serving/dots3.py) keeps,
+# beside each latent page, the indexer's key of every token: one
+# ``index_width``-wide row. A decode step scores every live row of a slot
+# against the token's index queries, one weighted sum of rectified head
+# scores a row:
+#
+#   q_idx        [b, hi, di]        the token's index queries
+#   w            [b, hi]   float32  its head weights (scale folded in)
+#   index_pages  [pages, page_size, di]   one layer (kv_cache.py)
+#   out          [b, n * page_size] float32:  ``sum_h w[h] relu(q_idx[h]
+#                . key)`` of the row at each table entry, ``NEG_INF`` at
+#                and past ``length``
+#
+# The walk is the latent kernel's: step ``(i, j)`` DMAs page
+# ``page_table[i, j]`` and writes that entry's ``page_size`` scores.
+
+INDEX_KERNEL_NAME = "index_decode_scores"
+
+
+def index_supported(hi, di, page_size, dtype=None):
+    """Whether Mosaic takes the index kernel: whole sublane tiles of
+    heads, a key of whole lane tiles, pages of whole lane tiles of rows
+    (a page's scores are one output row), a bfloat16 or float32 page."""
+    if dtype is not None and jnp.dtype(dtype) not in (
+            jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return False
+    return hi % 8 == 0 and di % 128 == 0 and page_size % 128 == 0
+
+
+def _index_kernel(pt_ref, base_ref, len_ref, q_ref, w_ref, k_ref, o_ref, *,
+                  ps):
+    i, j = pl.program_id(0), pl.program_id(1)
+    base, length = base_ref[i, j], len_ref[i]
+
+    @pl.when(base >= length)
+    def _skip():
+        o_ref[...] = jnp.full_like(o_ref, jnp.float32(NEG_INF))
+
+    @pl.when(base < length)
+    def _page():
+        s = lax.dot_general(q_ref[...], k_ref[...],
+                            (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # [hi, ps]
+        s = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0,
+                    keepdims=True)                               # [1, ps]
+        pos = base + lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        o_ref[...] = jnp.where(pos >= length, jnp.float32(NEG_INF), s)
+
+
+def index_decode_scores_pallas(q_idx, w, index_pages, page_table, lengths,
+                               *, page_base=None, interpret=False):
+    """The index kernel (layouts above)."""
+    b, hi, di = q_idx.shape
+    ps = index_pages.shape[1]
+    n = page_table.shape[1]
+    if not interpret and not index_supported(hi, di, ps, index_pages.dtype):
+        raise ValueError(
+            f"index_decode_scores_pallas: unsupported geometry hi={hi} "
+            f"di={di} ps={ps} {index_pages.dtype}")
+    if index_pages.shape[2] != di:
+        raise ValueError(f"index_decode_scores: q {q_idx.shape} and index "
+                         f"pages {index_pages.shape} differ in width")
+    page_base, _ = _context_view(page_table, ps, page_base)
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, ps=ps),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n),
+            in_specs=[
+                pl.BlockSpec((None, hi, di), lambda i, j, *_: (i, 0, 0)),
+                pl.BlockSpec((None, hi, 1), lambda i, j, *_: (i, 0, 0)),
+                pl.BlockSpec((None, ps, di),
+                             lambda i, j, pt, *_: (pt[i, j], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, None, 1, ps),
+                                   lambda i, j, *_: (i, j, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, n, 1, ps), jnp.float32),
+        interpret=interpret,
+        name=INDEX_KERNEL_NAME,
+    )(page_table.astype(jnp.int32), page_base, lengths.astype(jnp.int32),
+      q_idx, w.astype(jnp.float32)[:, :, None], index_pages)
+    return out.reshape(b, n * ps)
+
+
+def index_decode_scores_reference(q_idx, w, index_pages, page_table,
+                                  lengths, *, page_base=None):
+    """The jnp form of the index kernel: gather each slot's pages, score
+    in float32, ``NEG_INF`` at and past ``length``."""
+    b = q_idx.shape[0]
+    ps = index_pages.shape[1]
+    keys = index_pages[page_table].reshape(b, -1, index_pages.shape[2])
+    s = jnp.einsum("bhd,bsd->bhs", q_idx, keys.astype(q_idx.dtype),
+                   preferred_element_type=jnp.float32)
+    s = jnp.sum(jnp.maximum(s, 0.0) * w.astype(jnp.float32)[:, :, None],
+                axis=1)
+    return jnp.where(_outside_context(page_table, ps, lengths, page_base),
+                     jnp.float32(NEG_INF), s)
+
+
+def index_resolved(hi, di, page_size, dtype, impl=None):
+    """The impl an :func:`index_decode_scores` call runs with (the rule
+    of :func:`latent_resolved`)."""
+    if impl is not None:
+        if impl not in ("jnp", "pallas"):
+            raise ValueError(f"unknown decode-attention impl {impl!r}")
+        return impl
+    if jax.default_backend() == "tpu" and index_supported(
+            hi, di, page_size, dtype):
+        return "pallas"
+    return "jnp"
+
+
+def index_decode_scores(q_idx, w, index_pages, page_table, lengths, *,
+                        page_base=None, impl=None, interpret=None):
+    """Dispatched index scores over a slot's pages (layouts above)."""
+    hi, di = q_idx.shape[1:]
+    if index_resolved(hi, di, index_pages.shape[1], index_pages.dtype,
+                      impl) == "pallas":
+        if interpret is None:
+            interpret = jax.devices()[0].platform == "cpu"
+        return index_decode_scores_pallas(
+            q_idx, w, index_pages, page_table, lengths,
+            page_base=page_base, interpret=interpret)
+    return index_decode_scores_reference(
+        q_idx, w, index_pages, page_table, lengths, page_base=page_base)

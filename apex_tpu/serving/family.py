@@ -324,7 +324,69 @@ def _axk1():
         config_from_dict=axk1.AXK1Config.from_dict)
 
 
-_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo, "axk1": _axk1}
+# ------------------------------------------------------------- dots3-note
+
+def _dots3():
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import dots3
+
+    def init_cache(cfg, geometry):
+        return dots3.init_cache(cfg, geometry.num_slots, geometry.num_pages,
+                                geometry.page_size, geometry.cache_dtype)
+
+    def prefill(params, cache, ids, positions, seg, token_rows, page_table,
+                last_idx, keep_scale=None, *, cfg, kernels):
+        return dots3.prefill(params, cache, ids, positions, seg, token_rows,
+                             page_table, last_idx, cfg=cfg,
+                             interpret=kernels.interpret)
+
+    def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+                    qparams, kernels):
+        return dots3.decode_step(params, cache, tokens, lengths, page_table,
+                                 cfg=cfg, decode_impl=kernels.decode_impl,
+                                 interpret=kernels.interpret)
+
+    def decode_attention(cfg, cache, kernels):
+        return dots3.decode_attention_resolved(
+            cfg, cache, kernels.decode_impl)
+
+    def fetch_attrs(extras):
+        # what the round's decode read: the context rows a full layer's
+        # indexer scored, the rows its attention then gathered, the ring
+        # rows inside a sliding layer's window
+        return dict(_expert_attrs(extras), **{
+            name: int(extras[name]) for name in (
+                "index_rows_scored", "sparse_rows_selected", "window_rows")})
+
+    def prefill_attrs(cfg, extras, trunk_rows):
+        # the dispatch's (query, key) pairs: every pair the indexer has
+        # to score, and those the selection leaves to attend to
+        return dict(_held_row_attrs(cfg, extras, trunk_rows),
+                    index_pairs=int(extras["index_pairs"]),
+                    sparse_pairs=int(extras["sparse_pairs"]))
+
+    def round_attrs(cfg, scheduler):
+        # pool pages that hold context (a latent and an index-key page a
+        # full layer each), ring pages that hold part of a window
+        live, ring = scheduler.context_pages(cfg.sliding_window_size)
+        return dict(latent_pages_live=live, window_pages=ring)
+
+    return Family(
+        name="dots3", check_config=dots3.check_config,
+        init_params=dots3.init_params,
+        cache_dtype=lambda cfg: jnp.dtype(cfg.cache_dtype),
+        init_cache=init_cache,
+        prefill=prefill, prefill_rows=prefill_rows,
+        decode_step=decode_step,
+        decode_attention=decode_attention, fetch_attrs=fetch_attrs,
+        prefill_attrs=prefill_attrs,
+        round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
+        one_prefill_a_round=True, model_type="dots3_note",
+        config_from_dict=dots3.Dots3Config.from_dict)
+
+
+_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo, "axk1": _axk1, "dots3": _dots3}
 
 
 @functools.lru_cache(maxsize=None)

@@ -67,7 +67,7 @@ def latent_row_width(width):
 
 
 def init_latent_cache(num_layers, num_pages, page_size, width,
-                      dtype=jnp.bfloat16):
+                      dtype=jnp.bfloat16, index_width=None):
     """Zeroed cache ``{"latent"}`` of a model with latent attention
     (serving/axk1.py): a third kind of state, ONE row a token a layer
     with no head axis, ``width`` live columns (the normalised compressed
@@ -82,9 +82,34 @@ def init_latent_cache(num_layers, num_pages, page_size, width,
     tiles, 128 is one) and every row scatter and every kernel call then
     copies the whole leaf to row-major and back (two 207 MB copies a
     layer a program at the cell's size: the compile-only verdict of
-    tests/test_decode_attention_mosaic.py; PERF.md §6, PR 31)."""
-    return {"latent": [
-        jnp.zeros((num_pages, page_size, latent_row_width(width)), dtype)
+    tests/test_decode_attention_mosaic.py; PERF.md §6, PR 31).
+
+    ``index_width`` (serving/dots3.py: layers that SELECT the rows they
+    attend to) adds ``{"index"}``: beside each latent leaf the indexer's
+    key of every token, ``[num_pages, page_size,
+    latent_row_width(index_width)]``, on the same page ids."""
+    def leaves(width):
+        return [jnp.zeros((num_pages, page_size, latent_row_width(width)),
+                          dtype) for _ in range(num_layers)]
+
+    cache = {"latent": leaves(width)}
+    if index_width is not None:
+        cache["index"] = leaves(index_width)
+    return cache
+
+
+def init_latent_ring(num_layers, num_slots, page_size, window, width,
+                     dtype=jnp.bfloat16):
+    """Zeroed ``{"ring"}``: the state of WINDOW layers whose attention is
+    latent (serving/dots3.py). A latent row a token a layer as
+    :func:`init_latent_cache`'s, kept in a ring of :func:`ring_pages`
+    pages a slot as :func:`init_hybrid_cache`'s window kind is: ``[1 +
+    num_slots * ring, page_size, latent_row_width(width)]`` a layer,
+    written at :func:`ring_write`, read through :func:`ring_table` /
+    :func:`ring_view`."""
+    pages = 1 + num_slots * ring_pages(window, page_size)
+    return {"ring": [
+        jnp.zeros((pages, page_size, latent_row_width(width)), dtype)
         for _ in range(num_layers)]}
 
 

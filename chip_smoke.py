@@ -725,6 +725,62 @@ def _decode_cases(size, interpret):
             [(5e-2,)])
 
 
+def _selection_cases(size, interpret):
+    """The kernels of a layer that SELECTS its keys (serving/dots3.py), at
+    small shapes of whole tiles: the decode indexer over index-key pages,
+    the latent kernel over a ring with its ``starts``, the prefill
+    indexer, the packed kernel under an int8 selection."""
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.ops import decode_attention_pallas as dap
+    from apex_tpu.serving import kv_cache
+
+    rs = np.random.RandomState(3)
+    bf = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.bfloat16)  # noqa: E731
+    b, n, ps, hi, di = 4, 3, 128, 8, 128
+    table = jnp.asarray(1 + rs.permutation(b * n).reshape(b, n), jnp.int32)
+    lens = jnp.asarray([5, ps, n * ps, 0], jnp.int32)
+    w = jnp.asarray(rs.randn(b, hi), jnp.float32)
+    yield "index decode scores bf16 paged", (
+        lambda q, pages: dap.index_decode_scores(
+            q, w, pages, table, lens, impl="pallas", interpret=interpret),
+        lambda q, pages: dap.index_decode_scores_reference(
+            q, w, pages, table, lens),
+        (bf(b, hi, di), bf(1 + b * n, ps, di)), [(2e-1,)])
+
+    window, width, rank, hq = 200, 256, 128, 8
+    ring = kv_cache.ring_pages(window, ps)
+    ring_lens = jnp.asarray([0, 90, 300, 1000], jnp.int32)
+    base, starts = kv_cache.ring_view(ring_lens, ring, ps, window)
+    view = dict(rank=rank, page_base=base, starts=starts)
+    yield "latent decode attention bf16 over a ring", (
+        lambda q, pages: dap.latent_decode_attention(
+            q, pages, kv_cache.ring_table(b, ring), ring_lens, sm_scale=0.1,
+            impl="pallas", interpret=interpret, **view),
+        lambda q, pages: dap.latent_decode_attention_reference(
+            q, pages, kv_cache.ring_table(b, ring), ring_lens, 0.1, **view),
+        (bf(b, hq, width), bf(1 + b * ring, ps, width)), [(5e-2,)])
+
+    S, k = 512, 128
+    seg = jnp.asarray(np.r_[np.ones(300), 2 * np.ones(180), np.zeros(32)],
+                      jnp.int32)
+    w_s = jnp.asarray(rs.randn(S, hi), jnp.float32)
+    yield "packed index scores bf16", (
+        lambda q, keys: attn.packed_index_scores(
+            q, w_s, keys, seg, impl="pallas", interpret=interpret),
+        lambda q, keys: attn.packed_index_scores(q, w_s, keys, seg,
+                                                 impl="jnp"),
+        (bf(hi, S, di), bf(S, di)), [(2e-1,)])
+    selected = attn.select_keys(attn.packed_index_scores(
+        bf(hi, S, di), w_s, bf(S, di), seg, impl="jnp"), k)
+    yield "packed attention bf16 under a selection", (
+        lambda q, keys, v: attn.selected_attention(
+            q, keys, v, seg, selected, sm_scale=0.07, impl="pallas",
+            interpret=interpret),
+        lambda q, keys, v: attn.selected_attention(
+            q, keys, v, seg, selected, sm_scale=0.07, impl="jnp"),
+        (bf(4, S, 192), bf(4, S, 192), bf(4, S, 128)), [(5e-2,)])
+
+
 def run_kernels(size, log, interpret):
     """Each case: ``(kernel_fn, reference_fn, args, tolerances)`` where
     both functions return the same pytree (output, then gradients) and
@@ -732,7 +788,8 @@ def run_kernels(size, log, interpret):
     last one repeating."""
     recs = {}
     cache0 = compile_cache.snapshot()
-    for cases in (_attention_cases, _row_kernel_cases, _decode_cases):
+    for cases in (_attention_cases, _row_kernel_cases, _decode_cases,
+                  _selection_cases):
         for name, (kernel, reference, args, tols) in cases(size, interpret):
             t0 = time.perf_counter()
             lowered = jax.jit(kernel).lower(*args)

@@ -402,3 +402,132 @@ def test_axk1_program_compiles_with_no_copy_of_a_latent_leaf(one_chip,
         assert bounded == 2
         assert len(re.findall(r" conditional\(", text)) == 1 + bounded
         assert text.count("tpu_custom_call") >= 4 * (2 + 3)
+
+
+# ---- the dots3-note family's kernels at the widths serve-dots3-longcontext runs ----
+
+SPARSE = dict(slots=16, pages=1280, ps=128, table=8704 // 128, rows=8192)
+
+
+def test_selection_kernels_compile_for_the_v5e(one_chip):
+    """The decode index kernel over 68 table entries of 128-wide keys,
+    the latent kernel with ``starts`` over a ring of 6 pages of 1,152
+    columns (rank 1,024, 64 heads) and over 2,048 gathered rows of 640
+    (rank 512, 128 heads); the prefill index kernel (64 heads in a step)
+    and the packed kernel under an int8 selection at 128 heads of 192 /
+    128, 2,048 packed rows."""
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.ops import attention_pallas as ap
+
+    g, bf, f32, i32 = SPARSE, jnp.bfloat16, jnp.float32, jnp.int32
+    b, n = g["slots"], g["table"]
+    assert dap.index_supported(64, 128, g["ps"], bf)
+    assert dap.latent_supported(64, 1152, 1024, g["ps"], bf)
+    assert ap.index_scores_supported(g["rows"], 64, 128)
+
+    def compiled(f, *args):
+        return jax.jit(f).lower(*(_sds(one_chip, *a) for a in args)) \
+            .compile().as_text()
+
+    text = compiled(
+        lambda q, w, p, t, ln: dap.index_decode_scores(
+            q, w, p, t, ln, impl="pallas", interpret=False),
+        ((b, 64, 128), bf), ((b, 64), f32), ((g["pages"], g["ps"], 128), bf),
+        ((b, n), i32), ((b,), i32))
+    assert dap.INDEX_KERNEL_NAME in text
+    text = compiled(
+        lambda q, p, t, ln, base, st: dap.latent_decode_attention(
+            q, p, t, ln, rank=1024, sm_scale=0.06, page_base=base, starts=st,
+            impl="pallas", interpret=False),
+        ((b, 64, 1152), bf), ((1 + b * 6, g["ps"], 1152), bf), ((b, 6), i32),
+        ((b,), i32), ((b, 6), i32), ((b,), i32))
+    assert dap.LATENT_KERNEL_NAME in text
+    text = compiled(
+        lambda q, p, t, ln: dap.latent_decode_attention(
+            q, p, t, ln, rank=512, sm_scale=0.07, impl="pallas",
+            interpret=False),
+        ((b, 128, 640), bf), ((b * 16, g["ps"], 640), bf), ((b, 16), i32),
+        ((b,), i32))
+    assert dap.LATENT_KERNEL_NAME in text
+    S = 2048
+    text = compiled(
+        lambda q, w, k, s: attn.packed_index_scores(
+            q, w, k, s, impl="pallas", interpret=False),
+        ((64, S, 128), bf), ((S, 64), f32), ((S, 128), bf), ((S,), i32))
+    assert ap.INDEX_SCORES_KERNEL_NAME in text
+    text = compiled(
+        lambda q, k, v, s, sel: attn.selected_attention(
+            q, k, v, s, sel, sm_scale=0.07, impl="pallas", interpret=False),
+        ((128, S, 192), bf), ((128, S, 192), bf), ((128, S, 128), bf),
+        ((S,), i32), ((S, S), jnp.int8))
+    assert ap.PACKED_KERNEL_NAME in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_dots3_program_compiles_with_no_copy_of_a_pool_leaf(
+        one_chip, monkeypatch, program):
+    """Published widths, the cell's 16 slots, 1,280 pages of 128 and 68
+    table entries, one full layer (dense MLP) and one sliding layer (16
+    held experts + the shared one): the decode program reaches the index
+    kernel, the latent kernel twice (the gathered rows or the pool's
+    pages under ONE cond; the ring) and the grouped matmuls; the prefill
+    program (4,096 packed rows: four trunks behind one switch, of which
+    the 4,096-row one alone is past ``index_topk`` and runs the indexer)
+    the packed kernel in every trunk. The latent leaf (0.21 GB) and the index
+    leaf (0.04 GB) of the pool are aliased input to output and no array
+    of their shapes is copied; a ring leaf (0.03 GB) XLA may stage in
+    its faster memory by itself (an async copy pair, seen at nine
+    layers), which is not asserted on."""
+    import functools
+
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.serving import dots3
+
+    monkeypatch.setattr(attn, "_tpu_available", lambda: True)
+    g = SPARSE
+    cfg = dots3.Dots3Config(vocab_size=2048,
+                            layer_types=(dots3.FULL, dots3.SLIDING),
+                            held_experts=(0, 16))
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: dots3.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = sds(jax.eval_shape(
+        lambda: dots3.init_cache(cfg, g["slots"], g["pages"], g["ps"])))
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    b, S = g["slots"], 4096
+    if program == "decode":
+        fn = functools.partial(dots3.decode_step, cfg=cfg,
+                               decode_impl="pallas", moe_impl="pallas",
+                               interpret=False)
+        args = (i32(b), i32(b), i32(b, g["table"]))
+    else:
+        fn = functools.partial(dots3.prefill, cfg=cfg, attn_impl="pallas",
+                               moe_impl="pallas", interpret=False)
+        args = (i32(S), i32(S), i32(S), i32(S), i32(b + 1, g["table"]),
+                i32(b))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    for width in (640, 128):
+        made = re.findall(rf"= bf16\[{g['pages']},{g['ps']},{width}\]\S* "
+                          rf"([\w-]+)\(", text)
+        assert made and not {"copy", "copy-done"} & set(made), \
+            (width, sorted(set(made)))
+    mem = compiled.memory_analysis()
+    pool_bytes = g["pages"] * g["ps"] * (640 + 128) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if program == "decode":
+        assert dap.INDEX_KERNEL_NAME in text
+        assert text.count(dap.LATENT_KERNEL_NAME) >= 2
+        assert len(re.findall(r" conditional\(", text)) == 1
+        assert mem.temp_size_in_bytes < pool_bytes / 2
+    else:
+        from apex_tpu.ops import attention_pallas as ap
+
+        # the 4,096-row trunk alone is past index_topk: one index kernel
+        assert text.count(ap.INDEX_SCORES_KERNEL_NAME) >= 1
+        assert text.count(ap.PACKED_KERNEL_NAME) >= 4 * 2
