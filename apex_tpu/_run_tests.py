@@ -40,7 +40,7 @@ SUITES = {
             "test_attention_pallas.py", "test_xent_pallas.py",
             "test_mosaic_block_rules.py", "test_tile_params.py",
             "test_decode_attention_pallas.py",
-            "test_decode_attention_mosaic.py"],
+            "test_decode_attention_mosaic.py", "test_packed_grid.py"],
     "serving": ["test_serving.py", "test_serving_slo.py",
                 "test_serving_generation.py",
                 "test_serving_resilience.py",

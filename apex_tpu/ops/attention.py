@@ -300,6 +300,15 @@ def _packed_gqa_bwd(sm_scale, window, impl, interpret, res, g):
 _packed_gqa.defvjp(_packed_gqa_fwd, _packed_gqa_bwd)
 
 
+def _packed_impl(S, dk, dv):
+    """What an unset ``impl`` of the packed attention is: the kernel on a
+    TPU where it takes the shape, the jnp form elsewhere."""
+    from apex_tpu.ops import attention_pallas as ap
+
+    return "pallas" if _tpu_available() and ap.packed_supported(S, dk, dv) \
+        else "jnp"
+
+
 def packed_gqa_attention(q, k, v, segment_ids, *, sm_scale=None,
                          window=None, sink=None, impl=None,
                          interpret=None):
@@ -316,19 +325,29 @@ def packed_gqa_attention(q, k, v, segment_ids, *, sm_scale=None,
     on a shape it does not support raises); unset, the kernel runs on a
     TPU where ``attention_pallas.packed_supported`` holds, the jnp form
     elsewhere. ``interpret`` defaults to True on the CPU platform only."""
-    from apex_tpu.ops import attention_pallas as ap
-
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if impl is not None and impl not in ("jnp", "pallas"):
         raise ValueError(f"unknown packed-attention impl {impl!r}")
     if impl is None:
-        impl = "pallas" if _tpu_available() and ap.packed_supported(
-            q.shape[1], q.shape[2], v.shape[2]) else "jnp"
+        impl = _packed_impl(q.shape[1], q.shape[2], v.shape[2])
     if interpret is None:
         interpret = _on_cpu()
     return _packed_gqa(q, k, v, segment_ids.astype(jnp.int32),
                        float(sm_scale), window, sink, impl, bool(interpret))
+
+
+def packed_attention_grid(S, hq, n_kv, dk, dv, *, window=None,
+                          selected=False):
+    """``(steps, dense steps)`` of one packed-attention call on these
+    shapes with ``impl`` unset: the grid steps the kernel takes (a group
+    of heads on a live block pair each) and what a grid of one step a
+    (head, q block, k block) would; ``(0, 0)`` where the jnp form runs."""
+    from apex_tpu.ops import attention_pallas as ap
+
+    if _packed_impl(S, dk, dv) != "pallas":
+        return 0, 0
+    return ap.packed_grid_steps(S, hq, n_kv, dk, dv, window, selected)
 
 
 # ------------------------- attention over SELECTED keys (packed prefill)
@@ -442,8 +461,7 @@ def selected_attention(q, k, v, segment_ids, selected, *, sm_scale=None,
     if impl is not None and impl not in ("jnp", "pallas"):
         raise ValueError(f"unknown packed-attention impl {impl!r}")
     if impl is None:
-        impl = "pallas" if _tpu_available() and ap.packed_supported(
-            q.shape[1], q.shape[2], v.shape[2]) else "jnp"
+        impl = _packed_impl(q.shape[1], q.shape[2], v.shape[2])
     seg = segment_ids.astype(jnp.int32)
     if impl == "pallas":
         if interpret is None:

@@ -48,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -1008,13 +1009,25 @@ fused_attention_rows.defvjp(_fwd_rule, _bwd_rule)
 #
 #   q [hq, S, dk], k [n_kv, S, dk], v [n_kv, S, dv], seg [S] -> [hq, S, dv]
 #
-# Grid (query head, q block, k block), online softmax across the k
-# blocks. Packed index order is position order inside a segment, so
-# "causal" and "within the window" are index differences; a k block
-# wholly above the diagonal or wholly behind the window is neither
-# computed nor fetched (its block index is clamped onto a live one).
+# Packed index order is position order inside a segment, so "causal" and
+# "within the window" are index differences, and which (q block, k block)
+# pairs hold any pair a query may see follows from ``(S, block, window)``
+# alone: :func:`packed_live_pairs` lists them while tracing and the
+# kernel takes the list as scalar-prefetch operands. Grid (group of
+# query heads, LIVE pair), q block major and k blocks ascending, online
+# softmax across a q block's pairs: a k block wholly above the diagonal
+# or wholly behind the window is no grid step at all. A step builds its
+# pair's mask ONCE (positions only where the pair crosses the diagonal
+# or the window's edge; segments and the selection on every pair) and
+# scores every head of its group under it; heads that share a KV head
+# share the step's K and V block.
 
 PACKED_KERNEL_NAME = "packed_gqa_attention"
+PAIR_FIRST, PAIR_LAST, PAIR_CROSSES = 1, 2, 4
+_HEADS_A_STEP = (8, 4, 2, 1)
+# what a step's blocks and scratch may take of the 16 MiB of VMEM a
+# kernel is scoped to unless it asks for more
+_PACKED_VMEM_BUDGET = 12 << 20
 
 
 def packed_block(S):
@@ -1031,17 +1044,75 @@ def packed_supported(S, dk, dv):
     return blk != 0 and (blk % 128 == 0) and dk <= 512 and dv <= 512
 
 
-def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
-                   blk, n_k, window, has_sink, has_sel=False):
+@functools.lru_cache(maxsize=None)
+def packed_live_pairs(S, blk, window):
+    """``[3, n_live]`` int32 (numpy): the q block, the k block and the
+    flags of every block pair that holds a (query, key) a causal mask
+    and ``window`` leave, q block major, k blocks ascending. Flags:
+    ``PAIR_FIRST`` / ``PAIR_LAST`` of its q block, ``PAIR_CROSSES`` where
+    the positional mask can hide a pair of it (the diagonal block, a
+    block the window's edge runs through)."""
+    n = S // blk
+    iq, ik = np.divmod(np.arange(n * n), n)
+    live = ik <= iq                        # not wholly above the diagonal
+    if window is not None:                 # nor wholly behind the window
+        live &= (ik + 1) * blk - 1 > iq * blk - window
+    iq, ik = iq[live], ik[live]
+    crosses = ik == iq
+    if window is not None:
+        crosses |= (iq - ik) * blk + blk - 1 >= window
+    edge = np.flatnonzero(np.diff(iq, prepend=-1, append=n))
+    flags = PAIR_CROSSES * crosses
+    flags[edge[:-1]] |= PAIR_FIRST
+    flags[edge[1:] - 1] |= PAIR_LAST
+    return np.stack([iq, ik, flags]).astype(np.int32)
+
+
+def _packed_vmem_bytes(g, kv, blk, dk, dv, itemsize, has_sel):
+    """What a step of ``g`` query heads over ``kv`` KV heads holds in
+    VMEM: the double-buffered blocks, the scratch, the pair's mask and
+    one head's float32 scores and weights."""
+    lanes = lambda n: -(-n // 128) * 128                     # noqa: E731
+    blocks = 2 * itemsize * blk * (g + kv) * (lanes(dk) + lanes(dv))
+    scratch = 4 * blk * g * (lanes(dv) + 2 * 128)
+    pair = blk * lanes(blk) * (4 + 3 * 4 + (2 if has_sel else 0))
+    return blocks + scratch + pair
+
+
+def packed_heads_a_step(hq, n_kv, blk, dk, dv, itemsize=2, has_sel=False):
+    """How many query heads one grid step scores: the largest of 8 / 4 /
+    2 / 1 that lies inside one KV head's queries (or, a KV head a query
+    head, divides the heads) and whose blocks fit the kernel's VMEM."""
+    group = hq // n_kv
+    for g in _HEADS_A_STEP:
+        if (group if group > 1 else hq) % g == 0 and _packed_vmem_bytes(
+                g, 1 if group > 1 else g, blk, dk, dv, itemsize,
+                has_sel) <= _PACKED_VMEM_BUDGET:
+            return g
+    return 1
+
+
+def packed_grid_steps(S, hq, n_kv, dk, dv, window=None, has_sel=False):
+    """``(grid steps of the kernel, grid steps of a (head, q block, k
+    block) grid)`` for one call on these shapes."""
+    blk = packed_block(S)
+    n_live = packed_live_pairs(S, blk, window).shape[1]
+    g = packed_heads_a_step(hq, n_kv, blk, dk, dv, has_sel=has_sel)
+    return hq // g * n_live, hq * (S // blk) ** 2
+
+
+def _packed_kernel(iq_ref, ik_ref, flag_ref, q_ref, k_ref, v_ref, sq_ref,
+                   skv_ref, *rest, scale, blk, heads, shared_kv, window,
+                   has_sink, has_sel=False):
     if has_sel:
         sel_ref, *rest = rest
     if has_sink:
-        sink_ref, o_ref, acc_scr, m_scr, l_scr = rest
-    else:
-        o_ref, acc_scr, m_scr, l_scr = rest
-    iq, ik = pl.program_id(1), pl.program_id(2)
+        sink_ref, *rest = rest
+    o_ref, acc_scr, m_scr, l_scr, mask_scr = rest
+    pair = pl.program_id(1)
+    iq, ik, flags = iq_ref[pair], ik_ref[pair], flag_ref[pair]
 
-    @pl.when(ik == 0)
+    @pl.when(flags & PAIR_FIRST != 0)
     def _init():
         acc_scr[...] = jnp.zeros_like(acc_scr)
         if has_sink:
@@ -1051,34 +1122,43 @@ def _packed_kernel(q_ref, k_ref, v_ref, sq_ref, skv_ref, *rest, scale,
             m_scr[...] = jnp.full_like(m_scr, jnp.float32(-1e30))
             l_scr[...] = jnp.zeros_like(l_scr)
 
-    live = ik <= iq                        # not wholly above the diagonal
-    if window is not None:                 # nor wholly behind the window
-        live = live & ((ik + 1) * blk - 1 > iq * blk - window)
+    # the pair's mask, once for every head of the step
+    hidden = sq_ref[...] != skv_ref[...]
+    if has_sel:
+        hidden = hidden | (sel_ref[...].astype(jnp.int32) == 0)
+    crosses = flags & PAIR_CROSSES != 0
 
-    @pl.when(live)
-    def _block():
-        s = lax.dot_general(q_ref[...], k_ref[...],
+    @pl.when(crosses)
+    def _on_an_edge():
+        row = iq * blk + lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+        col = ik * blk + lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+        behind = col > row
+        if window is not None:
+            behind = behind | (row - col >= window)
+        mask_scr[...] = (hidden | behind).astype(jnp.int32)
+
+    @pl.when(jnp.logical_not(crosses))
+    def _inside():
+        mask_scr[...] = hidden.astype(jnp.int32)
+
+    for h in range(heads):
+        kv = ... if shared_kv else h    # one K / V block for all, or its own
+        masked = mask_scr[...] != 0
+        s = lax.dot_general(q_ref[h], k_ref[kv],
                             (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-        row = iq * blk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = ik * blk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        masked = (col > row) | (sq_ref[...] != skv_ref[...])
-        if window is not None:
-            masked = masked | (row - col >= window)
-        if has_sel:
-            masked = masked | (sel_ref[...].astype(jnp.int32) == 0)
         s = jnp.where(masked, jnp.float32(-1e30), s * jnp.float32(scale))
-        m_prev = m_scr[...]
+        m_prev = m_scr[h]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(masked, 0.0, jnp.exp(s - m_new))
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * alpha + lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[kv], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        m_scr[h] = m_new
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(flags & PAIR_LAST != 0)
     def _finish():
         l = l_scr[...]
         o_ref[...] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)).astype(
@@ -1098,52 +1178,71 @@ def packed_gqa_attention_pallas(q, k, v, seg, sm_scale, *, window=None,
                                  and not packed_supported(S, dk, dv)):
         raise ValueError(f"packed_gqa_attention_pallas: unsupported "
                          f"q {q.shape} k {k.shape} v {v.shape}")
-    group, n_blk = hq // n_kv, S // blk
-    has_sink = sink is not None
+    heads = packed_heads_a_step(hq, n_kv, blk, dk, dv, q.dtype.itemsize,
+                                selected is not None)
+    return _packed_call(q, k, v, seg, sink, selected, heads=heads,
+                        sm_scale=float(sm_scale), window=window,
+                        interpret=bool(interpret))
 
-    def k_block(iq, ik):
-        # a block that is not live keeps the index of one that is: an
-        # unchanged block index is not fetched again
-        lo = 0 if window is None else \
-            jnp.maximum(iq * blk - window + 1, 0) // blk
-        return jnp.clip(ik, lo, iq)
 
-    q_spec = pl.BlockSpec((None, blk, dk), lambda h, iq, ik: (h, iq, 0))
+@functools.partial(jax.jit, static_argnames=("heads", "sm_scale", "window",
+                                             "interpret"))
+def _packed_call(q, k, v, seg, sink, selected, *, heads, sm_scale, window,
+                 interpret):
+    """``heads`` query heads a grid step. A ``jit`` of its own so that a
+    program traces and lowers the kernel once for all its layers of one
+    shape: a step's unrolled heads cost every trace of it ~0.5 s."""
+    hq, S, dk = q.shape
+    n_kv, dv = k.shape[0], v.shape[2]
+    blk = packed_block(S)
+    group = hq // n_kv
+    has_sink, has_sel = sink is not None, selected is not None
+    pairs = packed_live_pairs(S, blk, window)
+    if group > 1:     # a step's heads lie inside one KV head's queries
+        kv_heads, kv_of = None, lambda g: g * heads // group  # noqa: E731
+    else:
+        kv_heads, kv_of = heads, lambda g: g                  # noqa: E731
+
     in_specs = [
-        q_spec,
-        pl.BlockSpec((None, blk, dk),
-                     lambda h, iq, ik: (h // group, k_block(iq, ik), 0)),
-        pl.BlockSpec((None, blk, dv),
-                     lambda h, iq, ik: (h // group, k_block(iq, ik), 0)),
-        pl.BlockSpec((blk, 1), lambda h, iq, ik: (iq, 0)),
-        pl.BlockSpec((1, blk), lambda h, iq, ik: (0, k_block(iq, ik))),
+        pl.BlockSpec((heads, blk, dk),
+                     lambda g, p, iq, ik, fl: (g, iq[p], 0)),
+        pl.BlockSpec((kv_heads, blk, dk),
+                     lambda g, p, iq, ik, fl: (kv_of(g), ik[p], 0)),
+        pl.BlockSpec((kv_heads, blk, dv),
+                     lambda g, p, iq, ik, fl: (kv_of(g), ik[p], 0)),
+        pl.BlockSpec((blk, 1), lambda g, p, iq, ik, fl: (iq[p], 0)),
+        pl.BlockSpec((1, blk), lambda g, p, iq, ik, fl: (0, ik[p])),
     ]
     seg = seg.astype(jnp.int32)
     operands = [q, k, v, seg[:, None], seg[None, :]]
-    kw = {}
-    if selected is not None:
+    if has_sel:
         in_specs.append(pl.BlockSpec(
-            (blk, blk), lambda h, iq, ik: (iq, k_block(iq, ik))))
+            (blk, blk), lambda g, p, iq, ik, fl: (iq[p], ik[p])))
         operands.append(selected.astype(jnp.int8))
-        kw["has_sel"] = True
     if has_sink:
-        in_specs.append(pl.BlockSpec((None, 1, 1), lambda h, iq, ik: (h, 0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (heads, 1, 1), lambda g, p, iq, ik, fl: (g, 0, 0)))
         operands.append(sink.astype(jnp.float32).reshape(hq, 1, 1))
-    kern = functools.partial(_packed_kernel, scale=float(sm_scale), blk=blk,
-                             n_k=n_blk, window=window, has_sink=has_sink,
-                             **kw)
+    kern = functools.partial(_packed_kernel, scale=sm_scale, blk=blk,
+                             heads=heads, shared_kv=group > 1, window=window,
+                             has_sink=has_sink, has_sel=has_sel)
     return pl.pallas_call(
         kern,
-        grid=(hq, n_blk, n_blk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, blk, dv), lambda h, iq, ik: (h, iq, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(hq // heads, pairs.shape[1]),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((heads, blk, dv),
+                                   lambda g, p, iq, ik, fl: (g, iq[p], 0)),
+            scratch_shapes=[pltpu.VMEM((heads, blk, dv), jnp.float32),
+                            pltpu.VMEM((heads, blk, 1), jnp.float32),
+                            pltpu.VMEM((heads, blk, 1), jnp.float32),
+                            pltpu.VMEM((blk, blk), jnp.int32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((hq, S, dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk, dv), jnp.float32),
-                        pltpu.VMEM((blk, 1), jnp.float32),
-                        pltpu.VMEM((blk, 1), jnp.float32)],
         interpret=interpret,
         name=PACKED_KERNEL_NAME,
-    )(*operands)
+    )(*(jnp.asarray(row) for row in pairs), *operands)
 
 
 # --------------------------------------- the indexer's scores (prefill)

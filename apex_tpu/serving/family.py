@@ -231,6 +231,22 @@ def _held_row_attrs(cfg, extras, trunk_rows):
                 expert_rows_full=int((held > rows).sum()))
 
 
+def _attend_step_attrs(trunk_rows, layers):
+    """``prefill.fetch``'s account of the packed attention kernel's grid
+    (``ops.attention.packed_attention_grid``): the steps the dispatch's
+    layers took, a group of heads on a live block pair each, and what a
+    grid of one step a (head, q block, k block) would have taken.
+    ``layers``: ``(hq, n_kv, dk, dv, window, selected)`` a layer. Both 0
+    where the jnp form runs."""
+    from apex_tpu.ops.attention import packed_attention_grid
+
+    steps = [packed_attention_grid(trunk_rows, hq, n_kv, dk, dv,
+                                   window=window, selected=selected)
+             for hq, n_kv, dk, dv, window, selected in layers]
+    return dict(attend_steps=sum(s for s, _ in steps),
+                attend_steps_dense=sum(d for _, d in steps))
+
+
 def _mimo():
     import jax.numpy as jnp
 
@@ -263,6 +279,13 @@ def _mimo():
         live, ring = scheduler.context_pages(cfg.sliding_window)
         return dict(global_pages_live=live, window_pages=ring)
 
+    def prefill_attrs(cfg, extras, trunk_rows):
+        layers = [(cfg.num_attention_heads, *mimo.layer_geometry(cfg, window),
+                   cfg.sliding_window if window else None, False)
+                  for window in map(bool, cfg.hybrid_layer_pattern)]
+        return dict(_held_row_attrs(cfg, extras, trunk_rows),
+                    **_attend_step_attrs(trunk_rows, layers))
+
     return Family(
         name="mimo", check_config=mimo.check_config,
         init_params=mimo.init_params,
@@ -271,7 +294,7 @@ def _mimo():
         prefill=prefill, prefill_rows=prefill_rows,
         decode_step=decode_step,
         decode_attention=decode_attention, fetch_attrs=_expert_attrs,
-        prefill_attrs=_held_row_attrs,
+        prefill_attrs=prefill_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
         one_prefill_a_round=True, model_type="mimo_v2",
         config_from_dict=mimo.MiMoConfig.from_dict)
@@ -310,6 +333,13 @@ def _axk1():
         live, _ = scheduler.context_pages()
         return dict(latent_pages_live=live)
 
+    def prefill_attrs(cfg, extras, trunk_rows):
+        h = cfg.num_attention_heads     # expanded: a KV head a query head
+        layers = [(h, h, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim,
+                   cfg.v_head_dim, None, False)] * cfg.num_layers
+        return dict(_held_row_attrs(cfg, extras, trunk_rows),
+                    **_attend_step_attrs(trunk_rows, layers))
+
     return Family(
         name="axk1", check_config=axk1.check_config,
         init_params=axk1.init_params,
@@ -318,7 +348,7 @@ def _axk1():
         prefill=prefill, prefill_rows=prefill_rows,
         decode_step=decode_step,
         decode_attention=decode_attention, fetch_attrs=_expert_attrs,
-        prefill_attrs=_held_row_attrs,
+        prefill_attrs=prefill_attrs,
         round_attrs=round_attrs, refused=tuple(OPTIONS_OFF),
         one_prefill_a_round=True, model_type="axk1",
         config_from_dict=axk1.AXK1Config.from_dict)
@@ -362,7 +392,11 @@ def _dots3():
     def prefill_attrs(cfg, extras, trunk_rows):
         # the dispatch's (query, key) pairs: every pair the indexer has
         # to score, and those the selection leaves to attend to
+        layers = [(kd.heads, kd.heads, kd.nope + kd.rope, kd.dv, kd.window,
+                   kd.window is None and trunk_rows > cfg.index_topk)
+                  for kd, _ in dots3.layer_kinds(cfg)]
         return dict(_held_row_attrs(cfg, extras, trunk_rows),
+                    **_attend_step_attrs(trunk_rows, layers),
                     index_pairs=int(extras["index_pairs"]),
                     sparse_pairs=int(extras["sparse_pairs"]))
 

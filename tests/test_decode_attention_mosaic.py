@@ -463,6 +463,42 @@ def test_selection_kernels_compile_for_the_v5e(one_chip):
     assert ap.PACKED_KERNEL_NAME in text
 
 
+@pytest.mark.parametrize("hq,dk,window,selected", [
+    (128, 192, None, True), (64, 256, 513, False)],
+    ids=["full-under-a-selection", "sliding-513"])
+def test_packed_prefill_kernel_compiles_at_dots3s_8192_rows(
+        one_chip, hq, dk, window, selected):
+    """The cell's longest trunk, a KV head a query head, V 128 wide: the
+    full layers' 128 heads of 192 under an int8 ``[S, S]`` selection (528
+    live block pairs a group of heads) and the sliding layers' 64 heads
+    of 256 under a window of 513 (93), at the heads a step the rule
+    gives these widths."""
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.ops import attention_pallas as ap
+
+    S, dv, bf = SPARSE["rows"], 128, jnp.bfloat16
+    heads = ap.packed_heads_a_step(hq, hq, 256, dk, dv, 2, selected)
+    assert heads > 1 and ap.packed_grid_steps(
+        S, hq, hq, dk, dv, window, selected) == (
+            hq // heads * (528 if window is None else 93), hq * 32 * 32)
+
+    def f(q, k, v, seg, *sel):
+        if sel:
+            return attn.selected_attention(q, k, v, seg, sel[0],
+                                           sm_scale=0.07, impl="pallas",
+                                           interpret=False)
+        return attn.packed_gqa_attention(q, k, v, seg, sm_scale=0.06,
+                                         window=window, impl="pallas",
+                                         interpret=False)
+
+    args = [_sds(one_chip, (hq, S, dk), bf), _sds(one_chip, (hq, S, dk), bf),
+            _sds(one_chip, (hq, S, dv), bf), _sds(one_chip, (S,), jnp.int32)]
+    if selected:
+        args.append(_sds(one_chip, (S, S), jnp.int8))
+    text = jax.jit(f).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and ap.PACKED_KERNEL_NAME in text
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_dots3_program_compiles_with_no_copy_of_a_pool_leaf(
         one_chip, monkeypatch, program):

@@ -228,7 +228,7 @@ def test_the_program_holds_one_switch_whose_branches_run_fewer_rows(params):
 
 @pytest.mark.parametrize("rows,kernels,digest", [
     (2048, dict(attn_impl="pallas", moe_impl="pallas", interpret=False),
-     "9c782d2c6232c3f0625c2023c7870791c5476c1f2aa9202b1658ee033e2f8ca7"),
+     "d4f405886cbdf8d5fc2a0ff76c71fbb8542b08e7d6bff44074188b62d1094530"),
     (128, {},
      "2fbd87d8ed8be3320938020b699d0a5829f09536f7272d3ed33241d70ad15a17"),
 ], ids=["cell-2048", "rehearsal-128"])
@@ -241,7 +241,10 @@ def test_mimo_prefill_program_is_the_one_pr32_measured(
     moving to the family seam in PR 30 left alone) plus the expert
     counts it now returns and, in the 2,048-row trunk, the expert
     layer's ONE cond on the held assignments (``moe.held_row_bound``;
-    the shorter trunks and the 128-row program hold none). Source
+    the shorter trunks and the 128-row program hold none); since PR 34
+    the 2,048-row text holds the packed kernel's new ``pallas_call`` (a
+    grid of live block pairs, a group of heads a step, inside a ``jit``
+    of its own), the 128-row ``jnp`` text is as it was. Source
     positions and object addresses are cut out, as in
     ``test_decode_attention_mosaic``'s digest of the decode program. A
     PR that changes MiMo's prefill on purpose records the new digests."""
