@@ -56,7 +56,8 @@ SUITES = {
                 "test_fault_sites.py", "test_import_arrows.py",
                 "test_docs_guard.py", "test_apexlint.py"],
     "benchmark": ["test_benchmark_rehearsal_train.py",
-                  "test_benchmark_rehearsal_serve.py"],
+                  "test_benchmark_rehearsal_serve.py",
+                  "test_span_account.py"],
     "telemetry": ["test_telemetry.py", "test_bench_labels.py",
                   "test_dispatch.py", "test_dispatch_tiles.py",
                   "test_costs.py", "test_window_report.py",

@@ -499,7 +499,7 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
 
 
 def decode_attention_resolved(cfg, cache, decode_impl):
-    """The decode-attention impl, for the ``decode.dispatch`` span."""
+    """The decode-attention impl (``engine.decode_attn_impl``)."""
     from apex_tpu.ops import decode_attention_pallas as dap
 
     leaf = cache["latent"][0]

@@ -506,11 +506,9 @@ class ServingEngine:
                                   kernels=self._kernels)
 
         # which decode-attention program the decode program is built
-        # with ("pallas" | "jnp"); the decode.dispatch span carries it
+        # with ("pallas" | "jnp")
         self.decode_attn_impl = family.decode_attention(
             cfg, self.cache, self._kernels)
-        self._dispatch_attrs = {"prefill": {}, "decode": dict(
-            attn_impl=self.decode_attn_impl)}
 
         # the decode program: at K=1 the single decode step; at K>1 the
         # ONE lax.scan K-block program replaces it (K is static — at
@@ -730,8 +728,7 @@ class ServingEngine:
         def call():
             # the call until it returns its futures (with ``recover``
             # on, the fetch too: it is inside the watchdog)
-            with spans.span(f"{program}.dispatch",
-                            **self._dispatch_attrs[program]):
+            with spans.span(f"{program}.dispatch"):
                 faults_mod.fire(site, tick=self.tick,
                                 step=self.decode_steps,
                                 call=self.prefill_batches)
@@ -1058,7 +1055,8 @@ class ServingEngine:
         if None in walls or list(walls) != sorted(walls):
             return
         spans.record("request.queue", walls[0], walls[1], rid=req.rid,
-                     prompt=len(req.prompt))
+                     prompt=len(req.prompt), rounds=req.queued_rounds,
+                     blocked=req.blocked)
         spans.record("request.prefill", walls[1], walls[2], rid=req.rid)
         spans.record("request.decode", walls[2], walls[3], rid=req.rid,
                      tokens=len(req.out_tokens))
@@ -1487,8 +1485,17 @@ class ServingEngine:
         dryrun/trace-replay surface). In overlap mode
         (``overlap=`` / ``APEX_SERVE_OVERLAP``) the round is the
         deferred-fetch pipelined variant — same schedule, same tokens
-        (see the module docstring); the serial body is untouched."""
+        (see the module docstring); the serial body is untouched.
+
+        The ``engine.round`` span carries ``cpu_s``: this thread's CPU
+        seconds over the round (``time.thread_time``; a wait inside
+        ``np.asarray`` does not advance it), so a round that ran tens
+        of milliseconds over says whether the thread was running
+        meanwhile. With ``recover`` on (default off) each dispatch runs
+        on the watchdog's thread and its CPU is not the round's."""
         with spans.span("engine.round", tick=self.tick) as sp:
+            recording = spans.enabled()
+            cpu0 = recording and time.thread_time()
             info = self._step_overlap(arrivals) if self.overlap \
                 else self._step_serial(arrivals)
             # every (rid, n_tokens, wall) whose tokens a fetch of this
@@ -1496,9 +1503,11 @@ class ServingEngine:
             # fetch of the round before)
             emitted, self._emitted = self._emitted, []
             attrs, self._round_attrs = self._round_attrs, {}
-            if self.family.round_attrs is not None and spans.enabled():
-                attrs.update(self.family.round_attrs(self.cfg,
-                                                     self.scheduler))
+            if recording:
+                if self.family.round_attrs is not None:
+                    attrs.update(self.family.round_attrs(self.cfg,
+                                                         self.scheduler))
+                attrs["cpu_s"] = time.thread_time() - cpu0
             sp.set(prefilled=len(info["prefilled"]),
                    decoded=info["decoded_slots"], emitted=emitted, **attrs)
         return info
@@ -1635,8 +1644,8 @@ class ServingEngine:
                                        sch.slots[i].request.rid,
                                        tick=now, wall=wall)
             to_prefill = self._cow_prefix_hits(admitted)
-            sp.set(evicted=len(evicted), admitted=len(admitted),
-                   queue_depth=sch.queue_depth())
+            sp.set(admitted=len(admitted), queue_depth=sch.queue_depth(),
+                   stopped=sch.stopped)
         self.resilience.admissions += len(admitted)
         prefilled = self._run_prefill(to_prefill) if to_prefill else []
         active = sch.active_indices()

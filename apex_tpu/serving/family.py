@@ -31,8 +31,8 @@ A family is a :class:`Family` of plain functions:
 * ``decode_block`` (K steps in one dispatch) and
   ``quantize_decode_params``, or None where the family has none;
 * ``decode_attention(cfg, cache, kernels)`` -> the impl ("pallas" |
-  "jnp") the decode program is built with, for the ``decode.dispatch``
-  span;
+  "jnp") the decode program is built with
+  (``engine.decode_attn_impl``);
 * ``refused``: the engine options the family cannot honour, by name. A
   per-call demand of one raises at engine build, naming it; an
   environment preference for one is dropped (CLAUDE.md: explicit request
@@ -402,9 +402,9 @@ def _dots3():
 
     def round_attrs(cfg, scheduler):
         # pool pages that hold context (a latent and an index-key page a
-        # full layer each), ring pages that hold part of a window
-        live, ring = scheduler.context_pages(cfg.sliding_window_size)
-        return dict(latent_pages_live=live, window_pages=ring)
+        # full layer each)
+        live, _ = scheduler.context_pages()
+        return dict(latent_pages_live=live)
 
     return Family(
         name="dots3", check_config=dots3.check_config,

@@ -468,8 +468,8 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
 
 
 def decode_attention_resolved(cfg, cache, decode_impl):
-    """The decode-attention impl of each layer kind, for the
-    ``decode.dispatch`` span: ``"pallas"``, ``"jnp"`` or a mix."""
+    """The decode-attention impl of each layer kind
+    (``engine.decode_attn_impl``): ``"pallas"``, ``"jnp"`` or a mix."""
     from apex_tpu.ops import decode_attention_pallas as dap
 
     impls = set()

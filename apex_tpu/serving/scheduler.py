@@ -133,6 +133,11 @@ class Request:
     first_token_wall: Optional[float] = None
     admitted_tick: Optional[int] = None
     finished_tick: Optional[int] = None
+    # why it waited (``admit``): the admit calls that left it queued,
+    # and the last one's reason, ``ContinuousBatchingScheduler.stopped``
+    # for the candidate admission stopped at, "behind" for the rest
+    queued_rounds: int = 0
+    blocked: Optional[str] = None
 
     def done(self):
         return len(self.out_tokens) >= self.max_new_tokens
@@ -187,6 +192,10 @@ class ContinuousBatchingScheduler:
         self.queue = deque()
         self.completed = []
         self.shed = []            # deadline-shed requests (engine-fed)
+        # why the last :meth:`admit` call left requests queued: "slots"
+        # (no free slot), "budget" (``token_budget``), "pages" (the
+        # allocator refused); None when the queue emptied
+        self.stopped = None
         self._preempted = []      # requests preempted since last drain
 
     # ------------------------------------------------------- bookkeeping
@@ -311,8 +320,12 @@ class ContinuousBatchingScheduler:
         counts. With a prefix cache attached, the prompt's cached
         cover enters the slot by reference (full pages) and
         copy-on-write (partial tail), and only the remainder
-        allocates."""
+        allocates. A call that leaves requests queued says why in
+        ``self.stopped`` and on each of them (``Request.blocked``,
+        ``Request.queued_rounds``): one write a queued request, none
+        with the queue empty."""
         admitted = []
+        stopped = None
         while self.queue:
             req = self._select(tick)
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -321,10 +334,12 @@ class ContinuousBatchingScheduler:
             # queued is admittable once slots/pages free up
             assert need <= self.max_pages, (req.rid, need)
             if not free:
+                stopped = "slots"
                 break
             known = req.resume_tokens or req.prompt
             if token_budget is not None and admitted \
                     and len(known) > token_budget:
+                stopped = "budget"
                 break
             shared, covered, tail = [], 0, None
             # a RESUMED request skips the prefix lookup: its effective
@@ -346,6 +361,7 @@ class ContinuousBatchingScheduler:
                                              reserve - len(shared),
                                              protect=matched, tick=tick)
             if pages is None:
+                stopped = "pages"
                 break
             self.queue.remove(req)
             idx = free[0]
@@ -373,6 +389,12 @@ class ContinuousBatchingScheduler:
             admitted.append(idx)
             if token_budget is not None:
                 token_budget -= len(known)
+        self.stopped = stopped
+        if stopped is not None:
+            for waiting in self.queue:
+                waiting.queued_rounds += 1
+                waiting.blocked = "behind"
+            req.blocked = stopped
         return admitted
 
     # -------------------------------------- KV-pressure preemption (15)

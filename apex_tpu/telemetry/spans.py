@@ -24,11 +24,20 @@ round, 11 with a prefill, 3 a finished request) = ~240 records/s: a 51 s
 window, a 40 s drain and the ramp before them are ~25,000 records, a
 fifth of the ring, and ``covers(t_open)`` held there (every
 ``program_span`` metric of its traced run read). At that cell's 8.4 ms
-byte floor (119 rounds/s) it would be ~80,000: still inside. A record is
-a 7-tuple of
-two floats, two ints, a shared name and an optional small dict, about
-250 bytes (500 with attributes), so a full ring is bounded by ~64 MB and
-a day-long server holds its last 2**17 spans, no more. What fell off is
+byte floor (119 rounds/s) it would be ~80,000: still inside. PR 35 added
+attributes, not records (``cpu_s`` on the round, ``stopped``,
+``rounds`` and ``blocked``; ``attn_impl``, ``evicted`` and a family's
+unread page count went), so the rate stands. A record is a
+7-tuple of two floats, two ints, a shared name and an optional dict:
+200 bytes bare, ~410 with one attribute, ~460 with three
+(``sys.getsizeof`` over a served trace, CPython 3.12). ``engine.round``
+is the heavy one: ~650 bytes of counts and ~100 more for every lane in
+``emitted``, so ~7 kB at that cell's 64 lanes and ~9 kB for the six
+records of its decode round, ~1.5 kB a record and ~300 kB/s (``cpu_s``
+is one float of those 9 kB). The 25,000
+records of a run are ~40 MB; a full ring there is bounded by ~200 MB
+(~55 MB at GPT-2's 16 lanes), and a day-long server holds its last
+2**17 spans, nine minutes at that rate, no more. What fell off is
 counted in ``dropped()``, and :func:`covers` says whether everything
 since a given stamp is still held, so that a reader returns nothing
 rather than a number from a truncated window.

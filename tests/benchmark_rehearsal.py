@@ -118,10 +118,11 @@ def check_correct(rehearsed, which):
 # A reader sums its root's children out of a defaultdict, so a child
 # span the program has renamed reads 0.0, not None: the value stays
 # finite and only the name can tell. Every dotted string constant of
-# perf/span_ring.py and perf/layer_metrics/ has to be a string literal
-# of the program.
+# perf/span_ring.py, perf/span_account.py and perf/layer_metrics/ has to
+# be a string literal of the program.
 
 _SPAN_NAME = re.compile(r"^[a-z_]+\.[a-z_]+$")
+_HELPERS = ("span_ring.py", "span_account.py")
 
 
 def _string_constants(path):
@@ -142,23 +143,63 @@ def _string_constants(path):
     return out
 
 
+def _helper_reads(path):
+    """``{top-level name of a helper: the span names whoever uses that
+    name reads}``: a function's own dotted constants, and those of every
+    top-level function or constant of the file it refers to, however far
+    down (``first_token_parts`` -> ``requests``; ``rounds`` ->
+    ``WAITS``)."""
+    with open(path) as fh:
+        tree = ast.parse(fh.read())
+    own, refers = {}, {}
+    for top in tree.body:
+        if isinstance(top, ast.FunctionDef):
+            defined = [top.name]
+        elif isinstance(top, ast.Assign):
+            defined = [t.id for t in top.targets if isinstance(t, ast.Name)]
+        else:
+            continue
+        docs = {id(n.value) for n in ast.walk(top)
+                if isinstance(n, ast.Expr)
+                and isinstance(n.value, ast.Constant)}
+        texts = {n.value for n in ast.walk(top)
+                 if isinstance(n, ast.Constant) and isinstance(n.value, str)
+                 and id(n) not in docs and _SPAN_NAME.match(n.value)}
+        names = {n.id for n in ast.walk(top) if isinstance(n, ast.Name)}
+        for name in defined:
+            own[name], refers[name] = texts, names
+    reads = {}
+    for name in own:
+        seen, todo = set(), [name]
+        while todo:
+            at = todo.pop()
+            if at in own and at not in seen:
+                seen.add(at)
+                todo += refers[at]
+        reads[name] = set().union(*(own[at] for at in seen))
+    return reads
+
+
 @functools.cache
 def spans_perf_reads():
     """``{span name: sorted metric names that read it}``."""
-    ring = _string_constants(os.path.join(REPO, "perf", "span_ring.py"))
+    helpers = {"perf." + name[:-3]: _helper_reads(
+        os.path.join(REPO, "perf", name)) for name in _HELPERS}
     reads = {}
     for path in glob.glob(os.path.join(REPO, "perf", "layer_metrics",
                                        "*.py")):
         metric = os.path.basename(path)[:-3]
-        with open(path) as fh:
-            names = {n.id for n in ast.walk(ast.parse(fh.read()))
-                     if isinstance(n, ast.Name)}
         for text, _ in _string_constants(path).items():
             if _SPAN_NAME.match(text):
                 reads.setdefault(text, set()).add(metric)
-        for text, functions in ring.items():
-            if _SPAN_NAME.match(text) and functions & names:
-                reads.setdefault(text, set()).add(metric)
+        with open(path) as fh:
+            imports = [n for n in ast.walk(ast.parse(fh.read()))
+                       if isinstance(n, ast.ImportFrom)
+                       and n.module in helpers]
+        for node in imports:
+            for alias in node.names:
+                for text in helpers[node.module].get(alias.name, ()):
+                    reads.setdefault(text, set()).add(metric)
     return {span: sorted(metrics) for span, metrics in reads.items()}
 
 

@@ -109,9 +109,7 @@ def test_engine_spans_carry_the_expert_and_latent_counts(bf16_run):
     # the family's own name for its pool, and none of MiMo's
     assert a["latent_pages_live"] >= 1
     assert "global_pages_live" not in a and "window_pages" not in a
-    dispatch = [r for r in spans.snapshot() if r.name == "decode.dispatch"
-                and r.attrs.get("attn_impl")]
-    assert dispatch and dispatch[-1].attrs["attn_impl"] == "jnp"   # the CPU
+    assert bf16_run[2].decode_attn_impl == "jnp"   # the CPU
 
 
 def test_prefill_fetch_span_carries_the_row_bound_account(monkeypatch):

@@ -246,24 +246,15 @@ def _drive(eng, **kw):
 
 def test_engine_reports_its_decode_attention_program():
     """``decode_attn_impl`` says what the decode program was built
-    with, and the ``decode.dispatch`` span carries it: "jnp" for the
-    default engine on the CPU, "pallas" for a demanded (interpreted)
-    kernel, same tokens from both."""
-    from apex_tpu.telemetry import spans
-
-    def last_dispatch():
-        return [r for r in spans.snapshot()
-                if r.name == "decode.dispatch"][-1].attrs
-
+    with: "jnp" for the default engine on the CPU, "pallas" for a
+    demanded (interpreted) kernel, same tokens from both."""
     eng = _engine()
     assert eng.decode_attn_impl == "jnp"
     want = _drive(eng)
-    assert last_dispatch() == {"attn_impl": "jnp"}
 
     eng = _engine(decode_impl="pallas", interpret=True)
     assert eng.decode_attn_impl == "pallas"
     assert _drive(eng) == want
-    assert last_dispatch() == {"attn_impl": "pallas"}
     assert eng.decode_cache_size() == 1
 
 

@@ -156,17 +156,21 @@ def test_disabled_appends_nothing_and_shares_one_null_context():
 # --------------------------------------------------------- the engine round
 
 def test_serving_is_identical_with_spans_on_and_off(params):
-    outs = {}
+    outs, waited = {}, {}
     for on in (True, False):
         spans.set_enabled(on)
         spans.clear()
         engine = _engine(params)
         done = engine.run_trace(_requests())
         outs[on] = {r.rid: list(r.out_tokens) for r in done}
+        # the queue's account lives on the requests, recorder on or off
+        waited[on] = {r.rid: (r.queued_rounds, r.blocked) for r in done}
         assert engine.decode_cache_size() == 1
         assert engine.prefill_cache_size() == 1
         assert bool(spans.snapshot()) is on
     assert outs[True] == outs[False] and len(outs[True]) == 6
+    assert waited[True] == waited[False]
+    assert any(rounds for rounds, _ in waited[True].values())
 
 
 def test_one_round_yields_the_documented_span_tree(params):
@@ -183,8 +187,8 @@ def test_one_round_yields_the_documented_span_tree(params):
     assert root.attrs["tick"] == 0 and root.attrs["prefilled"] == 3
     assert root.attrs["decoded"] == 3
     schedule, pack = children[0], children[1]
-    assert schedule.attrs == {"evicted": 0, "admitted": 3,
-                              "queue_depth": 0}
+    assert schedule.attrs == {"admitted": 3, "queue_depth": 0,
+                              "stopped": None}
     assert pack.attrs == {"rows": 3, "tokens": 5 + 6 + 7, "trunk_rows": 32}
     # a round that only decodes has no prefill span
     spans.clear()
@@ -193,6 +197,87 @@ def test_one_round_yields_the_documented_span_tree(params):
              if not r.name.startswith("request.")]
     assert names == ["engine.schedule", "decode.stage", "decode.dispatch",
                      "decode.fetch", "decode.commit", "engine.round"]
+
+
+def _queue_account(engine, requests, reason):
+    """``({rid: (rounds, blocked)}, [stopped of each engine.schedule])``
+    of three requests sent at once and served to their end; after the
+    first round the second is the candidate, the third behind it."""
+    engine.step(arrivals=requests)
+    assert [(r.queued_rounds, r.blocked) for r in requests] == [
+        (0, None), (1, reason), (1, "behind")]
+    while not all(r.done() for r in requests):
+        engine.step()
+    records = spans.snapshot()
+    waits = {r.rid: (r.attrs["rounds"], r.attrs["blocked"])
+             for r in _named(records, "request.queue")}
+    return waits, [r.attrs["stopped"]
+                   for r in _named(records, "engine.schedule")]
+
+
+def _three(prompt, answer=2):
+    return [Request(rid=i, prompt=[1 + i + j for j in range(prompt)],
+                    max_new_tokens=answer) for i in range(3)]
+
+
+@pytest.mark.parametrize("reason", ["slots", "budget", "pages"])
+def test_the_queue_says_why_admission_stopped(params, reason):
+    """Three requests at once into an engine that takes one a round:
+    the first enters, the second is the candidate admission stops at,
+    the third is behind it; a round later the third is the candidate."""
+    if reason == "slots":       # one slot
+        engine = ServingEngine(_cfg(), params=params, seed=3, num_slots=1,
+                               page_size=8, num_pages=24, max_seq=64,
+                               prefill_len=64)
+        requests = _three(prompt=5)
+    elif reason == "budget":    # two prompts pass one dispatch's tokens
+        engine = _engine(params)
+        engine._admit_tokens = 40
+        requests = _three(prompt=24)
+    else:                       # a pool of 5 pages, 4 a request
+        engine = ServingEngine(_cfg(), params=params, seed=3, num_slots=4,
+                               page_size=8, num_pages=6, max_seq=64,
+                               prefill_len=64)
+        requests = _three(prompt=24, answer=8)
+    waits, stopped = _queue_account(engine, requests, reason)
+    assert waits[0] == (0, None)
+    assert waits[1][1] == reason and waits[1][0] >= 1
+    # the third waited behind the second, then was the candidate itself
+    assert waits[2][1] == reason and waits[2][0] > waits[1][0]
+    assert stopped[0] == reason and stopped[-1] is None
+    assert set(stopped) == {reason, None}
+    assert engine.scheduler.stopped is None
+
+
+def test_the_request_behind_the_candidate_is_marked_behind(params):
+    """The bare scheduler, one admit call: the candidate gets the
+    reason, the one behind it "behind", each one more round queued; a
+    call that empties the queue writes nothing and resets the reason."""
+    engine = _engine(params)
+    sch = engine.scheduler
+    first, second, third = requests = _three(prompt=24)
+    for req in requests:
+        sch.submit(req, tick=0)
+    assert len(sch.admit(0, token_budget=40)) == 1
+    assert sch.stopped == "budget"
+    assert (first.queued_rounds, first.blocked) == (0, None)
+    assert (second.queued_rounds, second.blocked) == (1, "budget")
+    assert (third.queued_rounds, third.blocked) == (1, "behind")
+    assert len(sch.admit(1)) == 2 and sch.stopped is None
+    assert (second.queued_rounds, third.queued_rounds) == (1, 1)
+    assert third.blocked == "behind"       # its last reason stays
+
+
+def test_the_round_carries_its_threads_cpu_seconds(params):
+    engine = _engine(params)
+    engine.run_trace(_requests())
+    rounds = _named(spans.snapshot(), "engine.round")
+    assert rounds
+    for r in rounds:
+        assert 0.0 <= r.attrs["cpu_s"] <= (r.t1 - r.t0) + 1e-3
+    # a thread that computes all through a round reads about its wall
+    # (the CPU backend runs the programs on other threads: no equality)
+    assert sum(r.attrs["cpu_s"] for r in rounds) > 0.0
 
 
 def test_dispatch_and_fetch_spans_add_up_to_device_dispatch_s(params):
@@ -238,7 +323,11 @@ def test_every_token_is_recoverable_and_request_spans_tile(params):
         assert tiles["request.prefill"].t1 == tiles["request.decode"].t0 \
             == req.first_token_wall
         assert tiles["request.decode"].t1 == req.finish_wall
-        assert tiles["request.queue"].attrs == {"prompt": len(req.prompt)}
+        queued = tiles["request.queue"].attrs
+        assert queued["prompt"] == len(req.prompt)
+        assert (queued["rounds"], queued["blocked"]) == (
+            req.queued_rounds, req.blocked)
+        assert (queued["rounds"] >= 1) == (queued["blocked"] is not None)
         assert tiles["request.decode"].attrs == {
             "tokens": req.max_new_tokens}
 
