@@ -49,7 +49,8 @@ SUITES = {
                 "test_serving_tp.py", "test_kv_tier.py",
                 "test_router.py", "test_router_chaos.py",
                 "test_mimo_serving.py", "test_axk1_serving.py",
-                "test_dots3_serving.py", "test_prefill_trunk_rows.py"],
+                "test_dots3_serving.py", "test_prefill_trunk_rows.py",
+                "test_round_halves.py"],
     "api_parity": ["test_api_parity_round3.py"],
     "harness": ["test_run_tests.py", "test_chip_smoke.py",
                 "test_compile_cache.py", "test_resilience.py",

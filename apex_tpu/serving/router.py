@@ -497,6 +497,11 @@ class Router:
             _faults.fire("router_slow", tick=self.tick, replica=r.name)
             return r.engine.step()
 
+        # a replica's round that prefilled takes two fleet rounds
+        # (``ServingEngine.step``), and its own clock counts it once:
+        # it follows the fleet's, so no event of a request is stamped a
+        # tick behind the router's own of the same request
+        r.engine.tick = max(r.engine.tick, self.tick)
         try:
             if self.step_timeout_s:
                 serve_res.guarded_dispatch(call, self.step_timeout_s,
@@ -598,7 +603,10 @@ class Router:
         replica steps (failures classified into the health machine,
         breaker trips drain-and-replay), dead replicas pace their
         probe schedule, draining replicas drive their probe, and
-        orphans retry. Returns the router tick just driven."""
+        orphans retry. Returns the router tick just driven. A
+        replica's step is ONE ``ServingEngine.step`` call: its round
+        that prefilled has its first tokens out after this fleet round
+        and decodes in the next."""
         now = self.tick
         self._autoscale_tick()
         for r in self.replicas:

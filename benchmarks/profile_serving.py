@@ -223,7 +223,8 @@ warm_reqs = [
     for i in range(SLOTS)]
 for r in warm_reqs:
     engine.submit(r)
-engine.step()
+engine.step()       # the round's prefill half,
+engine.step()       # and its decode half
 tokens0, lengths0 = engine.scheduler.decode_inputs()
 pt0 = np.asarray(engine.scheduler.page_table_rows(), np.int32)
 qparams = engine.qparams

@@ -102,7 +102,7 @@ def one_cycle(eng, si, choice):
     os.environ["APEX_SERVE_KV_RESTORE"] = choice
     # apexlint: disable=APX004 — host-clocked restore round: the host wall IS the measured quantity (the §0 scan protocol times device programs; this row compares two host-driven restore paths on one engine)
     t0 = time.perf_counter()
-    eng.step()  # admit + restore(choice) + one decode dispatch
+    eng.step()  # admit + restore(choice); the decode half is the next call's
     # apexlint: disable=APX004 — host-clocked restore round: the host wall IS the measured quantity (the §0 scan protocol times device programs; this row compares two host-driven restore paths on one engine)
     wall = time.perf_counter() - t0
     return wall, tokens

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 import axk1_toy as A
 import mimo_toy as T
+from round_halves import whole_round
 from apex_tpu.ops import decode_attention_pallas as dap
 from apex_tpu.serving import ServingEngine, axk1
 from apex_tpu.serving import family as family_mod
@@ -510,9 +511,9 @@ def test_axk1_engine_prefills_one_dispatch_a_round():
                            prefill_len=32)
     requests = [Request(rid=i, prompt=[7 + i] * 20, max_new_tokens=8)
                 for i in range(4)]
-    info = engine.step(arrivals=requests)
+    info = whole_round(engine, arrivals=requests)
     assert len(info["prefilled"]) == 1 and engine.scheduler.queue_depth() == 3
-    for _ in range(3):
-        assert len(engine.step()["prefilled"]) == 1
+    for _ in range(3):      # a round: its prefill half, its decode half
+        assert len(whole_round(engine)["prefilled"]) == 1
     assert engine.prefill_batches == 4 and engine.scheduler.queue_depth() == 0
     assert len(requests[0].out_tokens) == 5

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 import dots3_toy as D
 import mimo_toy as T
+from round_halves import whole_round
 from apex_tpu.ops import attention as attn_ops
 from apex_tpu.ops import decode_attention_pallas as dap
 from apex_tpu.serving import ServingEngine, dots3
@@ -632,9 +633,9 @@ def test_dots3_engine_prefills_one_dispatch_a_round():
     assert sorted(engine.cache) == ["index", "latent", "ring"]
     requests = [Request(rid=i, prompt=[7 + i] * 20, max_new_tokens=8)
                 for i in range(4)]
-    info = engine.step(arrivals=requests)
+    info = whole_round(engine, arrivals=requests)
     assert len(info["prefilled"]) == 1 and engine.scheduler.queue_depth() == 3
-    for _ in range(3):
-        assert len(engine.step()["prefilled"]) == 1
+    for _ in range(3):      # a round: its prefill half, its decode half
+        assert len(whole_round(engine)["prefilled"]) == 1
     assert engine.prefill_batches == 4 and engine.scheduler.queue_depth() == 0
     assert len(requests[0].out_tokens) == 5

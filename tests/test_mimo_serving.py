@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 
 import mimo_toy as T
+from round_halves import whole_round
 from apex_tpu.serving import ServingEngine
 from apex_tpu.serving import family as family_mod
 from apex_tpu.serving import kv_cache
@@ -668,11 +669,11 @@ def test_mimo_engine_prefills_one_dispatch_a_round():
     assert not family_mod.family_of(object()).one_prefill_a_round
     requests = [Request(rid=i, prompt=[7 + i] * 20, max_new_tokens=8)
                 for i in range(6)]
-    info = engine.step(arrivals=requests)
+    info = whole_round(engine, arrivals=requests)
     batches = [engine.prefill_batches]
     assert len(info["prefilled"]) == 1 and engine.scheduler.queue_depth() == 5
     for _ in range(5):
-        info = engine.step()
+        info = whole_round(engine)      # its prefill half, its decode half
         batches.append(engine.prefill_batches)
         assert len(info["prefilled"]) == 1
     assert batches == [1, 2, 3, 4, 5, 6] and engine.scheduler.queue_depth() == 0

@@ -368,7 +368,8 @@ def test_decode_jaxpr_stable_across_admit_evict(f32_setup):
     a = Request(rid=0, prompt=[3, 5, 7, 9], max_new_tokens=10)
     b = Request(rid=1, prompt=[2, 4], max_new_tokens=3)
     eng.submit(a)
-    eng.step()
+    eng.step()                    # the round's prefill half,
+    eng.step()                    # and its decode half
     size_before = eng.decode_cache_size()
     eng.step(arrivals=[b])        # admit mid-stream
     while not (a.done() and b.done()):

@@ -421,9 +421,12 @@ def test_without_watchdog_the_engine_dies(setup, monkeypatch):
     _plan(monkeypatch, [{"site": "serve_decode", "kind": "raise",
                          "message": "relay reset by peer",
                          "match_ctx": {"tick": 0}}])
-    eng.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=4))
+    req = Request(rid=0, prompt=[1, 2, 3], max_new_tokens=4)
+    eng.submit(req)
+    eng.step()                  # the round returns at its first token
+    assert len(req.out_tokens) == 1 and eng.tick == 0
     with pytest.raises(RuntimeError, match="relay reset"):
-        eng.step()
+        eng.step()              # the same round's decode half
 
 
 # ------------------------------------------------ combined / overlap

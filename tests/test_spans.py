@@ -175,18 +175,28 @@ def test_serving_is_identical_with_spans_on_and_off(params):
 
 def test_one_round_yields_the_documented_span_tree(params):
     engine = _engine(params)
-    engine.step(arrivals=_requests(3))     # admits, prefills and decodes
+    engine.step(arrivals=_requests(3))     # admits and prefills
+    engine.step()                          # the same round: decodes
     records = spans.snapshot()
-    root, = _named(records, "engine.round")
-    children = [r for r in records if r.parent == root.id
-                and not r.name.startswith("request.")]
-    assert [r.name for r in children] == ROUND_CHILDREN
-    assert root.t0 <= children[0].t0 and children[-1].t1 <= root.t1
-    for before, after in zip(children, children[1:]):
-        assert before.t1 <= after.t0      # inside the parent, no overlap
-    assert root.attrs["tick"] == 0 and root.attrs["prefilled"] == 3
-    assert root.attrs["decoded"] == 3
-    schedule, pack = children[0], children[1]
+    first, second = _named(records, "engine.round")
+    halves = [[r for r in records if r.parent == root.id
+               and not r.name.startswith("request.")]
+              for root in (first, second)]
+    # the round's tree, cut where the first call returned: behind the
+    # prefill's commit
+    assert [r.name for r in halves[0]] == ROUND_CHILDREN[:6]
+    assert [r.name for r in halves[1]] == ROUND_CHILDREN[6:]
+    for root, children in zip((first, second), halves):
+        assert root.t0 <= children[0].t0 and children[-1].t1 <= root.t1
+        for before, after in zip(children, children[1:]):
+            assert before.t1 <= after.t0  # inside the parent, no overlap
+    assert first.t1 <= second.t0
+    assert first.attrs["tick"] == 0 and first.attrs["prefilled"] == 3
+    assert first.attrs["decoded"] == 0
+    assert first.attrs["returned"] == "prefill"
+    assert second.attrs["tick"] == 0 and second.attrs["prefilled"] == 0
+    assert second.attrs["decoded"] == 3 and "returned" not in second.attrs
+    schedule, pack = halves[0][0], halves[0][1]
     assert schedule.attrs == {"admitted": 3, "queue_depth": 0,
                               "stopped": None}
     assert pack.attrs == {"rows": 3, "tokens": 5 + 6 + 7, "trunk_rows": 32}
