@@ -46,7 +46,8 @@ SUITES = {
                 "test_serving_resilience.py",
                 "test_serving_chaos.py",
                 "test_serving_multitok.py",
-                "test_serving_tp.py", "test_kv_tier.py",
+                "test_serving_tp.py", "test_serving_weights.py",
+                "test_kv_tier.py",
                 "test_router.py", "test_router_chaos.py",
                 "test_mimo_serving.py", "test_axk1_serving.py",
                 "test_dots3_serving.py", "test_prefill_trunk_rows.py",

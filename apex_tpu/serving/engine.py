@@ -201,6 +201,22 @@ def detokenize(tokens):
     return "".join(chr(97 + int(t) % 26) for t in tokens)
 
 
+_DTYPE_TAGS = {"float32": "fp32", "bfloat16": "bf16", "float16": "fp16"}
+
+
+def _weight_attrs(params, served):
+    """``engine.weights``' attributes: the bytes of the tree the programs
+    read by dtype (``bytes_bf16``, ``bytes_fp32``, ...) and how many of
+    its leaves are copies made from the caller's tree."""
+    own = {id(leaf) for leaf in jax.tree_util.tree_leaves(params)}
+    attrs = {"leaves_cast": 0}
+    for leaf in jax.tree_util.tree_leaves(served):
+        key = "bytes_" + _DTYPE_TAGS.get(leaf.dtype.name, leaf.dtype.name)
+        attrs[key] = attrs.get(key, 0) + int(leaf.nbytes)
+        attrs["leaves_cast"] += id(leaf) not in own
+    return attrs
+
+
 class ServingEngine:
     def __init__(self, cfg, params=None, *, num_slots=4, page_size=16,
                  num_pages=64, max_seq=None, prefill_len=64,
@@ -478,6 +494,14 @@ class ServingEngine:
                 self.qparams = jax.device_put(
                     self.qparams,
                     tp_mod.qparams_shardings(self.qparams, self.mesh))
+        # the tree both programs are handed (the family's
+        # ``serve_params``, built once where the params lie);
+        # ``self.params`` stays the caller's
+        with spans.span("engine.weights") as sp:
+            self.served_params = self.params \
+                if self.family.serve_params is None \
+                else self.family.serve_params(self.params, cfg)
+            sp.set(**_weight_attrs(self.params, self.served_params))
         self.cache = self._fresh_cache()
         self.allocator = self.prefix.allocator if self.prefix \
             is not None else PageAllocator(num_pages)
@@ -1134,7 +1158,7 @@ class ServingEngine:
             sp.set(tokens=cursor, trunk_rows=trunk_rows)
         t0 = time.perf_counter()
         with spans.span("prefill.stage"):
-            args = [self.params, self.cache, jnp.asarray(ids),
+            args = [self.served_params, self.cache, jnp.asarray(ids),
                     jnp.asarray(positions), jnp.asarray(seg),
                     jnp.asarray(token_rows), jnp.asarray(pt),
                     jnp.asarray(gather_idx)]
@@ -1424,7 +1448,7 @@ class ServingEngine:
                 for i in assert_lanes:
                     self._assert_writable(sch.slots[i], sch.slots[i].pos,
                                           sch.slots[i].pos)
-            args = [self.params, self.qparams, self.cache,
+            args = [self.served_params, self.qparams, self.cache,
                     jnp.asarray(tokens, dtype=jnp.int32),
                     jnp.asarray(lengths, dtype=jnp.int32),
                     jnp.asarray(pt)]

@@ -30,6 +30,9 @@ A family is a :class:`Family` of plain functions:
   ``prefill.pack`` span;
 * ``decode_block`` (K steps in one dispatch) and
   ``quantize_decode_params``, or None where the family has none;
+* ``serve_params(params, cfg)``: the tree both programs are handed,
+  built once from the caller's (the GPT-2 family's matmul weights in
+  the compute dtype), or None where they read the caller's tree;
 * ``decode_attention(cfg, cache, kernels)`` -> the impl ("pallas" |
   "jnp") the decode program is built with
   (``engine.decode_attn_impl``);
@@ -91,6 +94,7 @@ class Family:
     decode_attention: Callable
     decode_block: Optional[Callable] = None
     quantize_decode_params: Optional[Callable] = None
+    serve_params: Optional[Callable] = None
     fetch_attrs: Optional[Callable] = None
     prefill_attrs: Optional[Callable] = None
     round_attrs: Optional[Callable] = None
@@ -199,7 +203,8 @@ def _gpt2():
         prefill=prefill, prefill_rows=smodel.trunk_rows,
         decode_step=decode_step,
         decode_block=decode_block, decode_attention=decode_attention,
-        quantize_decode_params=smodel.quantize_decode_params)
+        quantize_decode_params=smodel.quantize_decode_params,
+        serve_params=smodel.serving_params)
 
 
 # ------------------------------------------------------------------- MiMo

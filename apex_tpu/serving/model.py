@@ -13,7 +13,10 @@ fp32 accumulation cast back to the compute dtype
 interleaving of the fused qkv projection, approximate-gelu MLP, and
 tied logits against the word table (``parallel_lm_logits``). Parity
 with ``GPTModel.apply`` is asserted in tests/test_serving.py — the
-serving stack's numbers are the training stack's numbers.
+serving stack's numbers are the training stack's numbers. The engine
+hands the programs :func:`serving_params` of that tree (the matmul
+weights in the compute dtype, cast once at build); they take the tree
+itself too and then cast in every step, to the same operands.
 
 Two jitted programs (built once per engine — the ISSUE 10
 jaxpr-stability contract):
@@ -39,6 +42,7 @@ is the trainer's Switch block, which has no serving program (the engine
 as a whole serves experts through the MiMo family).
 """
 
+import functools
 import math
 
 import jax
@@ -104,11 +108,58 @@ def init_gpt_params(cfg, seed=0):
         check_vma=False))(ids, pos)
 
 
+# the four projections of ``_trunk_layer``: their weights and biases are
+# what :func:`serving_params` casts
+_MATMULS = frozenset(
+    ("query_key_value", "dense", "dense_h_to_4h", "dense_4h_to_h"))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _cast(leaves, dtype):
+    return [leaf.astype(dtype) for leaf in leaves]
+
+
+def serving_params(params, cfg):
+    """The tree the prefill and decode programs read, built once: the
+    caller's ``params`` with the layers' matmul weights and biases in
+    the compute dtype, and a compute-dtype copy of the tied word table
+    under ``"word_logits"`` for the logits matmul. ``_mm`` feeds the MXU
+    the compute-dtype rounding of an fp32 weight either way; cast here,
+    a step streams half the bytes and the numbers are the same, bit for
+    bit. The embedding gather, the position table and the norms keep
+    reading the caller's arrays, as before.
+
+    A leaf already in the compute dtype is served as handed (a bf16
+    checkpoint; an fp32 config serves ``params`` itself). The casts are
+    ONE jitted program over the leaves, each copy keeping its leaf's
+    sharding (``tp > 1`` places ``params`` first)."""
+    dtype = jnp.dtype(compute_dtype(cfg))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    picked = [i for i, (path, leaf) in enumerate(flat)
+              if leaf.dtype != dtype
+              and _MATMULS & {getattr(k, "key", None) for k in path}]
+    word = params["word_embeddings"]
+    cast_word = word.dtype != dtype
+    if not picked and not cast_word:
+        return params
+    cast = _cast([flat[i][1] for i in picked]
+                 + ([word] if cast_word else []), dtype)
+    leaves = [leaf for _, leaf in flat]
+    for i, leaf in zip(picked, cast):
+        leaves[i] = leaf
+    served = dict(jax.tree_util.tree_unflatten(treedef, leaves))
+    if cast_word:
+        served["word_logits"] = cast[-1]
+    return served
+
+
 def _mm(x, w, dtype):
     """x @ w^T, fp32 accumulation (the layers.py `_mm` idiom)."""
     x = x.astype(dtype)
     with jax.named_scope("weights_cast"):
-        w = w.astype(dtype)   # fp32 serving weights: cast in every step
+        # a no-op on the served tree (:func:`serving_params`); the
+        # caller's fp32 tree is cast here in every step
+        w = w.astype(dtype)
     return lax.dot_general(
         x, w,
         (((x.ndim - 1,), (1,)), ((), ())),
@@ -155,6 +206,12 @@ def quantize_decode_params(params, cfg):
     wq, scale = quant_mod.quantize_weight(params["word_embeddings"])
     qp["word_logits"] = {"wq": wq, "scale": scale}
     return qp
+
+
+def _logits_table(params):
+    """The tied table the logits matmul reads: the served tree's
+    compute-dtype copy (:func:`serving_params`), else the word table."""
+    return params.get("word_logits", params["word_embeddings"])
 
 
 def _wmat(x, full_w, qrec, dtype):
@@ -330,7 +387,7 @@ def prefill(params, cache, ids, positions, seg, token_rows, page_table,
         cache = _write_behind(cache, written, dest_page, dest_off, tokens,
                               keep_scale, rows[0])
     with jax.named_scope("lm_head"):
-        logits = _mm(x_last, word, dtype)
+        logits = _mm(x_last, _logits_table(params), dtype)
     return cache, logits
 
 
@@ -471,7 +528,7 @@ def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
         x = _layer_norm(x, params["transformer"]["final_layernorm"],
                         cfg.layernorm_epsilon)
     with jax.named_scope("lm_head"):
-        logits = _wmat(x, word,
+        logits = _wmat(x, _logits_table(params),
                        qparams["word_logits"] if qparams is not None
                        else None, dtype)
     with jax.named_scope("sample"):

@@ -237,7 +237,7 @@ def make_decode_scan(eps, pt):
         # value (the §0 result-caching rule); semantically zero
         tokens = tokens + (eps * 0.0).astype(jnp.int32)
         cache, nxt, logits = smodel.decode_step(
-            engine.params, cache, tokens, lengths, pt, cfg=cfg,
+            engine.served_params, cache, tokens, lengths, pt, cfg=cfg,
             qparams=qparams, interpret=engine.interpret)
         if SAMPLING:
             # the pinned program includes the sampling ops (greedy

@@ -262,11 +262,11 @@ def _server_lowerings(engine):
     s, r = engine.prefill_len, engine.prefill_requests
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     prefill = engine._prefill_fn.lower(
-        engine.params, engine.cache, i32(s), i32(s), i32(s), i32(s),
-        i32(engine.num_slots + 1, engine.max_pages),
+        engine.served_params, engine.cache, i32(s), i32(s), i32(s),
+        i32(s), i32(engine.num_slots + 1, engine.max_pages),
         i32(r * engine._gather_w))
     decode = engine._decode_fn.lower(
-        engine.params, engine.qparams, engine.cache,
+        engine.served_params, engine.qparams, engine.cache,
         i32(engine.num_slots), i32(engine.num_slots),
         i32(engine.num_slots, engine.max_pages))
     return {"prefill_mosaic_calls": _mosaic_calls(prefill),

@@ -94,7 +94,9 @@ def test_serve_cell_program_copies_no_cache_leaf(one_chip, monkeypatch,
         return jax.tree_util.tree_map(
             lambda a: _sds(one_chip, a.shape, a.dtype), tree)
 
-    params = sds(jax.eval_shape(lambda: smodel.init_gpt_params(cfg, 0)))
+    # the tree the engine hands the programs: no weight is cast in a step
+    params = sds(jax.eval_shape(lambda: smodel.serving_params(
+        smodel.init_gpt_params(cfg, 0), cfg)))
     cache = sds(jax.eval_shape(
         lambda: kv_cache.init_cache(layers, h, pages, ps, d)))
     i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
@@ -123,6 +125,7 @@ def test_serve_cell_program_copies_no_cache_leaf(one_chip, monkeypatch,
     leaf = rf"bf16\[(?:{pages},{ps}|{pages * ps}),{h * d}\]"
     made = re.findall(rf"= {leaf}\S* ([\w-]+)\(", text)
     assert made and "copy" not in made, sorted(set(made))
+    assert "weights_cast" not in text
     mem = compiled.memory_analysis()
     leaf_bytes = pages * ps * h * d * 2
     assert mem.alias_size_in_bytes >= 2 * layers * leaf_bytes

@@ -481,29 +481,37 @@ def _scoped_share(text, scopes):
     return found, under / len(ops)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill", "train"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "train",
+                                     "decode_served", "prefill_served"])
 def test_lowered_programs_carry_the_scope_names(program, params,
                                                 toy_training):
+    """``*_served``: the programs on the tree the engine hands them
+    (``model.serving_params``), whose matmul weights need no cast."""
     cfg = _cfg()
     if program == "train":
         text, scopes = toy_training[2], TRAIN_SCOPES
     else:
+        tree = smodel.serving_params(params, cfg) \
+            if program.endswith("_served") else params
         cache = init_cache(cfg.num_layers, cfg.num_attention_heads, 24, 8,
                            cfg.head_dim, jnp.bfloat16)
         table = jnp.zeros((4, 8), jnp.int32)
-        if program == "decode":
+        if program.startswith("decode"):
             fn = functools.partial(smodel.decode_step, cfg=cfg)
-            args = (params, cache, jnp.ones((4,), jnp.int32),
+            args = (tree, cache, jnp.ones((4,), jnp.int32),
                     jnp.full((4,), 3, jnp.int32), table)
             scopes = SERVE_SCOPES
         else:
             fn = functools.partial(smodel.prefill, cfg=cfg)
             flat = jnp.zeros((64,), jnp.int32)
-            args = (params, cache, flat, flat, flat, flat,
+            args = (tree, cache, flat, flat, flat, flat,
                     jnp.zeros((5, 8), jnp.int32), jnp.zeros((4,), jnp.int32))
             scopes = SERVE_SCOPES[:-1]      # first tokens are picked eagerly
         text = jax.jit(fn).lower(*args).as_text(dialect="hlo",
                                                 debug_info=True)
+        if tree is not params:
+            assert _scoped_share(text, ("weights_cast",))[0] == set()
+            scopes = tuple(s for s in scopes if s != "weights_cast")
     found, share = _scoped_share(text, scopes)
     assert found == set(scopes)
     assert share >= 0.95, share
