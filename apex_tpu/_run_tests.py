@@ -40,7 +40,8 @@ SUITES = {
             "test_attention_pallas.py", "test_xent_pallas.py",
             "test_mosaic_block_rules.py", "test_tile_params.py",
             "test_decode_attention_pallas.py",
-            "test_decode_attention_mosaic.py", "test_packed_grid.py"],
+            "test_decode_attention_mosaic.py", "test_packed_grid.py",
+            "test_ssm_ops.py"],
     "serving": ["test_serving.py", "test_serving_slo.py",
                 "test_serving_generation.py",
                 "test_serving_resilience.py",
@@ -51,7 +52,7 @@ SUITES = {
                 "test_router.py", "test_router_chaos.py",
                 "test_mimo_serving.py", "test_axk1_serving.py",
                 "test_dots3_serving.py", "test_prefill_trunk_rows.py",
-                "test_round_halves.py"],
+                "test_round_halves.py", "test_falcon_h1_serving.py"],
     "api_parity": ["test_api_parity_round3.py"],
     "harness": ["test_run_tests.py", "test_chip_smoke.py",
                 "test_compile_cache.py", "test_resilience.py",

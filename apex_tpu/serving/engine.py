@@ -636,8 +636,9 @@ class ServingEngine:
         self._decode_extras = None
         self._round_attrs = {}
         # the same of the last prefill dispatch, beside the trunk rows
-        # it ran on (``prefill.fetch``'s attributes)
-        self._prefill_extras = (None, 0)
+        # it ran on and the tokens and sequences it packed
+        # (``prefill.fetch``'s attributes)
+        self._prefill_extras = (None, 0, 0, 0)
         # wall seconds inside swap-tier staging copies (device_get at
         # swap-out + scatter at swap-in) — the host-copy clock the
         # kv_restore crossover sweep measures against the replay
@@ -1177,7 +1178,7 @@ class ServingEngine:
         # state adopted only after a clean return: a timed-out round's
         # late result can never overwrite the recovered engine
         self.cache, logits, extras = self._dispatch(phase, call)
-        self._prefill_extras = (extras, trunk_rows)
+        self._prefill_extras = (extras, trunk_rows, cursor, len(rows))
         return logits, t0
 
     def _replay_prefill(self, resumed):
@@ -1260,10 +1261,12 @@ class ServingEngine:
                 # rows r*W hold each request's last-prompt-token logits
                 next_toks = self._sample_first_tokens(
                     logits, np.arange(len(batch)) * self._gather_w, batch)
-                extras, trunk_rows = self._prefill_extras
+                extras, trunk_rows, tokens, seqs = self._prefill_extras
                 if extras is not None and spans.enabled():
                     sp.set(**self.family.prefill_attrs(
                         self.cfg, extras, trunk_rows))
+                if self.family.pack_attrs is not None and spans.enabled():
+                    sp.set(**self.family.pack_attrs(self.cfg, tokens, seqs))
                 wall = time.perf_counter()
             self.device_dispatch_s += wall - t0
             with spans.span("prefill.commit"):

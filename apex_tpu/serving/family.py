@@ -98,6 +98,7 @@ class Family:
     fetch_attrs: Optional[Callable] = None
     prefill_attrs: Optional[Callable] = None
     round_attrs: Optional[Callable] = None
+    pack_attrs: Optional[Callable] = None
     refused: Tuple[str, ...] = ()
     # admission stops at one prefill dispatch's tokens a round, the rest
     # of the queue waits for the next (scheduler.admit's token_budget)
@@ -425,7 +426,61 @@ def _dots3():
         config_from_dict=dots3.Dots3Config.from_dict)
 
 
-_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo, "axk1": _axk1, "dots3": _dots3}
+# --------------------------------------------------------------- Falcon-H1
+
+def _falcon_h1():
+    import jax.numpy as jnp
+
+    from apex_tpu.serving import falcon_h1
+
+    def init_cache(cfg, geometry):
+        return falcon_h1.init_cache(cfg, geometry.num_slots,
+                                    geometry.num_pages, geometry.page_size,
+                                    geometry.cache_dtype)
+
+    def prefill(params, cache, ids, positions, seg, token_rows, page_table,
+                last_idx, keep_scale=None, *, cfg, kernels):
+        return falcon_h1.prefill(params, cache, ids, positions, seg,
+                                 token_rows, page_table, last_idx, cfg=cfg,
+                                 interpret=kernels.interpret)
+
+    def decode_step(params, cache, tokens, lengths, page_table, *, cfg,
+                    qparams, kernels):
+        return falcon_h1.decode_step(params, cache, tokens, lengths,
+                                     page_table, cfg=cfg,
+                                     decode_impl=kernels.decode_impl,
+                                     interpret=kernels.interpret)
+
+    def decode_attention(cfg, cache, kernels):
+        return falcon_h1.decode_attention_resolved(
+            cfg, cache, kernels.decode_impl)
+
+    def round_attrs(cfg, scheduler):
+        # slots whose state row a decode step updates (one state read
+        # and written a layer each)
+        return dict(ssm_slots_live=sum(s is not None
+                                       for s in scheduler.slots))
+
+    def pack_attrs(cfg, tokens, segments):
+        # the rows the chunked scan ran over and the sequences whose
+        # final states it wrote
+        return dict(ssm_tokens=tokens, ssm_segments=segments)
+
+    return Family(
+        name="falcon_h1", check_config=falcon_h1.check_config,
+        init_params=falcon_h1.init_params,
+        cache_dtype=lambda cfg: jnp.dtype(cfg.cache_dtype),
+        init_cache=init_cache,
+        prefill=prefill, prefill_rows=prefill_rows,
+        decode_step=decode_step, decode_attention=decode_attention,
+        round_attrs=round_attrs, pack_attrs=pack_attrs,
+        refused=tuple(OPTIONS_OFF), one_prefill_a_round=True,
+        model_type="falcon_h1",
+        config_from_dict=falcon_h1.FalconH1Config.from_dict)
+
+
+_BUILDERS = {"gpt2": _gpt2, "mimo": _mimo, "axk1": _axk1, "dots3": _dots3,
+             "falcon_h1": _falcon_h1}
 
 
 @functools.lru_cache(maxsize=None)

@@ -113,6 +113,32 @@ def init_latent_ring(num_layers, num_slots, page_size, window, width,
         for _ in range(num_layers)]}
 
 
+def init_slot_state(num_layers, num_slots, state_shape, conv_shape,
+                    dtype=jnp.bfloat16):
+    """Zeroed ``{"ssm", "conv"}``: a FOURTH kind of state, neither paged
+    nor a ring: a fixed-size leaf row that a SLOT owns for the life of
+    the engine (serving/falcon_h1.py: a recurrent layer's state and its
+    convolution's last inputs). One ``[num_slots + 1, *state_shape]``
+    and one ``[num_slots + 1, *conv_shape]`` leaf a layer (the family
+    keeps its conv inputs as one flat row a slot); row ``s`` is
+    slot ``s``'s, the last row a spare that absorbs what padding tokens
+    and dead lanes write, as the page table's spare row does.
+
+    No allocator hands these rows out: admission takes a slot and its
+    row with it. A prefill writes a segment's final state WHOLE into the
+    row of the slot that owns its last token, so a new request needs no
+    reset and a replayed stream rebuilds its row from its tokens; a
+    decode step updates the live rows in place (ops/ssm.py). The slot
+    axis leads where every pool leaf leads with its page axis: the
+    engine's page hops (prefix cache, host swap) do not apply to these
+    leaves, and the family that keeps them refuses both."""
+    rows = int(num_slots) + 1
+    return {"ssm": [jnp.zeros((rows,) + tuple(state_shape), dtype)
+                    for _ in range(num_layers)],
+            "conv": [jnp.zeros((rows,) + tuple(conv_shape), dtype)
+                     for _ in range(num_layers)]}
+
+
 def write_rows(leaf, page, off, rows):
     """``leaf [pages, page_size, heads * width]`` with ``rows [T, heads,
     width]`` scattered, one ``[heads * width]`` row a token, at

@@ -30,8 +30,9 @@ h=768, 12 heads, vocab 50304) with random weights from a seed:
   attention fwd+bwd in both backward structures, with segment ids, with
   dropout; layer norm; scale-mask softmax; the fused LM head; paged
   decode attention at the served model's heads and at GPT-2 large's
-  20) compiled by Mosaic at the GPT-2-small shape and compared with its
-  ``jnp`` reference.
+  20; the selection kernels; the one-step state update of a recurrent
+  layer at Falcon-H1's head and state widths) compiled by Mosaic and
+  compared with its ``jnp`` reference.
 * four chips — when ``jax.device_count() >= 4``: the trainer again on a
   dp=2 x tp=2 mesh, loss trajectory against the one-chip run, memory in
   use on every device.
@@ -781,6 +782,28 @@ def _selection_cases(size, interpret):
         (bf(4, S, 192), bf(4, S, 192), bf(4, S, 128)), [(5e-2,)])
 
 
+def _ssm_cases(size, interpret):
+    """The one-step state update of a recurrent layer (ops/ssm.py, the
+    Falcon-H1 family's mixer) at the published head width and state size
+    (128 x 256, two groups), a dead lane among the live ones."""
+    from apex_tpu.ops import ssm
+
+    rs = np.random.RandomState(4)
+    b, H, P, N, G = 4, 4, 128, 256, 2
+    f32 = lambda *shape: jnp.asarray(rs.randn(*shape), jnp.float32)  # noqa: E731
+    dt = jnp.asarray(rs.uniform(1e-3, 1e-1, (b, H)), jnp.float32)
+    A, D = -jnp.exp(f32(H)), f32(H)
+    live = jnp.asarray([True, False, True, True])
+    yield "ssm decode update bf16 state", (
+        lambda s, x, B, C: ssm.ssm_decode_update(
+            s, x, dt, A, B, C, D, live, impl="pallas", interpret=interpret),
+        lambda s, x, B, C: ssm.ssm_decode_update(
+            s, x, dt, A, B, C, D, live, impl="jnp"),
+        (f32(b + 1, H, P, N).astype(jnp.bfloat16),
+         f32(b, H, P).astype(jnp.bfloat16), f32(b, G, N).astype(jnp.bfloat16),
+         f32(b, G, N).astype(jnp.bfloat16)), [(5e-2,)])
+
+
 def run_kernels(size, log, interpret):
     """Each case: ``(kernel_fn, reference_fn, args, tolerances)`` where
     both functions return the same pytree (output, then gradients) and
@@ -789,7 +812,7 @@ def run_kernels(size, log, interpret):
     recs = {}
     cache0 = compile_cache.snapshot()
     for cases in (_attention_cases, _row_kernel_cases, _decode_cases,
-                  _selection_cases):
+                  _selection_cases, _ssm_cases):
         for name, (kernel, reference, args, tols) in cases(size, interpret):
             t0 = time.perf_counter()
             lowered = jax.jit(kernel).lower(*args)

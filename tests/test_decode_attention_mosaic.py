@@ -570,3 +570,138 @@ def test_dots3_program_compiles_with_no_copy_of_a_pool_leaf(
         # the 4,096-row trunk alone is past index_topk: one index kernel
         assert text.count(ap.INDEX_SCORES_KERNEL_NAME) >= 1
         assert text.count(ap.PACKED_KERNEL_NAME) >= 4 * 2
+
+
+# ---- the Falcon-H1 family at the widths serve-falconh1-decode runs ----
+
+HYBRID = dict(slots=192, pages=2048, ps=128, table=3072 // 128, rows=2048)
+
+
+def test_ssm_decode_kernel_compiles_and_aliases_its_state(one_chip):
+    """The one-step kernel at the published widths (32 heads of 128 x
+    256, two groups) and the cell's 192 slots: Mosaic takes it, the
+    state leaf is aliased input to output and nothing of its shape is
+    copied; its temporaries are the small operands'."""
+    from apex_tpu.ops import ssm
+
+    b, H, P, N, G = HYBRID["slots"], 32, 128, 256, 2
+
+    def f(state, x, dt, A, B, C, D, live):
+        return ssm.ssm_decode_update(state, x, dt, A, B, C, D, live,
+                                     impl="pallas", interpret=False)
+
+    compiled = jax.jit(f, donate_argnums=(0,)).lower(
+        _sds(one_chip, (b + 1, H, P, N), jnp.bfloat16),
+        _sds(one_chip, (b, H, P), jnp.bfloat16),
+        _sds(one_chip, (b, H), jnp.float32),
+        _sds(one_chip, (H,), jnp.float32),
+        _sds(one_chip, (b, G, N), jnp.bfloat16),
+        _sds(one_chip, (b, G, N), jnp.bfloat16),
+        _sds(one_chip, (H,), jnp.float32),
+        _sds(one_chip, (b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert ssm.KERNEL_NAME in text and "tpu_custom_call" in text
+    made = re.findall(rf"= bf16\[{b + 1},{H},{P},{N}\]\S* ([\w-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element"}, made
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (b + 1) * H * P * N * 2
+    assert mem.temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_falcon_h1_program_neither_copies_nor_stages_a_state_leaf(
+        one_chip, monkeypatch, program):
+    """Published widths, the cell's 192 slots, 2,048 pages of 128 and 24
+    table entries, two of the six layers: both programs alias every
+    leaf input to output, and no operation makes an array of the state
+    leaf's shape ([193, 32, 128, 256]: 0.40 GB a layer, 2.4 GB over the
+    six) but the decode kernel's aliased output and the prefill's
+    in-place row writes: no copy, no staging in the faster memory. The
+    decode program (two of the six layers) reaches the state kernel and
+    the decode-attention kernel in every layer; the prefill program (one
+    layer; 2,048 packed rows: four trunks behind one switch) the packed
+    kernel in every trunk, and writes the conv rows ([193, 15,360]) in
+    place too. At six layers the prefill program holds 1.16 GB of
+    temporaries beside 11.5 GB of arguments (a compile here)."""
+    import functools
+
+    from apex_tpu.ops import attention as attn
+    from apex_tpu.ops import ssm
+    from apex_tpu.serving import falcon_h1
+
+    monkeypatch.setattr(attn, "_tpu_available", lambda: True)
+    g = HYBRID
+    layers = 2 if program == "decode" else 1
+    cfg = falcon_h1.FalconH1Config(vocab_size=32640,
+                                   num_hidden_layers=layers)
+
+    def sds(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+
+    params = sds(jax.eval_shape(
+        lambda: falcon_h1.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = sds(jax.eval_shape(lambda: falcon_h1.init_cache(
+        cfg, g["slots"], g["pages"], g["ps"])))
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    b, S = g["slots"], g["rows"]
+    if program == "decode":
+        fn = functools.partial(falcon_h1.decode_step, cfg=cfg,
+                               decode_impl="pallas", ssm_impl="pallas",
+                               interpret=False)
+        args = (i32(b), i32(b), i32(b, g["table"]))
+    else:
+        fn = functools.partial(falcon_h1.prefill, cfg=cfg,
+                               attn_impl="pallas", interpret=False)
+        args = (i32(S), i32(S), i32(S), i32(S), i32(b + 1, g["table"]),
+                i32(b))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    state = re.findall(rf"= bf16\[{b + 1},32,128,256\]\S* ([\w-]+)\(", text)
+    assert state and set(state) <= {
+        "parameter", "get-tuple-element", "dynamic-update-slice",
+        "fusion", "tuple"}, sorted(set(state))
+    assert not re.search(rf"bf16\[{b + 1},32,128,256\]\S*S\(1\)", text)
+    mem = compiled.memory_analysis()
+    state_bytes = (b + 1) * 32 * 128 * 256 * 2
+    assert mem.alias_size_in_bytes >= layers * state_bytes
+    if program == "decode":
+        assert text.count(ssm.KERNEL_NAME) >= 2
+        assert text.count(dap.GROUPED_KERNEL_NAME) >= 2
+        assert mem.temp_size_in_bytes < state_bytes / 4
+    else:
+        from apex_tpu.ops import attention_pallas as ap
+
+        conv = re.findall(rf"= bf16\[{b + 1},15360\]\S* ([\w-]+)\(", text)
+        assert not {"copy", "copy-done"} & set(conv), sorted(set(conv))
+        assert len(re.findall(r" conditional\(", text)) == 1
+        assert text.count(ap.PACKED_KERNEL_NAME) >= 4
+
+
+def test_falcon_h1_references_cache_rounding_survives_the_tpu_compiler(
+        one_chip):
+    """The judge's reference holds the state and the conv's inputs at the
+    cache's bfloat16 from ``cache_from`` on. Compiled for a v5e as the
+    judge runs it (a layer one program, the token walk a loop inside it),
+    the rounding is still there: ``reduce-precision`` ops on the conv's
+    inputs, the state in the loop and the conv tail. A conversion to
+    bfloat16 and back inside one program is one a TPU's compiler may
+    drop, leaving the reference's state at the float32 math."""
+    import functools
+
+    import falcon_h1_toy as T
+
+    cfg = T.ref_config(T.toy_config())
+    lp = jax.eval_shape(lambda: T.toy_params(T.toy_config())["layers"][0])
+    lp = jax.tree_util.tree_map(
+        lambda a: _sds(one_chip, a.shape, a.dtype), lp)
+    fn = jax.jit(functools.partial(T.reference._layer, cfg))
+    with jax.default_matmul_precision("highest"):
+        text = fn.lower(lp, _sds(one_chip, (96, cfg["hidden_size"]),
+                                 jnp.float32),
+                        _sds(one_chip, (2,), jnp.int32),
+                        _sds(one_chip, (), jnp.int32)).compile().as_text()
+    ops = re.findall(r"= (f32\[[\d,]*\])\S* reduce-precision\(", text)
+    assert len(ops) >= 3, ops
+    assert "f32[4,8,16]" in ops, ops     # the state: heads x d_head x N
